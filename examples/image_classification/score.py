@@ -22,7 +22,7 @@ def score(model_prefix, load_epoch, data_val, image_shape=(3, 224, 224),
         preprocess_threads=data_nthreads)
     if max_num_batches:
         val = mx.io.ResizeIter(val, max_num_batches)
-    mod = mx.mod.Module(symbol=sym, context=mx.gpu(0))
+    mod = mx.mod.Module(symbol=sym, context=mx.current_context())
     mod.bind(data_shapes=val.provide_data,
              label_shapes=val.provide_label, for_training=False)
     mod.set_params(arg_params, aux_params)

@@ -102,7 +102,7 @@ def main():
         sym_gen_factory(vocab_size, args.num_embed, args.num_hidden,
                         args.num_layers, args.batch_size),
         default_bucket_key=train.default_bucket_key,
-        context=mx.gpu(0))
+        context=mx.current_context())
     mod.fit(train, num_epoch=args.num_epochs,
             eval_metric=mx.metric.Perplexity(ignore_label=0),
             optimizer="adam",

@@ -18,6 +18,10 @@ __version__ = "0.1.0"
 
 from . import base
 from .base import MXNetError
+# JAX decides at its first compile whether its persistent cache is in
+# use, so the cache is placed before anything below can compile
+from .compile.cache import wire_jax_cache as _wire_jax_cache
+_wire_jax_cache()
 from .context import Context, cpu, gpu, tpu, current_context, num_gpus, num_tpus
 from . import operator  # registers the Custom op before nd codegen runs
 from . import ndarray

@@ -34,7 +34,7 @@ from __future__ import annotations
 from .key import (ProgramKey, program_key, fingerprint, arg_signature,
                   optimizer_fingerprint, mesh_fingerprint, symbol_digest)
 from .cache import (PersistentCache, CacheEntryError, default_cache,
-                    cache_enabled)
+                    cache_enabled, wire_jax_cache)
 from .registry import (ProgramRecord, JitProgram, load_or_compile,
                        shared_programs, guarded_loaded_program,
                        note_entry_point, get_record, compile_report,
@@ -44,7 +44,7 @@ __all__ = [
     "ProgramKey", "program_key", "fingerprint", "arg_signature",
     "optimizer_fingerprint", "mesh_fingerprint", "symbol_digest",
     "PersistentCache", "CacheEntryError", "default_cache",
-    "cache_enabled",
+    "cache_enabled", "wire_jax_cache",
     "ProgramRecord", "JitProgram", "load_or_compile", "shared_programs",
     "guarded_loaded_program", "note_entry_point", "get_record",
     "compile_report", "donation_supported", "reset",

@@ -10,7 +10,8 @@ Entry layout (self-describing, CRC-guarded)::
     uint32 big-endian header length
     header JSON   {version, digest, name, kind, fingerprint, crc32,
                    payload_len, created, backend}
-    payload bytes (pickled (serialized_executable, in_tree, out_tree))
+    payload bytes (pickled (serialized_executable, in_tree, out_tree,
+                   device ids the program was compiled for))
 
 Every write is atomic (``base.atomic_write``: temp + fsync + rename), so
 a process killed at any byte never tears an existing entry. On read the
@@ -39,7 +40,7 @@ import zlib
 from ..base import MXNetError, atomic_write
 
 __all__ = ["PersistentCache", "CacheEntryError", "default_cache",
-           "cache_enabled"]
+           "cache_enabled", "wire_jax_cache", "JAX_CACHE_DIR"]
 
 _MAGIC = b"MXPROG1\n"
 _SUFFIX = ".mxprog"
@@ -237,27 +238,31 @@ class PersistentCache:
         return removed
 
 
-_jax_cache_wired = [False]
+# where JAX's own persistent compilation cache lives when nobody placed
+# it from outside: a FIXED path inside the checkout. The directory is
+# part of JAX's cache key, so a temporary name, a pid or a timestamp in
+# it would make every run a miss.
+JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def _maybe_wire_jax_cache(directory):
-    """Point JAX's own persistent compilation cache at ``<dir>/xla`` —
-    a second, backend-level layer that caches the XLA optimization
-    output on TPU/GPU (jax skips it on CPU). Our ``.mxprog`` entries
-    remain the primary layer: they skip tracing AND compilation."""
-    if _jax_cache_wired[0]:
-        return
-    _jax_cache_wired[0] = True
-    from .. import config
-    if not config.get("MXTPU_COMPILE_JAX_CACHE"):
-        return
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(directory, "xla"))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:
-        pass
+def wire_jax_cache():
+    """Place JAX's persistent compilation cache; returns the directory.
+    THE one place the program configures it, called once when the
+    package is imported — before the first compile, because JAX decides
+    once per process whether its cache is in use. If
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set in code; otherwise the cache goes to
+    :data:`JAX_CACHE_DIR`. This layer is independent of the ``.mxprog``
+    AOT entries under ``MXTPU_COMPILE_CACHE_DIR``, which are keyed by
+    digest and may live anywhere."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+    jax.config.update("jax_compilation_cache_dir", JAX_CACHE_DIR)
+    return JAX_CACHE_DIR
 
 
 def cache_enabled():
@@ -275,6 +280,4 @@ def default_cache():
     from .. import config
     if not cache_enabled():
         return None
-    directory = str(config.get("MXTPU_COMPILE_CACHE_DIR"))
-    _maybe_wire_jax_cache(directory)
-    return PersistentCache(directory)
+    return PersistentCache(str(config.get("MXTPU_COMPILE_CACHE_DIR")))

@@ -27,7 +27,7 @@ __all__ = ["ProgramKey", "program_key", "fingerprint", "arg_signature",
            "optimizer_fingerprint", "mesh_fingerprint", "symbol_digest"]
 
 # bump when the on-disk entry layout or the key material schema changes
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2
 
 # optimizer attributes that do NOT feed the trace and so must stay OUT
 # of the key: the step counter and the base learning rate are runtime

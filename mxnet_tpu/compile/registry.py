@@ -236,9 +236,16 @@ def load_or_compile(key, lower, cache=None):
             from jax.experimental import serialize_executable
             t0 = time.perf_counter()
             with _span("load"):
-                blob, in_tree, out_tree = pickle.loads(payload)
+                blob, in_tree, out_tree, dev_ids = pickle.loads(payload)
+                # load onto the devices the program was compiled for:
+                # the default is EVERY device of the backend, and a
+                # one-device program loaded eight-wide rejects its
+                # first call
+                import jax
+                by_id = {d.id: d for d in jax.devices()}
                 exe = serialize_executable.deserialize_and_load(
-                    blob, in_tree, out_tree)
+                    blob, in_tree, out_tree,
+                    execution_devices=[by_id[i] for i in dev_ids])
             load_s = time.perf_counter() - t0
             rec.load_s += load_s
             rec.cache_hits += 1
@@ -273,7 +280,10 @@ def load_or_compile(key, lower, cache=None):
             with _span("serialize"):
                 blob, in_tree, out_tree = \
                     serialize_executable.serialize(exe)
-                cache.put(key, pickle.dumps((blob, in_tree, out_tree)))
+                dev_ids = [d.id for d in exe._executable
+                           ._unloaded_executable.device_list]
+                cache.put(key, pickle.dumps(
+                    (blob, in_tree, out_tree, dev_ids)))
             rec.serialized = True
         except Exception as e:
             # backends without executable serialization (or unpicklable

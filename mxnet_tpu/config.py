@@ -337,11 +337,12 @@ register("MXTPU_TRACE_ANNOTATE", True, bool,
 register("MXTPU_PALLAS_TILES", "", str,
          "Pallas fused-kernel output-tile override '<bm>,<bn>' "
          "(ops/pallas_fused.py): tried first by select_tiles/"
-         "select_conv_tiles when it divides the shape. Values must be "
-         "positive multiples of 8 within the built-in candidate bounds "
-         "(bm<=1024, bn<=512) — invalid values raise MXNetError at "
-         "selection time (a bad tile fails the tuner trial, not the "
-         "process). Empty = built-in largest-dividing selection")
+         "select_conv_tiles when it divides the shape and Mosaic "
+         "accepts it. Values must be positive multiples of 8 within "
+         "the built-in candidate bounds (bm<=1024, bn<=512) — invalid "
+         "values raise MXNetError at selection time (a bad tile fails "
+         "the tuner trial, not the process). Empty = built-in "
+         "largest-legal selection")
 register("MXTPU_TUNE_DIR", "", str,
          "TuningRecord store directory (tune/record.py). Empty = "
          "<MXTPU_COMPILE_CACHE_DIR>/tune when the compile cache is "
@@ -359,10 +360,6 @@ register("MXTPU_TUNE_HBM_BUDGET", 0, int,
          "pruning: batch-size candidates whose compiled train-step "
          "proxy reports memory_analysis peak above this are pruned "
          "without a measured trial; 0 = no HBM pruning")
-register("MXTPU_COMPILE_JAX_CACHE", True, bool,
-         "Also point JAX's own persistent compilation cache at "
-         "CACHE_DIR/xla (a second, backend-level layer on TPU/GPU; "
-         "the .mxprog entries remain the primary AOT layer)")
 register("MXTPU_PARTITION_RULES", "", str,
          "Regex -> PartitionSpec parameter layout rules for mesh binds "
          "(parallel/partition.py): ';'-separated 'regex=spec' clauses, "

@@ -72,17 +72,29 @@ class Context:
     # -- JAX mapping ---------------------------------------------------------
     @property
     def jax_device(self):
-        """The ``jax.Device`` this context denotes."""
-        dt = self.device_type
-        if dt in ("cpu", "cpu_pinned", "cpu_shared"):
+        """The ``jax.Device`` this context denotes. An accelerator
+        context names one chip or none: in a process that has no
+        accelerator, or with a ``device_id`` past the last chip, it
+        raises — ``tpu(5)`` on four chips is not chip 1, and ``tpu(0)``
+        without a chip is not the CPU. CPU ids stay nominal, as in the
+        reference (``cpu(1)`` is valid on any host)."""
+        if self.device_type in ("cpu", "cpu_pinned", "cpu_shared"):
             devs = [d for d in jax.devices() if d.platform == "cpu"]
             if not devs:
                 devs = jax.devices("cpu")
-        else:  # gpu / tpu → whatever accelerator backs this process
-            devs = [d for d in jax.devices() if d.platform != "cpu"]
-            if not devs:  # CPU-only process: alias accelerator ctx to cpu
-                devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+            return devs[self.device_id % len(devs)]
+        from .base import MXNetError
+        devs = [d for d in jax.devices() if d.platform != "cpu"]
+        if not devs:
+            raise MXNetError(
+                f"context {self} names an accelerator, but JAX sees none "
+                f"(default backend '{jax.default_backend()}'); use "
+                "mx.cpu() or mx.current_context()")
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                f"context {self}: device_id out of range, this process "
+                f"has {len(devs)} {devs[0].platform} device(s)")
+        return devs[self.device_id]
 
     def empty_cache(self):
         """Reference API parity (context.py:161); XLA owns the allocator, so

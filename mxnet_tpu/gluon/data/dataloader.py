@@ -125,9 +125,32 @@ class DataLoader:
             return
         yield from self._multi_worker_iter()
 
+    def _refuse_device_samples(self, batches):
+        """One process per chip: a forked worker cannot read an array
+        that lives on the accelerator its parent holds — on a TPU it
+        hangs in the first index (observed on a v5e; numpy-backed
+        datasets fork fine there). Probe one sample here, in the
+        parent, and turn the hang into an error. On a CPU backend the
+        fork can read the parent's arrays and nothing is checked."""
+        import jax
+        if jax.default_backend() == "cpu" or not batches:
+            return
+        sample = self._dataset[batches[0][0]]
+        leaves = sample if isinstance(sample, (tuple, list)) else (sample,)
+        if any(isinstance(x, NDArray) for x in leaves):
+            from ...base import MXNetError
+            raise MXNetError(
+                "DataLoader(num_workers>0): the dataset yields device "
+                "arrays, and a forked worker cannot touch the "
+                f"{jax.default_backend()} device this process holds. "
+                "Build the dataset from numpy arrays (workers return "
+                "numpy batches anyway), or use num_workers=0")
+
     def _multi_worker_iter(self):
         """Pipelined workers: keep 2x workers batches in flight, yield in
         order (reference: dataloader.py:143 _MultiWorkerIter)."""
+        batches = list(self._batch_sampler)
+        self._refuse_device_samples(batches)
         ctx = multiprocessing.get_context("fork")
         key_queue = ctx.Queue()
         data_queue = ctx.Queue(2 * self._num_workers)
@@ -139,7 +162,6 @@ class DataLoader:
             w.start()
             workers.append(w)
         try:
-            batches = list(self._batch_sampler)
             sent = 0
             rcvd = 0
             buf = {}

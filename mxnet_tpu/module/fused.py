@@ -465,7 +465,7 @@ class FusedSymbolStep:
 
         if zero_flat or any(zero_big):
             from jax.sharding import PartitionSpec as _P
-            from ..ops.pallas_fused import _shard_map
+            from jax import shard_map as _shard_map
 
             def _zero_update(p, g, s, s_specs, lr, t, lrm, wd):
                 """One sharded optimizer step. ``lrm``/``wd`` are the
@@ -495,7 +495,7 @@ class FusedSymbolStep:
                     body, mesh=mesh,
                     in_specs=(_P(), _P(), _P(), _P()) + tuple(s_specs),
                     out_specs=(_P(axis),) + tuple(s_specs),
-                    check_rep=False)(p, g, lr, t, *s)
+                    check_vma=False)(p, g, lr, t, *s)
                 return res[0], tuple(res[1:])
 
         # base_key is a runtime ARGUMENT, not a closure constant: baked
@@ -978,9 +978,10 @@ class FusedSymbolStep:
         """Route one compile through the registry: AOT-load from the
         persistent cache when a valid entry exists (zero fresh XLA
         compiles on a warm restart), else trace+compile inside a
-        ``compile::compile`` span and serialize back. Any failure of
-        the AOT machinery itself degrades to the plain jit — slower,
-        never wrong."""
+        ``compile::compile`` span and serialize back. The registry
+        absorbs its own cache-entry and serialization failures; an
+        error raised here is the trace's or the compiler's, and it
+        surfaces."""
         from .. import compile as compile_mod
         from ..ops.pallas_fused import mesh_scope
 
@@ -990,18 +991,9 @@ class FusedSymbolStep:
             with mesh_scope(self.mesh, self.data_axis):
                 return self._step_jit.lower(*args)
 
-        try:
-            key = self._program_key(sig)
-            exe, source = compile_mod.load_or_compile(key, _lower)
-            compile_mod.note_entry_point(key.name, key, sig)
-        except Exception as e:  # AOT path unavailable: degrade loudly
-            import logging
-            logging.getLogger("mxnet_tpu.compile").warning(
-                "fused step AOT compile path failed (%s); using the "
-                "plain jit", e)
-            from .. import fault as _fault
-            _fault.count("compile.aot_fallback")
-            return self._step_jit
+        key = self._program_key(sig)
+        exe, source = compile_mod.load_or_compile(key, _lower)
+        compile_mod.note_entry_point(key.name, key, sig)
         self._note_cost(sig, exe)
         if source != "cache":
             return exe

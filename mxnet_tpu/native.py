@@ -6,10 +6,16 @@ zero-copy record access and background prefetch live in
 ``native/recordio.cc``. The library is built on first use with the
 in-image toolchain (``make -C native``); every consumer falls back to the
 pure-Python implementation when the toolchain or build is unavailable.
+
+Freshness is decided by CONTENT: a stamp beside the library holds the
+sha256 of the sources that produced it, and a library whose stamp does
+not match the tree's sources is rebuilt, never loaded — file times say
+nothing in a copied or freshly checked-out tree.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,23 +26,39 @@ __all__ = ["get_lib", "NativeRecordReader", "available"]
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libmxtpu_io.so")
+_STAMP_PATH = _LIB_PATH + ".stamp"
+_SOURCES = ("recordio.cc", "Makefile")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _needs_build(src):
-    return not os.path.exists(_LIB_PATH) or (
-        os.path.exists(src) and
-        os.path.getmtime(_LIB_PATH) < os.path.getmtime(src))
+def _source_hash():
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _needs_build():
+    """True unless the library exists AND its stamp names exactly the
+    sources in the tree."""
+    if not os.path.exists(_LIB_PATH):
+        return True
+    try:
+        with open(_STAMP_PATH) as f:
+            return f.read().strip() != _source_hash()
+    except OSError:
+        return True
 
 
 def _build():
     """Rebuild the library multi-process safely.
 
     Spawn DataLoader workers all import this module and may race the
-    mtime-triggered rebuild; a worker that dlopens a half-written .so
+    rebuild; a worker that dlopens a half-written .so
     segfaults. So: (1) an ``fcntl.flock`` file lock serializes builders
     across processes, (2) the compiler writes to a temp file in the
     same directory which is ``os.rename``d into place — rename is
@@ -46,13 +68,13 @@ def _build():
     what the winner just produced."""
     import fcntl
     import tempfile
-    src = os.path.join(_NATIVE_DIR, "recordio.cc")
     lock_path = _LIB_PATH + ".lock"
     with open(lock_path, "w") as lf:
         fcntl.flock(lf, fcntl.LOCK_EX)
         try:
-            if not _needs_build(src):
+            if not _needs_build():
                 return
+            built_from = _source_hash()
             fd, tmp = tempfile.mkstemp(suffix=".so", dir=_NATIVE_DIR)
             os.close(fd)
             # make must CREATE the target — the empty mkstemp file
@@ -66,6 +88,12 @@ def _build():
                      os.path.basename(tmp)],
                     check=True, capture_output=True)
                 os.rename(tmp, _LIB_PATH)
+                # stamp AFTER the library is in place: a death between
+                # the two leaves a library with a stale stamp, which
+                # the next process rebuilds
+                with open(_STAMP_PATH + ".tmp", "w") as f:
+                    f.write(built_from)
+                os.replace(_STAMP_PATH + ".tmp", _STAMP_PATH)
             finally:
                 if os.path.exists(tmp):
                     os.unlink(tmp)
@@ -80,24 +108,20 @@ def get_lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        src = os.path.join(_NATIVE_DIR, "recordio.cc")
         try:
-            # rebuild BEFORE the first dlopen when the source is newer —
+            # rebuild BEFORE the first dlopen when the sources changed —
             # relinking an already-mapped .so truncates live code pages,
             # and a second CDLL on the same inode returns the stale
             # handle anyway. _build serializes across processes (flock)
             # and renames atomically, so spawn workers racing here each
             # end up dlopening a complete library.
-            if _needs_build(src):
+            if _needs_build():
                 _build()
-        except Exception:
-            # rebuild failed (e.g. no libjpeg on this host): a prebuilt
-            # library still serves the reader/prefetch surface — decode
-            # consumers probe hasattr(rio_decode_batch) and degrade
-            pass
-        try:
             lib = ctypes.CDLL(_LIB_PATH)
-        except Exception:
+        except (OSError, subprocess.CalledProcessError):
+            # no toolchain / no libjpeg / unloadable: consumers run the
+            # pure-Python path. A library these sources did not produce
+            # is not an alternative
             return None
         lib.rio_open.restype = ctypes.c_void_p
         lib.rio_open.argtypes = [ctypes.c_char_p]

@@ -33,7 +33,16 @@ On TPU the backward recomputes the normalized activation from the raw
 residuals (one elementwise pass — precisely the memory-traffic win);
 off-TPU the interpreter has to materialize it anyway, so it doubles as
 the residual. Off-TPU the whole path runs in interpret mode / stock XLA
-ops, so tier-1 CPU tests exercise the same op, rewrite, and VJP.
+ops, so tier-1 CPU tests exercise the same op, rewrite, and VJP;
+:func:`interpret_mode` is the one place that decides which.
+
+Tiles are chosen for Mosaic, not for the interpreter: the last block
+dimension is a multiple of 128 or the whole array dimension, the
+second-to-last a multiple of the dtype's sublane count (8 for 4-byte,
+16 for 2-byte, 32 for 1-byte elements) or whole, and the double-buffered
+working set fits the VMEM budget the kernels request
+(``_VMEM_LIMIT_BYTES``). A shape no such tile serves returns None from
+the selectors, and the rewrite pass bails that site by name.
 """
 from __future__ import annotations
 
@@ -45,16 +54,24 @@ import threading
 import jax
 import jax.numpy as jnp
 
+from jax import shard_map as _shard_map
+
 from .registry import register_op
 
-try:  # jax>=0.4.35 moved shard_map out of experimental
-    from jax import shard_map as _shard_map  # type: ignore
-except ImportError:  # pragma: no cover - version shim
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 __all__ = ["bn_relu_matmul", "bn_relu_conv_nchw", "select_tiles",
-           "select_conv_tiles", "conv_tile_failure",
+           "select_conv_tiles", "conv_tile_failure", "interpret_mode",
            "fused_bn_relu_conv", "mesh_scope", "active_mesh"]
+
+
+def interpret_mode():
+    """Whether ``pallas_call`` runs interpreted — THE decision, for
+    every kernel in the package (``operator.PallasKernel`` included).
+    Mosaic exists only on a TPU backend, so everywhere else the kernels
+    interpret (what tier-1 runs on CPU). On a TPU backend the answer is
+    always False and no caller can ask otherwise: an interpreted kernel
+    on the chip still returns right answers, which is exactly how a
+    kernel Mosaic refuses would go unnoticed."""
+    return jax.default_backend() != "tpu"
 
 # ---------------------------------------------------------------------------
 # trace-time mesh scope (ROADMAP item 1: shard_map-compatible kernels)
@@ -69,7 +86,7 @@ __all__ = ["bn_relu_matmul", "bn_relu_conv_nchw", "select_tiles",
 # lowering, and the op reads it when the pallas_call is built. AD never
 # differentiates through the shard_map (it sits inside the ops' custom
 # VJPs, whose backward is plain jnp): jax cannot transpose a
-# check_rep=False shard_map, and check_rep=False is mandatory because
+# check_vma=False shard_map, and check_vma=False is mandatory because
 # pallas_call has no replication rule.
 _MESH_SCOPE = threading.local()
 
@@ -109,10 +126,18 @@ def _batch_shards(batch):
         return None
     return mesh, axis, batch // ndev
 
-# output-tile candidates, largest first; TPU-friendly multiples of 8.
-# small trailing candidates keep interpret-mode (CPU test) shapes fusable.
+# output-tile candidates, largest first. Divisibility alone does not make
+# one usable: _blocks() admits only what Mosaic's block rules accept.
 _BM_CANDIDATES = (1024, 512, 256, 128, 64, 32, 16, 8)
 _BN_CANDIDATES = (512, 256, 128, 64, 32, 16, 8)
+_LANE = 128
+# Scoped VMEM the kernels request. The chip has 128 MiB per core (v5e)
+# but scopes a kernel to 16 MiB unless told otherwise, and a whole-row
+# f32 block of a 56x56 stage is already past that. A tile is admitted
+# only while its estimated working set stays under HALF of this: the
+# other half is headroom for Mosaic's own temporaries, which the
+# estimate cannot see.
+_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 # MXTPU_PALLAS_TILES parse cache: (raw env string, parsed (bm, bn))
 _TILE_OVERRIDE_CACHE = ("", None)
@@ -129,9 +154,10 @@ def _tile_override():
     table), bounded by the built-in candidate maxima (bm ≤ 1024,
     bn ≤ 512). Anything else raises MXNetError at selection time, so a
     bad tile fails the BIND/TRIAL that consulted it, never the process
-    and never silently. A valid tile that merely doesn't divide the
-    shape at hand is not an error — selection falls back to the
-    built-in candidates (the knob steers, the shape decides)."""
+    and never silently. A valid tile that doesn't divide the shape at
+    hand, or that Mosaic would refuse for it, is not an error —
+    selection falls back to the built-in candidates (the knob steers,
+    the shape decides)."""
     global _TILE_OVERRIDE_CACHE
     raw = os.environ.get("MXTPU_PALLAS_TILES", "").strip()
     if not raw:
@@ -163,54 +189,101 @@ def _tile_override():
     return (bm, bn)
 
 
-def select_tiles(m, n):
-    """(bm, bn) output-tile split for an (M, K) @ (K, N) fused matmul,
-    or None when no candidate divides (a truncated grid would leave
-    output tiles uninitialized). An ``MXTPU_PALLAS_TILES`` override is
-    preferred per dimension when it divides."""
-    ov = _tile_override()
-    bm = ov[0] if ov is not None and m % ov[0] == 0 else \
-        next((c for c in _BM_CANDIDATES if m % c == 0), None)
-    bn = ov[1] if ov is not None and n % ov[1] == 0 else \
-        next((c for c in _BN_CANDIDATES if n % c == 0), None)
-    if bm is None or bn is None:
+def _sublane(dtype):
+    """Rows of one native tile: 8 for 4-byte, 16 for 2-byte, 32 for
+    1-byte elements (the last dimension is always 128 lanes)."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _pad(n, unit):
+    return -(-n // unit) * unit
+
+
+def _blocks(full, candidates, unit, prefer=None):
+    """Block sizes Mosaic accepts along one dimension of extent
+    ``full``, largest first: the dividing candidates that are multiples
+    of ``unit`` (128 for the last block dimension, the dtype's sublane
+    count for the one before it) and the dimension taken whole, which
+    is always legal (the compiler pads it). ``prefer`` — the tuner's
+    override — moves to the front when it is among them."""
+    out = {c for c in candidates if full % c == 0 and c % unit == 0}
+    out.add(int(full))
+    out = sorted(out, reverse=True)
+    if prefer in out:
+        out.remove(prefer)
+        out.insert(0, prefer)
+    return out
+
+
+def _fits_vmem(act, other, out, k, itemsize):
+    """Whether one grid step's working set (element counts of the
+    PADDED activation / weight / output blocks, contraction length
+    ``k``) stays inside the admitted half of ``_VMEM_LIMIT_BYTES``: the
+    three streamed blocks double-buffered (the pipeline fetches block
+    i+1 while block i computes), the folded scale and shift vectors
+    (each padded out to full 128-lane tiles, double-buffered), and the
+    f32 temporaries the kernel body materializes — the upcast and the
+    normalized activation, and the f32 accumulator."""
+    need = (2 * (act + other + out) * itemsize
+            + 4 * _pad(k, 8) * _LANE * 4
+            + (2 * act + out) * 4)
+    return need <= _VMEM_LIMIT_BYTES // 2
+
+
+def select_tiles(m, n, k, dtype=jnp.float32):
+    """(bm, bn) output-tile split Mosaic accepts for an (M, K) @ (K, N)
+    fused matmul in ``dtype``, or None. The x block is (bm, K) and the
+    w block (K, bn): bm is a second-to-last block dimension, bn a last
+    one. M and N must divide by 8 (a ragged MXU feed is not worth a
+    kernel). An ``MXTPU_PALLAS_TILES`` override is preferred per
+    dimension when it is legal for the shape."""
+    if m % 8 or n % 8:
         return None
-    return bm, bn
+    ov = _tile_override() or (None, None)
+    item = jnp.dtype(dtype).itemsize
+    kl, ks = _pad(k, _LANE), _pad(k, _sublane(dtype))
+    for bm in _blocks(m, _BM_CANDIDATES, _sublane(dtype), ov[0]):
+        for bn in _blocks(n, _BN_CANDIDATES, _LANE, ov[1]):
+            bnp = _pad(bn, _LANE)
+            if _fits_vmem(bm * kl, ks * bnp, bm * bnp, k, item):
+                return bm, bn
+    return None
 
 
-def select_conv_tiles(n_out, spatial):
-    """(bo, bs) output tiles for the NCHW-native fused 1×1 conv — bo over
-    output channels, bs over the flattened spatial dim — or None (the
-    rewrite pass's bail-out rule). Output channels must divide by an
-    8-multiple candidate (MXU sublane alignment); the spatial dim may
-    instead be taken whole when small, because odd per-sample extents
-    (7·7=49, 14·14=196) are the NORM mid-network and still block fine.
-    An ``MXTPU_PALLAS_TILES`` override ``"<bm>,<bn>"`` maps to
-    (bs, bo) — bm is the spatial-like dim, bn the channel-like one —
-    and is preferred per dimension when it divides."""
-    ov = _tile_override()
-    bo = ov[1] if ov is not None and n_out % ov[1] == 0 else \
-        next((c for c in _BN_CANDIDATES if n_out % c == 0), None)
-    bs = ov[0] if ov is not None and spatial % ov[0] == 0 else \
-        next((c for c in _BM_CANDIDATES if spatial % c == 0), None)
-    if bs is None and spatial <= 1024:
-        bs = int(spatial)
-    if bo is None or bs is None:
+def select_conv_tiles(n_out, spatial, n_in, dtype=jnp.float32):
+    """(bo, bs) output tiles Mosaic accepts for the NCHW-native fused
+    1×1 conv in ``dtype`` — bo over output channels, bs over the
+    flattened spatial dim — or None (the rewrite pass's bail-out rule).
+    The weight block is (bo, C), the activation block (1, C, bs) and
+    the output block (1, bo, bs): bs is the LANE dimension, so it is a
+    128-multiple that divides H·W or H·W whole — 56², 28², 14² and 7²
+    have no such divisor and are taken whole, padded to the next 128 —
+    and bo a sublane-multiple or num_filter whole. Output channels must
+    divide by 8. An ``MXTPU_PALLAS_TILES`` override ``"<bm>,<bn>"``
+    maps to (bs, bo) — bm is the spatial-like dim, bn the channel-like
+    one — and is preferred per dimension when it is legal."""
+    if n_out % 8:
         return None
-    return bo, bs
+    ov = _tile_override() or (None, None)
+    item = jnp.dtype(dtype).itemsize
+    cl, cs = _pad(n_in, _LANE), _pad(n_in, _sublane(dtype))
+    for bs in _blocks(spatial, _BM_CANDIDATES, _LANE, ov[0]):
+        bsp = _pad(bs, _LANE)
+        for bo in _blocks(n_out, _BN_CANDIDATES, _sublane(dtype), ov[1]):
+            bop = _pad(bo, _sublane(dtype))
+            if _fits_vmem(cs * bsp, bop * cl, bop * bsp, n_in, item):
+                return bo, bs
+    return None
 
 
-def conv_tile_failure(n_out, spatial):
-    """Which dimension made ``select_conv_tiles`` return None — the
-    fusion report's bail-out reason must point at the right one."""
-    why = []
-    if next((c for c in _BN_CANDIDATES if n_out % c == 0), None) is None:
-        why.append(f"num_filter={n_out} not divisible by 8")
-    if next((c for c in _BM_CANDIDATES if spatial % c == 0), None) \
-            is None and spatial > 1024:
-        why.append(f"spatial={spatial} not divisible by 8 and too "
-                   "large (> 1024) for a whole-row block")
-    return "; ".join(why) or "no tile split fits"
+def conv_tile_failure(n_out, spatial, n_in, dtype=jnp.float32):
+    """Why ``select_conv_tiles`` returned None — the fusion report's
+    bail-out reason."""
+    if n_out % 8:
+        return f"num_filter={n_out} not divisible by 8"
+    return (f"no Mosaic-legal block of the (num_filter={n_out}, "
+            f"spatial={spatial}) output fits VMEM at C={n_in} in "
+            f"{jnp.dtype(dtype).name}")
 
 
 # ---------------------------------------------------------------------------
@@ -220,9 +293,11 @@ def _make_kernel(relu):
     def _kernel(x_ref, w_ref, scale_ref, shift_ref, o_ref):
         """One (bm, bn) output tile of the (M, K) @ (K, N) form:
         normalize (+ReLU) the x tile on the fly (VMEM, fused into the
-        MXU feed) and contract over the whole K."""
+        MXU feed) and contract over the whole K. The prologue runs in
+        f32 — the VPU has no narrower arithmetic on v5e — and the MXU
+        is fed in the input dtype."""
         x = x_ref[...]
-        z = x * scale_ref[...] + shift_ref[...]
+        z = x.astype(jnp.float32) * scale_ref[...] + shift_ref[...]
         if relu:
             z = jnp.maximum(z, 0.0)
         o_ref[...] = jnp.dot(
@@ -235,15 +310,15 @@ def _make_nchw_kernel(relu):
     def _kernel(w_ref, x_ref, scale_ref, shift_ref, o_ref):
         """One (1, bo, bs) output block of the NCHW-native fused conv:
         normalize (+ReLU) the (1, C, bs) activation block on the fly
-        and contract the (bo, C) weight block over the whole C."""
-        x = x_ref[...]                       # (1, C, bs)
-        z = x * scale_ref[...] + shift_ref[...]  # (C, 1) broadcasts
-        if relu:
+        and contract the (bo, C) weight block over the whole C. f32
+        prologue, input-dtype MXU feed (see ``_make_kernel``)."""
+        x = x_ref[0]                          # (C, bs)
+        z = x.astype(jnp.float32) * scale_ref[...] + shift_ref[...]
+        if relu:                              # (C, 1) broadcasts
             z = jnp.maximum(z, 0.0)
-        o_ref[...] = jnp.dot(
-            w_ref[...], z[0].astype(x.dtype),
-            preferred_element_type=jnp.float32
-        ).astype(o_ref.dtype)[None]
+        o_ref[0] = jnp.dot(
+            w_ref[...], z.astype(x.dtype),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
     return _kernel
 
 
@@ -258,6 +333,16 @@ def _make_prologue_kernel(relu):
     return _kernel
 
 
+def _mosaic_params(grid_rank):
+    """Compiler parameters of the tiled kernels when Mosaic compiles
+    them: every grid axis writes disjoint output blocks, and the VMEM
+    scope is the one the tile selectors budgeted against."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * grid_rank,
+        vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
 def _conv1x1(xhat, w4):
     dn = jax.lax.conv_dimension_numbers(xhat.shape, w4.shape,
                                         ("NCHW", "OIHW", "NCHW"))
@@ -269,7 +354,7 @@ def _conv1x1(xhat, w4):
 # the generic (M, K) @ (K, N) fused matmul (bench tool / kernel tests)
 # ---------------------------------------------------------------------------
 @functools.lru_cache(maxsize=None)
-def _fused_matmul(relu, bm, bn, interpret):
+def _fused_matmul(relu, bm, bn):
     from jax.experimental import pallas as pl
     kernel = _make_kernel(relu)
 
@@ -277,6 +362,7 @@ def _fused_matmul(relu, bm, bn, interpret):
     def f(x, w, scale, shift):
         m, k = x.shape
         n = w.shape[1]
+        interpret = interpret_mode()
         return pl.pallas_call(
             kernel,
             grid=(m // bm, n // bn),
@@ -289,7 +375,10 @@ def _fused_matmul(relu, bm, bn, interpret):
             out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
             interpret=interpret,
-        )(x, w, scale.reshape(1, k), shift.reshape(1, k))
+            compiler_params=None if interpret else _mosaic_params(2),
+            name="bn_relu_matmul",
+        )(x, w, scale.reshape(1, k).astype(jnp.float32),
+          shift.reshape(1, k).astype(jnp.float32))
 
     def f_fwd(x, w, scale, shift):
         # raw-input residuals: the normalized activation is recomputed
@@ -313,77 +402,67 @@ def _fused_matmul(relu, bm, bn, interpret):
     return f
 
 
-def bn_relu_matmul(x, w, scale, shift, bm=None, bn=None, relu=True,
-                   interpret=None):
+def bn_relu_matmul(x, w, scale, shift, bm=None, bn=None, relu=True):
     """``act(x * scale + shift) @ w`` without materializing the
     normalized activation. x: (M, K); w: (K, N); scale/shift: (K,) — the
     folded BN parameters gamma/sqrt(var+eps) and beta - mu*scale.
 
-    Tiles default to ``select_tiles``; explicit bm/bn must divide M/N.
-    ``interpret`` defaults to True off-TPU so the same code path runs in
-    CPU tests. Differentiable via a custom VJP (exact gradients of the
-    composed expression, normalized activation recomputed in backward).
+    Tiles default to ``select_tiles``; explicit bm/bn must divide M/N
+    (and, on the chip, be blocks Mosaic accepts). Interpreted off-TPU
+    (:func:`interpret_mode`) so the same code path runs in CPU tests.
+    Differentiable via a custom VJP (exact gradients of the composed
+    expression, normalized activation recomputed in backward).
     """
     m, k = x.shape
     n = w.shape[1]
-    # each tile is selected independently, so an explicit bm (or bn)
-    # only needs the OTHER dimension to have a dividing candidate
-    if bm is None:
-        bm = next((c for c in _BM_CANDIDATES if m % c == 0), None)
-        if bm is None:
+    if bm is None or bn is None:
+        tiles = select_tiles(m, n, k, x.dtype)
+        if tiles is None:
             raise ValueError(
-                f"bn_relu_matmul: no tile candidate divides M={m} "
-                "(must be divisible by 8); pad the problem or pass an "
-                "explicit bm")
-    if bn is None:
-        bn = next((c for c in _BN_CANDIDATES if n % c == 0), None)
-        if bn is None:
-            raise ValueError(
-                f"bn_relu_matmul: no tile candidate divides N={n} "
-                "(must be divisible by 8); pad the problem or pass an "
-                "explicit bn")
+                f"bn_relu_matmul: no tile Mosaic accepts for M={m}, "
+                f"N={n}, K={k} in {x.dtype} (M and N must be divisible "
+                "by 8 and one block must fit VMEM); pad the problem or "
+                "pass explicit bm/bn")
+        bm = tiles[0] if bm is None else bm
+        bn = tiles[1] if bn is None else bn
     if m % bm or n % bn:
         raise ValueError(
             f"bn_relu_matmul needs M % bm == 0 and N % bn == 0 "
             f"(got M={m}, N={n}, bm={bm}, bn={bn}); pad the problem or "
             "pass smaller blocks — a truncated grid would leave output "
             "tiles uninitialized")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _fused_matmul(bool(relu), int(bm), int(bn),
-                         bool(interpret))(x, w, scale, shift)
+    return _fused_matmul(bool(relu), int(bm), int(bn))(x, w, scale, shift)
 
 
 # ---------------------------------------------------------------------------
 # the NCHW-native fused conv forward (used by the graph op)
 # ---------------------------------------------------------------------------
-def bn_relu_conv_nchw(x, w, scale, shift, relu=True, interpret=None):
+def bn_relu_conv_nchw(x, w, scale, shift, relu=True):
     """NCHW-native fused BN-apply(+ReLU)+1×1-conv FORWARD: ``act(x *
     scale + shift) ⊛ w`` contracted over channels, x (B, C, H, W),
     w (O, C) → (B, O, H, W). On TPU this is the tiled fused-matmul
-    kernel — the normalized activation never reaches HBM. In interpret
-    mode (CPU tests) the interpreter must materialize it regardless, so
-    the prologue runs as a whole-array Pallas kernel and the stock 1×1
-    convolution does the contraction; pass ``interpret=False`` to force
-    the tiled kernel (still interpretable off-TPU only via
-    ``interpret=True`` in its pallas_call — i.e. don't).
+    kernel, compiled by Mosaic — the normalized activation never
+    reaches HBM. Off-TPU (:func:`interpret_mode`; CPU tests) the
+    interpreter must materialize it regardless, so the prologue runs as
+    a whole-array interpreted Pallas kernel and the stock 1×1
+    convolution does the contraction. Returns ``(out, xhat)``; xhat is
+    None on TPU.
 
     Forward only; the graph op's custom VJP (analytic fused BN backward)
     lives in ``_fused_bn_conv_vjp``.
 
     Under an active :func:`mesh_scope` whose batch axis divides B, the
-    pallas_call wraps itself in ``shard_map(..., check_rep=False)``
+    pallas_call wraps itself in ``shard_map(..., check_vma=False)``
     over the batch dimension — per-device kernel on the batch shard,
     weights/folded-stats replicated — so the op composes with GSPMD
     partitioning instead of being an opaque custom call the mesh bind
-    must reject (ROADMAP item 1)."""
+    must reject."""
     from jax.experimental import pallas as pl
     b, c, h, w_sp = x.shape
     s = h * w_sp
     o = w.shape[0]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret:
+    ms = _batch_shards(b)
+    if interpret_mode():
         kern = _make_prologue_kernel(relu)
 
         def _prologue(xl, sc, sh):
@@ -395,23 +474,23 @@ def bn_relu_conv_nchw(x, w, scale, shift, relu=True, interpret=None):
 
         sc = scale.reshape(1, c, 1, 1)
         sh = shift.reshape(1, c, 1, 1)
-        ms = _batch_shards(b)
         if ms is not None:
             from jax.sharding import PartitionSpec as P
             mesh, axis, _ = ms
             xhat = _shard_map(_prologue, mesh=mesh,
                               in_specs=(P(axis), P(), P()),
                               out_specs=P(axis),
-                              check_rep=False)(x, sc, sh)
+                              check_vma=False)(x, sc, sh)
         else:
             xhat = _prologue(x, sc, sh)
         return _conv1x1(xhat, w.reshape(o, c, 1, 1)).astype(x.dtype), \
             xhat
-    tiles = select_conv_tiles(o, s)
+    tiles = select_conv_tiles(o, s, c, x.dtype)
     if tiles is None:
+        # the rewrite pass bails such sites by name (symbol/fusion.py);
+        # reaching here means the pass and the op disagree on the dtype
         raise ValueError(
-            f"bn_relu_conv_nchw: {conv_tile_failure(o, s)}; pad the "
-            "problem")
+            f"bn_relu_conv_nchw: {conv_tile_failure(o, s, c, x.dtype)}")
     bo, bs = tiles
     kern = _make_nchw_kernel(relu)
 
@@ -430,19 +509,20 @@ def bn_relu_conv_nchw(x, w, scale, shift, relu=True, interpret=None):
                                    lambda g, i, j: (g, i, j)),
             out_shape=jax.ShapeDtypeStruct((bl, o, s), xl.dtype),
             interpret=False,
+            compiler_params=_mosaic_params(3),
+            name="bn_relu_conv1x1",
         )(wl, xl, sc, sh)
 
     xr = x.reshape(b, c, s)
-    sc = scale.reshape(c, 1)
-    sh = shift.reshape(c, 1)
-    ms = _batch_shards(b)
+    sc = scale.reshape(c, 1).astype(jnp.float32)
+    sh = shift.reshape(c, 1).astype(jnp.float32)
     if ms is not None:
         from jax.sharding import PartitionSpec as P
         mesh, axis, _ = ms
         out = _shard_map(_tiled, mesh=mesh,
                          in_specs=(P(), P(axis), P(), P()),
                          out_specs=P(axis),
-                         check_rep=False)(w, xr, sc, sh)
+                         check_vma=False)(w, xr, sc, sh)
     else:
         out = _tiled(w, xr, sc, sh)
     return out.reshape(b, o, h, w_sp), None
@@ -452,7 +532,7 @@ def bn_relu_conv_nchw(x, w, scale, shift, relu=True, interpret=None):
 # the graph op: BN(+ReLU)+1×1 conv with the analytic fused backward
 # ---------------------------------------------------------------------------
 @functools.lru_cache(maxsize=None)
-def _fused_bn_conv_vjp(relu, batch_stats, fix_gamma, eps, interpret):
+def _fused_bn_conv_vjp(relu, batch_stats, fix_gamma, eps):
     """Whole-op custom VJP: (data, gamma, beta, moving_mean, moving_var,
     w2 (O, C)) -> (out, mean, var). The backward is the ANALYTIC fused
     BatchNorm backward (cuDNN BatchNormBackward coverage): d(data) is
@@ -479,8 +559,7 @@ def _fused_bn_conv_vjp(relu, batch_stats, fix_gamma, eps, interpret):
         if mean is None:
             mean, var = mm, mv
         _, scale, shift = fold(x, gamma, beta, mean, var)
-        out, xhat = bn_relu_conv_nchw(x, w2, scale, shift, relu=relu,
-                                      interpret=interpret)
+        out, xhat = bn_relu_conv_nchw(x, w2, scale, shift, relu=relu)
         return out, mean, var, xhat
 
     @jax.custom_vjp
@@ -684,31 +763,13 @@ def fused_bn_relu_conv(data, gamma, beta, moving_mean, moving_var, weight,
     BatchNorm, so the executors' running-aux fold (Symbol._bn_aux_updates)
     applies to this op unchanged. ``momentum`` is consumed there, not
     here."""
-    B, C, H, W = data.shape
+    C = data.shape[1]
     O = weight.shape[0]
     batch_stats = bool(training) and not use_global_stats
-    if select_conv_tiles(O, H * W) is None:
-        # shapes the rewrite pass should have bailed on — compute the
-        # reference composition instead of failing mid-trace
-        if batch_stats:
-            mean = jnp.mean(data, axis=(0, 2, 3))
-            var = jnp.var(data, axis=(0, 2, 3))
-        else:
-            mean, var = moving_mean, moving_var
-        g = jnp.ones_like(gamma) if fix_gamma else gamma
-        scale = g * jax.lax.rsqrt(var + eps)
-        shift = beta - mean * scale
-        z = data * scale.reshape(1, C, 1, 1) + shift.reshape(1, C, 1, 1)
-        if act_type == "relu":
-            z = jnp.maximum(z, 0.0)
-        out = _conv1x1(z.astype(data.dtype),
-                       weight.astype(data.dtype).reshape(O, C, 1, 1))
-    else:
-        out, mean, var = _fused_bn_conv_vjp(
-            act_type == "relu", batch_stats, bool(fix_gamma), float(eps),
-            jax.default_backend() != "tpu",
-        )(data, gamma, beta, moving_mean, moving_var,
-          weight.reshape(O, C).astype(data.dtype))
+    out, mean, var = _fused_bn_conv_vjp(
+        act_type == "relu", batch_stats, bool(fix_gamma), float(eps),
+    )(data, gamma, beta, moving_mean, moving_var,
+      weight.reshape(O, C).astype(data.dtype))
     if not no_bias and bias is not None:
         out = out + bias.reshape(1, -1, 1, 1)
     return out.astype(data.dtype), mean, var

@@ -26,6 +26,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["ring_attention", "local_attention_block", "sequence_shard"]
@@ -106,11 +107,6 @@ def ring_attention(query, key, value, mesh: Mesh, seq_axis: str = "sp",
     numerical accuracy while no device ever holds more than T/P of the
     sequence.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     qspec = P(batch_axis, seq_axis, None, None)
     body = functools.partial(_ring_attention_sharded, axis_name=seq_axis,
                              causal=causal, scale=scale)

@@ -1,8 +1,7 @@
 """Post-training quantization subsystem (round 19).
 
-On a bandwidth-bound machine halving bytes IS the speedup (the step has
-sat at ~114% of the HBM roofline since BENCH_r05), and quantization is
-the largest untouched byte lever: int8 weights move a quarter of the
+On a bandwidth-bound machine halving bytes IS the speedup, and
+quantization is the largest untouched byte lever: int8 weights move a quarter of the
 f32 bytes, and an int8 KV-cache halves-and-then-some the decode state
 that every decode step re-reads. Two measured deliverables:
 

@@ -27,11 +27,11 @@ class PallasModule:
         self._kernels = {}
 
     def get_kernel(self, kernel_fn, out_shape, name=None, grid=None,
-                   vjp=None, interpret="auto"):
+                   vjp=None):
         """(CudaModule.get_kernel analog: rtc.py:106)"""
         name = name or getattr(kernel_fn, "__name__", "pallas_kernel")
         pk = PallasKernel(kernel_fn, out_shape, name=name, grid=grid,
-                          vjp=vjp, interpret=interpret)
+                          vjp=vjp)
         self._kernels[name] = pk
         return pk
 

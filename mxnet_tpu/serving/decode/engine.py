@@ -293,24 +293,15 @@ class DecodePredictor:
 
     def _acquire(self, pkey_id, kind, bucket, jit_fn, args):
         """Acquire one compiled program through the compile registry
-        (AOT cache, retrace guard), mirroring Predictor._acquire_program
-        including the degrade-to-plain-jit fallback."""
+        (AOT cache, retrace guard), mirroring
+        Predictor._acquire_program: cache-entry failures are the
+        registry's to absorb, a trace or compile error surfaces."""
         from ... import compile as compile_mod
-        try:
-            key = self._program_key(kind, bucket)
-            exe, source = compile_mod.load_or_compile(
-                key, lambda: jit_fn.lower(*args))
-            compile_mod.note_entry_point(
-                key.name, key, compile_mod.arg_signature(args[1]))
-        except Exception as e:
-            import logging
-            logging.getLogger("mxnet_tpu.compile").warning(
-                "decode AOT compile path failed (%s); using the plain "
-                "jit", e)
-            from ... import fault as _fault
-            _fault.count("compile.aot_fallback")
-            self._materialized += 1
-            return jit_fn
+        key = self._program_key(kind, bucket)
+        exe, source = compile_mod.load_or_compile(
+            key, lambda: jit_fn.lower(*args))
+        compile_mod.note_entry_point(
+            key.name, key, compile_mod.arg_signature(args[1]))
         self._note_cost(pkey_id, key, exe)
         if source == "cache":
             self._cache_loads += 1

@@ -397,25 +397,16 @@ class Predictor:
     def _acquire_program(self, bucket, args):
         """One compiled program per (bucket, request dtypes), acquired
         through the compile registry: a warm persistent cache turns
-        warmup's per-bucket compile storm into file loads. Failures of
-        the AOT machinery degrade to the plain jit."""
+        warmup's per-bucket compile storm into file loads. The registry
+        absorbs its own cache-entry failures; a trace or compile error
+        surfaces."""
         from .. import compile as compile_mod
         dtypes = tuple(str(a.dtype) for a in args[1])
-        try:
-            key = self._program_key(bucket, dtypes)
-            exe, source = compile_mod.load_or_compile(
-                key, lambda: self._infer_jit.lower(*args))
-            compile_mod.note_entry_point(
-                key.name, key, compile_mod.arg_signature(args[1]))
-        except Exception as e:
-            import logging
-            logging.getLogger("mxnet_tpu.compile").warning(
-                "predictor AOT compile path failed (%s); using the "
-                "plain jit", e)
-            from .. import fault as _fault
-            _fault.count("compile.aot_fallback")
-            self._materialized += 1
-            return self._infer_jit
+        key = self._program_key(bucket, dtypes)
+        exe, source = compile_mod.load_or_compile(
+            key, lambda: self._infer_jit.lower(*args))
+        compile_mod.note_entry_point(
+            key.name, key, compile_mod.arg_signature(args[1]))
         self._note_cost(bucket, dtypes, exe)
         if source == "cache":
             self._cache_loads += 1

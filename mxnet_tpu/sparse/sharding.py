@@ -26,12 +26,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .rowsparse import RowSparseRows, dedup_rows
+from jax import shard_map
 
-try:  # jax>=0.4.35 moved shard_map out of experimental
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover - version shim
-    from jax.experimental.shard_map import shard_map  # type: ignore
+from .rowsparse import RowSparseRows, dedup_rows
 
 __all__ = ["ShardedEmbeddingTable", "shard_spec"]
 

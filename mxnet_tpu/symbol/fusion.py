@@ -27,9 +27,10 @@ Match rules (each failure bails that site, recorded in the report):
 - BN axis is 1 (channel) and its batch-stat outputs have no graph
   consumers (the running-aux fold reads them through the walker, not
   through graph edges);
-- shapes are known and tile-divisible: M = N·H·W and num_filter must
-  both divide by a Pallas output-tile candidate (select_tiles) — a
-  truncated grid would leave output tiles uninitialized.
+- shapes are known and the site has a (bo, bs) output block Mosaic
+  accepts in every dtype the program will run in (select_conv_tiles:
+  lane/sublane rules and the VMEM budget) — the bail-out happens here,
+  by name and reason, never as a compile error.
 
 The rewrite is non-destructive: it returns a NEW graph sharing
 unaffected nodes (same uids, so per-node RNG salts stay aligned with
@@ -132,12 +133,15 @@ def _conv_matches(node, attrs) -> bool:
             and attrs.get("layout") in (None, "NCHW"))
 
 
-def fuse_symbol(sym: Symbol, shapes: Dict[str, tuple]
-                ) -> Tuple[Symbol, dict]:
+def fuse_symbol(sym: Symbol, shapes: Dict[str, tuple],
+                dtypes=("float32",)) -> Tuple[Symbol, dict]:
     """Rewrite matched BN(+ReLU)→1×1-conv subgraphs of ``sym`` onto the
     fused Pallas op. ``shapes`` maps variable names (arguments AND aux)
     to concrete shapes — executors pass their bound array shapes so the
-    tile-divisibility bail-out is decided here, not mid-trace.
+    tile bail-out is decided here, not mid-trace. ``dtypes`` lists every
+    activation dtype the rewritten program will be compiled in, the one
+    it runs in first (a bf16 step is also compiled in f32 by the bytes
+    gate's proxy): a site needs a legal tile in each of them.
 
     Returns ``(new_sym, report)``; when nothing matched, ``new_sym`` is
     ``sym`` itself. The report lists rewritten sites and per-site
@@ -219,10 +223,13 @@ def fuse_symbol(sym: Symbol, shapes: Dict[str, tuple]
         if out_c is None:
             bail("num_filter unknown")
             continue
-        tiles = select_conv_tiles(out_c, h * w)
-        if tiles is None:
-            bail(conv_tile_failure(out_c, h * w))
+        per_dtype = [select_conv_tiles(out_c, h * w, c, dt)
+                     for dt in dtypes]
+        if None in per_dtype:
+            bail(conv_tile_failure(out_c, h * w, c,
+                                   dtypes[per_dtype.index(None)]))
             continue
+        tiles = per_dtype[0]
         claimed.update({id(bn)} | ({id(relu)} if relu is not None
                                    else set()))
         sites[id(node)] = {"bn": bn, "relu": relu, "tiles": tiles}

@@ -31,5 +31,11 @@ class PallasFusionPass(GraphPass):
 
     def apply(self, sym, shapes, ctx):
         from ..fusion import fuse_symbol
-        new_sym, rep = fuse_symbol(sym, shapes)
+        # the program runs in the bind's compute dtype, and the bytes
+        # gate compiles its proxy in f32: a site must tile in both
+        run = str(ctx.compute_dtype) if ctx.compute_dtype is not None \
+            else "float32"
+        new_sym, rep = fuse_symbol(sym, shapes,
+                                   dtypes=(run,) if run == "float32"
+                                   else (run, "float32"))
         return (new_sym if rep["sites"] else None), rep

@@ -1,9 +1,9 @@
 """StepTimeline: where does a training step's wall time and byte budget go?
 
-The step is HBM-bandwidth-bound (~114% of the v5e roofline, BENCH_r05),
-so the two numbers that decide every optimization are *measured seconds
-per phase* and *measured bytes per step* — not FLOPs. The timeline
-attributes both:
+The training step's arithmetic intensity sits far below the chip's
+ridge point (docs/perf_analysis.md §3), so the two numbers that decide
+an optimization are *measured seconds per phase* and *measured bytes
+per step* — not FLOPs. The timeline attributes both:
 
 - **Phase attribution**: ``fit()`` opens one timeline for the run;
   each step's wall time splits across ``data_wait`` (blocked on the

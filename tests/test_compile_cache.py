@@ -402,3 +402,27 @@ def test_cli_ls_verify_prune(tmp_path):
     assert r.returncode == 0
     assert json.loads(r.stdout.strip().splitlines()[-1])["removed"]
     assert _entry_paths(cache_dir) == []
+
+
+def test_jax_cache_is_placed_from_outside_or_at_a_fixed_path(monkeypatch):
+    """JAX's own persistent cache: with ``JAX_COMPILATION_CACHE_DIR``
+    set the program sets no directory in code — not even when the
+    ``.mxprog`` layer has a directory of its own — and with it unset
+    the directory is the fixed ``<repo>/.jax_cache``."""
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # what importing jax with the variable set leaves behind
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+        jax.config.update("jax_compilation_cache_dir", "/x")
+        with mx.config.override("MXTPU_COMPILE_CACHE_DIR", "/y"):
+            assert compile_mod.wire_jax_cache() == "/x"
+            assert compile_mod.default_cache().directory == "/y"
+            assert jax.config.jax_compilation_cache_dir == "/x"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_mod.wire_jax_cache() == \
+            os.path.join(_ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(_ROOT, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

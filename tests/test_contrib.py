@@ -519,13 +519,24 @@ class TestMiscParity:
             "'rank_' + os.environ['PROCESS_ID']), 'w').write('ok')\n")
         launcher = (pathlib.Path(__file__).parent.parent / "tools"
                     / "launch.py")
-        out = subprocess.run(
-            [sys.executable, str(launcher), "-n", "2",
-             sys.executable, str(script)],
-            capture_output=True, timeout=60)
+        cmd = [sys.executable, str(launcher), "-n", "2",
+               sys.executable, str(script)]
+        import os
+        out = subprocess.run(cmd, capture_output=True, timeout=60,
+                             env=dict(os.environ, JAX_PLATFORMS="cpu"))
         assert out.returncode == 0, out.stderr.decode()
         assert (tmp_path / "rank_0").read_text() == "ok"
         assert (tmp_path / "rank_1").read_text() == "ok"
+        # workers that may open a chip: two per host is refused, with
+        # the reason, before anything starts
+        (tmp_path / "rank_0").unlink()
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_PLATFORMS"}
+        out = subprocess.run(cmd, capture_output=True, timeout=60,
+                             env=env)
+        assert out.returncode != 0
+        assert b"one process" in out.stderr
+        assert not (tmp_path / "rank_0").exists()
 
 
 class TestQuantizedConvNet:

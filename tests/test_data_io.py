@@ -136,6 +136,22 @@ def test_dataloader_multiworker():
     np.testing.assert_allclose(got, x)
 
 
+def test_dataloader_workers_refuse_device_backed_samples(monkeypatch):
+    """On an accelerator backend a forked worker cannot index an array
+    on the chip its parent holds (it hangs there): the loader says so
+    before forking. numpy-backed datasets are served as ever."""
+    import jax
+    x = np.arange(64).reshape(16, 2, 2).astype(np.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    loader = gdata.DataLoader(gdata.ArrayDataset(mx.nd.array(x)),
+                              batch_size=8, num_workers=2)
+    with pytest.raises(mx.MXNetError, match="forked worker"):
+        next(iter(loader))
+    loader = gdata.DataLoader(gdata.ArrayDataset(x), batch_size=8,
+                              num_workers=2)
+    assert len(list(loader)) == 2
+
+
 def test_samplers():
     s = gdata.SequentialSampler(10)
     assert list(s) == list(range(10))

@@ -168,3 +168,18 @@ def test_image_record_iter_native_path(tmp_path):
         assert len(content) == 8 * 8 * 3
         n += 1
     assert n == 8
+
+
+def test_library_freshness_is_decided_by_source_content(tmp_path,
+                                                        monkeypatch):
+    """A library is current only when the stamp beside it names the
+    sources in the tree — a copied tree's file times say nothing."""
+    from mxnet_tpu import native
+    assert not native._needs_build()      # available() built + stamped
+    stamp = tmp_path / "stamp"
+    monkeypatch.setattr(native, "_STAMP_PATH", str(stamp))
+    assert native._needs_build()          # no stamp: unknown origin
+    stamp.write_text("0" * 64)
+    assert native._needs_build()          # built from other sources
+    stamp.write_text(native._source_hash())
+    assert not native._needs_build()

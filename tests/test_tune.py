@@ -337,13 +337,17 @@ def test_default_store_configuration(tmp_path):
 # ---------------------------------------------------------------------------
 def test_pallas_tiles_override_changes_selection():
     from mxnet_tpu.ops import pallas_fused as pf
-    base = pf.select_tiles(512, 256)
-    with mx.config.override("MXTPU_PALLAS_TILES", "128,64"):
-        assert pf.select_tiles(512, 256) == (128, 64)
+    base = pf.select_tiles(512, 256, 64)
+    with mx.config.override("MXTPU_PALLAS_TILES", "128,128"):
+        assert pf.select_tiles(512, 256, 64) == (128, 128)
         # non-dividing override falls back per dimension
-        assert pf.select_tiles(8, 256) == (8, 64)
-        assert pf.select_conv_tiles(64, 128) == (64, 128)
-    assert pf.select_tiles(512, 256) == base
+        assert pf.select_tiles(8, 256, 64) == (8, 128)
+        assert pf.select_conv_tiles(64, 128, 32) == (64, 128)
+    with mx.config.override("MXTPU_PALLAS_TILES", "128,64"):
+        # an override Mosaic would refuse (64 lanes of 256) steers
+        # nothing: the built-in scan answers for that dimension
+        assert pf.select_tiles(512, 256, 64) == (128, 256)
+    assert pf.select_tiles(512, 256, 64) == base
 
 
 @pytest.mark.parametrize("bad", [
@@ -360,7 +364,7 @@ def test_pallas_tiles_invalid_is_loud(bad):
     from mxnet_tpu.ops import pallas_fused as pf
     with mx.config.override("MXTPU_PALLAS_TILES", bad):
         with pytest.raises(MXNetError, match="MXTPU_PALLAS_TILES"):
-            pf.select_tiles(512, 256)
+            pf.select_tiles(512, 256, 64)
 
 
 def test_invalid_tile_fails_trial_not_search():
@@ -372,7 +376,7 @@ def test_invalid_tile_fails_trial_not_search():
                      name="t")
 
     def measure(cfg, budget):
-        tiles = pf.select_tiles(512, 256)     # raises on the bad knob
+        tiles = pf.select_tiles(512, 256, 64)  # raises on the bad knob
         return float(tiles[0])
 
     best, trials = TrialRunner(sp, measure, name="t").search()

@@ -39,8 +39,8 @@ def build_model(batch, stem="std", compute_dtype="bfloat16"):
     if stem != "std":
         kw["stem"] = stem
     net = resnet_sym.get_symbol(1000, 50, "3,224,224", **kw)
-    model = mx.mod.Module(context=mx.gpu(0), symbol=net, fused=True,
-                          compute_dtype=compute_dtype)
+    model = mx.mod.Module(context=mx.current_context(), symbol=net,
+                          fused=True, compute_dtype=compute_dtype)
     model.bind(data_shapes=[("data", (batch, 3, 224, 224))],
                label_shapes=[("softmax_label", (batch,))])
     model.init_params(mx.init.Xavier(rnd_type="gaussian",

@@ -38,6 +38,20 @@ def find_free_port():
     return port
 
 
+def refuse_shared_chips(parser, n_local):
+    """One process per chip: workers inherit ONE environment, so on a
+    host with TPUs every one of ``n_local`` workers would open every
+    chip, and all but the first would fail or hang. More than one
+    worker per host is therefore only launched when the workers are
+    pinned to the CPU (``JAX_PLATFORMS=cpu``)."""
+    if n_local > 1 and os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        parser.error(
+            f"refusing to start {n_local} workers on this host: they "
+            "would share one environment and each open every TPU chip "
+            "(a chip belongs to one process). Run one worker per host, "
+            "or set JAX_PLATFORMS=cpu for a CPU job")
+
+
 def run_elastic(args):
     """One host's share of the multi-host supervisor contract."""
     sys.path.insert(0, os.path.join(
@@ -93,9 +107,11 @@ def main():
     if args.elastic:
         if not args.workdir:
             parser.error("--elastic requires --workdir")
+        refuse_shared_chips(parser, args.procs_per_host)
         return run_elastic(args)
     if args.num_workers is None:
         parser.error("-n/--num-workers is required without --elastic")
+    refuse_shared_chips(parser, args.num_workers)
 
     coordinator = args.coordinator or f"127.0.0.1:{find_free_port()}"
     procs = []

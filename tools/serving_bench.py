@@ -64,7 +64,7 @@ def build_predictor(buckets, batch=64, small=False):
         net = resnet_sym.get_symbol(1000, 50, "3,224,224", stem="s2d")
         feat = (3, 224, 224)
     mx.random.seed(0)
-    mod = mx.mod.Module(context=mx.gpu(0), symbol=net)
+    mod = mx.mod.Module(context=mx.current_context(), symbol=net)
     mod.bind(data_shapes=[("data", (batch,) + feat)],
              label_shapes=[("softmax_label", (batch,))],
              for_training=False)
