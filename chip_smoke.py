@@ -12,11 +12,13 @@ phase runs again over all of them. Weights are random, from a seed.
 
 It fails — non-zero exit, no result line — unless JAX's default backend
 is ``tpu``, and on any failed assertion or exception in any phase; no
-phase is wrapped in a handler. The last line of standard output is one
-JSON object: ``{"ok": true, "device": {...}, "phases": {...}}`` with
-per-phase wall and set-up (trace + compile) seconds. Seconds are
-reported, never compared: nothing here is warmed or repeated enough to
-be a rate.
+phase is wrapped in a handler. The last two lines of standard output
+are one JSON object each: first ``{"phases": {...}, "mesh": ...}`` with
+per-phase ``ok``, wall and set-up (trace + compile) seconds, then the
+result line the driver parses, which has exactly these keys:
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+Seconds are reported, never compared: nothing here is warmed or repeated
+enough to be a rate.
 
     python3 chip_smoke.py          # on a machine with a TPU
 
@@ -449,6 +451,16 @@ def decode_phase(size):
 
 
 # ---------------------------------------------------------------------------
+def result_line(device):
+    """The object of the last line of standard output. The driver accepts
+    exactly ``ok`` and ``device`` = ``platform``/``kind``/``count``; what
+    else the run has to say goes on the line before."""
+    return {"ok": True,
+            "device": {"platform": str(device["platform"]),
+                       "kind": str(device["kind"]),
+                       "count": int(device["count"])}}
+
+
 def main():
     t_all = time.perf_counter()
     device = preamble()
@@ -475,8 +487,9 @@ def main():
         mesh = "not run: 1 device"
     print(f"chip_smoke: all phases ok in "
           f"{time.perf_counter() - t_all:.1f}s")
-    print(json.dumps({"ok": True, "device": device, "phases": phases,
-                      "mesh": mesh}))
+    print(json.dumps({"phases": phases, "mesh": mesh}))
+    # the result line: these keys and no others, last on stdout
+    print(json.dumps(result_line(device)), flush=True)
 
 
 if __name__ == "__main__":

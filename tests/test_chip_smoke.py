@@ -57,6 +57,32 @@ def test_main_refuses_before_any_phase_without_a_chip(monkeypatch):
     assert started == []
 
 
+def test_last_line_is_the_result_object_and_nothing_else(monkeypatch,
+                                                         capsys):
+    """The driver parses the last line of standard output and accepts
+    exactly ``{"ok", "device": {"platform", "kind", "count"}}``; the
+    per-phase summary is the line before, not part of it."""
+    import json
+    dev = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    monkeypatch.setattr(chip_smoke, "preamble", lambda: dict(dev))
+    monkeypatch.setattr(mx, "tpu", lambda i=0: mx.cpu(0))
+    monkeypatch.setattr(
+        chip_smoke, "train_phase",
+        lambda *a, **k: (None, {"ok": True, "losses": [2.0, 1.0]}))
+    monkeypatch.setattr(chip_smoke, "serve_phase",
+                        lambda *a, **k: {"ok": True})
+    monkeypatch.setattr(chip_smoke, "decode_phase",
+                        lambda *a, **k: {"ok": True})
+    chip_smoke.main()
+    lines = capsys.readouterr().out.strip().splitlines()
+    last = json.loads(lines[-1])
+    assert last == {"ok": True, "device": dev}
+    assert type(last["device"]["count"]) is int
+    summary = json.loads(lines[-2])
+    assert set(summary["phases"]) == {"train", "kernels", "serve", "decode"}
+    assert summary["mesh"] == "not run: 1 device"
+
+
 def test_accelerator_context_raises_without_an_accelerator():
     """``mx.tpu(0)`` in a CPU-only process is an error, not the CPU; an
     honest default context is still the CPU."""
