@@ -18,24 +18,32 @@ under a canonical :class:`~.key.ProgramKey`:
 observability surface: per-program compile wall time, cache
 hit/miss/error counters, and the retrace guard — per entry point, how
 many times it recompiled and the diverging argument signature (or key
-material) that caused it. Compile/load/serialize work runs inside
-``compile::`` profiler spans so cold-start cost shows up in
-``mx.profiler`` dumps next to the ``serving::``/``ft::`` domains.
+material) that caused it. Every acquisition is a
+``compile/acquire:<program>`` span (telemetry/trace.py) with
+``compile/load``, ``compile/compile`` and ``compile/serialize`` inside,
+so cold-start cost shows up in ``mx.profiler`` dumps (``compile::``
+rows) next to the ``serving::``/``ft::`` domains and, in a traced run,
+on the profiler's clock. An entry point that stays a plain ``jax.jit``
+(``parallel.TrainStep``) reports its first call through
+:func:`jit_acquire`.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import pickle
 import threading
 import time
 import weakref
 
+from ..telemetry import trace as _trace
 from .cache import CacheEntryError, default_cache
 from .key import arg_signature  # noqa: F401  (re-export for callers)
 
-__all__ = ["ProgramRecord", "load_or_compile", "shared_programs",
-           "JitProgram", "guarded_loaded_program", "note_entry_point",
-           "get_record", "compile_report", "donation_supported", "reset"]
+__all__ = ["ProgramRecord", "load_or_compile", "jit_acquire",
+           "shared_programs", "JitProgram", "guarded_loaded_program",
+           "note_entry_point", "get_record", "compile_report",
+           "donation_supported", "reset"]
 
 logger = logging.getLogger("mxnet_tpu.compile")
 
@@ -112,11 +120,6 @@ def _restore_record(rec):
             return cur
         _records[rec.digest] = rec
         return rec
-
-
-def _span(name):
-    from .. import profiler
-    return profiler.Domain("compile").new_task(name)
 
 
 def _count(name, delta=1):
@@ -217,6 +220,11 @@ def load_or_compile(key, lower, cache=None):
     overwrites the bad entry. It can never produce a wrong program: the
     digest pins every trace input and the CRC pins the bytes.
     """
+    with _trace.span(f"acquire:{key.name}", "compile"):
+        return _load_or_compile(key, lower, cache)
+
+
+def _load_or_compile(key, lower, cache):
     rec = _ensure(key)
     if cache is None:
         cache = default_cache()
@@ -234,8 +242,7 @@ def load_or_compile(key, lower, cache=None):
     if payload is not None:
         try:
             from jax.experimental import serialize_executable
-            t0 = time.perf_counter()
-            with _span("load"):
+            with _trace.span("load", "compile") as sp:
                 blob, in_tree, out_tree, dev_ids = pickle.loads(payload)
                 # load onto the devices the program was compiled for:
                 # the default is EVERY device of the backend, and a
@@ -246,7 +253,7 @@ def load_or_compile(key, lower, cache=None):
                 exe = serialize_executable.deserialize_and_load(
                     blob, in_tree, out_tree,
                     execution_devices=[by_id[i] for i in dev_ids])
-            load_s = time.perf_counter() - t0
+            load_s = sp.dur
             rec.load_s += load_s
             rec.cache_hits += 1
             rec.source = "cache"
@@ -264,26 +271,23 @@ def load_or_compile(key, lower, cache=None):
             logger.warning(
                 "compile-cache entry %s failed to deserialize (%s); "
                 "falling back to a fresh compile", key.short, e)
-    t0 = time.perf_counter()
-    with _span("compile"):
+    with _trace.span("compile", "compile") as sp:
         lowered = lower()
         exe = lowered.compile()
-    compile_s = time.perf_counter() - t0
+    compile_s = sp.dur
     rec.compile_s += compile_s
     rec.compiles += 1
     rec.source = "compile"
     _count("compile.fresh_compiles")
     if cache is not None:
-        t0 = time.perf_counter()
+        sp = _trace.span("serialize", "compile").start()
         try:
             from jax.experimental import serialize_executable
-            with _span("serialize"):
-                blob, in_tree, out_tree = \
-                    serialize_executable.serialize(exe)
-                dev_ids = [d.id for d in exe._executable
-                           ._unloaded_executable.device_list]
-                cache.put(key, pickle.dumps(
-                    (blob, in_tree, out_tree, dev_ids)))
+            blob, in_tree, out_tree = serialize_executable.serialize(exe)
+            dev_ids = [d.id for d in exe._executable
+                       ._unloaded_executable.device_list]
+            cache.put(key, pickle.dumps(
+                (blob, in_tree, out_tree, dev_ids)))
             rec.serialized = True
         except Exception as e:
             # backends without executable serialization (or unpicklable
@@ -292,11 +296,63 @@ def load_or_compile(key, lower, cache=None):
             _count("compile.serialize_unsupported")
             logger.debug("compile-cache serialize skipped for %s: %s",
                          key.short, e)
-        rec.serialize_s += time.perf_counter() - t0
+        rec.serialize_s += sp.stop()
     _note_memory(key, rec, exe)
     _refresh_prof_counters()
     _emit_event(key, "compile", compile_s)
     return exe, "compile"
+
+
+# ---------------------------------------------------------------------------
+# plain-jit path: parallel.TrainStep
+# ---------------------------------------------------------------------------
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_JAX_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_jax_cache_events = threading.local()   # this thread's hits and misses
+_jax_cache_listening = [False]
+
+
+def _on_jax_event(event, **kwargs):
+    if event in (_JAX_CACHE_HIT, _JAX_CACHE_MISS):
+        ev = _jax_cache_events
+        ev.seen = getattr(ev, "seen", ()) + (event,)
+
+
+@contextlib.contextmanager
+def jit_acquire(name, kind, args):
+    """Report the first call of a plain ``jax.jit`` entry point — the
+    call that traces and compiles — as one program acquisition: a
+    ``compile/acquire:<name>`` span and a row in ``compile_report()``
+    keyed on the call's argument signature. It counts as loaded when
+    JAX's persistent cache reported a hit and no miss on this thread
+    meanwhile (``jax.monitoring``), as a fresh compile otherwise. The
+    program itself is not routed through the AOT cache."""
+    import jax.monitoring
+    from .key import program_key
+    with _lock:
+        if not _jax_cache_listening[0]:
+            jax.monitoring.register_event_listener(_on_jax_event)
+            _jax_cache_listening[0] = True
+    _jax_cache_events.seen = ()
+    with _trace.span(f"acquire:{name}", "compile") as sp:
+        yield
+    seen = _jax_cache_events.seen
+    sig = arg_signature(args)
+    key = program_key(kind, name, input_sigs=sig)
+    rec = _ensure(key)
+    rec.arg_sig = sig
+    if _JAX_CACHE_HIT in seen and _JAX_CACHE_MISS not in seen:
+        rec.load_s += sp.dur
+        rec.cache_hits += 1
+        rec.source = "cache"
+        _count("compile.cache_hits")
+    else:
+        rec.compile_s += sp.dur
+        rec.compiles += 1
+        rec.source = "compile"
+        _count("compile.fresh_compiles")
+    _refresh_prof_counters()
+    _emit_event(key, rec.source, sp.dur)
 
 
 def guarded_loaded_program(exe, fallback, what, on_reject=None):

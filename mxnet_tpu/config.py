@@ -324,16 +324,14 @@ register("MXTPU_TRACE_DIR", "", str,
          "spans (serving request->batch->bucket, fit step->phase) land "
          "in a bounded ring and export as Chrome trace-event JSON "
          "(trace-<pid>-NNNNN.json, loadable in Perfetto / "
-         "chrome://tracing). Empty = tracing off (zero hot-path cost)")
+         "chrome://tracing). Set, tracing is on; empty, it is on only "
+         "while a jax.profiler trace runs (spans then land in the ring "
+         "and in the profiler's trace as mx:<cat>/<name>, no file)")
 register("MXTPU_TRACE_RING", 16384, int,
          "Span capacity of the in-memory trace ring: the newest N "
          "completed spans are kept, older ones are overwritten "
          "(trace::dropped counts them) — tracing never allocates "
          "unboundedly on the hot path")
-register("MXTPU_TRACE_ANNOTATE", True, bool,
-         "Mirror trace spans as jax.profiler.TraceAnnotation while a "
-         "jax trace runs, so host spans and device timelines correlate "
-         "by name in the same profile")
 register("MXTPU_PALLAS_TILES", "", str,
          "Pallas fused-kernel output-tile override '<bm>,<bn>' "
          "(ops/pallas_fused.py): tried first by select_tiles/"

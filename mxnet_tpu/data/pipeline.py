@@ -34,12 +34,13 @@ from __future__ import annotations
 
 import copy
 import queue
-import time
 import threading
 
 import numpy as np
 
 from ..io import DataBatch, DataDesc, DataIter
+from ..telemetry import registry as _treg
+from ..telemetry import trace as _trace
 from . import workers as wk
 from .report import register_pipeline
 
@@ -218,8 +219,6 @@ class DataPipeline(DataIter):
         self._zero_stats()
         self._trace_id = None       # fit's trace (set_trace): stage
         self._trace_parent = None   # spans link to the run-root span
-        from .. import profiler
-        self._dom = profiler.Domain("data")
         register_pipeline(self)
         wk.register_closeable(self)
 
@@ -304,14 +303,16 @@ class DataPipeline(DataIter):
         self._trace_id = trace_id
         self._trace_parent = parent_id
 
-    def _trace_stage(self, name, t0, dt, **args):
-        if self._trace_id is None:
-            return
-        from ..telemetry import trace as _trace
-        _trace.record_span(f"data:{name}", "data", t0, dt,
-                           trace_id=self._trace_id,
-                           parent_id=self._trace_parent,
-                           args=args or None)
+    def _stage_span(self, name, kind="work", **args):
+        """One interval of a stage: the profiler table's
+        ``data::<name>`` row always (not for the consumer's wait, whose
+        aggregate is ``data::wait_s``), and while tracing is on the
+        ``data:<name>`` span on the trace ``fit()`` handed over."""
+        return _trace.span(
+            f"data:{name}", "data", kind=kind, trace=self._trace_id,
+            parent=self._trace_parent, args=args or None,
+            agg=False if kind == "wait"
+            else _treg.timer(f"prof::data::{name}").record)
 
     # -- stage threads ---------------------------------------------------------
     def _start_stream(self):
@@ -332,15 +333,12 @@ class DataPipeline(DataIter):
     def _source_loop(self, group, skip):
         ordinal = 0
         while not group.stopped:
-            t0 = time.perf_counter()
-            with self._dom.new_task("source"):
+            with self._stage_span("source", ordinal=ordinal) as sp:
                 try:
                     batch = self._base.next()
                 except StopIteration:
                     break
-            dt = time.perf_counter() - t0
-            self._acc("_source_busy_s", dt)
-            self._trace_stage("source", t0, dt, ordinal=ordinal)
+            self._acc("_source_busy_s", sp.dur)
             if skip > 0:       # checkpoint resume: replay to the cursor
                 skip -= 1
                 continue
@@ -368,19 +366,16 @@ class DataPipeline(DataIter):
                                      worker=widx):
                 raise faultinject.FaultInjected(
                     "data_worker", batch=ordinal + 1, worker=widx)
-            t0 = time.perf_counter()
-            if self._transform is not None:
-                with self._dom.new_task("decode"):
+            with self._stage_span("decode", ordinal=ordinal,
+                                  worker=widx) as sp:
+                if self._transform is not None:
                     batch = self._transform(batch)
-            dt = time.perf_counter() - t0
             n_items = self.batch_size or (
                 len(batch.data[0]) if batch.data else 0)
             with self._slock:
-                self._decode_busy_s += dt
+                self._decode_busy_s += sp.dur
                 self._batches_decoded += 1
                 self._items_decoded += n_items
-            self._trace_stage("decode", t0, dt, ordinal=ordinal,
-                              worker=widx)
             wk.q_put(self._q_done, (ordinal, batch), group)
 
     def _stager_loop(self, group):
@@ -416,18 +411,15 @@ class DataPipeline(DataIter):
         is never mutated."""
         if not self._stage_device:
             return batch
-        t0 = time.perf_counter()
-        with self._dom.new_task("stage"):
+        with self._stage_span("stage") as sp:
             staged = copy.copy(batch)
             if batch.data is not None:
                 staged.data = [self._put(a) for a in batch.data]
             if batch.label:
                 staged.label = [self._put(a) for a in batch.label]
-        dt = time.perf_counter() - t0
         with self._slock:
-            self._stage_busy_s += dt
+            self._stage_busy_s += sp.dur
             self._batches_staged += 1
-        self._trace_stage("stage", t0, dt)
         return staged
 
     def _put(self, arr):
@@ -436,8 +428,15 @@ class DataPipeline(DataIter):
             return arr          # raw payloads (bytes/numpy) pass through
         try:
             import jax
-            dev = jax.device_put(arr._data, self._sharding) \
-                if self._sharding is not None else jax.device_put(arr._data)
+            data, target = arr._data, self._sharding
+            if target is not None and len(target.device_set) == 1 and \
+                    data.devices() == target.device_set:
+                # already on the bind's one device: handed on as it is
+                # (a put would commit it, and every eager program that
+                # takes it afterwards would be built again)
+                return arr
+            dev = jax.device_put(data, target) \
+                if target is not None else jax.device_put(data)
             return _wrap(dev, arr._ctx)
         except Exception:
             return arr
@@ -446,27 +445,27 @@ class DataPipeline(DataIter):
     def next(self):
         if self._group is None:
             self._start_stream()
-        t0 = time.perf_counter()
-        starved = False
+        starved = None
         try:
             item = self._q_out.get_nowait()
         except queue.Empty:
-            starved = True      # consumer arrived before the pipeline
+            # consumer arrived before the pipeline: blocked from here
             item = None
-            while item is None:
-                err = self._group.error()
-                if err is not None:
-                    self._stop_stream()
-                    raise err
-                try:
-                    item = self._q_out.get(timeout=0.05)
-                except queue.Empty:
-                    continue
+            with self._stage_span("wait", kind="wait") as starved:
+                while item is None:
+                    err = self._group.error()
+                    if err is not None:
+                        self._stop_stream()
+                        raise err
+                    try:
+                        item = self._q_out.get(timeout=0.05)
+                    except queue.Empty:
+                        continue
         with self._slock:
             self._next_calls += 1
-            if starved:
+            if starved is not None:
                 self._waits += 1
-                self._wait_s += time.perf_counter() - t0
+                self._wait_s += starved.dur
         if item is _EOE:
             self._end_of_epoch()
             raise StopIteration

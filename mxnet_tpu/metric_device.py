@@ -84,7 +84,11 @@ class _DevRef:
         cur_t = f.num_update
         if cur_t == self.last_t:
             return
-        val = np.asarray(f._metric_state[self.idx])
+        # the one place fit()'s loop waits for the device: a wait phase
+        # of the step it is read in (telemetry/timeline.py)
+        from .telemetry import timeline as _tlmod
+        with _tlmod.phase("device_read"):
+            val = np.asarray(f._metric_state[self.idx])
         cur = int(val) if val.dtype.kind in "iu" else float(val)
         metric.sum_metric += cur - self.last_val
         metric.num_inst += (cur_t - self.last_t) * self.inst_per_step

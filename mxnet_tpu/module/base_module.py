@@ -289,9 +289,10 @@ class BaseModule:
 
         A :class:`~mxnet_tpu.telemetry.StepTimeline` spans the loop:
         every step's wall time is attributed across data-wait /
-        H2D-staging / compile / device-step / metric+FT-sync phases
-        (the fused step attributes its inner phases into the same
-        timeline; nesting subtracts, so nothing double-counts), and —
+        H2D-staging / compile / device-step / metric+FT-sync /
+        callbacks phases (the fused step attributes its inner phases
+        into the same timeline; nesting subtracts, so nothing
+        double-counts), each a span of ``telemetry/trace.py``, and —
         with ``MXTPU_TELEMETRY_DIR`` set — step milestones, epoch ends,
         and periodic report snapshots land in the durable event log.
         """
@@ -340,7 +341,7 @@ class BaseModule:
                 if monitor is not None:
                     monitor.tic()
                 # the outer span: the fused step's inner h2d_stage /
-                # compile / device_step phases nest inside and claim
+                # compile / dispatch phases nest inside and claim
                 # their share; the eager path books it all here
                 with tl.phase("device_step"):
                     self.forward_backward(data_batch)
@@ -360,8 +361,9 @@ class BaseModule:
                     batch_end_params = BatchEndParam(
                         epoch=epoch, nbatch=nbatch, eval_metric=eval_metric,
                         locals=locals())
-                    for callback in _as_list(batch_end_callback):
-                        callback(batch_end_params)
+                    with tl.phase("callbacks"):
+                        for callback in _as_list(batch_end_callback):
+                            callback(batch_end_params)
                 tl.step_end(epoch=epoch)
                 nbatch += 1
 

@@ -502,9 +502,9 @@ class FusedSymbolStep:
         # into the executable it would make every process's programs
         # unique (next_key() differs per run) and the persistent compile
         # cache could never hit across restarts
-        def step_fn(pvals, opt_state, flat_p, flat_state, aux_vals,
-                    flat_aux, mstate, fstate, feed_vals, t, lr,
-                    base_key):
+        def mx_fused_step(pvals, opt_state, flat_p, flat_state, aux_vals,
+                          flat_aux, mstate, fstate, feed_vals, t, lr,
+                          base_key):
             key = jax.random.fold_in(base_key, t)
 
             # zero perturbations of each site's gathered rows: the
@@ -732,12 +732,12 @@ class FusedSymbolStep:
             # zero-copy); leave graph outputs (None) to GSPMD
             out_shardings = (prep, srep, frep, fsrep, arep, farep, mrep,
                              rep, None, rep)
-            self._step_jit = jax.jit(step_fn, donate_argnums=donate,
+            self._step_jit = jax.jit(mx_fused_step, donate_argnums=donate,
                                      in_shardings=in_shardings,
                                      out_shardings=out_shardings,
                                      **jit_kw)
         else:
-            self._step_jit = jax.jit(step_fn, donate_argnums=donate,
+            self._step_jit = jax.jit(mx_fused_step, donate_argnums=donate,
                                      **jit_kw)
         self._jit_options = jit_kw.get("compiler_options")
         # compiled-program cache per feed signature: the jit above is
@@ -758,9 +758,14 @@ class FusedSymbolStep:
         pipeline's stager: batches staged with THIS sharding make
         step()'s own device_put a no-op, so the transfer fully overlaps
         the previous step instead of landing on the dispatch path.
-        None on single-device binds (plain device_put suffices)."""
+        A single-device bind names the device its state lives on: a
+        bare ``jax.device_put`` leaves an array that is committed to
+        another backend (a host-resident batch under a TPU bind) where
+        it is, and the compiled step then copies it inside every
+        dispatch (PERF.md section 6, PR 26)."""
         if self.mesh is None:
-            return None
+            leaves = jax.tree_util.tree_leaves(self._state_args())
+            return leaves[0].sharding if leaves else None
         from jax.sharding import NamedSharding, PartitionSpec as P
         return NamedSharding(self.mesh, P(self.data_axis))
 
@@ -920,8 +925,10 @@ class FusedSymbolStep:
                     tl.note_cost(flops=cost.get("flops"),
                                  bytes_accessed=cost.get("bytes accessed"))
                     self._noted_cost = (weakref.ref(tl), sig)
-        with tl.phase("device_step") if tl else _tlmod.null_phase():
-            # mesh scope so a plain-jit fallback tracing HERE still
+        with tl.phase("dispatch") if tl else _tlmod.null_phase():
+            # on the host this is the enqueue of the compiled program,
+            # inside fit()'s device_step; mesh scope so a plain-jit
+            # fallback tracing HERE still
             # shard_maps the fused kernels (no-op when already compiled
             # or off-mesh)
             from ..ops.pallas_fused import mesh_scope
@@ -1044,7 +1051,9 @@ class FusedSymbolStep:
         self._skip_lag.append(self._fault_state)
         if len(self._skip_lag) <= self._max_consec:
             return
-        consec = int(np.asarray(self._skip_lag.popleft())[1])
+        from ..telemetry import timeline as _tlmod
+        with _tlmod.phase("device_read"):
+            consec = int(np.asarray(self._skip_lag.popleft())[1])
         if consec >= self._max_consec:
             from .. import fault as _fault
             _fault.count("guard.aborts")

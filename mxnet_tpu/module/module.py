@@ -33,6 +33,7 @@ from .. import optimizer as opt
 from ..base import MXNetError
 from ..io import DataDesc
 from ..model import load_checkpoint, save_checkpoint
+from ..telemetry import trace as _trace
 from .base_module import BaseModule, _check_input_names
 
 __all__ = ["Module"]
@@ -301,6 +302,12 @@ class Module(BaseModule):
         if self.binded:
             self.logger.warning("Already binded, ignoring bind()")
             return
+        with _trace.span("bind", "setup"):
+            self._bind(data_shapes, label_shapes, for_training,
+                       inputs_need_grad, shared_module, grad_req)
+
+    def _bind(self, data_shapes, label_shapes, for_training,
+              inputs_need_grad, shared_module, grad_req):
         self.for_training = for_training
         self.inputs_need_grad = inputs_need_grad
         self._data_shapes = _norm_shapes(data_shapes)
@@ -363,6 +370,10 @@ class Module(BaseModule):
         if self.optimizer_initialized and not force_init:
             self.logger.warning("optimizer already initialized, ignoring...")
             return
+        with _trace.span("init_optimizer", "setup"):
+            self._init_optimizer(kvstore, optimizer, optimizer_params)
+
+    def _init_optimizer(self, kvstore, optimizer, optimizer_params):
         # normalize the summed batch gradient like the reference
         # (module.py:494-507: rescale_grad defaults to 1/batch_size,
         # scaled by num_workers for dist kvstore)

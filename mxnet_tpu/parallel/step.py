@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ndarray.ndarray import NDArray, _wrap
+from ..telemetry import trace as _trace
 
 __all__ = ["TrainStep", "softmax_ce_loss", "l2_loss"]
 
@@ -110,6 +111,7 @@ class TrainStep:
         self._pvals = None
         self._opt_state = None
         self._step_jit = None
+        self._trace_id = None    # every call's span is on one trace
         # declarative alternative to param_spec_fn: regex -> PartitionSpec
         # rules (parallel/partition.py). Explicit param_spec_fn wins; with
         # neither, rules come from MXTPU_PARTITION_RULES.
@@ -187,7 +189,7 @@ class TrainStep:
         from .. import random as _random_mod
         base_key = _random_mod.next_key()
 
-        def step_fn(pvals, opt_state, x, y, t, lr):
+        def mx_train_step(pvals, opt_state, x, y, t, lr):
             key = jax.random.fold_in(base_key, t)
             if preprocess is not None:
                 x = preprocess(x)
@@ -261,53 +263,82 @@ class TrainStep:
             # different sharding for the updated params, forcing a reshard
             # of every parameter on every step's input boundary
             out_shardings = (pshard, sshard, rep, rep)
-            self._step_jit = jax.jit(step_fn, donate_argnums=donate,
+            self._step_jit = jax.jit(mx_train_step, donate_argnums=donate,
                                      in_shardings=in_shardings,
                                      out_shardings=out_shardings)
         else:
-            self._step_jit = jax.jit(step_fn, donate_argnums=donate)
+            self._step_jit = jax.jit(mx_train_step, donate_argnums=donate)
 
     # -- public ---------------------------------------------------------------
     def __call__(self, x, y):
-        if self._pvals is None:
-            # ensure deferred params are materialized (one eager fwd if needed)
-            try:
-                for p in self.param_list:
-                    p._check_and_get()
-            except Exception:
-                import numpy as _np
-                from .. import autograd as _ag
-                xa = x._data if isinstance(x, NDArray) else jnp.asarray(x)
-                xa1 = xa[:1]
-                if self.preprocess is not None:
-                    # the eager materialization forward must see the same
-                    # dtype/layout the compiled step computes on
-                    xa1 = self.preprocess(xa1)
-                with _ag.train_mode():
-                    self.net.forward(_wrap(xa1))
-                self.param_list = self.net._get_param_list()
-                self._trainable = [p.grad_req != "null"
-                                   for p in self.param_list]
-            self._init_state()
-        if self._step_jit is None:
-            self._build_step()
+        # tracing is asked for once a call; the call and what it holds
+        # are spans of telemetry/trace.py, every call on one trace
+        on = _trace.enabled()
+        if on and self._trace_id is None:
+            self._trace_id = _trace.new_trace_id()
+        with _trace.span("step", "step", trace=self._trace_id, on=on,
+                         args={"step": self._num_update + 1}):
+            return self._call(x, y, on)
+
+    def _call(self, x, y, on):
+        first = self._step_jit is None
+        if first:
+            with _trace.span("compile", "step", on=on):
+                if self._pvals is None:
+                    self._materialize(x)
+                    self._init_state()
+                self._build_step()
         xa = x._data if isinstance(x, NDArray) else jnp.asarray(x)
         ya = y._data if isinstance(y, NDArray) else jnp.asarray(y)
         if self.mesh is not None:
-            batch = NamedSharding(self.mesh, P(self.data_axis))
-            xa = jax.device_put(xa, batch)
-            ya = jax.device_put(ya, batch)
+            with _trace.span("h2d_stage", "step", on=on):
+                batch = NamedSharding(self.mesh, P(self.data_axis))
+                xa = jax.device_put(xa, batch)
+                ya = jax.device_put(ya, batch)
         lr = self.lr if self.lr_schedule is None \
             else self.lr_schedule(self._num_update)
         # cache the lr device scalar (it changes rarely; shipping a fresh
         # host scalar per step costs a transfer round trip)
         if self._lr_cache is None or self._lr_cache[0] != lr:
             self._lr_cache = (lr, jnp.asarray(lr, jnp.float32))
-        self._pvals, self._opt_state, self._t_dev, loss = self._step_jit(
-            self._pvals, self._opt_state, xa, ya, self._t_dev,
-            self._lr_cache[1])
+        args = (self._pvals, self._opt_state, xa, ya, self._t_dev,
+                self._lr_cache[1])
+        if first:
+            # the first call traces and compiles, or loads from JAX's
+            # persistent cache: a plain jax.jit, so the compile registry
+            # is told of the acquisition, it does not make it
+            from ..compile import registry as _creg
+            with _trace.span("compile", "step", on=on), \
+                    _creg.jit_acquire("mx_train_step", "train_step", args):
+                out = self._step_jit(*args)
+        else:
+            # on the host an enqueue; once the runtime's limit of
+            # executions in flight is reached it blocks for a device step
+            with _trace.span("dispatch", "step", on=on):
+                out = self._step_jit(*args)
+        self._pvals, self._opt_state, self._t_dev, loss = out
         self._num_update += 1
         return _wrap(loss)
+
+    def _materialize(self, x):
+        """Ensure deferred params are materialized (one eager forward
+        if needed)."""
+        try:
+            for p in self.param_list:
+                p._check_and_get()
+        except Exception:
+            from .. import autograd as _ag
+            xa = x._data if isinstance(x, NDArray) else jnp.asarray(x)
+            xa1 = xa[:1]
+            if self.preprocess is not None:
+                # the eager materialization forward must see the same
+                # dtype/layout the compiled step computes on
+                xa1 = self.preprocess(xa1)
+            with _ag.train_mode():
+                self.net.forward(_wrap(xa1))
+            self.param_list = self.net._get_param_list()
+            self._trainable = [p.grad_req != "null"
+                               for p in self.param_list]
 
     def sync_params(self):
         """Write the step's parameter buffers back into the net's Parameters

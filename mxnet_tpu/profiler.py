@@ -36,6 +36,7 @@ import time
 from typing import Dict, Optional
 
 from .telemetry import registry as _treg
+from .telemetry import trace as _ttrace
 
 __all__ = ["set_config", "set_state", "dump", "dumps", "pause", "resume",
            "state", "counters", "Domain", "Task", "Frame", "Event",
@@ -252,58 +253,28 @@ class Domain:
         return self.name
 
 
-_TRACE_ANN = None          # resolved jax.profiler.TraceAnnotation class
-
-
-def _trace_annotation_cls():
-    """Resolve (once) the TraceAnnotation class. Spans run in serving's
-    per-micro-batch hot loop, so the import + attribute walk must not
-    repeat per call; ``False`` caches a failed resolution."""
-    global _TRACE_ANN
-    if _TRACE_ANN is None:
-        try:
-            import jax
-            _TRACE_ANN = jax.profiler.TraceAnnotation
-        except Exception:
-            _TRACE_ANN = False
-    return _TRACE_ANN or None
-
-
 class _Span:
-    """start()/stop() span recorded into the aggregate table and, when a
-    jax trace is running, as a TraceAnnotation on the device timeline."""
+    """start()/stop() facade over the span primitive
+    (telemetry/trace.py): the aggregate table's ``<domain>::<name>`` row
+    always, and while tracing is on the trace ring and a
+    ``mx:<domain>/<name>`` TraceAnnotation on the profiler's clock."""
 
     def __init__(self, domain, name):
         self.domain = domain
         self.name = name
-        self._t0 = None
-        self._ann = None
-        self._timer = None     # registry handle, resolved at first stop
+        self._span = None
 
     def start(self):
-        self._t0 = time.perf_counter()
-        cls = _trace_annotation_cls()
-        if cls is not None:
-            try:
-                self._ann = cls(f"{self.domain}::{self.name}")
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
-        else:
-            self._ann = None
+        # scope=False: the reference API lets a task stop out of order
+        # or on another thread, so it is never another span's parent
+        self._span = _ttrace.span(self.name, str(self.domain),
+                                  scope=False).start()
         return self
 
     def stop(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
-        if self._t0 is not None:
-            dt = time.perf_counter() - self._t0
-            if self._timer is None:
-                self._timer = _treg.timer(
-                    f"{_PROF}{self.domain}::{self.name}")
-            self._timer.record(dt)
-            self._t0 = None
+        if self._span is not None:
+            self._span.stop()
+            self._span = None
         return self
 
     def __enter__(self):

@@ -235,7 +235,7 @@ class Predictor:
         self._hoist_keys = hoist_keys
         self._live_aux_names = live_aux_names
 
-        def infer_fn(pvals_t, data_vals, avals, hvals):
+        def mx_predict(pvals_t, data_vals, avals, hvals):
             amap = dict(zip(pval_names, pvals_t))
             amap.update(zip(live_aux_names, avals))
             bsz = data_vals[0].shape[0]
@@ -260,7 +260,7 @@ class Predictor:
         # backend warning)
         donate = {"donate_argnums": (1,)} \
             if compile_mod.donation_supported() else {}
-        self._infer_jit = jax.jit(infer_fn, **donate)
+        self._infer_jit = jax.jit(mx_predict, **donate)
         self._donate = bool(donate)
         self._programs = {}     # (bucket, dtypes) -> compiled program
         self._program_costs = {}  # (bucket, dtypes) -> XLA cost dict

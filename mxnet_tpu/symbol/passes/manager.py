@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ... import config
 from ...telemetry import registry as _treg
+from ...telemetry import trace as _trace
 from .base import GraphPass, PassContext, flag_active
 
 __all__ = ["PassManager", "default_manager", "apply_pipeline",
@@ -158,8 +159,14 @@ def measure_symbol_bytes(sym, shapes, mode="train", data_names=None,
         with _LOCK:
             if key in _MEASURE_MEMO:
                 return _MEASURE_MEMO[key]
-    val = _measure(sym, shapes, kind, data_names, mesh=mesh,
-                   batch_names=batch_names, data_axis=data_axis)
+    with _trace.span("lower", "compile"):
+        lowered = _lower_proxy(sym, shapes, kind, data_names, mesh=mesh,
+                               batch_names=batch_names, data_axis=data_axis)
+    val = None
+    if lowered is not None:
+        with _trace.span("compile", "compile"):
+            compiled = lowered.compile()
+        val = _bytes_accessed(compiled)
     if key is not None:
         with _LOCK:
             if len(_MEASURE_MEMO) >= _MEASURE_MEMO_MAX:
@@ -192,8 +199,10 @@ def _integer_feed_names(sym):
     return names
 
 
-def _measure(sym, shapes, kind, data_names=None, mesh=None,
-             batch_names=None, data_axis="data"):
+def _lower_proxy(sym, shapes, kind, data_names=None, mesh=None,
+                 batch_names=None, data_axis="data"):
+    """The program proxy of :func:`measure_symbol_bytes`, lowered; None
+    where a shape is missing."""
     import numpy as np
     import jax
     from ...executor import build_graph_fns
@@ -280,7 +289,11 @@ def _measure(sym, shapes, kind, data_names=None, mesh=None,
         else:
             lowered = jax.jit(fn).lower(arg_s, aux_s,
                                         jax.random.PRNGKey(0))
-    cost = lowered.compile().cost_analysis()
+    return lowered
+
+
+def _bytes_accessed(compiled):
+    cost = compiled.cost_analysis()
     if isinstance(cost, (list, tuple)):
         cost = cost[0] if cost else {}
     cost = dict(cost) if cost else {}
@@ -353,7 +366,8 @@ class PassManager:
                 continue
             # a pass that throws fails the bind: carrying on with the
             # unrewritten graph would report a program nobody asked for
-            new_sym, prep = p.apply(cur, shapes, ctx)
+            with _trace.span(f"apply:{p.name}", "pass"):
+                new_sym, prep = p.apply(cur, shapes, ctx)
             entry["sites"] = list(prep.get("sites", ()))
             entry["bailouts"] = list(prep.get("bailouts", ()))
             if new_sym is None or not entry["sites"]:
@@ -371,18 +385,22 @@ class PassManager:
             measure = gate == "1" or (gate not in ("0", "false", "off")
                                       and flag == "auto")
             if measure:
-                if cur_bytes is None:
-                    cur_bytes = measure_symbol_bytes(
-                        cur, shapes, mode, data_names=ctx.data_names,
+                # the gate: the program before (once a pipeline) and
+                # after this pass, each lowered and compiled for its
+                # bytes
+                with _trace.span(f"gate:{p.name}", "pass"):
+                    if cur_bytes is None:
+                        cur_bytes = measure_symbol_bytes(
+                            cur, shapes, mode, data_names=ctx.data_names,
+                            mesh=mesh, batch_names=ctx.batch_names,
+                            data_axis=ctx.data_axis)
+                        if report["baseline_bytes"] is None:
+                            report["baseline_bytes"] = cur_bytes
+                    new_bytes = measure_symbol_bytes(
+                        new_sym, shapes, mode, data_names=ctx.data_names,
                         mesh=mesh, batch_names=ctx.batch_names,
-                        data_axis=ctx.data_axis)
-                    if report["baseline_bytes"] is None:
-                        report["baseline_bytes"] = cur_bytes
-                new_bytes = measure_symbol_bytes(
-                    new_sym, shapes, mode, data_names=ctx.data_names,
-                    mesh=mesh, batch_names=ctx.batch_names,
-                    data_axis=ctx.data_axis) \
-                    if cur_bytes is not None else None
+                        data_axis=ctx.data_axis) \
+                        if cur_bytes is not None else None
                 if cur_bytes is None or new_bytes is None:
                     _treg.counter("passes::unmeasured").inc()
                 else:

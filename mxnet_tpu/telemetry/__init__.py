@@ -12,20 +12,23 @@ The observability layer everything reports into (``mx.telemetry``):
   strict superset of all of them (pinned in tests/test_telemetry.py).
 - **timeline.py** — :class:`StepTimeline`: ``fit()`` attributes every
   step's wall time across data-wait / H2D / compile / device-step /
-  metric-sync phases, and the fused step records XLA cost-analysis
-  bytes-accessed from the already-compiled program — live
-  arithmetic-intensity and roofline-fraction gauges for the
-  bandwidth-bound regime (ROADMAP item 2's currency).
+  metric-sync / callbacks phases, each a span of trace.py, and the
+  fused step records XLA cost-analysis bytes-accessed from the
+  already-compiled program — the live bytes, flops and
+  arithmetic-intensity gauges (counts XLA gives; no time enters them).
 - **export.py** — with ``MXTPU_TELEMETRY_DIR`` set: rotating JSONL
   event log (train-step milestones, serving batches, checkpoint and
   compile-cache events), periodic atomic report snapshots, and a
   Prometheus-style text rendering. ``tools/telemetry.py`` tails,
   summarizes, and diffs the exports; ``diff --gate-bytes`` is the
   reusable bytes-accessed regression gate.
-- **trace.py** (round 14) — structured host tracing: spans with
-  trace/span ids in a bounded ring, propagated serving request ->
-  batch -> bucket and fit step -> pipeline stage -> step phase,
-  exported as Chrome trace-event JSON under ``MXTPU_TRACE_DIR``.
+- **trace.py** (round 14) — the span primitive: one clock read per
+  interval feeds its registry aggregate always and, while tracing is on
+  (``MXTPU_TRACE_DIR`` set or a ``jax.profiler`` trace running), a
+  bounded ring of spans with trace/span/parent ids (serving request ->
+  batch -> bucket; fit -> step -> phase -> pipeline stage) and a
+  ``mx:<cat>/<name>`` annotation on the profiler's clock; the ring
+  exports as Chrome trace-event JSON under ``MXTPU_TRACE_DIR``.
 - **memory.py** (round 14) — per-program HBM accounting read off every
   compiled executable's ``memory_analysis()``: ``mx.memory_report()``,
   ``mem::`` gauges, and the ``--gate-peak-mem`` CI gate's input.

@@ -329,7 +329,7 @@ def report(reset=False, subsystems=None):
       superset of the six legacy ``*_report()`` surfaces),
     - ``metrics``: the flat registry snapshot (``subsystem::name`` ->
       values), including the ``step::`` StepTimeline phases and
-      roofline gauges.
+      cost gauges.
 
     ``reset=True`` clears both layers. The flat ``metrics`` snapshot is
     taken FIRST, in one atomic read-and-clear — it is the layer

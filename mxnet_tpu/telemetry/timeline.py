@@ -9,21 +9,25 @@ per step* — not FLOPs. The timeline attributes both:
   each step's wall time splits across ``data_wait`` (blocked on the
   host input pipeline), ``h2d_stage`` (device_put of the feed),
   ``compile`` (program acquisition — trace/compile or AOT load),
-  ``device_step`` (the compiled program call), ``metric_ft_sync``
-  (metric update + fault-guard bookkeeping), with the remainder
+  ``device_step`` (forward_backward + update; inside it the fused
+  step's ``dispatch``, the enqueue of the compiled program),
+  ``metric_ft_sync`` (metric update + fault-guard bookkeeping),
+  ``callbacks`` (the batch-end callbacks) and ``device_read`` (a wait:
+  the loop blocked on a value from the device), with the remainder
   reported honestly as ``unattributed``. Phases NEST: an inner phase's
   time is subtracted from its enclosing phase's self-time, so the
   self-times sum to (at most) the step wall time by construction —
   the fused step attributes its h2d/compile/dispatch from *inside*
   ``fit()``'s outer ``device_step`` span without double counting.
+  The run, every step and every phase are spans of the one primitive
+  (trace.py): the timeline reads no clock of its own.
 - **Byte attribution**: the fused step records XLA cost-analysis
   ``bytes accessed`` / ``flops`` from the *already compiled* program
   (no second compile) into ``step::bytes_accessed`` / ``step::flops``
-  gauges, and the timeline derives the live ``step::arithmetic_
-  intensity_flop_b`` and ``step::roofline_fraction`` gauges — the
-  measured-objective posture of the fusion pass (r6's "strictly fewer
-  bytes" pin), generalized into gauges every run exports and
-  ``tools/telemetry.py diff --gate-bytes`` can gate on.
+  gauges and the ``step::arithmetic_intensity_flop_b`` gauge derived
+  from them — the measured-objective posture of the fusion pass (r6's
+  "strictly fewer bytes" pin), generalized into gauges every run
+  exports and ``tools/telemetry.py diff --gate-bytes`` can gate on.
 
 Everything lands in the telemetry registry under ``step::`` (histograms
 ``step::wall_s``, ``step::phase::<name>_s``) and, when
@@ -33,20 +37,20 @@ periodic snapshots through the durable exporter (export.py).
 from __future__ import annotations
 
 import threading
-import time
 
 from . import registry
 from . import trace as _trace
 
-__all__ = ["StepTimeline", "current", "null_phase", "peak_hbm_bytes_s",
-           "set_step_cost", "PHASES"]
+__all__ = ["StepTimeline", "current", "phase", "null_phase",
+           "peak_hbm_bytes_s", "set_step_cost", "PHASES"]
 
-PHASES = ("data_wait", "h2d_stage", "compile", "device_step",
-          "metric_ft_sync")
+PHASES = ("data_wait", "h2d_stage", "compile", "device_step", "dispatch",
+          "metric_ft_sync", "callbacks", "device_read")
+# the phases in which this thread is blocked: their spans' kind
+_WAITS = frozenset({"data_wait", "device_read"})
 
-# HBM GB/s per chip (public spec sheets) — the roofline denominator.
-# bench.py reads this table through peak_hbm_bytes_s so the bench and
-# the live gauges can never disagree on the peak.
+# HBM GB/s per chip (public spec sheets); chip_smoke.py and bench.py
+# read this table through peak_hbm_bytes_s.
 _PEAK_HBM_GBS = {
     "TPU v5 lite": 819.0,
     "TPU v5e": 819.0,
@@ -61,8 +65,7 @@ _PEAK_HBM_GBS = {
 
 def peak_hbm_bytes_s(device=None) -> float:
     """Peak HBM bytes/s for ``device`` (default: jax.devices()[0]);
-    0.0 when unknown (e.g. the CPU proxy — roofline gauges stay unset
-    there rather than reporting a fiction)."""
+    0.0 when unknown (e.g. the CPU proxy)."""
     if device is None:
         try:
             import jax
@@ -154,6 +157,13 @@ def current():
     return None
 
 
+def phase(name):
+    """``current().phase(name)``, or the no-op phase on a thread that
+    runs no timeline: for a site that opens one phase."""
+    tl = current()
+    return tl.phase(name) if tl is not None else _NULL
+
+
 class StepTimeline:
     """Per-step wall-time attribution for one training run.
 
@@ -177,15 +187,11 @@ class StepTimeline:
     the named phases cover >= 90% of the wall on the CPU proxy.
     """
 
-    def __init__(self, name="train", hbm_peak_bytes_s=None):
+    def __init__(self, name="train"):
         self.name = name
         self.steps = 0
-        self._stack = []        # open spans: [name, t_enter, child_s]
+        self._stack = []        # open phases: [name, span, child_s]
         self._acc = {}          # this step's per-phase self seconds
-        self._t_step = None
-        self._wall_avg = None   # EWMA of step wall seconds
-        self._hbm = peak_hbm_bytes_s() if hbm_peak_bytes_s is None \
-            else float(hbm_peak_bytes_s)
         self._flops = None
         self._bytes = None
         self._phases = {}       # name -> _Phase (reused, no per-step alloc)
@@ -197,17 +203,14 @@ class StepTimeline:
         self._snapshot_every = int(
             config.get("MXTPU_TELEMETRY_SNAPSHOT_STEPS"))
         self._snap_thread = None
-        # structured tracing (telemetry/trace.py): the timeline IS the
-        # phase measurement, so trace spans are recorded FROM the
-        # _enter/_exit bookkeeping below — same perf_counter reads,
-        # never a second clock. All of it is off unless MXTPU_TRACE_DIR
-        # is set (checked once per step, not per phase).
+        # the run, its steps and their phases are spans of the one
+        # primitive (telemetry/trace.py) on one trace, each the child
+        # of the innermost span open on this thread. Whether tracing is
+        # on is asked once per step, not per phase
         self._trace_on = False
         self._trace_id = None    # one trace per run (fit/epoch loop)
-        self._root_span = None   # the run-root span id ("fit:<name>")
-        self._step_span = None   # current step's span id
-        self._t_activate = None
-        self._t_step0 = None
+        self._root = None        # the run's span ("fit:<name>")
+        self._step = None        # the open step's span
 
     # -- lifecycle ------------------------------------------------------------
     def activate(self):
@@ -216,11 +219,10 @@ class StepTimeline:
         global _current, _current_tid
         _current = self
         _current_tid = threading.get_ident()
-        self._t_activate = time.perf_counter()
         self._trace_on = _trace.enabled()
-        if self._trace_on and self._trace_id is None:
-            self._trace_id = _trace.new_trace_id()
-            self._root_span = _trace.new_span_id()
+        self._root = _trace.span(self.name, "train", agg=False,
+                                 on=self._trace_on).start()
+        self._trace_id = self._root.trace_id
         return self
 
     @property
@@ -231,7 +233,7 @@ class StepTimeline:
 
     @property
     def root_span_id(self):
-        return self._root_span
+        return self._root.span_id if self._root is not None else None
 
     def close(self):
         """Deactivate; flush a final snapshot + event when exporting."""
@@ -239,13 +241,15 @@ class StepTimeline:
         if _current is self:
             _current = None
             _current_tid = None
-        if self._trace_id is not None and self._t_activate is not None:
-            _trace.record_span(
-                self.name, "train", self._t_activate,
-                time.perf_counter() - self._t_activate,
-                trace_id=self._trace_id, span_id=self._root_span,
-                args={"steps": self.steps})
-            self._t_activate = None
+        if self._step is not None:
+            # the loop raised inside a step: its span must not stay
+            # open on this thread's stack
+            self._step.stop()
+            self._step = None
+        if self._root is not None:
+            self._root.args = {"steps": self.steps}
+            self._root.stop()
+            self._root = None
         if _trace.enabled():
             _trace.export_trace()
         from . import export
@@ -264,24 +268,20 @@ class StepTimeline:
         return p
 
     def _enter(self, name):
-        sid = _trace.new_span_id() if self._trace_on else None
-        self._stack.append([name, time.perf_counter(), 0.0, sid])
+        sp = _trace.span(name, "step",
+                         kind="wait" if name in _WAITS else "work",
+                         trace=self._trace_id, agg=False,
+                         on=self._trace_on).start()
+        self._stack.append([name, sp, 0.0])
 
     def _exit(self):
         if not self._stack:      # defensive: never raise out of a step
             return
-        name, t0, child, sid = self._stack.pop()
-        dur = time.perf_counter() - t0
+        name, sp, child = self._stack.pop()
+        dur = sp.stop()
         self._acc[name] = self._acc.get(name, 0.0) + max(0.0, dur - child)
         if self._stack:
             self._stack[-1][2] += dur
-        if sid is not None:
-            # the phase record IS the trace span — same t0/dur, one
-            # ring append, no I/O
-            parent = self._stack[-1][3] if self._stack else self._step_span
-            _trace.record_span(name, "step", t0, dur,
-                               trace_id=self._trace_id, span_id=sid,
-                               parent_id=parent or self._root_span)
 
     # -- steps ----------------------------------------------------------------
     def step_start(self):
@@ -290,19 +290,18 @@ class StepTimeline:
         epoch-start batch fetch so that (often epoch-heaviest) data
         wait is attributed to the first step rather than discarded —
         the loop's per-batch step_start then must not reset it."""
-        if self._t_step is not None:
+        if self._step is not None:
             return
         self._trace_on = _trace.enabled()
-        if self._trace_on:
-            if self._trace_id is None:
-                self._trace_id = _trace.new_trace_id()
-                self._root_span = _trace.new_span_id()
-            self._step_span = _trace.new_span_id()
-        else:
-            self._step_span = None
-        self._t_step = self._t_step0 = time.perf_counter()
         self._acc = {}
         self._stack = []
+        self._step = _trace.span("step", "step", trace=self._trace_id,
+                                 args={"step": self.steps + 1},
+                                 agg=False, on=self._trace_on).start()
+        if self._trace_id is None:
+            # tracing came on after activate(): the run's later steps
+            # share the first traced step's trace
+            self._trace_id = self._step.trace_id
 
     def note_cost(self, flops=None, bytes_accessed=None):
         """Record the compiled step program's XLA cost analysis (called
@@ -319,20 +318,12 @@ class StepTimeline:
             set_step_cost(flops=self._flops, bytes_accessed=self._bytes)
 
     def step_end(self, **event_fields):
-        """Close one step: record wall + per-phase histograms, refresh
-        the roofline gauges, and (exporter on) emit milestone events /
-        periodic snapshots."""
-        if self._t_step is None:
+        """Close one step: record wall + per-phase histograms, and
+        (exporter on) emit milestone events / periodic snapshots."""
+        if self._step is None:
             return None
-        wall = time.perf_counter() - self._t_step
-        self._t_step = None
-        if self._step_span is not None:
-            _trace.record_span("step", "step", self._t_step0, wall,
-                               trace_id=self._trace_id,
-                               span_id=self._step_span,
-                               parent_id=self._root_span,
-                               args={"step": self.steps + 1})
-            self._step_span = None
+        wall = self._step.stop()
+        self._step = None
         self.steps += 1
         self._steps_c.inc()
         self._wall_h.observe(wall)
@@ -342,13 +333,6 @@ class StepTimeline:
             attributed += secs
         registry.histogram("step::phase::unattributed_s").observe(
             max(0.0, wall - attributed))
-        # live roofline: bytes moved per second of measured step time,
-        # over the chip's peak HBM rate (EWMA smooths dispatch jitter)
-        self._wall_avg = wall if self._wall_avg is None else \
-            0.9 * self._wall_avg + 0.1 * wall
-        if self._bytes and self._hbm and self._wall_avg:
-            registry.gauge("step::roofline_fraction").set(
-                (self._bytes / self._hbm) / self._wall_avg)
         from . import export
         if export.enabled():
             if self.steps == 1 or self.steps % self._event_every == 0:

@@ -130,6 +130,63 @@ def test_fit_params_bit_identical_pipeline_on_vs_off():
     assert not _pipeline_threads(), "fit must close the pipeline it made"
 
 
+# -- staging on a single-device bind ------------------------------------------
+def test_single_device_bind_names_its_device_for_the_stager():
+    """fit()'s stager learns where the state lives: a bare device_put
+    would leave a batch committed to another backend where it is, and
+    the step would copy it inside every dispatch."""
+    import jax
+    net = mx.sym.SoftmaxOutput(
+        mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=2,
+                              name="fc"), name="softmax")
+    mod = mx.mod.Module(net, context=mx.cpu(), fused=True)
+    mod.bind(data_shapes=[("data", (8, 10))],
+             label_shapes=[("softmax_label", (8,))])
+    mod.init_params(mx.init.Xavier())
+    mod.init_optimizer(optimizer="sgd")
+    assert mod._fused is not None and mod._fused.mesh is None
+    home = mod._fused._pvals[0].devices()
+    assert len(home) == 1
+    sharding = mod._fused.staging_sharding()
+    assert sharding.device_set == home
+    it = mx.io.NDArrayIter(np.zeros((16, 10), np.float32),
+                           np.zeros((16,), np.float32), 8)
+    from mxnet_tpu.data import maybe_wrap_for_fit
+    pipe, owned = maybe_wrap_for_fit(it, mod)
+    try:
+        assert pipe is owned and pipe._sharding.device_set == home
+        # a batch that lives elsewhere is staged to the bind's device
+        (away,) = [d for d in jax.devices() if d not in home][:1]
+        off = mx.nd.NDArray(jax.device_put(np.ones((8, 10), np.float32),
+                                           away))
+        assert pipe._put(off)._data.devices() == home
+    finally:
+        pipe.close()
+
+
+def test_stager_hands_on_a_batch_already_on_the_device():
+    """A batch that is where the bind is goes through untouched: a put
+    would commit it, and the eager programs that take it afterwards
+    (the benchmark's device-resident ring) would be built again."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    here, there = jax.devices()[0], jax.devices()[1]
+    pipe = DataPipeline(mx.io.NDArrayIter(np.zeros((8, 4), np.float32),
+                                          None, 8),
+                        sharding=SingleDeviceSharding(here), name="asis")
+    try:
+        on = mx.nd.NDArray(jax.device_put(np.ones((8, 4), np.float32),
+                                          here))
+        off = mx.nd.NDArray(jax.device_put(np.ones((8, 4), np.float32),
+                                           there))
+        assert pipe._put(on) is on
+        moved = pipe._put(off)
+        assert moved is not off and moved._data.devices() == {here}
+        np.testing.assert_array_equal(moved.asnumpy(), off.asnumpy())
+    finally:
+        pipe.close()
+
+
 # -- overlap / observability --------------------------------------------------
 class _SlowSource(mx.io.DataIter):
     """Deterministic iterator with a real per-batch production cost."""

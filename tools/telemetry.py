@@ -191,8 +191,7 @@ def summarize(directory):
             metrics = snap.get("metrics", {})
             headline = {}
             for key in (BYTES_METRIC, "step::flops",
-                        "step::arithmetic_intensity_flop_b",
-                        "step::roofline_fraction"):
+                        "step::arithmetic_intensity_flop_b"):
                 m = metrics.get(key)
                 if m is not None:
                     headline[key] = m.get("value")
