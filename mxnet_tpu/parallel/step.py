@@ -28,11 +28,23 @@ __all__ = ["TrainStep", "softmax_ce_loss", "l2_loss"]
 
 def softmax_ce_loss(logits, labels):
     """Mean softmax cross entropy with integer labels (the train_imagenet
-    objective; reference op: SoftmaxOutput src/operator/softmax_output.cc)."""
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    picked = jnp.take_along_axis(
-        logp, labels.astype(jnp.int32)[:, None], axis=-1)
-    return -jnp.mean(picked)
+    objective; reference op: SoftmaxOutput src/operator/softmax_output.cc).
+
+    ``logits`` is ``(rows, classes)``, ``labels`` is ``(rows,)`` with every
+    value in ``[0, classes)``; the loss is computed in float32. The label's
+    log-probability is picked by a compare against an iota and a masked row
+    sum, not by a gather: a gather wants the logits row-major and
+    materialised, a reduction fuses into a pass that already reads them
+    where the projection left them. A label outside ``[0, classes)`` picks
+    nothing: that row's loss is the log-sum-exp of its logits less their
+    maximum, and its gradient the softmax (a gather gave NaN past the end).
+    """
+    x = logits.astype(jnp.float32)
+    s = x - jax.lax.stop_gradient(jnp.max(x, axis=-1, keepdims=True))
+    lse = jnp.log(jnp.sum(jnp.exp(s), axis=-1))
+    hit = jnp.arange(s.shape[-1]) == labels.astype(jnp.int32)[:, None]
+    picked = jnp.sum(jnp.where(hit, s, 0.0), axis=-1)
+    return jnp.mean(lse - picked)
 
 
 def l2_loss(pred, target):
