@@ -300,6 +300,18 @@ class _TraceState:
         return getattr(_TraceState._current, "value", None)
 
 
+class remat_units:
+    """While active, blocks marked ``_remat_unit`` checkpoint their call
+    inside a staged forward (``TrainStep(remat="layer")``)."""
+
+    def __enter__(self):
+        self._prev = getattr(_TraceState._current, "remat_units", False)
+        _TraceState._current.remat_units = True
+
+    def __exit__(self, *exc):
+        _TraceState._current.remat_units = self._prev
+
+
 def stateful_write(param, value):
     """Write an NDArray/array into a Parameter, trace-aware.
 
@@ -385,13 +397,48 @@ class HybridBlock(Block):
             if p._deferred_init:
                 p._finish_deferred_init()
 
+    #: a block that sets this is one unit of recomputation: while a
+    #: staged forward is traced under ``remat_units()`` its call runs
+    #: under ``jax.checkpoint``, so the backward pass keeps the unit's
+    #: inputs and recomputes its insides
+    _remat_unit = False
+
     def __call__(self, *args):
         from ..symbol.symbol import Symbol as _Sym
         if args and isinstance(args[0], _Sym):
             return self.forward(*args)
         if self._active and _TraceState.active() is None:
             return self._call_cached(*args)
+        if self._remat_unit and _TraceState.active() is not None \
+                and getattr(_TraceState._current, "remat_units", False):
+            return self._call_remat(*args)
         return self.forward(*args)
+
+    def _call_remat(self, *args):
+        """``forward`` under ``jax.checkpoint``. Parameter writes made
+        inside (``stateful_write``) leave the checkpointed function as
+        outputs and are handed to the trace around it."""
+        import jax
+        outer = _TraceState.active()
+        seen = {}
+
+        def unit(*arrays):
+            inner = _TraceState()
+            _TraceState._current.value = inner
+            try:
+                out = self.forward(*[_wrap(a) for a in arrays])
+            finally:
+                _TraceState._current.value = outer
+            outs = out if isinstance(out, tuple) else (out,)
+            seen["params"] = list(inner.writes)
+            seen["tuple"] = isinstance(out, tuple)
+            return tuple(o._data for o in outs), tuple(inner.writes.values())
+
+        outs, writes = jax.checkpoint(unit)(*[a._data for a in args])
+        for p, w in zip(seen["params"], writes):
+            outer.writes[p] = w
+        outs = tuple(_wrap(o) for o in outs)
+        return outs if seen["tuple"] else outs[0]
 
     def forward(self, x, *args):
         """Gather this block's params and defer to ``hybrid_forward``

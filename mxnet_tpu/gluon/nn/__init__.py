@@ -2,8 +2,9 @@
 from .activations import *  # noqa: F401,F403
 from .basic_layers import *  # noqa: F401,F403
 from .conv_layers import *  # noqa: F401,F403
+from .seq_layers import *  # noqa: F401,F403
 
-from . import activations, basic_layers, conv_layers
+from . import activations, basic_layers, conv_layers, seq_layers
 
 __all__ = (activations.__all__ + basic_layers.__all__ +  # noqa: F405
-           conv_layers.__all__)  # noqa: F405
+           conv_layers.__all__ + seq_layers.__all__)  # noqa: F405

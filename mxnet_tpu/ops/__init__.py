@@ -11,6 +11,7 @@ from . import reduce  # noqa: F401
 from . import shape_ops  # noqa: F401
 from . import creation  # noqa: F401
 from . import nn  # noqa: F401
+from . import seq  # noqa: F401
 from . import random_ops  # noqa: F401
 from . import linalg  # noqa: F401
 from . import optimizer_ops  # noqa: F401

@@ -1,0 +1,358 @@
+"""Sequence-model operators: RMSNorm, the Mamba-2 mixer (the chunked
+state-space scan, SSD), a latent mixture of experts that is told which
+experts it holds, and causal grouped-query attention in blocks.
+
+Every op here is one chip's share of a layer: it is told how many heads
+and groups it holds and which experts, computes with what it holds, and
+returns its partial result. No code stands in for the chips that hold the
+rest; ``tests/test_seq_ops.py`` adds the shares up to the uncut layer.
+
+The step's device time is a function of shapes alone: the expert layer's
+receive buffer is static and every row of it is computed, filled or not.
+
+Named scopes (``mx_ssd_*``, ``mx_moe_*``, ``mx_attn_*``) mark each
+mechanism in the compiled program; ``telemetry.trace.hlo_scopes`` maps
+the program's instructions back to them. The expert layer's matrix
+products have scopes of their own (``mx_moe_score``: the router's;
+``mx_moe_latent``: both latent projections; ``mx_moe_gmm_*``;
+``mx_moe_shared``), so that ``mx_moe_route``, ``mx_moe_dispatch`` and
+``mx_moe_combine`` hold the choice, the sort, the gathers and the
+scatter-add alone.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .registry import register_op
+
+_F32 = jnp.float32
+_NEG = -1e30
+
+
+def _mm(x, w):
+    """``x @ w.T`` for a ``(out, in)`` weight, summed in float32, returned
+    in ``x``'s dtype."""
+    return lax.dot_general(x, w, (((x.ndim - 1,), (1,)), ((), ())),
+                           preferred_element_type=_F32).astype(x.dtype)
+
+
+def _relu2(x):
+    return jnp.square(jnp.maximum(x, 0))
+
+
+# ---------------------------------------------------------------------------
+# RMSNorm
+# ---------------------------------------------------------------------------
+@register_op("RMSNorm")
+def rms_norm(data, gamma, eps=1e-5, num_groups=1, **kw):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, or over
+    each of ``num_groups`` equal slices of it; computed in float32."""
+    shape = data.shape
+    g = int(num_groups)
+    x = data.astype(_F32).reshape(shape[:-1] + (g, shape[-1] // g))
+    x = x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
+    return (x.reshape(shape) * gamma.astype(_F32)).astype(data.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2
+# ---------------------------------------------------------------------------
+def causal_conv1d(x, weight, bias):
+    """Depthwise causal convolution over time. ``x``: (B, L, C);
+    ``weight``: (C, K), its last tap on the current step; ``bias``: (C,)."""
+    k = weight.shape[1]
+    length = x.shape[1]
+    pad = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    w = weight.astype(x.dtype)
+    out = bias.astype(x.dtype)
+    for j in range(k):
+        out = out + pad[:, j:j + length, :] * w[:, j]
+    return out
+
+
+def ssd_chunked(x, dt, a, b, c, chunk):
+    """The state-space recurrence ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t
+    (x) B_t``, ``y_t = S_t C_t`` from a zero state, in chunks: inside a
+    chunk the masked product ``(C B^T o L) (dt x)``, between chunks the
+    states carried by their decay.
+
+    ``x``: (B, L, H, P); ``dt``: (B, L, H) float32, after softplus;
+    ``a``: (H,) float32, negative; ``b``, ``c``: (B, L, G, N) with
+    ``H % G == 0``. Any ``L``: the tail is padded with steps of ``dt =
+    0``, which neither move the state nor reach an earlier output.
+    Returns (B, L, H, P) float32."""
+    bsz, length, h, p = x.shape
+    g, n = b.shape[2], b.shape[3]
+    r = h // g
+    q = int(chunk)
+    pad = (-length) % q
+    if pad:
+        x, dt, b, c = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+                       for t in (x, dt, b, c))
+    nc = (length + pad) // q
+    with jax.named_scope("mx_ssd_fwd"):
+        dtype = x.dtype
+        dt = dt.reshape(bsz, nc, q, g, r)
+        xdt = (x.reshape(bsz, nc, q, g, r, p).astype(_F32)
+               * dt[..., None])
+        b = b.reshape(bsz, nc, q, g, n)
+        c = c.reshape(bsz, nc, q, g, n)
+        la = dt * a.reshape(g, r)                       # log decay a step
+        cs = jnp.cumsum(la, axis=2)                     # (B, nc, q, g, r)
+        # inside a chunk
+        cb = jnp.einsum("zcign,zcjgn->zcgij", c, b,
+                        preferred_element_type=_F32)
+        seg = cs[:, :, :, None] - cs[:, :, None, :]     # (B,nc,i,j,g,r)
+        tri = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]
+        decay = jnp.exp(jnp.where(tri[:, :, None, None], seg, -jnp.inf))
+        scores = (cb.transpose(0, 1, 3, 4, 2)[..., None] * decay)
+        y = jnp.einsum("zcijgr,zcjgrp->zcigrp", scores.astype(dtype),
+                       xdt.astype(dtype), preferred_element_type=_F32)
+        # each chunk's own state at its end
+        to_end = jnp.exp(cs[:, :, -1:] - cs)            # (B, nc, q, g, r)
+        own = jnp.einsum("zcjgrp,zcjgn->zcgrpn",
+                         (xdt * to_end[..., None]).astype(dtype), b,
+                         preferred_element_type=_F32)
+        # the state entering each chunk: the earlier chunks' states,
+        # each decayed by the chunks between
+        tot = jnp.cumsum(cs[:, :, -1], axis=1)          # (B, nc, g, r)
+        between = tot[:, :-1, None] - tot[:, None, :-1]  # (B, c-1, c', g, r)
+        low = jnp.arange(nc - 1)[:, None] >= jnp.arange(nc - 1)[None, :]
+        carry = jnp.exp(jnp.where(low[:, :, None, None], between, -jnp.inf))
+        entering = jnp.einsum("zcdgr,zdgrpn->zcgrpn", carry, own[:, :-1],
+                              precision=lax.Precision.HIGHEST)
+        entering = jnp.pad(entering, ((0, 0), (1, 0)) + ((0, 0),) * 4)
+        y = y + jnp.einsum("zcign,zcgrpn->zcigrp", c,
+                           entering.astype(dtype),
+                           preferred_element_type=_F32) \
+            * jnp.exp(cs)[..., None]
+    return y.reshape(bsz, nc * q, h, p)[:, :length]
+
+
+@register_op("Mamba2Mixer")
+def mamba2_mixer(data, in_proj_weight, conv_weight, conv_bias, dt_bias,
+                 a_log, d, norm_weight, out_proj_weight, num_heads=1,
+                 head_dim=64, state_size=128, num_groups=1, chunk_size=128,
+                 eps=1e-5, **kw):
+    """The Mamba-2 mixer over the heads and groups held here.
+
+    ``data``: (B, L, hidden). ``in_proj_weight``: (2 d_inner + 2 G N + H,
+    hidden) with ``d_inner = H * head_dim``, rows ``[z | x B C | dt]``;
+    ``conv_weight``: (d_inner + 2 G N, K); ``out_proj_weight``: (hidden,
+    d_inner). The gated norm is over each group's ``d_inner / G``
+    channels, so a chip that holds whole groups computes it locally.
+    Returns this share's partial sum of the layer's output."""
+    h, p, n, g = int(num_heads), int(head_dim), int(state_size), \
+        int(num_groups)
+    bsz, length, _ = data.shape
+    d_in = h * p
+    zxbcdt = _mm(data, in_proj_weight)
+    z = zxbcdt[..., :d_in]
+    xbc = zxbcdt[..., d_in:2 * d_in + 2 * g * n]
+    dt = zxbcdt[..., 2 * d_in + 2 * g * n:]
+    with jax.named_scope("mx_ssd_conv"):
+        xbc = jax.nn.silu(causal_conv1d(xbc, conv_weight, conv_bias))
+    x = xbc[..., :d_in].reshape(bsz, length, h, p)
+    b = xbc[..., d_in:d_in + g * n].reshape(bsz, length, g, n)
+    c = xbc[..., d_in + g * n:].reshape(bsz, length, g, n)
+    dt = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
+    a = -jnp.exp(a_log.astype(_F32))
+    y = ssd_chunked(x, dt, a, b, c, chunk_size)
+    with jax.named_scope("mx_ssd_gate"):
+        y = y + d.astype(_F32)[:, None] * x.astype(_F32)
+        y = y.reshape(bsz, length, d_in) * jax.nn.silu(z.astype(_F32))
+        y = rms_norm(y, norm_weight, eps=eps, num_groups=g)
+    return _mm(y.astype(data.dtype), out_proj_weight)
+
+
+# ---------------------------------------------------------------------------
+# LatentMoE
+# ---------------------------------------------------------------------------
+def grouped_product(buf, w1, w2):
+    """Every held expert's ``relu2(rows W1) W2`` over its slice of the
+    receive buffer: ``buf`` (E, rows, latent), ``w1`` (E, latent, ff),
+    ``w2`` (E, ff, latent). The whole buffer is computed, whether a row
+    holds a token or nothing: the work is the same for every routing, and
+    a product that skipped empty tiles would make a step's time depend on
+    the seed. Do not write one."""
+    with jax.named_scope("mx_moe_gmm_up"):
+        hid = _relu2(jnp.einsum("erd,edf->erf", buf, w1,
+                                preferred_element_type=_F32)
+                     ).astype(buf.dtype)
+    with jax.named_scope("mx_moe_gmm_down"):
+        return jnp.einsum("erf,efd->erd", hid, w2,
+                          preferred_element_type=_F32).astype(buf.dtype)
+
+
+def route(scores_in, router_weight, router_bias, top_k, scaling,
+          norm_topk=True):
+    """Sigmoid scores over every expert of the model, the ``top_k`` by
+    ``score + bias`` chosen, the chosen scores normalised to sum 1 and
+    scaled. Returns ``(gate (T, E_all) float32, zero where not chosen;
+    chosen (T, E_all) bool)``. Ties at the ``top_k``-th place are all
+    taken (between floats they do not occur)."""
+    with jax.named_scope("mx_moe_score"):
+        logits = lax.dot_general(
+            scores_in, router_weight, (((1,), (1,)), ((), ())),
+            preferred_element_type=_F32)
+    with jax.named_scope("mx_moe_route"):
+        s = jax.nn.sigmoid(logits)
+        biased = s + router_bias.astype(_F32)
+        kth = lax.top_k(biased, int(top_k))[0][:, -1:]
+        chosen = biased >= lax.stop_gradient(kth)
+        gate = jnp.where(chosen, s, 0.0)
+        if norm_topk:
+            gate = gate / jnp.sum(gate, -1, keepdims=True)
+        return gate * scaling, chosen
+
+
+def balanced_bias(router_bias, load, rate):
+    """Auxiliary-loss-free balancing, one step of it: ``b_e + rate *
+    sign(mean load - load_e)`` from the loads the router's choice gave
+    every expert (here: over the tokens of this call; a deployment sums
+    them over its data-parallel groups first)."""
+    load = load.astype(_F32)
+    return router_bias.astype(_F32) \
+        + rate * jnp.sign(jnp.mean(load) - load)
+
+
+@register_op("LatentMoE", num_outputs=3)
+def latent_moe(data, router_weight, router_bias, down_weight, up_weight,
+               w1, w2, shared_w1, shared_w2, counters=None, expert_ids=(0,),
+               top_k=1, buffer_rows=0, scaling=1.0, norm_topk=True,
+               bias_rate=0.0, **kw):
+    """A mixture of experts that works in a latent, for the experts held
+    here.
+
+    ``data``: (B, L, hidden). The router (``router_weight`` (E_all,
+    hidden), ``router_bias`` (E_all,)) sees every expert of the model;
+    ``expert_ids`` names the ones whose weights ``w1`` (E, latent, ff),
+    ``w2`` (E, ff, latent) are here. The (token, expert) pairs that chose
+    a held expert are sorted into a static buffer of ``buffer_rows`` rows,
+    ``buffer_rows / E`` an expert; pairs beyond an expert's rows are
+    counted, not computed. ``shared_w1`` (ff_s, hidden), ``shared_w2``
+    (hidden, ff_s): the shared expert's columns held here.
+
+    Returns ``(out (B, L, hidden), counters (4,) float32, bias (E_all,)
+    float32)``. The counters: the pairs that chose a held expert, the
+    pairs beyond the buffer (added to ``counters``' own, when given: they
+    add up from call to call), the largest expert's load over the mean
+    load (over all experts), and the share of the buffer's rows that hold
+    a pair. The bias: ``router_bias`` after one step of the balancing
+    rule at ``bias_rate`` on this call's loads (``balanced_bias``), for
+    the caller to keep for the next call; no gradient reaches it."""
+    bsz, length, hidden = data.shape
+    u = data.reshape(bsz * length, hidden)
+    t = u.shape[0]
+    ids = jnp.asarray(tuple(int(e) for e in expert_ids), jnp.int32)
+    n_held = ids.shape[0]
+    cap = int(buffer_rows) // n_held
+    gate_all, chosen_all = route(u, router_weight, router_bias, top_k,
+                                 scaling, norm_topk)
+    with jax.named_scope("mx_moe_latent"):
+        v = _mm(u, down_weight)                         # (T, latent)
+    with jax.named_scope("mx_moe_dispatch"):
+        load = jnp.sum(chosen_all, axis=0, dtype=_F32)
+        gate = jnp.take(gate_all, ids, axis=1)          # (T, E)
+        chosen = jnp.take(chosen_all, ids, axis=1)
+        count = jnp.sum(chosen, axis=0, dtype=jnp.int32)
+        # an expert's tokens in order: a stable sort brings them first
+        order = jnp.argsort(jnp.logical_not(chosen), axis=0, stable=True)
+        order = order[:cap]
+        if cap > t:
+            order = jnp.pad(order, ((0, cap - t), (0, 0)))
+        valid = jnp.arange(cap)[None, :] < count[:, None]
+        token = jnp.where(valid, order.T, t)            # (E, cap); t: none
+        buf = jnp.take(v, token, axis=0, mode="fill", fill_value=0)
+        pair = jnp.where(valid, jnp.arange(n_held)[:, None] * t + token,
+                         n_held * t)
+        row_gate = jnp.take(gate.T.reshape(-1), pair, mode="fill",
+                            fill_value=0)
+    out_buf = grouped_product(buf, w1, w2)
+    with jax.named_scope("mx_moe_combine"):
+        weighted = out_buf.astype(_F32) * row_gate[..., None]
+        routed = jnp.zeros((t, v.shape[1]), _F32).at[token.reshape(-1)].add(
+            weighted.reshape(-1, v.shape[1]), mode="drop")
+    with jax.named_scope("mx_moe_latent"):
+        routed = _mm(routed.astype(data.dtype), up_weight)
+    with jax.named_scope("mx_moe_shared"):
+        shared = _mm(_relu2(_mm(u, shared_w1)), shared_w2)
+    kept = jnp.sum(jnp.minimum(count, cap)).astype(_F32)
+    held = jnp.sum(count).astype(_F32)
+    before = 0.0 if counters is None else counters[1].astype(_F32)
+    stats = jnp.stack([held, before + held - kept,
+                       jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
+                       kept / (n_held * cap)])
+    return (routed + shared).reshape(bsz, length, hidden), \
+        lax.stop_gradient(stats), \
+        lax.stop_gradient(balanced_bias(router_bias, load, bias_rate))
+
+
+# ---------------------------------------------------------------------------
+# causal grouped-query attention
+# ---------------------------------------------------------------------------
+def _attention_block(q, k, v, bias, scale):
+    """One (query block, key block) pair of ``parallel.ring``'s
+    recurrence, with the scores and the sums in float32 whatever the
+    inputs' dtype: ``(out, row_max, row_sum)``."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=_F32) * scale
+    if bias is not None:
+        s = s + bias
+    m = jnp.max(s, axis=-1)
+    p = jnp.exp(s - m[..., None])
+    o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                   preferred_element_type=_F32)
+    return o, m, jnp.sum(p, axis=-1)
+
+
+@register_op("CausalGQAttention")
+def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
+                        block=1024, scale=None, **kw):
+    """Causal attention over packed ``[q | k | v]`` rows, ``num_heads``
+    query heads sharing ``num_kv_heads`` key/value heads, no positional
+    encoding. Blocks of ``block`` queries against the blocks of keys at or
+    before them, accumulated by the running maximum and denominator of
+    ``parallel.ring.local_attention_block``'s recurrence; blocks past the
+    diagonal are never formed.
+
+    ``data``: (B, L, (num_heads + 2 num_kv_heads) * head_dim). Returns
+    (B, L, num_heads * head_dim)."""
+    hq, hk, dh = int(num_heads), int(num_kv_heads), int(head_dim)
+    bsz, length, _ = data.shape
+    q = data[..., :hq * dh].reshape(bsz, length, hq, dh)
+    k = data[..., hq * dh:(hq + hk) * dh].reshape(bsz, length, hk, dh)
+    v = data[..., (hq + hk) * dh:].reshape(bsz, length, hk, dh)
+    k = jnp.repeat(k, hq // hk, axis=2)
+    v = jnp.repeat(v, hq // hk, axis=2)
+    blk = min(int(block), length)
+    scale = scale if scale is not None else dh ** -0.5
+    outs = []
+    with jax.named_scope("mx_attn_fwd"):
+        for i0 in range(0, length, blk):
+            i1 = min(i0 + blk, length)
+            qi = q[:, i0:i1]
+            o = m = l = None
+            for j0 in range(0, i1, blk):
+                j1 = min(j0 + blk, i1)
+                bias = None
+                if j1 > i0:     # the block on the diagonal
+                    bias = jnp.where(
+                        jnp.arange(i0, i1)[:, None]
+                        >= jnp.arange(j0, j1)[None, :], 0.0, _NEG)
+                o_j, m_j, l_j = _attention_block(
+                    qi, k[:, j0:j1], v[:, j0:j1], bias, scale)
+                if o is None:
+                    o, m, l = o_j, m_j, l_j
+                    continue
+                m_new = jnp.maximum(m, m_j)
+                alpha, beta = jnp.exp(m - m_new), jnp.exp(m_j - m_new)
+                l = l * alpha + l_j * beta
+                o = o * alpha.transpose(0, 2, 1)[..., None] \
+                    + o_j * beta.transpose(0, 2, 1)[..., None]
+                m = m_new
+            outs.append(o / l.transpose(0, 2, 1)[..., None])
+    out = jnp.concatenate(outs, axis=1)
+    return out.reshape(bsz, length, hq * dh).astype(data.dtype)

@@ -63,6 +63,19 @@ def _remat_staged(staged):
     return wrapped
 
 
+def _remat_by_unit(staged):
+    """Trace the staged forward with the net's recomputation units on
+    (``gluon.block.remat_units``)."""
+    from ..gluon.block import remat_units
+
+    def wrapped(pvals, args, key):
+        with remat_units():
+            return staged(pvals, args, key)
+
+    wrapped._inner = staged
+    return wrapped
+
+
 class TrainStep:
     """One-XLA-computation training step for a HybridBlock.
 
@@ -91,7 +104,21 @@ class TrainStep:
         ``remat``: recompute activations during backward (jax.checkpoint),
         trading FLOPs for HBM — the reference's gradient mirroring
         (MXNET_BACKWARD_DO_MIRROR, graph_executor.cc mirror fn). Default
-        comes from that env var via mxnet_tpu.config."""
+        comes from that env var via mxnet_tpu.config. ``remat="layer"``
+        recomputes by unit: every block of the net marked ``_remat_unit``
+        keeps its inputs and recomputes its insides, so the peak holds
+        one unit's activations, not the net's (wrapping the whole
+        forward saves nothing at the peak).
+
+        The step takes the net's own parameter buffers, not a copy of
+        them (4 bytes a parameter) and, since every call donates them,
+        points the net's Parameters at the new ones after each call: the
+        net is always current.
+
+        Parameters with ``grad_req="null"`` get no gradient, no optimizer
+        state and no cast to ``compute_dtype``: they are state the forward
+        reads and may write (BatchNorm's statistics, a router's correction
+        bias, counters)."""
         self.net = net
         self.preprocess = preprocess
         self.loss_fn = _LOSSES[loss] if isinstance(loss, str) else loss
@@ -112,13 +139,15 @@ class TrainStep:
         if remat is None:
             from .. import config as _config
             remat = _config.get("MXNET_BACKWARD_DO_MIRROR")
-        self.remat = bool(remat)
+        self.remat = remat if remat == "layer" else bool(remat)
 
         self.param_list = net._get_param_list()
         self._trainable = [p.grad_req != "null" for p in self.param_list]
         # staged forward in training mode: fn(pvals, args, key)->(outs,writes)
         _, self._staged = net._build_jit(training=True)
-        if self.remat:
+        if self.remat == "layer":
+            self._staged = _remat_by_unit(self._staged)
+        elif self.remat:
             self._staged = _remat_staged(self._staged)
         self._pvals = None
         self._opt_state = None
@@ -144,10 +173,9 @@ class TrainStep:
     # -- state ----------------------------------------------------------------
     def _init_state(self):
         import jax.numpy as jnp
-        # copy the buffers: the step donates its param arrays, which would
-        # otherwise invalidate the net's live Parameter buffers
-        pvals = tuple(jnp.array(p.data()._data, copy=True)
-                      for p in self.param_list)
+        # the net's own buffers: every call donates them, and _call points
+        # the net's Parameters at what the call returns
+        pvals = tuple(p.data()._data for p in self.param_list)
         opt_state = tuple(
             self._opt_init(v) if t else ()
             for v, t in zip(pvals, self._trainable))
@@ -211,7 +239,8 @@ class TrainStep:
                 if compute_dtype is not None:
                     pv_c = tuple(
                         v.astype(compute_dtype)
-                        if v.dtype == jnp.float32 else v for v in pv)
+                        if v.dtype == jnp.float32 and tr else v
+                        for v, tr in zip(pv, trainable))
                     x_c = x.astype(compute_dtype) \
                         if x.dtype == jnp.float32 else x
                 else:
@@ -329,6 +358,8 @@ class TrainStep:
             with _trace.span("dispatch", "step", on=on):
                 out = self._step_jit(*args)
         self._pvals, self._opt_state, self._t_dev, loss = out
+        for p, v in zip(self.param_list, self._pvals):
+            p._data._data = v
         self._num_update += 1
         return _wrap(loss)
 
@@ -353,13 +384,8 @@ class TrainStep:
                                for p in self.param_list]
 
     def sync_params(self):
-        """Write the step's parameter buffers back into the net's Parameters
-        (copies — the step's own buffers get donated on the next call)."""
-        import jax.numpy as jnp
-        if self._pvals is None:
-            return
-        for p, v in zip(self.param_list, self._pvals):
-            p._check_and_get()._data = jnp.array(v, copy=True)
+        """Nothing to do: the net's Parameters hold the step's buffers
+        after every call. Kept for its callers."""
 
     @property
     def num_update(self):

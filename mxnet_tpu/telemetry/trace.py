@@ -63,6 +63,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import re
 import threading
 import time
 
@@ -70,7 +71,7 @@ from . import registry
 
 __all__ = ["enabled", "trace_dir", "new_trace_id", "new_span_id",
            "span", "current", "record_span", "spans", "export_trace",
-           "trace_files", "read_trace", "reset"]
+           "trace_files", "read_trace", "reset", "hlo_scopes"]
 
 # the clock of every span; record_span's callers (intervals measured
 # across threads) read their t0 from it too
@@ -430,3 +431,25 @@ def reset():
         _exports = 0
         _ring = []
         _thread_names.clear()
+
+
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name=\"([^\"]*)\"", re.M)
+
+
+def hlo_scopes(hlo_text, prefix="mx_"):
+    """From a compiled program's HLO text, ``{instruction name: scope}``
+    for every instruction whose ``op_name`` passes through a
+    ``jax.named_scope`` that starts with ``prefix`` (the innermost such
+    scope; a backward instruction carries its forward scope's name inside
+    ``transpose(jvp(...))``). The device trace names its events by
+    instruction, so this is what puts the program's own names on them."""
+    # a path component of its own or inside jvp(...)/transpose(...); the
+    # jitted function's own name, "jit(mx_train_step)", is no scope
+    scope = re.compile(r"(?<!jit\()\b(" + re.escape(prefix) + r"\w+)")
+    out = {}
+    for name, op_name in _HLO_INSTRUCTION.findall(hlo_text):
+        found = scope.findall(op_name)
+        if found:
+            out[name] = found[-1]
+    return out

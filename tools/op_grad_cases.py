@@ -92,6 +92,34 @@ CASES = {
         # recurrence is smooth in data, so plain FD applies.
         inputs=[_signed((2, 4, 3 * 2 * 3), 0)],
         attrs=dict(num_heads=2)),
+    "Mamba2Mixer": dict(
+        # ops/seq.py: 2 heads of 4, state 4, one group, chunk 4 over 6
+        # steps (a padded tail); in_proj rows [z 8 | x B C 16 | dt 2],
+        # small so that the decays' exponentials stay in FD's reach
+        inputs=[_signed((2, 6, 8), 0), 0.3 * _signed((26, 8), 1),
+                _signed((16, 4), 2), _signed((16,), 3), _signed((2,), 4),
+                _pos((2,), 5), _signed((2,), 6), _pos((8,), 7),
+                _signed((8, 8), 8)],
+        attrs=dict(num_heads=2, head_dim=4, state_size=4, num_groups=1,
+                   chunk_size=4),
+        grad_args=[0, 1, 2, 3, 4, 5, 6, 7, 8], tol=(6e-2, 6e-3)),
+    "LatentMoE": dict(
+        # ops/seq.py: 6 experts, 2 of 3 held, top-2, a buffer with room;
+        # the choice is piecewise constant, so FD sees the smooth part
+        # (tests/test_seq_ops.py pins the gradients against the plain
+        # reference); the bias takes no gradient
+        inputs=[_signed((1, 5, 8), 0), _signed((6, 8), 1),
+                _signed((6,), 2), _signed((4, 8), 3), _signed((8, 4), 4),
+                _signed((2, 4, 6), 5), _signed((2, 6, 4), 6),
+                _signed((3, 8), 7), _signed((8, 3), 8)],
+        attrs=dict(expert_ids=(1, 4), top_k=2, buffer_rows=10,
+                   scaling=2.5),
+        grad_args=[0, 3, 4, 5, 6, 7, 8], tol=(8e-2, 8e-3)),
+    "CausalGQAttention": dict(
+        # ops/seq.py: packed [q | k | v], 2 query heads on 1 key/value
+        # head of 3, blocks of 2 over 5 steps
+        inputs=[_signed((2, 5, (2 + 2) * 3), 0)],
+        attrs=dict(num_heads=2, num_kv_heads=1, head_dim=3, block=2)),
     "InstanceNorm": dict(
         inputs=[_img((2, 3, 4, 4)), _pos((3,), 1), _signed((3,), 2)]),
     "L2Normalization": dict(inputs=[_signed((3, 5), 0)]),
