@@ -302,11 +302,17 @@ class _TraceState:
 
 class remat_units:
     """While active, blocks marked ``_remat_unit`` checkpoint their call
-    inside a staged forward (``TrainStep(remat="layer")``)."""
+    inside a staged forward (``TrainStep(remat="layer")``). ``saved``:
+    unit prefix -> the bytes that unit holds for its backward pass
+    beside its inputs (``ops.remat``), once the forward is traced."""
+
+    def __init__(self):
+        self.saved = {}
 
     def __enter__(self):
-        self._prev = getattr(_TraceState._current, "remat_units", False)
-        _TraceState._current.remat_units = True
+        self._prev = getattr(_TraceState._current, "remat_units", None)
+        _TraceState._current.remat_units = self
+        return self
 
     def __exit__(self, *exc):
         _TraceState._current.remat_units = self._prev
@@ -400,7 +406,8 @@ class HybridBlock(Block):
     #: a block that sets this is one unit of recomputation: while a
     #: staged forward is traced under ``remat_units()`` its call runs
     #: under ``jax.checkpoint``, so the backward pass keeps the unit's
-    #: inputs and recomputes its insides
+    #: inputs and what its operators name (``ops.remat.kept``) and
+    #: recomputes the rest of its insides
     _remat_unit = False
 
     def __call__(self, *args):
@@ -409,16 +416,19 @@ class HybridBlock(Block):
             return self.forward(*args)
         if self._active and _TraceState.active() is None:
             return self._call_cached(*args)
-        if self._remat_unit and _TraceState.active() is not None \
-                and getattr(_TraceState._current, "remat_units", False):
-            return self._call_remat(*args)
+        if self._remat_unit and _TraceState.active() is not None:
+            units = getattr(_TraceState._current, "remat_units", None)
+            if units is not None:
+                return self._call_remat(units, *args)
         return self.forward(*args)
 
-    def _call_remat(self, *args):
-        """``forward`` under ``jax.checkpoint``. Parameter writes made
-        inside (``stateful_write``) leave the checkpointed function as
-        outputs and are handed to the trace around it."""
+    def _call_remat(self, units, *args):
+        """``forward`` under ``jax.checkpoint``, which keeps what
+        ``ops.remat`` says. Parameter writes made inside
+        (``stateful_write``) leave the checkpointed function as outputs
+        and are handed to the trace around it."""
         import jax
+        from ..ops import remat
         outer = _TraceState.active()
         seen = {}
 
@@ -434,7 +444,16 @@ class HybridBlock(Block):
             seen["tuple"] = isinstance(out, tuple)
             return tuple(o._data for o in outs), tuple(inner.writes.values())
 
-        outs, writes = jax.checkpoint(unit)(*[a._data for a in args])
+        # traced once: the jaxpr says what the unit keeps, then runs
+        arrays = [a._data for a in args]
+        traced, shapes = jax.make_jaxpr(
+            jax.checkpoint(unit, policy=remat.POLICY),
+            return_shape=True)(*arrays)
+        units.saved[self.prefix] = units.saved.get(self.prefix, 0) \
+            + remat.kept_bytes(traced.jaxpr)
+        outs, writes = jax.tree.unflatten(
+            jax.tree.structure(shapes),
+            jax.core.eval_jaxpr(traced.jaxpr, traced.consts, *arrays))
         for p, w in zip(seen["params"], writes):
             outer.writes[p] = w
         outs = tuple(_wrap(o) for o in outs)

@@ -18,6 +18,15 @@ products have scopes of their own (``mx_moe_score``: the router's;
 ``mx_moe_shared``), so that ``mx_moe_route``, ``mx_moe_dispatch`` and
 ``mx_moe_combine`` hold the choice, the sort, the gathers and the
 scatter-add alone.
+
+What a recomputation unit around these ops holds for its backward pass
+is said here, where the values are computed (``remat.kept``): the
+outputs of the matrix products a backward pass reads (not a unit's last
+ones, nor the attention's score blocks, which grow with the square of
+the length), the threshold of the routing's choice, what the dispatch's
+sort gave, the convolution's, the scan's and the attention's outputs,
+and a norm's sum of squares. Activations, gates, decay masks, casts and
+the scaled rows of a norm are computed again.
 """
 from __future__ import annotations
 
@@ -26,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .registry import register_op
+from .remat import kept
 
 _F32 = jnp.float32
 _NEG = -1e30
@@ -52,7 +62,8 @@ def rms_norm(data, gamma, eps=1e-5, num_groups=1, **kw):
     shape = data.shape
     g = int(num_groups)
     x = data.astype(_F32).reshape(shape[:-1] + (g, shape[-1] // g))
-    x = x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
+    # the reduction's result, a float a row, is kept; the scaling is not
+    x = x * lax.rsqrt(kept(jnp.mean(jnp.square(x), -1, keepdims=True) + eps))
     return (x.reshape(shape) * gamma.astype(_F32)).astype(data.dtype)
 
 
@@ -102,8 +113,8 @@ def ssd_chunked(x, dt, a, b, c, chunk):
         la = dt * a.reshape(g, r)                       # log decay a step
         cs = jnp.cumsum(la, axis=2)                     # (B, nc, q, g, r)
         # inside a chunk
-        cb = jnp.einsum("zcign,zcjgn->zcgij", c, b,
-                        preferred_element_type=_F32)
+        cb = kept(jnp.einsum("zcign,zcjgn->zcgij", c, b,
+                             preferred_element_type=_F32))
         seg = cs[:, :, :, None] - cs[:, :, None, :]     # (B,nc,i,j,g,r)
         tri = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]
         decay = jnp.exp(jnp.where(tri[:, :, None, None], seg, -jnp.inf))
@@ -112,9 +123,9 @@ def ssd_chunked(x, dt, a, b, c, chunk):
                        xdt.astype(dtype), preferred_element_type=_F32)
         # each chunk's own state at its end
         to_end = jnp.exp(cs[:, :, -1:] - cs)            # (B, nc, q, g, r)
-        own = jnp.einsum("zcjgrp,zcjgn->zcgrpn",
-                         (xdt * to_end[..., None]).astype(dtype), b,
-                         preferred_element_type=_F32)
+        own = kept(jnp.einsum("zcjgrp,zcjgn->zcgrpn",
+                              (xdt * to_end[..., None]).astype(dtype), b,
+                              preferred_element_type=_F32))
         # the state entering each chunk: the earlier chunks' states,
         # each decayed by the chunks between
         tot = jnp.cumsum(cs[:, :, -1], axis=1)          # (B, nc, g, r)
@@ -124,9 +135,9 @@ def ssd_chunked(x, dt, a, b, c, chunk):
         entering = jnp.einsum("zcdgr,zdgrpn->zcgrpn", carry, own[:, :-1],
                               precision=lax.Precision.HIGHEST)
         entering = jnp.pad(entering, ((0, 0), (1, 0)) + ((0, 0),) * 4)
-        y = y + jnp.einsum("zcign,zcgrpn->zcigrp", c,
-                           entering.astype(dtype),
-                           preferred_element_type=_F32) \
+        y = y + kept(jnp.einsum("zcign,zcgrpn->zcigrp", c,
+                                kept(entering.astype(dtype)),
+                                preferred_element_type=_F32)) \
             * jnp.exp(cs)[..., None]
     return y.reshape(bsz, nc * q, h, p)[:, :length]
 
@@ -148,18 +159,18 @@ def mamba2_mixer(data, in_proj_weight, conv_weight, conv_bias, dt_bias,
         int(num_groups)
     bsz, length, _ = data.shape
     d_in = h * p
-    zxbcdt = _mm(data, in_proj_weight)
+    zxbcdt = kept(_mm(data, in_proj_weight))
     z = zxbcdt[..., :d_in]
     xbc = zxbcdt[..., d_in:2 * d_in + 2 * g * n]
     dt = zxbcdt[..., 2 * d_in + 2 * g * n:]
     with jax.named_scope("mx_ssd_conv"):
-        xbc = jax.nn.silu(causal_conv1d(xbc, conv_weight, conv_bias))
+        xbc = jax.nn.silu(kept(causal_conv1d(xbc, conv_weight, conv_bias)))
     x = xbc[..., :d_in].reshape(bsz, length, h, p)
     b = xbc[..., d_in:d_in + g * n].reshape(bsz, length, g, n)
     c = xbc[..., d_in + g * n:].reshape(bsz, length, g, n)
     dt = jax.nn.softplus(dt.astype(_F32) + dt_bias.astype(_F32))
     a = -jnp.exp(a_log.astype(_F32))
-    y = ssd_chunked(x, dt, a, b, c, chunk_size)
+    y = kept(ssd_chunked(x, dt, a, b, c, chunk_size))
     with jax.named_scope("mx_ssd_gate"):
         y = y + d.astype(_F32)[:, None] * x.astype(_F32)
         y = y.reshape(bsz, length, d_in) * jax.nn.silu(z.astype(_F32))
@@ -178,12 +189,13 @@ def grouped_product(buf, w1, w2):
     a product that skipped empty tiles would make a step's time depend on
     the seed. Do not write one."""
     with jax.named_scope("mx_moe_gmm_up"):
-        hid = _relu2(jnp.einsum("erd,edf->erf", buf, w1,
-                                preferred_element_type=_F32)
+        hid = _relu2(kept(jnp.einsum("erd,edf->erf", buf, w1,
+                                     preferred_element_type=_F32))
                      ).astype(buf.dtype)
     with jax.named_scope("mx_moe_gmm_down"):
-        return jnp.einsum("erf,efd->erd", hid, w2,
-                          preferred_element_type=_F32).astype(buf.dtype)
+        return kept(jnp.einsum("erf,efd->erd", hid, w2,
+                               preferred_element_type=_F32
+                               ).astype(buf.dtype))
 
 
 def route(scores_in, router_weight, router_bias, top_k, scaling,
@@ -194,13 +206,15 @@ def route(scores_in, router_weight, router_bias, top_k, scaling,
     chosen (T, E_all) bool)``. Ties at the ``top_k``-th place are all
     taken (between floats they do not occur)."""
     with jax.named_scope("mx_moe_score"):
-        logits = lax.dot_general(
+        logits = kept(lax.dot_general(
             scores_in, router_weight, (((1,), (1,)), ((), ())),
-            preferred_element_type=_F32)
+            preferred_element_type=_F32))
     with jax.named_scope("mx_moe_route"):
         s = jax.nn.sigmoid(logits)
         biased = s + router_bias.astype(_F32)
-        kth = lax.top_k(biased, int(top_k))[0][:, -1:]
+        # the threshold alone stands for the choice: a unit that keeps it
+        # (T floats) finds gate and mask again without a second top_k
+        kth = kept(lax.top_k(biased, int(top_k))[0][:, -1:])
         chosen = biased >= lax.stop_gradient(kth)
         gate = jnp.where(chosen, s, 0.0)
         if norm_topk:
@@ -264,27 +278,29 @@ def latent_moe(data, router_weight, router_bias, down_weight, up_weight,
         if cap > t:
             order = jnp.pad(order, ((0, cap - t), (0, 0)))
         valid = jnp.arange(cap)[None, :] < count[:, None]
-        token = jnp.where(valid, order.T, t)            # (E, cap); t: none
-        buf = jnp.take(v, token, axis=0, mode="fill", fill_value=0)
+        # what the sort gave and the rows it gathered, kept: no backward
+        # pass sorts again, nor projects to the latent for the rows' sake
+        token = kept(jnp.where(valid, order.T, t))      # (E, cap); t: none
+        buf = kept(jnp.take(v, token, axis=0, mode="fill", fill_value=0))
         pair = jnp.where(valid, jnp.arange(n_held)[:, None] * t + token,
                          n_held * t)
-        row_gate = jnp.take(gate.T.reshape(-1), pair, mode="fill",
-                            fill_value=0)
+        row_gate = kept(jnp.take(gate.T.reshape(-1), pair, mode="fill",
+                                 fill_value=0))
     out_buf = grouped_product(buf, w1, w2)
     with jax.named_scope("mx_moe_combine"):
         weighted = out_buf.astype(_F32) * row_gate[..., None]
         routed = jnp.zeros((t, v.shape[1]), _F32).at[token.reshape(-1)].add(
             weighted.reshape(-1, v.shape[1]), mode="drop")
     with jax.named_scope("mx_moe_latent"):
-        routed = _mm(routed.astype(data.dtype), up_weight)
+        routed = _mm(kept(routed.astype(data.dtype)), up_weight)
     with jax.named_scope("mx_moe_shared"):
-        shared = _mm(_relu2(_mm(u, shared_w1)), shared_w2)
-    kept = jnp.sum(jnp.minimum(count, cap)).astype(_F32)
+        shared = _mm(_relu2(kept(_mm(u, shared_w1))), shared_w2)
+    placed = jnp.sum(jnp.minimum(count, cap)).astype(_F32)
     held = jnp.sum(count).astype(_F32)
     before = 0.0 if counters is None else counters[1].astype(_F32)
-    stats = jnp.stack([held, before + held - kept,
+    stats = jnp.stack([held, before + held - placed,
                        jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
-                       kept / (n_held * cap)])
+                       placed / (n_held * cap)])
     return (routed + shared).reshape(bsz, length, hidden), \
         lax.stop_gradient(stats), \
         lax.stop_gradient(balanced_bias(router_bias, load, bias_rate))
@@ -322,6 +338,7 @@ def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
     (B, L, num_heads * head_dim)."""
     hq, hk, dh = int(num_heads), int(num_kv_heads), int(head_dim)
     bsz, length, _ = data.shape
+    data = kept(data)       # the projection's output, named where it is read
     q = data[..., :hq * dh].reshape(bsz, length, hq, dh)
     k = data[..., hq * dh:(hq + hk) * dh].reshape(bsz, length, hk, dh)
     v = data[..., (hq + hk) * dh:].reshape(bsz, length, hk, dh)
@@ -355,4 +372,4 @@ def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
                 m = m_new
             outs.append(o / l.transpose(0, 2, 1)[..., None])
     out = jnp.concatenate(outs, axis=1)
-    return out.reshape(bsz, length, hq * dh).astype(data.dtype)
+    return kept(out.reshape(bsz, length, hq * dh).astype(data.dtype))

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import telemetry as _telemetry
 from ..ndarray.ndarray import NDArray, _wrap
 from ..telemetry import trace as _trace
 
@@ -65,12 +66,20 @@ def _remat_staged(staged):
 
 def _remat_by_unit(staged):
     """Trace the staged forward with the net's recomputation units on
-    (``gluon.block.remat_units``)."""
+    (``gluon.block.remat_units``), and publish what the units keep for
+    the backward pass beside their inputs: the gauges
+    ``remat::saved_bytes::<unit>`` and ``remat::units``, of the step
+    traced last."""
     from ..gluon.block import remat_units
 
     def wrapped(pvals, args, key):
-        with remat_units():
-            return staged(pvals, args, key)
+        with remat_units() as units:
+            out = staged(pvals, args, key)
+        _telemetry.remove("remat::")
+        for prefix, nbytes in units.saved.items():
+            _telemetry.gauge(f"remat::saved_bytes::{prefix}").set(nbytes)
+        _telemetry.gauge("remat::units").set(len(units.saved))
+        return out
 
     wrapped._inner = staged
     return wrapped
@@ -106,9 +115,11 @@ class TrainStep:
         (MXNET_BACKWARD_DO_MIRROR, graph_executor.cc mirror fn). Default
         comes from that env var via mxnet_tpu.config. ``remat="layer"``
         recomputes by unit: every block of the net marked ``_remat_unit``
-        keeps its inputs and recomputes its insides, so the peak holds
-        one unit's activations, not the net's (wrapping the whole
-        forward saves nothing at the peak).
+        keeps its inputs and what is dear to compute twice (matrix
+        products' outputs, a choice's or a sort's result, a scan's
+        output: ``ops.remat``) and recomputes the rest of its insides,
+        so the peak holds one unit's activations, not the net's
+        (wrapping the whole forward saves nothing at the peak).
 
         The step takes the net's own parameter buffers, not a copy of
         them (4 bytes a parameter) and, since every call donates them,
