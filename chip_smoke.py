@@ -43,7 +43,7 @@ FULL = {
     "layers": 50, "image": 224, "classes": 1000, "batch": 128,
     "stem": "s2d", "manual_steps": 6, "fit_batches": 3,
     "buckets": (1, 8, 32), "clients": 4, "requests_per_client": 8,
-    # the widest decode LM the repo ships (bench.py speculative_decode)
+    # the toy decode LM of serving/decode/model.py
     "lm": {"vocab_size": 256, "num_embed": 128, "num_heads": 8,
            "num_layers": 4, "max_seq": 64},
     "slots": 8, "seq_buckets": (16, 32), "streams": 6, "new_tokens": 16,
@@ -60,8 +60,8 @@ _OPT = {"learning_rate": 0.005, "momentum": 0.9, "wd": 1e-4}
 # preamble
 # ---------------------------------------------------------------------------
 def preamble():
-    """Refuse anything but a TPU backend whose ``device_kind`` the peak
-    tables know; print what the run is standing on. Returns the device
+    """Refuse anything but a TPU backend whose ``device_kind`` the HBM
+    peak table knows; print what the run is standing on. Returns the device
     description of the result line."""
     import jax
     if jax.default_backend() != "tpu":
@@ -72,12 +72,8 @@ def preamble():
     import jaxlib
     import mxnet_tpu as mx
     from mxnet_tpu.telemetry import peak_hbm_bytes_s
-    sys.path.insert(0, _HERE)
-    import bench
     devs = jax.devices()
     dev = devs[0]
-    # both raise/return-zero on a kind they do not know
-    bench._peak_flops(dev)
     if not peak_hbm_bytes_s(dev):
         raise SystemExit(
             f"chip_smoke: device_kind {dev.device_kind!r} is not in the "

@@ -1,6 +1,6 @@
 """Inference throughput benchmark — the analog of the reference's
 example/image-classification/benchmark_score.py (which produced the
-docs/faq/perf.md scoring tables: ResNet-50 713 img/s on 1x P100 @ batch 32).
+scoring tables of incubator-mxnet/docs/faq/perf.md: ResNet-50 713 img/s on 1x P100 @ batch 32).
 
 Scores the jitted symbolic forward on one TPU chip in bf16; batches are
 device-resident and dispatch is async with one trailing sync, matching the

@@ -98,88 +98,9 @@ def _save_model(args, rank=0):
         else "%s-%d" % (args.model_prefix, rank))
 
 
-def _benchmark(args, network, train):
-    """Timed steady-state loop over the symbolic Module path — the
-    north-star measurement (BASELINE.json drives this file). Prints ONE
-    bench.py-style JSON line. Async dispatch with a single sync, like
-    bench.py phase A: the donated fused-step params chain the steps."""
-    import json
-
-    import jax
-
-    devs = mx.cpu() if args.gpus is None or args.gpus == "" else [
-        mx.gpu(int(i)) for i in args.gpus.split(",")]
-    compute_dtype = "bfloat16" if args.dtype == "bfloat16" else None
-    model = mx.mod.Module(context=devs, symbol=network, fused=True,
-                          compute_dtype=compute_dtype)
-    model.bind(train.provide_data, train.provide_label)
-    model.init_params(mx.init.Xavier(rnd_type="gaussian",
-                                     factor_type="in", magnitude=2))
-    optimizer_params = {"learning_rate": args.lr, "wd": args.wd}
-    if args.optimizer in ("sgd", "nag", "signum", "lbsgd"):
-        optimizer_params["momentum"] = args.mom
-    model.init_optimizer(kvstore=None, optimizer=args.optimizer,
-                         optimizer_params=optimizer_params)
-    assert model._fused is not None
-
-    batches = []
-    for batch in train:
-        batches.append(batch)
-        if len(batches) >= 4:
-            break
-    train.reset()
-
-    steps = getattr(args, "benchmark_steps", 30)
-    for _ in range(3):  # compile + warmup
-        for b in batches[:1]:
-            model.forward(b, is_train=True)
-            model.backward()
-            model.update()
-    jax.block_until_ready(model._fused._pvals)
-
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.time()
-        for i in range(steps):
-            model.forward(batches[i % len(batches)], is_train=True)
-            model.backward()
-            model.update()
-        jax.block_until_ready(model._fused._pvals)
-        best = min(best, time.time() - t0)
-    img_s = args.batch_size * steps / best
-    # single source of truth for the reference number (cited in bench.py /
-    # BASELINE.md: 181.53 img/s, 1x P100, docs/faq/perf.md:176-185)
-    baseline = None
-    try:
-        import sys as _sys
-        root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))))
-        _sys.path.insert(0, root)
-        from bench import BASELINE_IMG_S as baseline
-    except Exception:
-        pass
-    print(json.dumps({
-        "metric": "module_fit_train_throughput",
-        "value": round(img_s, 2),
-        "unit": "images/sec",
-        "vs_baseline": round(img_s / baseline, 3) if baseline else None,
-        "network": args.network,
-        "batch": args.batch_size,
-        "steps": steps,
-        "step_time_s": round(best / steps, 5),
-        "path": "Module(fused) symbolic graph + functional optimizer "
-                f"[{args.optimizer}, dtype={args.dtype}]",
-    }))
-    return model
-
-
 def fit(args, network, data_loader, **kwargs):
     """Train ``network`` (a Symbol) on the iterators from ``data_loader``
     (reference: common/fit.py:141)."""
-    if getattr(args, "benchmark", 0):
-        train, _ = data_loader(args, None)
-        return _benchmark(args, network, train)
-
     kv = mx.kvstore.create(args.kv_store)
 
     head = "%(asctime)-15s Node[" + str(kv.rank) + "] %(message)s"

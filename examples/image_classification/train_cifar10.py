@@ -4,7 +4,7 @@
     python train_cifar10.py --data-train cifar10_train.rec \\
         --data-val cifar10_val.rec
 
-    # synthetic benchmark mode (no dataset needed)
+    # synthetic data, no dataset needed: the normal fit() with its Speedometer
     python train_cifar10.py --benchmark 1 --num-epochs 1
 """
 import argparse

@@ -5,7 +5,7 @@ example/image-classification/train_imagenet.py:58).
     python train_imagenet.py --network resnet --num-layers 50 \
         --data-train train.rec --data-val val.rec
 
-    # synthetic benchmark mode (no dataset needed)
+    # synthetic data, no dataset needed: the normal fit() with its Speedometer
     python train_imagenet.py --network resnet --num-layers 50 \
         --benchmark 1 --num-epochs 1 --dtype bfloat16
 """

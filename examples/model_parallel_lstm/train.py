@@ -70,7 +70,7 @@ def train(num_epoch=3, batch_size=32, hidden=64, num_layers=2, vocab=32,
     def spec_fn(p):
         # LSTM gate weights are (4*hidden, in): shard the gate dim over
         # the model axis — the TP analog of the reference putting each
-        # layer on its own GPU (lstm.py:65-100)
+        # layer on its own GPU (example/model-parallel/lstm/lstm.py:65-100)
         if ("lstm" in p.name and p.name.endswith("weight")
                 and len(p.shape) == 2 and p.shape[0] % model_par == 0):
             return P("model", None)

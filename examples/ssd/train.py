@@ -92,7 +92,7 @@ class TinySSD(HybridBlock):
 def ssd_losses(cls_pred, loc_pred, cls_target, loc_target, loc_mask):
     """Masked softmax CE (ignore_label=-1) + smooth-L1 on positives
     (reference: MultiBoxTarget outputs feeding SoftmaxOutput + smooth_l1
-    in symbol_builder.py)."""
+    in example/ssd/symbol/symbol_builder.py)."""
     logp = cls_pred.log_softmax(axis=-1)
     valid = (cls_target >= 0).astype("float32")
     tgt = cls_target.clip(0, None)

@@ -62,7 +62,7 @@ def main():
     loss = None
     for i in range(3):                     # warmup/compile
         loss = step(xs[i % 4], ys[i % 4])
-    float(loss.asnumpy())                  # arm real sync (see bench.py)
+    float(loss.asnumpy())                  # arm real sync
 
     best = float("inf")
     for _ in range(3):

@@ -43,8 +43,8 @@ import contextlib
 @contextlib.contextmanager
 def override(name: str, value):
     """Temporarily force a configuration variable's environment value
-    (None removes it). The one save/set/restore used by the bench and
-    sweep A/B toggles and the fusion tests — config state lives in the
+    (None removes it). The one save/set/restore used by the tuner's
+    trials, the serving predictor and the fusion tests — config state lives in the
     environment, so this is also the single place to change if that
     ever moves."""
     old = os.environ.get(name)

@@ -1,8 +1,8 @@
 """ONNX graph -> Symbol converter.
 
 TPU-native rebuild of the reference importer (reference:
-python/mxnet/contrib/onnx/_import/import_model.py, import_onnx.py,
-import_helper.py op mapping). The converter walks the ONNX graph in
+python/mxnet/contrib/onnx/_import/import_model.py, _import/import_onnx.py,
+_import/import_helper.py op mapping). The converter walks the ONNX graph in
 topological order, mapping each node onto the registered op surface;
 initializer tensors become arg_params.
 
@@ -107,7 +107,7 @@ def _pool_attrs(attrs, pool_type):
 def import_onnx_graph(graph):
     """Convert a GraphProto-shaped object; returns
     (sym, arg_params, aux_params) — the reference's from_onnx contract
-    (reference: import_onnx.py GraphProto.from_onnx)."""
+    (reference: _import/import_onnx.py GraphProto.from_onnx)."""
     from ... import symbol as sym_mod
     from ...ndarray import array as nd_array
     from ...symbol.symbol import var as sym_var

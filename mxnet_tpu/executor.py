@@ -175,7 +175,7 @@ class Executor:
     and everything else replicated before each jitted call — GSPMD then
     partitions the whole program across the devices, the TPU equivalent of
     the reference's DataParallelExecutorGroup slicing
-    (executor_group.py:129, decide_slices :267)."""
+    (python/mxnet/module/executor_group.py:129, decide_slices :267)."""
 
     def __init__(self, symbol, ctx, arg_dict: Dict[str, NDArray],
                  args_grad: Optional[Dict[str, NDArray]], grad_req,

@@ -88,9 +88,9 @@ class BottleneckV1(HybridBlock):
 class BottleneckV1b(HybridBlock):
     """ResNet v1.5 bottleneck: stride moves from the first 1x1 to the 3x3
     (the torchvision/gluoncv "v1b" variant — and the form the reference's
-    example/image-classification/symbols/resnet.py actually benchmarks).
-    On TPU the strided 3x3 also maps better onto the MXU than a strided
-    1x1 gather, measured ~6% faster end to end (tools/perf_probe.py)."""
+    example/image-classification/symbols/resnet.py actually benchmarks):
+    the strided convolution is the 3x3, not a 1x1 that reads a quarter
+    of its input."""
 
     def __init__(self, channels, stride, downsample=False, in_channels=0,
                  **kwargs):

@@ -4,7 +4,7 @@ TPU-native rebuild of the reference's gradient compression
 (reference: src/kvstore/gradient_compression.h:37-134, .cc quantize/
 dequantize kernels; python surface kvstore.py set_gradient_compression).
 
-Semantics (verified against tests/nightly/test_kvstore.py
+Semantics (verified against incubator-mxnet/tests/nightly/test_kvstore.py
 ``compute_expected_2bit_quantization``): per element, with error feedback
 ``v = grad + residual``:
 

@@ -15,8 +15,7 @@ all-reduce exactly where the reference's KVStore did.
 Small-parameter packing: a ResNet-scale model carries ~160 parameters and
 ~100 BatchNorm aux states, most of them tiny 1-D vectors. Handled as
 individual XLA buffers they fragment the step into thousands of small
-copies/converts (measured: ~1200 copy ops, ~4ms/step on v5e — see
-tools/step_profile.py). All 1-D float32 trainable parameters, their
+copies/converts. All 1-D float32 trainable parameters, their
 optimizer states, and all 1-D float32 aux states are therefore packed into
 single flat donated buffers; per-name values are static slices inside the
 program and the optimizer update over the packed buffer is one fused op
@@ -1096,10 +1095,12 @@ class FusedSymbolStep:
         """XLA cost analysis of the compiled step as a plain dict
         (keys like "flops", "bytes accessed"; {} when unavailable).
         The single unwrap point for the per-computation list some jax
-        versions return — bench.py, tools/perf_sweep.py and the fusion
-        A/B tests all read costs through here. A program already
-        acquired by :meth:`step` answers from the recorded cost
-        (``_note_cost``) instead of paying a second lower+compile."""
+        versions return; the tests that compare a rewritten step's
+        bytes with the plain one's read costs through here
+        (``benchmark/`` counts FLOPs from shapes, not from this). A
+        program already acquired by :meth:`step` answers from the
+        recorded cost (``_note_cost``) instead of paying a second
+        lower+compile."""
         cached = self._program_costs.get(self._feed_sig(feed))
         if cached:
             return dict(cached)
@@ -1122,9 +1123,9 @@ class FusedSymbolStep:
 
     def compiled_program(self, feed):
         """The ALREADY-acquired executable for this feed signature, or
-        None before :meth:`step` ran it. Tools (hlo_breakdown /
-        step_profile) read HLO text and analyses off this instead of
-        paying a second lower+compile."""
+        None before :meth:`step` ran it. ``chip_smoke.py`` and the
+        tests read HLO text and analyses off this instead of paying a
+        second lower+compile."""
         return self._program_exes.get(self._feed_sig(feed))
 
     def optimizer_memory(self):

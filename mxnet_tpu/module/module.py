@@ -5,7 +5,8 @@ python/mxnet/module/module.py — bind :363, init_optimizer :472,
 forward/backward/update :570-651).
 
 Architectural mapping: the reference binds one executor per GPU via
-DataParallelExecutorGroup (executor_group.py:129, decide_slices :267) and
+DataParallelExecutorGroup (python/mxnet/module/executor_group.py:129,
+decide_slices :267) and
 reduces gradients through KVStore. Here there is ONE executor whose arrays
 are sharded over a ``jax.sharding.Mesh`` built from the ctx list: the batch
 is split over the mesh's 'data' axis (the decide_slices equivalent, even
@@ -66,8 +67,9 @@ class Module(BaseModule):
         self._context = context
         if isinstance(group2ctxs, (list, tuple)):
             # reference shape: one dict per DP context
-            # (executor_group.py group2ctxs); combining DP with placement
-            # is not supported here — raise rather than drop either axis
+            # (module/executor_group.py group2ctxs); combining DP with
+            # placement is not supported here — raise rather than drop
+            # either axis
             if len(group2ctxs) > 1:
                 raise NotImplementedError(
                     "group2ctxs with multiple entries (model parallelism "

@@ -1,7 +1,7 @@
 """Functional operator library (single source of truth for nd/sym/jit).
 
-Importing this package registers the full op surface. Pallas kernels for the
-ops XLA can't fuse well live in ``mxnet_tpu.ops.pallas_kernels``.
+Importing this package registers the full op surface. The Pallas kernels
+(BN-apply + ReLU + 1x1 convolution) live in ``mxnet_tpu.ops.pallas_fused``.
 """
 from .registry import (OpDef, register_op, get_op, has_op, list_ops, alias,
                        parse_attr)

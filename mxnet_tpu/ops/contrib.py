@@ -442,7 +442,8 @@ def box_nms(data, overlap_thresh=0.5, topk=-1, coord_start=2, score_index=1,
 def fft(data, compute_size=128, **kw):
     """Real input (..., d) -> (..., 2d) interleaved real/imag of the
     unnormalized FFT along the last axis (reference: fft-inl.h; layout
-    verified against tests/python/gpu/test_operator_gpu.py:189)."""
+    verified against the reference's GPU operator test,
+    incubator-mxnet/tests/python/gpu/test_operator_gpu.py:189)."""
     spec = jnp.fft.fft(data.astype(jnp.float32), axis=-1)
     out = jnp.stack([spec.real, spec.imag], axis=-1)
     return out.reshape(data.shape[:-1] + (2 * data.shape[-1],)) \
@@ -452,7 +453,7 @@ def fft(data, compute_size=128, **kw):
 @register_op("ifft", aliases=["_contrib_ifft"])
 def ifft(data, compute_size=128, **kw):
     """Interleaved (..., 2d) -> real (..., d), unnormalized (x d) like
-    cuFFT inverse (reference: ifft-inl.h; test_operator_gpu.py:108
+    cuFFT inverse (reference: ifft-inl.h; the same test file, :108,
     compares out/d with np.fft.ifft)."""
     d = data.shape[-1] // 2
     pairs = data.reshape(data.shape[:-1] + (d, 2)).astype(jnp.float32)

@@ -1,17 +1,18 @@
 """Pallas fused BN-apply(+ReLU)+matmul kernel and its graph-level op.
 
-docs/perf_analysis.md §3 shows single-chip ResNet-50 training is
-HBM-bandwidth bound: every BN'd activation is touched ~8x per step and
-XLA cannot fuse the normalize/activation pass across the BN statistics
-barrier into the MXU convolution that consumes it. The cuDNN-style fix —
+In ResNet-50 training XLA cannot fuse the normalize/activation pass
+across the BN statistics barrier into the MXU convolution that consumes
+it, so every BN'd activation is written and read once more than the
+arithmetic needs. The cuDNN-style fix —
 the one the reference gets from NVIDIA's libraries — is a kernel whose
 PROLOGUE applies BN+ReLU while tiles stream into the matmul,
 eliminating the materialized normalized tensor (one write + one read of
 the full activation) per 1x1 convolution.
 
-``bn_relu_matmul`` is that kernel for the generic (M, K) @ (K, N) case
-(promoted here from tools/pallas_fused_bn_bench.py once the graph-level
-integration landed; the tool now imports it from here). The graph op
+``bn_relu_matmul`` is that kernel for the generic (M, K) @ (K, N)
+case. Whether it pays on the chip is not measured: on the v5e the pass
+gate keeps it out of the default step (PERF.md section 6, PR 21). The
+graph op
 uses the NCHW-native orientation (``_make_nchw_kernel``): per sample
 the (C, H·W) slab of an NCHW activation is contiguous, so contracting
 ``w (O, C) @ xhat (C, H·W)`` streams the activation directly — no

@@ -1,12 +1,12 @@
 """Closed-loop load generator for serving measurements.
 
 One implementation of the barrier-synchronized concurrent-client
-driver shared by ``bench.py`` (the ``resnet50_serving`` section),
-``tools/serving_bench.py`` (the frontier sweep), and the serving SLO
-test — the measurement methodology (barrier start, per-request latency
-under a lock, wall-clock window from barrier release to last join)
-must not fork across the three, or their ``batcher_efficiency``
-numbers stop being comparable.
+driver shared by the tuner's serving workloads (``tune/workloads.py``),
+the fleet drills (``tools/chaos_drill.py``) and the serving SLO test — the
+measurement methodology (barrier start, per-request latency under a
+lock, wall-clock window from barrier release to last join) must not
+fork across them, or their ``batcher_efficiency`` numbers stop being
+comparable. The benchmark's own generator is ``benchmark/traffic.py``.
 
 Clients are also where RETRY policy lives (round 17): a server that
 sheds with ``Overloaded`` is telling the client "back off and come
@@ -194,8 +194,7 @@ def _expand_profile(profile):
 def ramp(batcher, x_req, profile, tenants=None, timeout=300,
          deadline_ms=None, retries=0, backoff_ms=25, jitter=0.5):
     """Closed-loop load with a TIME-VARYING client count — the traffic
-    ramp the autoscaler drills (and ``bench.py fleet_autoscale``) drive
-    against a FleetRouter.
+    ramp the autoscaler drills drive against a FleetRouter.
 
     ``profile`` is expanded by :func:`_expand_profile` (stepped or
     sine). A pool of ``max(clients)`` worker threads runs for the whole

@@ -444,8 +444,9 @@ class Predictor:
     def program_cost(self, bucket=None):
         """XLA cost dict of one bucket's compiled program (largest
         bucket by default; {} when not yet materialized or
-        unavailable). bench.py pins the BN-folded serving program's
-        bytes-accessed strictly below the unfolded one through here."""
+        unavailable). ``tests/test_passes.py`` pins the BN-folded
+        serving program's bytes-accessed strictly below the unfolded
+        one through here."""
         b = self.buckets[-1] if bucket is None else bucket
         for (bk, _dt), cost in self._program_costs.items():
             if bk == b and cost:

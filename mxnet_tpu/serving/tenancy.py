@@ -18,8 +18,8 @@ fairness mechanism).
 Every tenant gets its own registry series —
 ``serving::tenant::<name>::latency_ms`` (histogram, p50/p99 at
 snapshot), ``::shed``, ``::slo_violations`` — so per-tenant SLO
-compliance is scrape-able and ``tools/telemetry.py diff --gate-slo``
-can gate a bench run on "the latency tenant violated nothing".
+compliance is scrape-able and shows in ``tools/telemetry.py diff``
+between two snapshots.
 
 SLO-violation accounting: a completed request whose client-observed
 latency exceeds ``slo_p99_ms`` counts one violation, as does a request

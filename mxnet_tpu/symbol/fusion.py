@@ -1,10 +1,10 @@
 """Graph-rewrite fusion pass: BN(+ReLU)→1×1-conv onto the Pallas kernel.
 
-docs/perf_analysis.md §3 identifies the single highest-leverage perf
-change for the v5e training step: every batch-norm'd activation is
-touched ~8×/step because XLA cannot fuse across the BatchNorm statistics
-barrier, and the 1×1 convolutions could absorb their BN/ReLU prologues
-the way the reference's cuDNN kernels do. This pass is the graph-level
+XLA cannot fuse across the BatchNorm statistics barrier, so every
+batch-norm'd activation of the training step is written and read once
+more than the arithmetic needs; the 1×1 convolutions could absorb their
+BN/ReLU prologues the way the reference's cuDNN kernels do. This pass
+is the graph-level
 integration of the verified Pallas kernel (ops/pallas_fused.py): it
 pattern-matches
 

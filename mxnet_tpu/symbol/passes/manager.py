@@ -1,6 +1,6 @@
 """Pass manager: ordered pipeline + measured bytes-accessed gate.
 
-The rewrites exist to cut HBM traffic (docs/perf_analysis.md §3), so
+The rewrites exist to cut HBM traffic, so
 XLA's own bytes count is the gate's currency and every rewrite must
 EARN its place by measurement, in the spirit of TVM's
 measurement-driven optimization (PAPERS.md). The manager runs the registered passes in order over a

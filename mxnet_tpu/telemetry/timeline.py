@@ -1,9 +1,9 @@
 """StepTimeline: where does a training step's wall time and byte budget go?
 
-The training step's arithmetic intensity sits far below the chip's
-ridge point (docs/perf_analysis.md §3), so the two numbers that decide
-an optimization are *measured seconds per phase* and *measured bytes
-per step* — not FLOPs. The timeline attributes both:
+The timeline attributes a step's *measured seconds per phase* on the
+host and records XLA's *bytes and FLOPs per step* for the compiled
+program (counts, not device times: those are the benchmark's, PERF.md
+section 3):
 
 - **Phase attribution**: ``fit()`` opens one timeline for the run;
   each step's wall time splits across ``data_wait`` (blocked on the
@@ -49,8 +49,8 @@ PHASES = ("data_wait", "h2d_stage", "compile", "device_step", "dispatch",
 # the phases in which this thread is blocked: their spans' kind
 _WAITS = frozenset({"data_wait", "device_read"})
 
-# HBM GB/s per chip (public spec sheets); chip_smoke.py and bench.py
-# read this table through peak_hbm_bytes_s.
+# HBM GB/s per chip (public spec sheets); chip_smoke.py reads this
+# table through peak_hbm_bytes_s.
 _PEAK_HBM_GBS = {
     "TPU v5 lite": 819.0,
     "TPU v5e": 819.0,

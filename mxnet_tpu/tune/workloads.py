@@ -20,16 +20,16 @@ Three measurement families, all reusing machinery that already exists:
   ``MXTPU_TUNE_HBM_BUDGET``).
 - :class:`ServingWorkload` — objective ``p99_ms`` at a fixed
   closed-loop load (``serving/loadgen.py`` through a DynamicBatcher —
-  the ONE closed-loop measurement implementation, shared with
-  ``tools/serving_bench.py``) over bucket-set × ``max_wait_us`` knobs.
+  the ONE closed-loop measurement implementation) over bucket-set ×
+  ``max_wait_us`` knobs.
 - :class:`DataPipelineWorkload` — objective ``wall_s_per_batch`` to
   drain N batches through a ``DataPipeline`` under the trial's
   ``MXTPU_DATA_WORKERS`` / ``MXTPU_DATA_STAGE_AHEAD``.
 
 ``conv_proxy()`` / ``sparse_proxy()`` are the built-in CPU-proxy
 workloads (the conv family's BN→ReLU→1×1-conv tower and the sparse
-family's two-tower embedding+conv recommender) shared by ``bench.py
-tuned_vs_default``, ``tools/tune.py``, and the tier-1 tests.
+family's two-tower embedding+conv recommender) shared by
+``tools/tune.py`` and the tier-1 tests.
 """
 from __future__ import annotations
 
@@ -206,8 +206,8 @@ def measure_serving(predictor, feat, max_wait_us, clients, per_client=8,
                     timeout=600):
     """THE closed-loop serving measurement: single-row clients through
     a DynamicBatcher over ``predictor``, plus the RAW compiled predict
-    rate at the top bucket for the efficiency column. Shared verbatim
-    by :class:`ServingWorkload` and ``tools/serving_bench.py``."""
+    rate at the top bucket for the efficiency column. What
+    :class:`ServingWorkload` measures."""
     import numpy as np
     from .. import serving
     from ..serving import loadgen
@@ -295,9 +295,7 @@ def measure_decode_serving(predictor, prompts, max_wait_us, clients,
                            per_client=2, max_new_tokens=6, timeout=600):
     """THE closed-loop decode measurement: streaming clients through a
     DecodeBatcher over ``predictor`` (``loadgen.token_closed_loop``,
-    the one token-granularity driver — shared with
-    ``tools/serving_bench.py --decode`` and the bench section). The
-    objective folds both token SLOs into one end-to-end generation p99
+    the one token-granularity driver). The objective folds both token SLOs into one end-to-end generation p99
     proxy: ``ttft_p99 + max_new_tokens * inter_token_p99``."""
     from ..serving import loadgen
     from ..serving.decode import DecodeBatcher
@@ -637,7 +635,7 @@ class DataPipelineWorkload(Workload):
 
 
 # ---------------------------------------------------------------------------
-# built-in CPU proxies (bench.py tuned_vs_default / tools/tune.py / tests)
+# built-in CPU proxies (tools/tune.py / tests)
 # ---------------------------------------------------------------------------
 def _conv_symbol():
     """The conv family proxy: a BN→ReLU→1×1-conv tower (the exact

@@ -91,19 +91,3 @@ def test_accelerator_context_raises_without_an_accelerator():
     with pytest.raises(mx.MXNetError, match="names an accelerator"):
         mx.gpu(0).jax_device
     assert mx.current_context().device_type == "cpu"
-
-
-def test_bench_refuses_a_device_it_has_no_peak_for():
-    """A utilization against an unknown peak is not a number: bench.py's
-    peak lookup raises for a ``device_kind`` outside its table."""
-    import bench
-
-    class _Cpu:
-        device_kind = "cpu"
-
-    class _V5e:
-        device_kind = "TPU v5 lite"
-
-    with pytest.raises(SystemExit, match="not in the peak table"):
-        bench._peak_flops(_Cpu())
-    assert bench._peak_flops(_V5e()) == 197e12

@@ -23,8 +23,7 @@
   parameter when a rule no longer divides.
 
 All cases run on the conftest-forced 8-device virtual CPU platform —
-the same mesh the driver's dryrun and bench.py's ``multichip_fused``
-section use.
+the same mesh the driver's dryrun (``__graft_entry__.py``) uses.
 """
 import os
 import warnings

@@ -1,16 +1,9 @@
 """Correctness of the fused BN-apply(+ReLU)+matmul Pallas kernels
-(mxnet_tpu/ops/pallas_fused.py — the path past the v5e HBM roofline,
-docs/perf_analysis.md §3/§5). Runs the real kernels on TPU and interpret
-mode elsewhere; the graph-level rewrite that routes BN→ReLU→1×1-conv
+(mxnet_tpu/ops/pallas_fused.py). Runs the real kernels on TPU and
+interpret mode elsewhere; the graph-level rewrite that routes BN→ReLU→1×1-conv
 subgraphs onto them is covered by tests/test_fusion_pass.py."""
-import os
-import sys
-
 import numpy as np
 import pytest
-
-sys.path.insert(0, os.path.join(os.path.dirname(
-    os.path.abspath(__file__)), "..", "tools"))
 
 
 def _inputs(m=512, k=64, n=256):
@@ -23,17 +16,24 @@ def _inputs(m=512, k=64, n=256):
     return x, w, scale, shift
 
 
+def unfused(x, w, scale, shift):
+    """The plain ``jax.numpy`` reference the kernels are held to."""
+    import jax.numpy as jnp
+    xhat = jnp.maximum(x * scale + shift, 0.0).astype(x.dtype)
+    return jnp.dot(xhat, w, preferred_element_type=jnp.float32).astype(
+        x.dtype)
+
+
 def test_bn_relu_matmul_matches_unfused():
     import jax
     from jax.experimental import pallas as pl
-    from mxnet_tpu.ops.pallas_fused import interpret_mode
-    from pallas_fused_bn_bench import _kernel, unfused
+    from mxnet_tpu.ops.pallas_fused import _make_kernel, interpret_mode
 
     m, k, n = 512, 64, 256
     x, w, scale, shift = _inputs(m, k, n)
     bm, bn = 256, 128
     out = pl.pallas_call(
-        _kernel,
+        _make_kernel(relu=True),
         grid=(m // bm, n // bn),
         in_specs=[
             pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
@@ -56,7 +56,6 @@ def test_bn_relu_matmul_api_and_grad():
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops.pallas_fused import bn_relu_matmul
-    from pallas_fused_bn_bench import unfused
 
     x, w, scale, shift = _inputs()
     out = bn_relu_matmul(x, w, scale, shift)

@@ -5,8 +5,7 @@ The acceptance bar for the dynamic batcher: with enough concurrent
 clients to keep full buckets in flight, end-to-end throughput THROUGH
 the queue/coalesce/pad/split machinery must reach >= 80% of the raw
 compiled predict-step rate at the largest bucket — i.e. the batching
-layer costs at most 20%. bench.py records the same ratio on the bench
-model as ``serving.batcher_efficiency``.
+layer costs at most 20%.
 """
 import numpy as np
 import pytest

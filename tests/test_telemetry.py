@@ -543,22 +543,6 @@ def test_diff_gate_bytes_fails_on_regression(tmp_path, capsys):
         {"old": 1000.0, "new": 1100.0}
 
 
-def test_diff_gate_reads_bench_json_too(tmp_path, capsys):
-    """BENCH_rNN.json files (bench.py output) double as gate baselines:
-    the gate reads xla_bytes_accessed_per_step or the embedded
-    telemetry snapshot."""
-    bench_old = tmp_path / "bench_old.json"
-    bench_old.write_text(json.dumps(
-        {"metric": "x", "xla_bytes_accessed_per_step": 500.0}))
-    bench_new = tmp_path / "bench_new.json"
-    bench_new.write_text(json.dumps(
-        {"metric": "x", "telemetry": {"metrics": {
-            "step::bytes_accessed": {"kind": "gauge", "value": 600.0}}}}))
-    assert telemetry_cli.main(["diff", str(bench_old), str(bench_new),
-                               "--gate-bytes"]) == 2
-    capsys.readouterr()
-
-
 # ---------------------------------------------------------------------------
 # serving fleet-readiness: per-predictor identity
 # ---------------------------------------------------------------------------

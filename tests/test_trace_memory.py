@@ -463,15 +463,6 @@ def test_gate_peak_mem_cli(tmp_path):
                        capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stderr
 
-    # a BENCH JSON baseline works through the memory.* fallback
-    bench_old = tmp_path / "bench_old.json"
-    bench_old.write_text(json.dumps(
-        {"memory": {"process_peak_bytes": 1000}}))
-    r = subprocess.run([sys.executable, cli, "diff", str(bench_old),
-                        str(new_bad), "--gate-peak-mem"],
-                       capture_output=True, text=True, timeout=120)
-    assert r.returncode == 2
-
 
 # ---------------------------------------------------------------------------
 # fleet aggregation (multi-process chaos drill)

@@ -20,9 +20,7 @@
 - MXTPU_PALLAS_TILES: loud validation, per-dimension override of the
   Pallas tile selection;
 - tools/tune.py verify: exit 2 on objective regression, exit 1 on a
-  corrupt store;
-- tools/serving_bench.py drives its sweep through the tuner's trial
-  runner (one closed-loop measurement implementation).
+  corrupt store.
 """
 import json
 import os
@@ -567,32 +565,6 @@ def test_cli_verify_exit_codes(tmp_path):
         f.truncate(32)
     r = _cli(tmp_path, "verify", "--json")
     assert r.returncode == 1, r.stdout + r.stderr
-
-
-# ---------------------------------------------------------------------------
-# one closed-loop measurement implementation
-# ---------------------------------------------------------------------------
-@pytest.mark.serving
-def test_serving_bench_drives_the_trial_runner():
-    """tools/serving_bench.py sweeps through TrialRunner over
-    tune.workloads.measure_serving — the same measurement autotune
-    uses — and returns trials in spec order with the frontier row in
-    trial.metrics."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "serving_bench", os.path.join(_ROOT, "tools",
-                                      "serving_bench.py"))
-    sb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(sb)
-    assert sb.parse_spec("1,8:500:4") == ((1, 8), 500, 4)
-    trials = sb.sweep(["1,2:400:2"], small=True, per_client=2)
-    assert len(trials) == 1
-    t = trials[0]
-    assert t.status == "measured", (t.status, t.reason)
-    assert t.objective == t.metrics["p99_ms"] > 0
-    for k in ("rows_s", "p50_ms", "efficiency", "hot_bucket",
-              "retraces"):
-        assert k in t.metrics
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,8 @@
 Walks every distinct registered op, instantiates inputs (defaults by
 signature arity + per-op overrides), and checks jax.grad against central
 finite differences — the registry-scale analog of the reference's
-check_numeric_gradient coverage in tests/python/unittest/test_operator.py.
+check_numeric_gradient coverage in its own
+incubator-mxnet/tests/python/unittest/test_operator.py.
 
 Run directly to see the status table; the frozen CI version lives in
 tests/test_op_gradients.py (same case table, imported from here).

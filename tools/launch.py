@@ -21,7 +21,7 @@ survivors at the shrunken world — the exit-75 relaunch protocol,
 across hosts:
 
     python tools/launch.py --elastic --hosts 2 --host-id 0 \\
-        --procs-per-host 1 --workdir /shared/job1 python worker.py ...
+        --procs-per-host 1 --workdir /shared/job1 python <worker>.py ...
 """
 import argparse
 import os
