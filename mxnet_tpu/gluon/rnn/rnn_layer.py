@@ -2,9 +2,14 @@
 
 Each layer keeps per-layer/direction i2h/h2h weights (reference param
 naming for checkpoint parity) and concatenates them into the flat
-cuDNN-layout vector consumed by the fused ``RNN`` op — one ``lax.scan``
-whose body is batched MXU matmuls (the cuDNN-fused-kernel analog,
-src/operator/cudnn_rnn-inl.h).
+cuDNN-layout vector consumed by the fused ``RNN`` op (the
+cuDNN-fused-kernel analog, src/operator/cudnn_rnn-inl.h). There a layer
+and direction is one i2h product over the whole sequence before a
+``lax.scan``, one h2h product a step inside it, and in the backward pass
+one whole-sequence product before the scan (the h2h pre-activations
+again), one product a step inside it and three whole-sequence products
+after it (``ops/nn.py`` ``_run_layer``). The op has no forward-mode
+derivative (``jax.jvp`` raises).
 """
 from __future__ import annotations
 

@@ -429,7 +429,10 @@ def dropout(data, p=0.5, mode="training", axes=None, key=None, training=None, **
 # ---------------------------------------------------------------------------
 # RNN — fused multi-layer RNN/LSTM/GRU via lax.scan
 # (reference: src/operator/rnn-inl.h + cudnn_rnn-inl.h; the cuDNN fused kernel
-# maps to one scan whose body is MXU matmuls over the whole batch)
+# maps, a layer and direction, to one product over the whole sequence, a
+# scan whose body is the one product that needs the step before it, and in
+# the backward pass one whole-sequence product before its scan and three
+# after it)
 # ---------------------------------------------------------------------------
 def _rnn_gate_count(mode):
     return {"rnn_relu": 1, "rnn_tanh": 1, "lstm": 4, "gru": 3}[mode]
@@ -483,49 +486,114 @@ def rnn_param_size(mode, num_layers, input_size, state_size, bidirectional=False
     return total
 
 
-def _lstm_cell_step(carry, x_t, wx, wh, bx, bh, h):
-    c, hprev = carry
-    gates = x_t @ wx.T + hprev @ wh.T + bx + bh
+# The elementwise part of one time step, ``cell(carry, *pre) -> carry`` with
+# the hidden state last in the carry: all that the four modes differ in.
+# ``pre`` is what the cell reads of the input's and the state's
+# pre-activations ``gx_t`` / ``gh_t`` (each with its bias, (N, G*H)): their
+# sum, or for the GRU, whose reset gate scales a part of ``gh_t``, the two.
+def _lstm_cell(carry, gates):
+    c, _ = carry
     i, f, g, o = jnp.split(gates, 4, axis=-1)
-    i, f, o = jax.nn.sigmoid(i), jax.nn.sigmoid(f), jax.nn.sigmoid(o)
-    g = jnp.tanh(g)
-    c_new = f * c + i * g
-    h_new = o * jnp.tanh(c_new)
-    return (c_new, h_new), h_new
+    c_new = jax.nn.sigmoid(f) * c + jax.nn.sigmoid(i) * jnp.tanh(g)
+    return c_new, jax.nn.sigmoid(o) * jnp.tanh(c_new)
 
 
-def _gru_cell_step(carry, x_t, wx, wh, bx, bh, h):
+def _gru_cell(carry, gx, gh):
     (hprev,) = carry
-    gx = x_t @ wx.T + bx
-    gh = hprev @ wh.T + bh
     rx, zx, nx = jnp.split(gx, 3, axis=-1)
     rh, zh, nh = jnp.split(gh, 3, axis=-1)
     r = jax.nn.sigmoid(rx + rh)
     z = jax.nn.sigmoid(zx + zh)
     n = jnp.tanh(nx + r * nh)
-    h_new = (1 - z) * n + z * hprev
-    return (h_new,), h_new
+    return ((1 - z) * n + z * hprev,)
 
 
-def _vanilla_cell_step(act):
-    def step(carry, x_t, wx, wh, bx, bh, h):
-        (hprev,) = carry
-        h_new = act(x_t @ wx.T + hprev @ wh.T + bx + bh)
-        return (h_new,), h_new
-    return step
+def _rnn_tanh_cell(carry, gates):
+    return (jnp.tanh(gates),)
+
+
+def _rnn_relu_cell(carry, gates):
+    return (jax.nn.relu(gates),)
+
+
+_RNN_CELLS = {"lstm": _lstm_cell, "gru": _gru_cell,
+              "rnn_tanh": _rnn_tanh_cell, "rnn_relu": _rnn_relu_cell}
+
+
+def _cell_reads(cell, gx, gh):
+    """``pre`` for ``cell``, of one step or of all steps at once."""
+    return (gx, gh) if cell is _gru_cell else (gx + gh,)
+
+
+def _recurrence_step(cell, carry, *pre):
+    """One step's new carry, each state in the dtype it came in."""
+    return tuple(new.astype(old.dtype)
+                 for new, old in zip(cell(carry, *pre), carry))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _recurrence(cell, reverse, gx, wh, bh, init):
+    """What of a layer is sequential: from the input's pre-activations
+    ``gx`` (T, N, G*H) and the entering carry, ``(final carry, ys)``. One
+    for the four modes and both directions. The scan's body holds the one
+    product that needs the step before it, ``h @ wh.T`` forward and
+    ``d gh_t @ wh`` backward; what the backward pass needs of every step
+    at once is a product over the whole sequence outside the scan: the
+    states' pre-activations again before it (the forward scan stacks each
+    step's entering carry and nothing as wide as the gates), ``wh``'s and
+    ``bh``'s gradients after it, not sums the loop carries. A
+    ``custom_vjp``: it has no forward-mode derivative."""
+    return _recurrence_fwd(cell, reverse, gx, wh, bh, init)[0]
+
+
+def _recurrence_fwd(cell, reverse, gx, wh, bh, init):
+    def body(carry, gx_t):
+        gh_t = carry[-1] @ wh.T + bh
+        new = _recurrence_step(cell, carry, *_cell_reads(cell, gx_t, gh_t))
+        return new, (new[-1], carry)
+
+    with jax.named_scope("mx_rnn_scan"):
+        carry, (ys, entering) = jax.lax.scan(body, init, gx, reverse=reverse)
+    return (carry, ys), (gx, wh, bh, entering)
+
+
+def _recurrence_bwd(cell, reverse, residuals, cotangents):
+    gx, wh, bh, entering = residuals
+    d_final, d_ys = cotangents
+
+    def body(d_carry, step):
+        carry, pre_t, d_y = step
+        _, pull = jax.vjp(partial(_recurrence_step, cell), carry, *pre_t)
+        d_prev, *d_pre_t = pull(d_carry[:-1] + (d_carry[-1] + d_y,))
+        d_h = (d_prev[-1] + d_pre_t[-1] @ wh).astype(d_prev[-1].dtype)
+        return d_prev[:-1] + (d_h,), tuple(d_pre_t)
+
+    with jax.named_scope("mx_rnn_scan"):
+        gh = jnp.einsum("tnh,gh->tng", entering[-1], wh) + bh
+        d_init, d_pre = jax.lax.scan(
+            body, d_final, (entering, _cell_reads(cell, gx, gh), d_ys),
+            reverse=not reverse)
+        # of gx and gh: the sum's cotangent is that of both
+        d_gx, d_gh = d_pre[0].astype(gx.dtype), d_pre[-1]
+        d_wh = jnp.einsum(
+            "tng,tnh->gh", d_gh, entering[-1],
+            preferred_element_type=jnp.promote_types(gh.dtype, jnp.float32))
+        d_bh = jnp.sum(d_gh, axis=(0, 1))
+    return d_gx, d_wh.astype(wh.dtype), d_bh.astype(bh.dtype), d_init
+
+
+_recurrence.defvjp(_recurrence_fwd, _recurrence_bwd)
 
 
 def _run_layer(xs, mode, wx, wh, bx, bh, h0, c0=None, reverse=False):
-    step = {"lstm": _lstm_cell_step, "gru": _gru_cell_step,
-            "rnn_tanh": _vanilla_cell_step(jnp.tanh),
-            "rnn_relu": _vanilla_cell_step(jax.nn.relu)}[mode]
+    """One layer in one direction over ``xs`` (T, N, C): ``(carry, ys)``.
+    The input's pre-activations of all T steps are one product before the
+    scan (so are ``wx``'s, ``bx``'s and ``xs``'s gradients, by autodiff of
+    it); the scan is ``_recurrence``."""
+    with jax.named_scope("mx_rnn_input"):
+        gx = jnp.einsum("tnc,gc->tng", xs, wx) + bx
     init = (c0, h0) if mode == "lstm" else (h0,)
-
-    def body(carry, x_t):
-        return step(carry, x_t, wx, wh, bx, bh, None)
-
-    carry, ys = jax.lax.scan(body, init, xs, reverse=reverse)
-    return carry, ys
+    return _recurrence(_RNN_CELLS[mode], reverse, gx, wh, bh, init)
 
 
 @register_op("RNN", num_outputs=-1)
@@ -534,9 +602,17 @@ def rnn(data, parameters, state, state_cell=None, state_size=None,
         state_outputs=False, lstm_state_clip_min=None, lstm_state_clip_max=None,
         lstm_state_clip_nan=False, training=None, key=None, **kw):
     """Fused RNN (reference: src/operator/rnn-inl.h, data layout (T, N, C);
-    state (L*dirs, N, H)). Implemented as stacked ``lax.scan`` — the TPU-native
-    replacement of the cuDNN fused RNN kernel. ``p`` applies dropout between
-    stacked layers in training mode (rnn-inl.h inter-layer dropout)."""
+    state (L*dirs, N, H)), the TPU-native replacement of the cuDNN fused RNN
+    kernel. A layer and direction runs as ``_run_layer``: forward, the
+    input's product for all T steps at once, then a ``lax.scan`` whose body
+    holds one product, ``h @ Wh.T``; backward, the states' pre-activations
+    of all steps again as one product, a scan whose body holds one product,
+    ``d gh_t @ Wh``, and after it three products over the whole sequence
+    (the gradients of ``Wh``, ``Wx`` and the input). The scan is a
+    ``jax.custom_vjp``, so the operator has reverse-mode derivatives and no
+    forward-mode one (``jax.jvp`` / ``jax.jacfwd`` raise). ``p`` applies
+    dropout between stacked layers in training mode (rnn-inl.h inter-layer
+    dropout)."""
     T, N, C = data.shape
     dirs = 2 if bidirectional else 1
     layers = rnn_unpack_params(parameters, mode, num_layers, C, state_size,

@@ -199,9 +199,11 @@ def test_kept_is_the_identity_outside_a_unit():
         lambda v: remat.kept(v.astype(jnp.bfloat16)) * 2)(a).jaxpr) == 12
 
 
-#: sha256 of the lowered text below at the parent of the PR that brought
-#: ``ops.remat`` (commit 4c1499b), computed there with this function
-LSTM_STEP_SHA256 = "f67946b1eb295d0c8336e30bbe37b14245159dded09cc25deaad73309a92411c"
+#: sha256 of the lowered text below as the PR that cut the ``RNN`` operator's
+#: scans down to what is sequential left it (PR 32; f67946b1... from the
+#: parent of the PR that brought ``ops.remat``, commit 4c1499b, until then),
+#: computed with this function
+LSTM_STEP_SHA256 = "fadd698662e942bb9bdb0a691e911ddb16f877c123d04d53178197b31832c5ec"
 
 
 def _lstm_step_text():
@@ -223,8 +225,8 @@ def _lstm_step_text():
 def test_lstm_lm_step_is_the_program_it_was():
     """``lstm-lm-train`` shares ``TrainStep`` and builds it without
     ``remat``: nothing of the units reaches it. Its lowered step at the
-    rehearsal sizes is, to the byte, the text of the parent commit (a PR
-    that means to change this cell's program computes the hash anew)."""
+    rehearsal sizes is, to the byte, the text recorded above (a PR that
+    means to change this cell's program computes the hash anew)."""
     step, args, text = _lstm_step_text()
     assert step.remat is False
     prims = collections.Counter()
