@@ -304,10 +304,13 @@ class remat_units:
     """While active, blocks marked ``_remat_unit`` checkpoint their call
     inside a staged forward (``TrainStep(remat="layer")``). ``saved``:
     unit prefix -> the bytes that unit holds for its backward pass
-    beside its inputs (``ops.remat``), once the forward is traced."""
+    beside its inputs (``ops.remat``), once the forward is traced.
+    ``trips``: how often what is being traced now will run (a scanned
+    body's trace stands for every trip: ``nn.HybridLoop`` sets it)."""
 
     def __init__(self):
         self.saved = {}
+        self.trips = 1
 
     def __enter__(self):
         self._prev = getattr(_TraceState._current, "remat_units", None)
@@ -450,7 +453,7 @@ class HybridBlock(Block):
             jax.checkpoint(unit, policy=remat.POLICY),
             return_shape=True)(*arrays)
         units.saved[self.prefix] = units.saved.get(self.prefix, 0) \
-            + remat.kept_bytes(traced.jaxpr)
+            + units.trips * remat.kept_bytes(traced.jaxpr)
         outs, writes = jax.tree.unflatten(
             jax.tree.structure(shapes),
             jax.core.eval_jaxpr(traced.jaxpr, traced.consts, *arrays))

@@ -1,9 +1,16 @@
 """A language model built from a layer pattern: one character a layer,
 ``M`` a Mamba-2 mixer, ``E`` a latent mixture of experts, ``*`` causal
 grouped-query attention (the ``hybrid_override_pattern`` of the
-``nemotron_h`` family's configurations). Pre-norm residual throughout:
-``x <- x + Mixer_l(RMSNorm_l(x))``, one final RMSNorm, an untied head, no
-bias but the convolution's.
+``nemotron_h`` family's configurations), ``G`` a gated MLP. Pre-norm
+residual throughout, ``x <- x + Mixer_l(RMSNorm_l(x))``, or with
+``post_norm`` a norm on either side of the mixer, ``x <- x +
+RMSNorm'_l(Mixer_l(RMSNorm_l(x)))``; one final RMSNorm, an untied head,
+no bias but the convolution's.
+
+With ``loops`` above 1 the whole stack, final norm included, runs that
+many times over its own output with the same weights, as one scanned body
+(``nn.HybridLoop``). With ``exit_gate`` the model returns what a loss over
+every pass needs (``parallel.exit_weighted_loss``) in place of logits.
 
 The model is told what it holds of each layer (heads, groups, experts,
 columns, rows of the vocabulary): one chip's share of a deployment, whose
@@ -11,6 +18,7 @@ partial sums go on to the next layer as they are.
 """
 from __future__ import annotations
 
+from ...ndarray.ndarray import _wrap
 from ..block import HybridBlock
 from .. import nn
 
@@ -18,52 +26,75 @@ __all__ = ["PatternLM"]
 
 
 class _Layer(HybridBlock):
-    """``x + mixer(norm(x))``: the unit that ``TrainStep(remat="layer")``
-    recomputes."""
+    """``x + mixer(norm(x))``, or ``x + post_norm(mixer(norm(x)))``: the
+    unit that ``TrainStep(remat="layer")`` recomputes."""
     _remat_unit = True
 
-    def __init__(self, units, mixer, epsilon, **kwargs):
+    def __init__(self, units, mixer, epsilon, post_norm=False, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
             self.norm = nn.RMSNorm(units, epsilon)
             self.mixer = mixer()
+            # reads the mixer's last product, which the unit then keeps
+            self.post_norm = nn.RMSNorm(units, epsilon, keep_input=True) \
+                if post_norm else None
 
     def hybrid_forward(self, F, x):
-        return x + self.mixer(self.norm(x))
+        out = self.mixer(self.norm(x))
+        return x + (out if self.post_norm is None else self.post_norm(out))
 
 
 class PatternLM(HybridBlock):
-    """``pattern``: the layers held, e.g. ``"MEMEMEMEM*E"``. ``mamba``,
-    ``moe``, ``attention``: the keyword arguments of ``nn.Mamba2Mixer``,
-    ``nn.LatentMoE`` and ``nn.GQAttention`` after ``in_units`` (what each
-    layer of that kind holds). Input (B, L) token ids below ``vocab``;
-    output (B * L, vocab) logits."""
+    """``pattern``: the layers held, e.g. ``"MEMEMEMEM*E"`` or
+    ``"*G*G"``. ``mamba``, ``moe``, ``attention``, ``mlp``: the keyword
+    arguments of ``nn.Mamba2Mixer``, ``nn.LatentMoE``, ``nn.GQAttention``
+    and ``nn.GatedMLP`` after ``in_units`` (what each layer of that kind
+    holds). ``post_norm``: a second norm in every layer, after its mixer.
+    ``loops``: how often the stack and the final norm run, each pass on
+    the one before's output.
+
+    Input (B, L) token ids below ``vocab``; output (B * L, vocab) logits
+    of the last pass. With ``exit_gate``, three outputs for a loss over
+    all passes: the hidden states after each pass, (``loops``, B * L,
+    units); the logits of the gates of all passes but the last
+    (``nn.ExitGate``), (``loops`` - 1, B * L) float32; and the head's
+    weight, (vocab, units), for the loss to compute each pass's logits
+    where it can drop them again."""
 
     def __init__(self, pattern, vocab, units, mamba=None, moe=None,
-                 attention=None, epsilon=1e-5, **kwargs):
+                 attention=None, mlp=None, epsilon=1e-5, post_norm=False,
+                 loops=1, exit_gate=False, **kwargs):
         super().__init__(**kwargs)
         make = {"M": lambda: nn.Mamba2Mixer(units, epsilon=epsilon,
                                             **mamba),
                 "E": lambda: nn.LatentMoE(units, **moe),
-                "*": lambda: nn.GQAttention(units, **attention)}
-        self._vocab = vocab
+                "*": lambda: nn.GQAttention(units, **attention),
+                "G": lambda: nn.GatedMLP(units, **mlp)}
+        self._vocab, self._units = vocab, units
         with self.name_scope():
             self.embed = nn.Embedding(vocab, units)
-            self.layers = []
+            # the container takes no part in its children's names
+            self.stack = nn.HybridLoop(loops, prefix="")
             for i, kind in enumerate(pattern):
                 if kind not in make:
                     raise ValueError(f"layer kind {kind!r} in {pattern!r}: "
-                                     "M, E and * are known")
-                layer = _Layer(units, make[kind], epsilon,
-                               prefix=f"l{i}_")
-                self.register_child(layer)
-                self.layers.append(layer)
-            self.final_norm = nn.RMSNorm(units, epsilon)
+                                     "M, E, * and G are known")
+                self.stack.add(_Layer(units, make[kind], epsilon, post_norm,
+                                      prefix=f"l{i}_"))
+            final = nn.RMSNorm(units, epsilon)
+            # a scan holds for its backward pass whatever its body computes
+            # outside a unit as autodiff leaves it (of this norm, three
+            # float32 copies of its rows a pass): a unit holds its input
+            final._remat_unit = loops > 1
+            self.stack.add(final)
             self.head = nn.Dense(vocab, use_bias=False, flatten=False,
                                  in_units=units)
+            self.gate = nn.ExitGate(units, loops) if exit_gate else None
 
     def hybrid_forward(self, F, x):
         h = self.embed(x)
-        for layer in self.layers:
-            h = layer(h)
-        return self.head(self.final_norm(h)).reshape((-1, self._vocab))
+        if self.gate is None:
+            return self.head(self.stack.last(h)).reshape((-1, self._vocab))
+        hidden = self.stack(h).reshape((0, -1, self._units))
+        # the weight's array of this trace, not the Parameter's own holder
+        return hidden, self.gate(hidden), _wrap(self.head.weight.data()._data)

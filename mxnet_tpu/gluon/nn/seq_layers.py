@@ -1,5 +1,7 @@
-"""Sequence-model blocks: ``RMSNorm``, ``Mamba2Mixer``, ``LatentMoE`` and
-``GQAttention`` over the ops of ``ops/seq.py``.
+"""Sequence-model blocks: ``RMSNorm``, ``Mamba2Mixer``, ``LatentMoE``,
+``GQAttention``, ``GatedMLP``, the ``HybridLoop`` container that runs its
+children several times as one scanned body, and ``ExitGate``, over the
+ops of ``ops/seq.py``.
 
 Each block is told what it holds of the layer: how many heads and groups,
 which experts, how many of the shared expert's columns. A block that holds
@@ -8,10 +10,12 @@ a share returns that share's partial sum (``ops/seq.py``).
 from __future__ import annotations
 
 from ... import autograd
-from ..block import HybridBlock, stateful_write
+from ...ndarray.ndarray import _wrap
+from ..block import HybridBlock, _TraceState, stateful_write
 
-__all__ = ["RMSNorm", "Mamba2Mixer", "LatentMoE", "GQAttention",
-           "MOE_COUNTERS", "publish_moe_counters"]
+__all__ = ["RMSNorm", "Mamba2Mixer", "LatentMoE", "GQAttention", "GatedMLP",
+           "HybridLoop", "ExitGate", "MOE_COUNTERS", "publish_moe_counters",
+           "publish_loop_counters"]
 
 #: what ``LatentMoE.counters`` holds, in order: the gauges
 #: ``moe::<name>::<block>`` of ``publish_moe_counters``
@@ -40,15 +44,22 @@ def publish_moe_counters(net):
 
 
 class RMSNorm(HybridBlock):
-    def __init__(self, in_channels, epsilon=1e-5, **kwargs):
+    """``keep_input``: inside a recomputation unit, hold the norm's input
+    for the backward pass (a norm after a sublayer, whose input is that
+    sublayer's last product)."""
+
+    def __init__(self, in_channels, epsilon=1e-5, keep_input=False,
+                 **kwargs):
         super().__init__(**kwargs)
-        self._epsilon = epsilon
+        self._attrs = {"eps": epsilon}
+        if keep_input:
+            self._attrs["keep_input"] = True
         with self.name_scope():
             self.gamma = self.params.get("gamma", shape=(in_channels,),
                                          init="ones")
 
     def hybrid_forward(self, F, x, gamma):
-        return F.RMSNorm(x, gamma, eps=self._epsilon)
+        return F.RMSNorm(x, gamma, **self._attrs)
 
 
 class Mamba2Mixer(HybridBlock):
@@ -138,15 +149,19 @@ class LatentMoE(HybridBlock):
 
 
 class GQAttention(HybridBlock):
-    """Causal grouped-query attention with no positional encoding
-    (``ops.seq.causal_gq_attention``) between a fused ``[q | k | v]``
-    projection and the output projection, over the heads held here."""
+    """Causal grouped-query attention (``ops.seq.causal_gq_attention``)
+    between a fused ``[q | k | v]`` projection and the output projection,
+    over the heads held here. ``rope_theta``: the base of the rotary
+    position encoding applied to queries and keys; without it the layer
+    has no positional encoding."""
 
     def __init__(self, in_units, num_heads, num_kv_heads, head_dim=128,
-                 block=1024, **kwargs):
+                 block=1024, rope_theta=None, **kwargs):
         super().__init__(**kwargs)
         self._attrs = {"num_heads": num_heads, "num_kv_heads": num_kv_heads,
                        "head_dim": head_dim, "block": block}
+        if rope_theta is not None:
+            self._attrs["rope_theta"] = float(rope_theta)
         with self.name_scope():
             self.qkv_weight = self.params.get(
                 "qkv_weight",
@@ -158,3 +173,146 @@ class GQAttention(HybridBlock):
         qkv = F.FullyConnected(x, qkv_weight, no_bias=True, flatten=False)
         out = F.CausalGQAttention(qkv, **self._attrs)
         return F.FullyConnected(out, o_weight, no_bias=True, flatten=False)
+
+
+class GatedMLP(HybridBlock):
+    """``W_down (silu(W_gate u) * W_up u)`` with no bias
+    (``ops.seq.gated_mlp``); ``gate_up_weight`` holds ``[W_gate | W_up]``
+    as the rows of one (2 ``units``, ``in_units``) matrix."""
+
+    def __init__(self, in_units, units, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.gate_up_weight = self.params.get(
+                "gate_up_weight", shape=(2 * units, in_units))
+            self.down_weight = self.params.get(
+                "down_weight", shape=(in_units, units))
+
+    def hybrid_forward(self, F, x, gate_up_weight, down_weight):
+        return F.GatedMLP(x, gate_up_weight, down_weight)
+
+
+class HybridLoop(HybridBlock):
+    """Runs its children, in the order added, ``loops`` times over their
+    own output with the same weights: ``h_t = children(h_{t-1})``. The
+    trips are one ``lax.scan`` body, so a program holds one copy of the
+    children whatever ``loops`` is, and a weight's gradient is the sum
+    over its uses. One trip is no loop: the children run once, unscanned.
+
+    Calling the block gives every trip's output stacked, (``loops``,
+    ...); ``last(x)`` gives the final trip's alone. The children keep
+    their input's shape and dtype. Recomputation units among them work
+    inside the body (``remat::saved_bytes`` counts a unit's bytes times
+    the trips). A child may not write a parameter (``stateful_write``:
+    the next trip would not read it), and random draws repeat from trip
+    to trip: the body is traced once."""
+
+    def __init__(self, loops, **kwargs):
+        super().__init__(**kwargs)
+        self._loops = int(loops)
+        #: how often the children's forward ran (was traced) in the last
+        #: call: 1 when the trips are a scan
+        self.body_traces = 0
+
+    def add(self, *blocks):
+        for block in blocks:
+            self.register_child(block)
+
+    def _once(self, x):
+        self.body_traces += 1
+        for block in self._children.values():
+            x = block(x)
+        return x
+
+    def _scan(self, x):
+        """``(last, every)`` of ``loops`` scanned trips from ``x``."""
+        import jax
+        outer = _TraceState.active()
+        units = getattr(_TraceState._current, "remat_units", None)
+
+        def body(h, _):
+            inner = _TraceState()
+            _TraceState._current.value = inner
+            if units is not None:
+                units.trips *= self._loops
+            try:
+                out = self._once(_wrap(h))._data
+            finally:
+                _TraceState._current.value = outer
+                if units is not None:
+                    units.trips //= self._loops
+            if inner.writes:
+                raise NotImplementedError(
+                    f"{self.name}: a child wrote "
+                    f"{[p.name for p in inner.writes]} inside the looped "
+                    "body; the next trip would not read it")
+            return out, out
+
+        with jax.named_scope("mx_loop_body"):
+            last, every = jax.lax.scan(body, x._data, None,
+                                       length=self._loops)
+        return _wrap(last), _wrap(every)
+
+    def last(self, x):
+        self.body_traces = 0
+        return self._once(x) if self._loops == 1 else self._scan(x)[0]
+
+    def forward(self, x):
+        self.body_traces = 0
+        if self._loops == 1:
+            return self._once(x).expand_dims(0)
+        return self._scan(x)[1]
+
+
+class ExitGate(HybridBlock):
+    """The gates of a stack run ``passes`` times (``ops.seq.exit_gate``):
+    from the stack's output after each pass, (``passes``, ..., hidden),
+    the logit ``h . w_g + b_g`` in float32 of leaving after each pass
+    before the last, (``passes`` - 1, N). Weight and bias start at zero,
+    so every gate starts at one half.
+
+    ``counters`` (no gradient, written every forward): the mean exit
+    probability of each pass, the mean expected number of passes and the
+    mean entropy of the exit distribution (``publish_loop_counters``)."""
+
+    def __init__(self, in_units, passes, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.weight = self.params.get("weight", shape=(1, in_units),
+                                          init="zeros")
+            self.bias = self.params.get("bias", shape=(1,), init="zeros")
+            self.counters = self.params.get("counters", shape=(passes + 2,),
+                                            init="zeros", grad_req="null")
+
+    def hybrid_forward(self, F, x, weight, bias, counters):
+        logits, stats = F.ExitGate(x, weight, bias)
+        stateful_write(self.counters, stats)
+        return logits
+
+
+def publish_loop_counters(net):
+    """Set the gauges of the ``HybridLoop`` and the ``ExitGate`` under
+    ``net`` (one of each a net): ``loop::trips``, ``loop::stack_traces``
+    (how often the looped children's forward was traced in the last call:
+    1 when the trips are a scan), and, from the counters the last forward
+    or ``TrainStep`` call wrote, ``loop::exit_mass::<t>``,
+    ``loop::expected_steps`` and ``loop::gate_entropy``. Returns
+    ``{gauge: value}``."""
+    from ... import telemetry
+    out = {}
+    todo = [net]
+    while todo:
+        block = todo.pop()
+        todo.extend(block._children.values())
+        if isinstance(block, HybridLoop):
+            out["loop::trips"] = float(block._loops)
+            out["loop::stack_traces"] = float(block.body_traces)
+        elif isinstance(block, ExitGate):
+            *mass, steps, entropy = block.counters.data().asnumpy()
+            for t, value in enumerate(mass, 1):
+                out[f"loop::exit_mass::{t}"] = float(value)
+            out["loop::expected_steps"] = float(steps)
+            out["loop::gate_entropy"] = float(entropy)
+    for name, value in out.items():
+        telemetry.gauge(name).set(value)
+    return out

@@ -1,6 +1,8 @@
 """Sequence-model operators: RMSNorm, the Mamba-2 mixer (the chunked
 state-space scan, SSD), a latent mixture of experts that is told which
-experts it holds, and causal grouped-query attention in blocks.
+experts it holds, causal grouped-query attention in blocks with or
+without rotary position encoding, a gated MLP, and the exit gate and
+exit-weighted loss of a stack that is run several times.
 
 Every op here is one chip's share of a layer: it is told how many heads
 and groups it holds and which experts, computes with what it holds, and
@@ -10,7 +12,8 @@ rest; ``tests/test_seq_ops.py`` adds the shares up to the uncut layer.
 The step's device time is a function of shapes alone: the expert layer's
 receive buffer is static and every row of it is computed, filled or not.
 
-Named scopes (``mx_ssd_*``, ``mx_moe_*``, ``mx_attn_*``) mark each
+Named scopes (``mx_ssd_*``, ``mx_moe_*``, ``mx_attn_*``, ``mx_rope``,
+``mx_gated_mlp``, ``mx_exit_head``, ``mx_exit_gate``) mark each
 mechanism in the compiled program; ``telemetry.trace.hlo_scopes`` maps
 the program's instructions back to them. The expert layer's matrix
 products have scopes of their own (``mx_moe_score``: the router's;
@@ -25,10 +28,14 @@ outputs of the matrix products a backward pass reads (not a unit's last
 ones, nor the attention's score blocks, which grow with the square of
 the length), the threshold of the routing's choice, what the dispatch's
 sort gave, the convolution's, the scan's and the attention's outputs,
-and a norm's sum of squares. Activations, gates, decay masks, casts and
-the scaled rows of a norm are computed again.
+and a norm's sum of squares. Activations, gates, decay masks, casts,
+rotated heads and the scaled rows of a norm are computed again. An
+exit's logits are never held: each exit's head and cross entropy is a
+unit of its own that keeps its hidden state (``exit_weighted_ce``).
 """
 from __future__ import annotations
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -56,9 +63,13 @@ def _relu2(x):
 # RMSNorm
 # ---------------------------------------------------------------------------
 @register_op("RMSNorm")
-def rms_norm(data, gamma, eps=1e-5, num_groups=1, **kw):
+def rms_norm(data, gamma, eps=1e-5, num_groups=1, keep_input=False, **kw):
     """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, or over
-    each of ``num_groups`` equal slices of it; computed in float32."""
+    each of ``num_groups`` equal slices of it; computed in float32.
+    ``keep_input``: the unit around this norm holds its input (a norm
+    *after* a sublayer reads that sublayer's last product)."""
+    if keep_input:
+        data = kept(data)
     shape = data.shape
     g = int(num_groups)
     x = data.astype(_F32).reshape(shape[:-1] + (g, shape[-1] // g))
@@ -324,12 +335,36 @@ def _attention_block(q, k, v, bias, scale):
     return o, m, jnp.sum(p, axis=-1)
 
 
+@register_op("RoPE")
+def rope(data, theta=10000.0, **kw):
+    """Rotary position encoding over the whole head, in the
+    ``rotate_half`` convention: with ``x = [x1 | x2]`` the two halves of
+    a head, position ``t`` and ``angle_i = t * theta^(-2i/D)`` for ``i <
+    D/2``, ``[x1 cos - x2 sin | x2 cos + x1 sin]``. ``data``: (B, L, H,
+    D), position = index along ``L``; the angles and the rotation in
+    float32, the result in ``data``'s dtype."""
+    length, d = data.shape[1], data.shape[-1]
+    half = d // 2
+    with jax.named_scope("mx_rope"):
+        inv = np.asarray(1.0 / float(theta) ** (np.arange(0, d, 2) / d),
+                         np.float32)
+        ang = jnp.arange(length, dtype=_F32)[:, None] * inv[None, :]
+        cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+        x = data.astype(_F32)
+        x1, x2 = x[..., :half], x[..., half:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                              axis=-1)
+        return out.astype(data.dtype)
+
+
 @register_op("CausalGQAttention")
 def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
-                        block=1024, scale=None, **kw):
+                        block=1024, scale=None, rope_theta=None, **kw):
     """Causal attention over packed ``[q | k | v]`` rows, ``num_heads``
-    query heads sharing ``num_kv_heads`` key/value heads, no positional
-    encoding. Blocks of ``block`` queries against the blocks of keys at or
+    query heads sharing ``num_kv_heads`` key/value heads; with
+    ``rope_theta`` the queries and keys are rotated by their position
+    first (``rope``), without it there is no positional encoding. Blocks
+    of ``block`` queries against the blocks of keys at or
     before them, accumulated by the running maximum and denominator of
     ``parallel.ring.local_attention_block``'s recurrence; blocks past the
     diagonal are never formed.
@@ -342,6 +377,8 @@ def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
     q = data[..., :hq * dh].reshape(bsz, length, hq, dh)
     k = data[..., hq * dh:(hq + hk) * dh].reshape(bsz, length, hk, dh)
     v = data[..., (hq + hk) * dh:].reshape(bsz, length, hk, dh)
+    if rope_theta is not None:
+        q, k = rope(q, rope_theta), rope(k, rope_theta)
     k = jnp.repeat(k, hq // hk, axis=2)
     v = jnp.repeat(v, hq // hk, axis=2)
     blk = min(int(block), length)
@@ -373,3 +410,110 @@ def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
             outs.append(o / l.transpose(0, 2, 1)[..., None])
     out = jnp.concatenate(outs, axis=1)
     return kept(out.reshape(bsz, length, hq * dh).astype(data.dtype))
+
+
+# ---------------------------------------------------------------------------
+# gated MLP
+# ---------------------------------------------------------------------------
+@register_op("GatedMLP")
+def gated_mlp(data, gate_up_weight, down_weight, **kw):
+    """``(silu(u W_gate) * (u W_up)) W_down``. ``gate_up_weight``: (2 f,
+    hidden), rows ``[gate | up]``, one product for both; ``down_weight``:
+    (hidden, f). The activation and the gating in float32. A unit around
+    it keeps neither product: the first is 2 f wide, the widest value of
+    a layer (5.5 times the layer's input at f = 2.75 hidden), and is
+    computed again."""
+    with jax.named_scope("mx_gated_mlp"):
+        gu = _mm(data, gate_up_weight)
+        f = gu.shape[-1] // 2
+        hid = jax.nn.silu(gu[..., :f].astype(_F32)) * gu[..., f:].astype(_F32)
+        return _mm(hid.astype(data.dtype), down_weight)
+
+
+# ---------------------------------------------------------------------------
+# a stack run several times: the exit gate and the exit-weighted loss
+# ---------------------------------------------------------------------------
+@register_op("ExitGate", num_outputs=2)
+def exit_gate(data, weight, bias, **kw):
+    """The gates of a stack run ``T`` times. ``data``: (T, ..., hidden),
+    the stack's output after each pass. Returns ``(logits (T - 1, N),
+    stats (T + 2,))``, float32: the logit ``h . w_g + b_g`` of every row
+    of the passes before the last (``lambda = sigmoid`` of it is the
+    probability of leaving after that pass, given that no earlier pass
+    was left), and, over the rows, the mean ``p(t)`` of each pass
+    (``exit_log_probs``), the mean expected number of passes and the mean
+    entropy of ``p``; no gradient reaches the stats."""
+    passes, hidden = data.shape[0], data.shape[-1]
+    with jax.named_scope("mx_exit_gate"):
+        # all gated passes' rows as one axis: one program for every T
+        h = data[:passes - 1].reshape(-1, hidden).astype(_F32)
+        logits = jnp.sum(h * weight.astype(_F32).reshape(-1), axis=-1) \
+            + bias.astype(_F32).reshape(())
+        logits = logits.reshape(passes - 1, data[0].size // hidden)
+        logp = lax.stop_gradient(exit_log_probs(logits))
+        p = jnp.exp(logp)
+        mass = jnp.mean(p, axis=1)
+        stats = jnp.concatenate([
+            mass, jnp.stack([jnp.sum(mass * jnp.arange(1, passes + 1)),
+                             -jnp.mean(jnp.sum(p * logp, axis=0))])])
+        return logits, stats
+
+
+def exit_log_probs(gate_logits):
+    """``log p(t)`` of leaving after pass ``t`` of ``T``, from the gates'
+    logits of passes ``1..T-1``: ``p(t) = lambda_t prod_{j<t} (1 -
+    lambda_j)``, and the last pass takes what is left, ``p(T) =
+    prod_{j<T} (1 - lambda_j)``. ``gate_logits``: (T - 1, N) -> (T, N)
+    float32; the rows of ``exp`` of it sum to one. One program for every
+    ``T``: no loop over the passes."""
+    z = gate_logits.astype(_F32)
+    passes = z.shape[0] + 1
+    leave, stay = jax.nn.log_sigmoid(z), jax.nn.log_sigmoid(-z)
+    shape = (passes, passes - 1)
+    earlier = (lax.broadcasted_iota(jnp.int32, shape, 0)
+               > lax.broadcasted_iota(jnp.int32, shape, 1)).astype(_F32)
+    stayed = jnp.einsum("tj,jn->tn", earlier, stay,
+                        precision=lax.Precision.HIGHEST)
+    return stayed + jnp.pad(leave, ((0, 1), (0, 0)))
+
+
+def softmax_ce_rows(logits, labels):
+    """Every row's softmax cross entropy with its integer label, in
+    float32: ``parallel.step.softmax_ce_loss`` before its mean (the label
+    picked by a compare and a masked row sum, not a gather)."""
+    x = logits.astype(_F32)
+    s = x - lax.stop_gradient(jnp.max(x, axis=-1, keepdims=True))
+    lse = jnp.log(jnp.sum(jnp.exp(s), axis=-1))
+    hit = jnp.arange(s.shape[-1]) == labels.astype(jnp.int32)[:, None]
+    picked = jnp.sum(jnp.where(hit, s, 0.0), axis=-1)
+    return lse - picked
+
+
+def exit_weighted_ce(hidden, gate_logits, head_weight, labels, beta=0.0):
+    """The expected next-token loss under the exit distribution, less
+    ``beta`` times that distribution's entropy: ``mean_n [sum_t p_n(t)
+    l_n(t) - beta H(p_n)]`` with ``l(t)`` the cross entropy of ``hidden[t]
+    head_weight^T`` and ``p`` from ``exit_log_probs``.
+
+    ``hidden``: (T, N, hidden), the stack's output after each pass;
+    ``gate_logits``: (T - 1, N); ``head_weight``: (vocab, hidden);
+    ``labels``: (N,). One exit after another as a scan whose body is a
+    recomputation unit: it keeps its hidden state and computes its
+    float32 logits again in the backward pass, so no two exits' logits
+    are ever held together."""
+
+    @jax.checkpoint
+    def one_exit(h, w, y):
+        logits = lax.dot_general(h, w, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=_F32)
+        return softmax_ce_rows(logits, y)
+
+    with jax.named_scope("mx_exit_head"):
+        _, ce = lax.scan(
+            lambda _, h: (None, one_exit(h, head_weight, labels)),
+            None, hidden)                               # (T, N)
+    with jax.named_scope("mx_exit_gate"):
+        logp = exit_log_probs(gate_logits)
+        p = jnp.exp(logp)
+        return jnp.mean(jnp.sum(p * ce, axis=0)
+                        + beta * jnp.sum(p * logp, axis=0))

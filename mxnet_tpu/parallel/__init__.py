@@ -14,8 +14,8 @@ collectives over a ``jax.sharding.Mesh``:
 - multi-host        → jax.distributed + the same mesh spanning hosts
 """
 from .mesh import make_mesh, current_mesh, set_default_mesh
-from .step import TrainStep
+from .step import TrainStep, exit_weighted_loss
 from .ring import ring_attention, sequence_shard
 
 __all__ = ["make_mesh", "current_mesh", "set_default_mesh", "TrainStep",
-           "ring_attention", "sequence_shard"]
+           "exit_weighted_loss", "ring_attention", "sequence_shard"]

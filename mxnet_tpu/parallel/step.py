@@ -22,9 +22,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry as _telemetry
 from ..ndarray.ndarray import NDArray, _wrap
+from ..ops.seq import exit_weighted_ce, softmax_ce_rows
 from ..telemetry import trace as _trace
 
-__all__ = ["TrainStep", "softmax_ce_loss", "l2_loss"]
+__all__ = ["TrainStep", "softmax_ce_loss", "l2_loss", "exit_weighted_loss"]
 
 
 def softmax_ce_loss(logits, labels):
@@ -40,16 +41,26 @@ def softmax_ce_loss(logits, labels):
     nothing: that row's loss is the log-sum-exp of its logits less their
     maximum, and its gradient the softmax (a gather gave NaN past the end).
     """
-    x = logits.astype(jnp.float32)
-    s = x - jax.lax.stop_gradient(jnp.max(x, axis=-1, keepdims=True))
-    lse = jnp.log(jnp.sum(jnp.exp(s), axis=-1))
-    hit = jnp.arange(s.shape[-1]) == labels.astype(jnp.int32)[:, None]
-    picked = jnp.sum(jnp.where(hit, s, 0.0), axis=-1)
-    return jnp.mean(lse - picked)
+    return jnp.mean(softmax_ce_rows(logits, labels))
 
 
 def l2_loss(pred, target):
     return 0.5 * jnp.mean(jnp.square(pred - target.reshape(pred.shape)))
+
+
+def exit_weighted_loss(beta=0.0):
+    """The loss of a net that runs its stack several times and may leave
+    after any pass (``PatternLM(loops=, exit_gate=True)``), over all of
+    the net's outputs: the expected next-token cross entropy under the
+    exit distribution, less ``beta`` times that distribution's entropy
+    (``ops.seq.exit_weighted_ce``). Every pass's logits are computed,
+    dropped and computed again in the backward pass, one pass at a
+    time."""
+    def loss(outs, labels):
+        hidden, gate_logits, head_weight = outs
+        return exit_weighted_ce(hidden, gate_logits, head_weight, labels,
+                                beta)
+    return loss
 
 
 _LOSSES = {"softmax_ce": softmax_ce_loss, "l2": l2_loss}
@@ -133,6 +144,7 @@ class TrainStep:
         self.net = net
         self.preprocess = preprocess
         self.loss_fn = _LOSSES[loss] if isinstance(loss, str) else loss
+        self._loss_named = isinstance(loss, str)
         optimizer_params = dict(optimizer_params or {})
         self.lr = optimizer_params.pop("learning_rate", lr)
         self.lr_schedule = lr_schedule
@@ -221,6 +233,7 @@ class TrainStep:
     def _build_step(self):
         staged = self._staged
         loss_fn = self.loss_fn
+        first_output = self._loss_named
         fopt = self._fopt
         trainable = self._trainable
         compute_dtype = self.compute_dtype
@@ -257,7 +270,9 @@ class TrainStep:
                 else:
                     x_c = x
                 outs, writes = staged(pv_c, (x_c,), key)
-                return loss_fn(outs[0], y), writes
+                if first_output or len(outs) == 1:
+                    outs = outs[0]
+                return loss_fn(outs, y), writes
 
             (loss, writes), grads = jax.value_and_grad(
                 fwd, has_aux=True)(pvals)

@@ -437,13 +437,16 @@ _HLO_INSTRUCTION = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name=\"([^\"]*)\"", re.M)
 
 
-def hlo_scopes(hlo_text, prefix="mx_"):
+def hlo_scopes(hlo_text, prefix="mx_", path=False):
     """From a compiled program's HLO text, ``{instruction name: scope}``
     for every instruction whose ``op_name`` passes through a
     ``jax.named_scope`` that starts with ``prefix`` (the innermost such
     scope; a backward instruction carries its forward scope's name inside
-    ``transpose(jvp(...))``). The device trace names its events by
-    instruction, so this is what puts the program's own names on them."""
+    ``transpose(jvp(...))``). With ``path`` the scope is every such scope
+    from the outermost in, joined by ``/`` (``mx_loop_body/mx_attn_fwd``
+    for the attention inside a scanned body). The device trace names its
+    events by instruction, so this is what puts the program's own names
+    on them."""
     # a path component of its own or inside jvp(...)/transpose(...); the
     # jitted function's own name, "jit(mx_train_step)", is no scope
     scope = re.compile(r"(?<!jit\()\b(" + re.escape(prefix) + r"\w+)")
@@ -451,5 +454,5 @@ def hlo_scopes(hlo_text, prefix="mx_"):
     for name, op_name in _HLO_INSTRUCTION.findall(hlo_text):
         found = scope.findall(op_name)
         if found:
-            out[name] = found[-1]
+            out[name] = "/".join(found) if path else found[-1]
     return out

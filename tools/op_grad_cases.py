@@ -5,6 +5,8 @@ Each entry: inputs (numpy arrays), attrs, optional mode:
   'grad' (default) — jax.grad vs directional finite differences
   'fwd'            — forward-only (stochastic / custom-backward / int ops)
   'skip'           — not runnable as a pure array op (reason required)
+and optional grad_args (inputs differentiated), tol, outputs (how many of
+the op's outputs take a gradient, from the first; all without).
 Shapes follow the op's reference contract (conv NCHW, RNN TNC, ...).
 """
 import numpy as np
@@ -120,6 +122,19 @@ CASES = {
         # head of 3, blocks of 2 over 5 steps
         inputs=[_signed((2, 5, (2 + 2) * 3), 0)],
         attrs=dict(num_heads=2, num_kv_heads=1, head_dim=3, block=2)),
+    "RoPE": dict(
+        # ops/seq.py: (B, L, H, D) with an even D; a small theta so that
+        # every pair turns visibly within 5 positions
+        inputs=[_signed((2, 5, 2, 4), 0)], attrs=dict(theta=50.0)),
+    "GatedMLP": dict(
+        # ops/seq.py: [gate | up] rows of one (2 f, hidden) weight, f = 4
+        inputs=[_signed((2, 5, 6), 0), 0.5 * _signed((8, 6), 1),
+                0.5 * _signed((6, 4), 2)]),
+    "ExitGate": dict(
+        # ops/seq.py: 3 passes of (2, 4) rows of 6; the second output
+        # (the gauges' statistics) takes no gradient
+        inputs=[_signed((3, 2, 4, 6), 0), _signed((1, 6), 1),
+                _signed((1,), 2)], outputs=1),
     "InstanceNorm": dict(
         inputs=[_img((2, 3, 4, 4)), _pos((3,), 1), _signed((3,), 2)]),
     "L2Normalization": dict(inputs=[_signed((3, 5), 0)]),
