@@ -153,7 +153,14 @@ class GQAttention(HybridBlock):
     between a fused ``[q | k | v]`` projection and the output projection,
     over the heads held here. ``rope_theta``: the base of the rotary
     position encoding applied to queries and keys; without it the layer
-    has no positional encoding."""
+    has no positional encoding. Where ``head_dim`` is a multiple of 128
+    and the program is lowered for a TPU the attention is the fused
+    kernels of ``ops.attn_kernel``, which choose their own block size;
+    everywhere else the blocked recurrence in plain JAX over blocks of
+    ``block`` rows, which is all ``block`` means (no result depends on
+    it). As a recomputation unit the layer keeps the packed rows, the
+    attention's output and, with the kernels, a float32 log-sum-exp a
+    row."""
 
     def __init__(self, in_units, num_heads, num_kv_heads, head_dim=128,
                  block=1024, rope_theta=None, **kwargs):

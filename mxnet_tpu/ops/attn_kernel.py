@@ -1,0 +1,320 @@
+"""Causal grouped-query attention as fused kernels (Pallas on Mosaic):
+one forward kernel and the backward's two, for ``ops.seq
+.causal_gq_attention`` where its program is lowered for a TPU and the
+heads are whole lane tiles.
+
+The arrays stay as the projection wrote them: ``q`` is (B, L, Hq * D),
+``k`` and ``v`` (B, L, Hk * D), and a head is the (block, D) window at
+column ``h`` of a block of rows, so nothing is transposed to (B, H, L, D)
+on the way in or out. Query head ``h`` reads key/value head ``h // (Hq //
+Hk)`` through the block index map; the keys' and values' gradients of a
+shared head are summed over its query heads in the kernel's grid.
+
+The arithmetic is the blocked recurrence's (``ops.seq._attention_block``):
+``q k^T`` and every sum in float32, the scale applied to the float32
+scores, ``-1e30`` for a masked score, the probabilities cast to ``v``'s
+dtype for the weighted sum, one division by the denominator at the end.
+The forward keeps a block's float32 scores, the running maximum, the
+running sum and the float32 accumulator in VMEM and writes the output and
+one float32 log-sum-exp a row; the backward forms ``P = exp(S - lse)``
+again from q, k and that log-sum-exp, block by block. Blocks past the
+diagonal are never computed, nor fetched (their index is clamped to the
+last block that is, and an unchanged index moves nothing). A length that
+is no multiple of the block is padded with zero rows: a padded key lies
+past every true query's diagonal, and a padded query's gradient is zero.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.extend.core import Primitive
+from jax.interpreters import mlir
+
+_F32 = jnp.float32
+_NEG = -1e30
+_LANES = 128
+_BLOCKS = (1024, 512, 256, 128)
+_VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+
+
+def block_size(length):
+    """The block of queries and of keys for a sequence of ``length``:
+    the largest of 1024, 512, 256, 128 that divides the length padded to
+    whole lane tiles. Returns ``(block, padded length)``."""
+    padded = -(-int(length) // _LANES) * _LANES
+    return next(b for b in _BLOCKS if padded % b == 0), padded
+
+
+def _lanes(x, width):
+    """A (rows, 128) value whose lanes all hold the row's number, as
+    (rows, ``width``)."""
+    return x if width == _LANES else jnp.tile(x, (1, width // _LANES))
+
+
+def _scores(rows, cols, i, j, blk, scale, diagonal, transposed=False):
+    """The scaled float32 scores of query block ``i`` against key block
+    ``j``, ``rows @ cols.T``: (queries, keys), or (keys, queries)
+    ``transposed``; on the ``diagonal`` block masked by the causal
+    order."""
+    s = lax.dot_general(rows, cols, _NT, preferred_element_type=_F32) * scale
+    if not diagonal:
+        return s
+    query = i * blk + lax.broadcasted_iota(jnp.int32, s.shape,
+                                           1 if transposed else 0)
+    key = j * blk + lax.broadcasted_iota(jnp.int32, s.shape,
+                                         0 if transposed else 1)
+    return jnp.where(query >= key, s, _NEG)
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
+                *, scale, blk):
+    i, j = pl.program_id(2), pl.program_id(3)
+    width = acc_ref.shape[-1]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(diagonal):
+        v = v_ref[...]
+        s = _scores(q_ref[...], k_ref[...], i, j, blk, scale, diagonal)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
+        p = jnp.exp(s - _lanes(m_next, blk))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1)[:, None]
+        m_ref[...] = m_next
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, width) + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=_F32)
+
+    pl.when(j < i)(functools.partial(block, False))
+
+    @pl.when(j == i)
+    def _():
+        block(True)
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / _lanes(l, width)).astype(o_ref.dtype)
+        # a row's number sits in every lane: turned, one row of it is the
+        # block's log-sum-exp along the lanes
+        lse_ref[...] = (m_ref[...] + jnp.log(l)).T[:1]
+
+
+def _head_spec(blk, dim, index):
+    """A (block, D) window of one head of (B, L, H * D) rows."""
+    return pl.BlockSpec((None, blk, dim), index)
+
+
+def _row_spec(blk, index):
+    """A block of one head's row numbers, (B, Hq, 1, L), along the lanes."""
+    return pl.BlockSpec((None, None, 1, blk), index)
+
+
+# grid (batch, query head, query block i, key block j): the queries' side
+# at (i, h); the keys' side at the group's head, and past the diagonal at
+# the diagonal's block again, which fetches nothing
+def _q_at(b, h, i, j):
+    return b, i, h
+
+
+def _kv_at(group, b, h, i, j):
+    return b, jnp.minimum(j, i), h // group
+
+
+def _row_at(b, h, i, j):
+    return b, h, 0, i
+
+
+def _call(kernel, name, grid, interpret, **specs):
+    return pl.pallas_call(
+        kernel, grid=grid, name=name, interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES), **specs)
+
+
+def _padded(x, axis, length):
+    """``x`` with zeros after its ``axis`` up to ``length``."""
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, length - x.shape[axis])
+    return jnp.pad(x, pad) if pad[axis][1] else x
+
+
+def forward(q, k, v, num_heads, num_kv_heads, scale, interpret=False):
+    """``(out, lse)``: the attention's output (B, L, Hq * D) in ``q``'s
+    dtype and every row's float32 log-sum-exp of its scaled, masked
+    scores (B, Hq, L)."""
+    hq, group = int(num_heads), int(num_heads) // int(num_kv_heads)
+    bsz, length, _ = q.shape
+    dim = q.shape[-1] // hq
+    blk, padded = block_size(length)
+    q, k, v = (_padded(t, 1, padded) for t in (q, k, v))
+    n = padded // blk
+    kv_at = functools.partial(_kv_at, group)
+    out, lse = _call(
+        functools.partial(_fwd_kernel, scale=scale, blk=blk),
+        "attn_fwd_kernel", (bsz, hq, n, n), interpret,
+        in_specs=[_head_spec(blk, dim, _q_at), _head_spec(blk, dim, kv_at),
+                  _head_spec(blk, dim, kv_at)],
+        out_specs=[_head_spec(blk, dim, _q_at), _row_spec(blk, _row_at)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((bsz, hq, 1, padded), _F32)],
+        scratch_shapes=[pltpu.VMEM((blk, _LANES), _F32),
+                        pltpu.VMEM((blk, _LANES), _F32),
+                        pltpu.VMEM((blk, dim), _F32)])(q, k, v)
+    return out[:, :length], lse[:, :, 0, :length]
+
+
+# ---------------------------------------------------------------------------
+# backward
+# ---------------------------------------------------------------------------
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+               acc_ref, *, scale, blk):
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(diagonal):
+        k, v = k_ref[...], v_ref[...]
+        s = _scores(q_ref[...], k, i, j, blk, scale, diagonal)
+        p = jnp.exp(s - jnp.expand_dims(lse_ref[0], -1))
+        dp = lax.dot_general(do_ref[...], v, _NT,
+                             preferred_element_type=_F32)
+        ds = p * (dp - jnp.expand_dims(delta_ref[0], -1))
+        acc_ref[...] += jnp.dot(ds.astype(k.dtype), k,
+                                preferred_element_type=_F32)
+
+    pl.when(j < i)(functools.partial(block, False))
+
+    @pl.when(j == i)
+    def _():
+        block(True)
+        dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                dv_ref, dk_acc, dv_acc, *, scale, blk, n):
+    # keys along the sublanes, queries along the lanes: a query's
+    # log-sum-exp and delta are rows as they lie
+    j, t = pl.program_id(2), pl.program_id(3)
+    i = t % n
+
+    @pl.when(t == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def block(diagonal):
+        q, do = q_ref[...], do_ref[...]
+        st = _scores(k_ref[...], q, i, j, blk, scale, diagonal,
+                     transposed=True)
+        pt = jnp.exp(st - lse_ref[...])
+        dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
+                               preferred_element_type=_F32)
+        dpt = lax.dot_general(v_ref[...], do, _NT,
+                              preferred_element_type=_F32)
+        dst = pt * (dpt - delta_ref[...])
+        dk_acc[...] += jnp.dot(dst.astype(q.dtype), q,
+                               preferred_element_type=_F32)
+
+    pl.when(i > j)(functools.partial(block, False))
+    pl.when(i == j)(functools.partial(block, True))
+
+    @pl.when(t == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def backward(q, k, v, out, lse, dout, num_heads, num_kv_heads, scale,
+             interpret=False):
+    """``(dq, dk, dv)`` from what ``forward`` took and gave and the
+    output's cotangent."""
+    hq, hk = int(num_heads), int(num_kv_heads)
+    group = hq // hk
+    bsz, length, _ = q.shape
+    dim = q.shape[-1] // hq
+    blk, padded = block_size(length)
+    delta = jnp.sum((dout.astype(_F32) * out.astype(_F32)).reshape(
+        bsz, length, hq, dim), axis=-1).transpose(0, 2, 1)
+    q, k, v, dout = (_padded(t, 1, padded) for t in (q, k, v, dout))
+    lse, delta = (_padded(t, 2, padded)[:, :, None] for t in (lse, delta))
+    n = padded // blk
+    kv_at = functools.partial(_kv_at, group)
+    head, row = functools.partial(_head_spec, blk, dim), \
+        functools.partial(_row_spec, blk)
+    dq = _call(
+        functools.partial(_dq_kernel, scale=scale, blk=blk),
+        "attn_bwd_dq_kernel", (bsz, hq, n, n), interpret,
+        in_specs=[head(_q_at), head(kv_at), head(kv_at), head(_q_at),
+                  row(_row_at), row(_row_at)],
+        out_specs=head(_q_at),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((blk, dim), _F32)])(
+            q, k, v, dout, lse, delta)
+
+    # grid (batch, key head, key block j, t): a key block meets, for each
+    # query head of its group in turn, the query blocks at or after it; the
+    # steps before those fetch the diagonal's block and compute nothing
+    def k_at(b, h, j, t):
+        return b, j, h
+
+    def q_at(b, h, j, t):
+        return b, jnp.maximum(t % n, j), h * group + t // n
+
+    def row_at(b, h, j, t):
+        return b, h * group + t // n, 0, jnp.maximum(t % n, j)
+
+    dk, dv = _call(
+        functools.partial(_dkv_kernel, scale=scale, blk=blk, n=n),
+        "attn_bwd_dkv_kernel", (bsz, hk, n, group * n), interpret,
+        in_specs=[head(q_at), head(k_at), head(k_at), head(q_at),
+                  row(row_at), row(row_at)],
+        out_specs=[head(k_at), head(k_at)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk, dim), _F32),
+                        pltpu.VMEM((blk, dim), _F32)])(
+            q, k, v, dout, lse, delta)
+    return dq[:, :length], dk[:, :length], dv[:, :length]
+
+
+# ---------------------------------------------------------------------------
+# the count of call sites lowered to the kernel
+# ---------------------------------------------------------------------------
+# Which form a call site takes is decided when its program is lowered
+# (``lax.platform_dependent``), so that is where a site is counted: an
+# identity whose lowering rule, reached only inside the TPU branch, adds
+# one to the gauge ``attn::kernel_sites``. ``TrainStep`` sets the gauge to
+# zero where it traces its step.
+GAUGE = "attn::kernel_sites"
+
+_site_p = Primitive("mx_attn_kernel_site")
+_site_p.def_impl(lambda x: x)
+_site_p.def_abstract_eval(lambda x: x)
+
+
+def _site_lowering(ctx, x):
+    from .. import telemetry
+    telemetry.gauge(GAUGE).inc()
+    return [x]
+
+
+mlir.register_lowering(_site_p, _site_lowering)
+
+
+def counted_site(x):
+    """``x``; lowering it counts one call site of the kernel."""
+    return _site_p.bind(x)

@@ -1,7 +1,8 @@
 """Sequence-model operators: RMSNorm, the Mamba-2 mixer (the chunked
 state-space scan, SSD), a latent mixture of experts that is told which
-experts it holds, causal grouped-query attention in blocks with or
-without rotary position encoding, a gated MLP, and the exit gate and
+experts it holds, causal grouped-query attention in blocks (fused
+kernels where the program is lowered for a TPU, ``ops.attn_kernel``) with
+or without rotary position encoding, a gated MLP, and the exit gate and
 exit-weighted loss of a stack that is run several times.
 
 Every op here is one chip's share of a layer: it is told how many heads
@@ -28,12 +29,15 @@ outputs of the matrix products a backward pass reads (not a unit's last
 ones, nor the attention's score blocks, which grow with the square of
 the length), the threshold of the routing's choice, what the dispatch's
 sort gave, the convolution's, the scan's and the attention's outputs,
-and a norm's sum of squares. Activations, gates, decay masks, casts,
+the attention's log-sum-exp a row where its kernels run, and a norm's sum
+of squares. Activations, gates, decay masks, casts,
 rotated heads and the scaled rows of a norm are computed again. An
 exit's logits are never held: each exit's head and cross entropy is a
 unit of its own that keeps its hidden state (``exit_weighted_ce``).
 """
 from __future__ import annotations
+
+import functools
 
 import numpy as np
 
@@ -41,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import attn_kernel
 from .registry import register_op
 from .remat import kept
 
@@ -357,33 +362,15 @@ def rope(data, theta=10000.0, **kw):
         return out.astype(data.dtype)
 
 
-@register_op("CausalGQAttention")
-def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
-                        block=1024, scale=None, rope_theta=None, **kw):
-    """Causal attention over packed ``[q | k | v]`` rows, ``num_heads``
-    query heads sharing ``num_kv_heads`` key/value heads; with
-    ``rope_theta`` the queries and keys are rotated by their position
-    first (``rope``), without it there is no positional encoding. Blocks
-    of ``block`` queries against the blocks of keys at or
-    before them, accumulated by the running maximum and denominator of
-    ``parallel.ring.local_attention_block``'s recurrence; blocks past the
-    diagonal are never formed.
-
-    ``data``: (B, L, (num_heads + 2 num_kv_heads) * head_dim). Returns
-    (B, L, num_heads * head_dim)."""
-    hq, hk, dh = int(num_heads), int(num_kv_heads), int(head_dim)
-    bsz, length, _ = data.shape
-    data = kept(data)       # the projection's output, named where it is read
-    q = data[..., :hq * dh].reshape(bsz, length, hq, dh)
-    k = data[..., hq * dh:(hq + hk) * dh].reshape(bsz, length, hk, dh)
-    v = data[..., (hq + hk) * dh:].reshape(bsz, length, hk, dh)
-    if rope_theta is not None:
-        q, k = rope(q, rope_theta), rope(k, rope_theta)
-    k = jnp.repeat(k, hq // hk, axis=2)
-    v = jnp.repeat(v, hq // hk, axis=2)
-    blk = min(int(block), length)
-    scale = scale if scale is not None else dh ** -0.5
-    outs = []
+def _blocked_attention(q, k, v, blk, scale, with_lse=False):
+    """The blocked recurrence in plain JAX: blocks of ``blk`` queries
+    against the blocks of keys at or before them, accumulated by the
+    running maximum and denominator; blocks past the diagonal are never
+    formed. ``q``, ``k``, ``v``: (B, L, H, D), as many heads each. Returns
+    (B, L, H, D) float32, and ``with_lse`` each row's log-sum-exp (B, H,
+    L) beside it."""
+    length = q.shape[1]
+    outs, lses = [], []
     with jax.named_scope("mx_attn_fwd"):
         for i0 in range(0, length, blk):
             i1 = min(i0 + blk, length)
@@ -408,7 +395,108 @@ def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
                     + o_j * beta.transpose(0, 2, 1)[..., None]
                 m = m_new
             outs.append(o / l.transpose(0, 2, 1)[..., None])
+            if with_lse:
+                lses.append(m + jnp.log(l))
     out = jnp.concatenate(outs, axis=1)
+    return (out, jnp.concatenate(lses, axis=-1)) if with_lse else out
+
+
+def _blocked_rows(q, k, v, hq, hk, scale, blk):
+    """``_blocked_attention`` over rows of heads: ``q`` (B, L, hq * D),
+    ``k``, ``v`` (B, L, hk * D). Returns the output as such rows in
+    ``q``'s dtype and the log-sum-exp (B, hq, L)."""
+    bsz, length, _ = q.shape
+    k, v = (jnp.repeat(t.reshape(bsz, length, hk, -1), hq // hk, axis=2)
+            for t in (k, v))
+    out, lse = _blocked_attention(q.reshape(bsz, length, hq, -1), k, v, blk,
+                                  scale, with_lse=True)
+    return out.reshape(q.shape).astype(q.dtype), lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _fused_attention(q, k, v, hq, hk, scale, blk):
+    """Attention over rows of heads whose program takes its form when it
+    is lowered: for a TPU the kernels of ``ops.attn_kernel``, forward and
+    backward, for any other platform the blocked recurrence and JAX's own
+    derivative of it. Either way the unit around it keeps the output and
+    one float32 log-sum-exp a row, and its backward pass runs no forward
+    a second time where the kernels are."""
+    return _fused_attention_fwd(q, k, v, hq, hk, scale, blk)[0]
+
+
+def _fused_attention_fwd(q, k, v, hq, hk, scale, blk):
+    with jax.named_scope("mx_attn_fwd"):
+        out, lse = lax.platform_dependent(
+            q, k, v,
+            tpu=lambda q, k, v: attn_kernel.forward(
+                attn_kernel.counted_site(q), k, v, hq, hk, scale),
+            default=lambda q, k, v: _blocked_rows(q, k, v, hq, hk, scale,
+                                                  blk))
+    out, lse = kept(out), kept(lse)
+    return out, (q, k, v, out, lse)
+
+
+def _fused_attention_bwd(hq, hk, scale, blk, res, dout):
+    q, k, v, out, lse = res
+    with jax.named_scope("mx_attn_fwd"):
+        return lax.platform_dependent(
+            q, k, v, out, lse, dout,
+            tpu=lambda *a: attn_kernel.backward(*a, hq, hk, scale),
+            default=lambda q, k, v, out, lse, dout: jax.vjp(
+                lambda *qkv: _blocked_rows(*qkv, hq, hk, scale, blk)[0],
+                q, k, v)[1](dout))
+
+
+_fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
+
+
+@register_op("CausalGQAttention")
+def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
+                        block=1024, scale=None, rope_theta=None, **kw):
+    """Causal attention over packed ``[q | k | v]`` rows, ``num_heads``
+    query heads sharing ``num_kv_heads`` key/value heads; with
+    ``rope_theta`` the queries and keys are rotated by their position
+    first (``rope``), without it there is no positional encoding. The
+    scores and every sum in float32, the probabilities in ``data``'s
+    dtype for the weighted sum, scale ``head_dim ** -0.5`` unless given.
+
+    Two forms of one recurrence (blocks of queries against the blocks of
+    keys at or before them, a running maximum and denominator, blocks
+    past the diagonal never formed), chosen by what the program can see,
+    not by the caller. Where ``head_dim`` is a multiple of 128 and the
+    program is lowered for a TPU, one fused kernel forward and two
+    backward (``ops.attn_kernel``): no block of scores reaches memory,
+    the heads are read where the projection wrote them, grouped heads
+    share keys and values through the block index, and the block size is
+    the kernel's (``attn_kernel.block_size``; ``block`` is not read).
+    Everywhere else (another ``head_dim``, another backend) the
+    recurrence in plain JAX over blocks of ``block`` rows
+    (``parallel.ring.local_attention_block``'s), differentiated by JAX. No
+    result depends on ``block``.
+
+    A recomputation unit around it keeps the packed rows, the output and,
+    where ``head_dim`` is a multiple of 128, one float32 log-sum-exp a
+    row; the rotation is computed again, the kernel's forward is not.
+
+    ``data``: (B, L, (num_heads + 2 num_kv_heads) * head_dim). Returns
+    (B, L, num_heads * head_dim)."""
+    hq, hk, dh = int(num_heads), int(num_kv_heads), int(head_dim)
+    bsz, length, _ = data.shape
+    data = kept(data)       # the projection's output, named where it is read
+    q = data[..., :hq * dh].reshape(bsz, length, hq, dh)
+    k = data[..., hq * dh:(hq + hk) * dh].reshape(bsz, length, hk, dh)
+    v = data[..., (hq + hk) * dh:].reshape(bsz, length, hk, dh)
+    if rope_theta is not None:
+        q, k = rope(q, rope_theta), rope(k, rope_theta)
+    blk = min(int(block), length)
+    scale = scale if scale is not None else dh ** -0.5
+    if dh % 128 == 0:
+        return _fused_attention(
+            q.reshape(bsz, length, hq * dh), k.reshape(bsz, length, hk * dh),
+            v.reshape(bsz, length, hk * dh), hq, hk, float(scale), blk)
+    k = jnp.repeat(k, hq // hk, axis=2)
+    v = jnp.repeat(v, hq // hk, axis=2)
+    out = _blocked_attention(q, k, v, blk, scale)
     return kept(out.reshape(bsz, length, hq * dh).astype(data.dtype))
 
 

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry as _telemetry
+from ..ops import attn_kernel as _attn_kernel
 from ..ndarray.ndarray import NDArray, _wrap
 from ..ops.seq import exit_weighted_ce, softmax_ce_rows
 from ..telemetry import trace as _trace
@@ -254,6 +255,9 @@ class TrainStep:
         base_key = _random_mod.next_key()
 
         def mx_train_step(pvals, opt_state, x, y, t, lr):
+            # of the step traced last, like the units' gauges: the lowering
+            # that follows counts the attention sites it gives the kernel
+            _telemetry.gauge(_attn_kernel.GAUGE).set(0)
             key = jax.random.fold_in(base_key, t)
             if preprocess is not None:
                 x = preprocess(x)

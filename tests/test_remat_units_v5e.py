@@ -64,16 +64,20 @@ def _sorts(text, scope):
             if re.search(r"\bsort\(", line) and scope in line]
 
 
-def test_units_keep_what_is_dear_and_the_step_fits(one_chip, no_jax_cache):
+def _compiled_step(name, one_chip):
+    """``(cell, net, compiled)``: the training step of a ``PatternLM`` cell
+    at its timed sizes, as its configuration module builds it, compiled
+    for the described chip from shapes alone."""
     import mxnet_tpu as mx
-    from mxnet_tpu.parallel import TrainStep
-    cell = harness.load_cell(CELL)
+    from mxnet_tpu.parallel import TrainStep, exit_weighted_loss
+    cell = harness.load_cell(name)
     sizes = cell.sizes
-    pattern = sizes["hybrid_override_pattern"]
     net = cell.model._net(sizes)
     net.initialize(mx.init.Zero())
     opt = dict(cell.config["optimizer"])
-    step = TrainStep(net, loss="softmax_ce", optimizer=opt.pop("name"),
+    loss = exit_weighted_loss(sizes["exit_entropy_beta"]) \
+        if "exit_entropy_beta" in sizes else "softmax_ce"
+    step = TrainStep(net, loss=loss, optimizer=opt.pop("name"),
                      optimizer_params=opt,
                      compute_dtype=cell.config["compute_dtype"],
                      remat="layer")
@@ -86,10 +90,18 @@ def test_units_keep_what_is_dear_and_the_step_fits(one_chip, no_jax_cache):
                   for p, t in zip(step.param_list, step._trainable))
     tokens = sizes["batch"] * sizes["seq_len"]
     step._build_step()
-    compiled = step._step_jit.lower(
+    return cell, net, step._step_jit.lower(
         pvals, state, spec((sizes["batch"], sizes["seq_len"]), jnp.int32),
         spec((tokens,), jnp.int32), spec((), jnp.uint32),
         spec(())).compile()
+
+
+def test_units_keep_what_is_dear_and_the_step_fits(one_chip, no_jax_cache):
+    import mxnet_tpu as mx
+    cell, _, compiled = _compiled_step(CELL, one_chip)
+    sizes = cell.sizes
+    pattern = sizes["hybrid_override_pattern"]
+    tokens = sizes["batch"] * sizes["seq_len"]
     m = compiled.memory_analysis()
     peak = _peak_bytes(compiled)
     snap = mx.telemetry.snapshot(prefix="remat::")
@@ -178,3 +190,68 @@ def test_lstm_lm_loops_hold_one_product_each(one_chip, no_jax_cache):
     assert len(bodies) == sizes["layers"] * 2 == 4
     assert per_body == [1, 1, 1, 1]
     assert peak <= LSTM_STEP_BYTES_BEFORE, peak
+
+
+# -- the attention of both ``PatternLM`` cells is the kernel --------------------
+def _kernel_calls(text, kernel):
+    """The Mosaic custom calls of ``kernel`` in a compiled program's text,
+    as ``[(instruction name, op_name)]``."""
+    return [(m.group(1), m.group(2)) for m in re.finditer(
+        r"^\s*%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*op_name=\"([^\"]*)\"", text, re.M)
+        if f"/{kernel}/" in m.group(2)]
+
+
+@pytest.mark.parametrize("name,heads", [("nemotron3-super-train-8k", 4),
+                                        ("ouro-2.6b-train-4k", 16)])
+def test_attention_is_the_kernel_forward_and_backward(one_chip, no_jax_cache,
+                                                      name, heads):
+    """Compiled for the described v5e from this CPU host, the step holds
+    the attention as Mosaic calls under ``mx_attn_fwd``: one forward
+    kernel a layer (the unit keeps its output and log-sum-exp, so the
+    backward loop holds none), one ``dQ`` and one ``dK, dV`` kernel a
+    layer, and no float32 (heads, block, block) score value anywhere."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops import attn_kernel
+    from mxnet_tpu.telemetry.trace import hlo_scopes
+    text = _compiled_step(name, one_chip)[2].as_text()
+    calls = {k: _kernel_calls(text, k) for k in
+             ("attn_fwd_kernel", "attn_bwd_dq_kernel", "attn_bwd_dkv_kernel")}
+    counts = {k: len(v) for k, v in calls.items()}
+    print(f"{name}: {counts}, attn::kernel_sites "
+          f"{mx.telemetry.gauge(attn_kernel.GAUGE).get()}")
+    # like layers share one lowered program: the gauge counts programs
+    assert mx.telemetry.gauge(attn_kernel.GAUGE).get() == 1
+    assert counts["attn_fwd_kernel"] >= 1
+    assert len(set(counts.values())) == 1, counts   # no second forward
+    scopes = hlo_scopes(text, path=True)
+    for kernel, found in calls.items():
+        for instruction, op_name in found:
+            assert re.search(r"(^|/)mx_attn_fwd$", scopes[instruction]), \
+                (kernel, op_name)
+    assert f"f32[{heads},1024,1024]" not in text
+    assert not re.search(rf"f32\[1,{heads},1024,1024\]", text)
+
+
+@pytest.mark.parametrize("length,hq,hk,dim,dtype,theta", [
+    (200, 4, 1, 128, "bfloat16", None),      # padded to one block of 256
+    (1100, 2, 1, 128, "bfloat16", 1e4),      # padded to nine blocks of 128
+    (1536, 4, 2, 256, "bfloat16", 1e4),      # heads of two lane tiles
+    (2048, 8, 8, 128, "float32", None)])     # blocks of 1024 in float32
+def test_mosaic_takes_the_kernels_at_other_shapes(one_chip, no_jax_cache,
+                                                  length, hq, hk, dim, dtype,
+                                                  theta):
+    """Any ``head_dim`` that is a multiple of 128 takes the kernels on a
+    TPU, whatever the length, the grouping or the dtype: Mosaic has to
+    compile them all (tiling, VMEM), forward and backward."""
+    from mxnet_tpu.ops import seq
+
+    def loss(data):
+        out = seq.causal_gq_attention(data, num_heads=hq, num_kv_heads=hk,
+                                      head_dim=dim, rope_theta=theta)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    data = jax.ShapeDtypeStruct((2, length, (hq + 2 * hk) * dim), dtype,
+                                sharding=one_chip)
+    text = jax.jit(jax.grad(loss)).lower(data).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
