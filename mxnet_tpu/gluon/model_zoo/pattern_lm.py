@@ -1,7 +1,10 @@
 """A language model built from a layer pattern: one character a layer,
 ``M`` a Mamba-2 mixer, ``E`` a latent mixture of experts, ``*`` causal
 grouped-query attention (the ``hybrid_override_pattern`` of the
-``nemotron_h`` family's configurations), ``G`` a gated MLP. Pre-norm
+``nemotron_h`` family's configurations), ``G`` a gated MLP, ``L``
+multi-head latent attention, ``F`` a mixture of gated experts on the full
+hidden vector (``deepseek_v3``'s two sublayers: ``LG`` a dense layer,
+``LF`` an expert layer). Pre-norm
 residual throughout, ``x <- x + Mixer_l(RMSNorm_l(x))``, or with
 ``post_norm`` a norm on either side of the mixer, ``x <- x +
 RMSNorm'_l(Mixer_l(RMSNorm_l(x)))``; one final RMSNorm, an untied head,
@@ -46,10 +49,11 @@ class _Layer(HybridBlock):
 
 class PatternLM(HybridBlock):
     """``pattern``: the layers held, e.g. ``"MEMEMEMEM*E"`` or
-    ``"*G*G"``. ``mamba``, ``moe``, ``attention``, ``mlp``: the keyword
-    arguments of ``nn.Mamba2Mixer``, ``nn.LatentMoE``, ``nn.GQAttention``
-    and ``nn.GatedMLP`` after ``in_units`` (what each layer of that kind
-    holds). ``post_norm``: a second norm in every layer, after its mixer.
+    ``"*G*G"`` or ``"LGLFLF"``. ``mamba``, ``moe``, ``attention``, ``mlp``,
+    ``latent_attention``, ``experts``: the keyword arguments of
+    ``nn.Mamba2Mixer``, ``nn.LatentMoE``, ``nn.GQAttention``,
+    ``nn.GatedMLP``, ``nn.LatentAttention`` and ``nn.GatedMoE`` after
+    ``in_units`` (what each layer of that kind holds). ``post_norm``: a second norm in every layer, after its mixer.
     ``loops``: how often the stack and the final norm run, each pass on
     the one before's output.
 
@@ -63,13 +67,17 @@ class PatternLM(HybridBlock):
 
     def __init__(self, pattern, vocab, units, mamba=None, moe=None,
                  attention=None, mlp=None, epsilon=1e-5, post_norm=False,
-                 loops=1, exit_gate=False, **kwargs):
+                 loops=1, exit_gate=False, latent_attention=None,
+                 experts=None, **kwargs):
         super().__init__(**kwargs)
         make = {"M": lambda: nn.Mamba2Mixer(units, epsilon=epsilon,
                                             **mamba),
                 "E": lambda: nn.LatentMoE(units, **moe),
                 "*": lambda: nn.GQAttention(units, **attention),
-                "G": lambda: nn.GatedMLP(units, **mlp)}
+                "G": lambda: nn.GatedMLP(units, **mlp),
+                "L": lambda: nn.LatentAttention(units, epsilon=epsilon,
+                                                **latent_attention),
+                "F": lambda: nn.GatedMoE(units, **experts)}
         self._vocab, self._units = vocab, units
         with self.name_scope():
             self.embed = nn.Embedding(vocab, units)
@@ -78,7 +86,7 @@ class PatternLM(HybridBlock):
             for i, kind in enumerate(pattern):
                 if kind not in make:
                     raise ValueError(f"layer kind {kind!r} in {pattern!r}: "
-                                     "M, E, * and G are known")
+                                     "M, E, *, G, L and F are known")
                 self.stack.add(_Layer(units, make[kind], epsilon, post_norm,
                                       prefix=f"l{i}_"))
             final = nn.RMSNorm(units, epsilon)

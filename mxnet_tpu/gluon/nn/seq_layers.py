@@ -1,7 +1,7 @@
 """Sequence-model blocks: ``RMSNorm``, ``Mamba2Mixer``, ``LatentMoE``,
-``GQAttention``, ``GatedMLP``, the ``HybridLoop`` container that runs its
-children several times as one scanned body, and ``ExitGate``, over the
-ops of ``ops/seq.py``.
+``GatedMoE``, ``GQAttention``, ``LatentAttention``, ``GatedMLP``, the
+``HybridLoop`` container that runs its children several times as one
+scanned body, and ``ExitGate``, over the ops of ``ops/seq.py``.
 
 Each block is told what it holds of the layer: how many heads and groups,
 which experts, how many of the shared expert's columns. A block that holds
@@ -13,18 +13,19 @@ from ... import autograd
 from ...ndarray.ndarray import _wrap
 from ..block import HybridBlock, _TraceState, stateful_write
 
-__all__ = ["RMSNorm", "Mamba2Mixer", "LatentMoE", "GQAttention", "GatedMLP",
-           "HybridLoop", "ExitGate", "MOE_COUNTERS", "publish_moe_counters",
-           "publish_loop_counters"]
+__all__ = ["RMSNorm", "Mamba2Mixer", "LatentMoE", "GatedMoE", "GQAttention",
+           "LatentAttention", "GatedMLP", "HybridLoop", "ExitGate",
+           "MOE_COUNTERS", "publish_moe_counters", "publish_loop_counters"]
 
-#: what ``LatentMoE.counters`` holds, in order: the gauges
+#: what an expert layer's ``counters`` hold, in order: the gauges
 #: ``moe::<name>::<block>`` of ``publish_moe_counters``
 MOE_COUNTERS = ("pairs_held", "overflow_pairs", "load_max_over_mean",
                 "buffer_fill")
 
 
 def publish_moe_counters(net):
-    """Read the counters of every ``LatentMoE`` under ``net`` (what the
+    """Read the counters of every expert layer (``LatentMoE``,
+    ``GatedMoE``) under ``net`` (what the
     last forward, or ``TrainStep`` call, wrote beside its output) and set
     the gauges ``moe::<counter>::<block>``: one read a layer, no work in
     the step. Returns ``{gauge: value}``."""
@@ -34,7 +35,7 @@ def publish_moe_counters(net):
     while todo:
         block = todo.pop()
         todo.extend(block._children.values())
-        if isinstance(block, LatentMoE):
+        if isinstance(block, (LatentMoE, GatedMoE)):
             name = block.counters.name.rsplit("_", 1)[0]
             for counter, value in zip(MOE_COUNTERS,
                                       block.counters.data().asnumpy()):
@@ -109,7 +110,8 @@ class LatentMoE(HybridBlock):
     call. With ``bias_update_rate`` above 0 every training forward also
     moves ``router_bias`` one step of the auxiliary-loss-free balancing
     rule on its own loads (``ops.seq.balanced_bias``), as BatchNorm moves
-    its statistics: the next call routes by the new bias."""
+    its statistics: the next call routes by the new bias. (Gated experts
+    on the full hidden vector, on the same routing path: ``GatedMoE``.)"""
 
     def __init__(self, in_units, num_experts, expert_ids, top_k, latent_units,
                  expert_units, shared_units, buffer_rows, scaling=1.0,
@@ -146,6 +148,97 @@ class LatentMoE(HybridBlock):
         if self._attrs["bias_rate"] and autograd.is_training():
             stateful_write(self.router_bias, bias)
         return out
+
+
+class GatedMoE(HybridBlock):
+    """A mixture of gated experts on the full hidden vector
+    (``ops.seq.gated_moe``): ``LatentMoE``'s router, correction bias,
+    counters and balancing step around experts that are gated MLPs
+    ``expert_units`` wide with no latent projection, the ones
+    ``expert_ids`` held here, which share ONE pool of ``buffer_rows``
+    rows (a pair is beyond the buffer only when the held experts' pairs
+    together outnumber its rows; ``buffer_fill`` is the pool's filled
+    share); the shared experts are one gated MLP ``shared_units`` wide,
+    whole."""
+
+    def __init__(self, in_units, num_experts, expert_ids, top_k,
+                 expert_units, shared_units, buffer_rows, scaling=1.0,
+                 norm_topk=True, bias_update_rate=0.0, **kwargs):
+        super().__init__(**kwargs)
+        held = len(expert_ids)
+        self._attrs = {"expert_ids": tuple(int(e) for e in expert_ids),
+                       "top_k": top_k, "buffer_rows": buffer_rows,
+                       "scaling": float(scaling),
+                       "norm_topk": bool(norm_topk),
+                       "bias_rate": float(bias_update_rate)}
+        with self.name_scope():
+            get = self.params.get
+            self.router_weight = get("router_weight",
+                                     shape=(num_experts, in_units))
+            self.router_bias = get("router_bias", shape=(num_experts,),
+                                   init="zeros", grad_req="null")
+            self.w1 = get("w1", shape=(held, in_units, expert_units))
+            self.w3 = get("w3", shape=(held, in_units, expert_units))
+            self.w2 = get("w2", shape=(held, expert_units, in_units))
+            self.shared_gate_up_weight = get(
+                "shared_gate_up_weight", shape=(2 * shared_units, in_units))
+            self.shared_down_weight = get("shared_down_weight",
+                                          shape=(in_units, shared_units))
+            self.counters = get("counters", shape=(len(MOE_COUNTERS),),
+                                init="zeros", grad_req="null")
+
+    def hybrid_forward(self, F, x, router_weight, router_bias, w1, w3, w2,
+                       shared_gate_up_weight, shared_down_weight, counters):
+        out, new, bias = F.GatedMoE(
+            x, router_weight, router_bias, w1, w3, w2,
+            shared_gate_up_weight, shared_down_weight, counters,
+            **self._attrs)
+        stateful_write(self.counters, new)
+        if self._attrs["bias_rate"] and autograd.is_training():
+            stateful_write(self.router_bias, bias)
+        return out
+
+
+class LatentAttention(HybridBlock):
+    """Causal multi-head latent attention (``ops.seq.latent_attention``)
+    over the ``num_heads`` heads held here: queries ``nope_dim +
+    rope_dim`` wide straight from the input (no query latent), keys and
+    values expanded from a ``latent_dim``-wide normed latent, one rotary
+    key of ``rope_dim`` shared by all heads, values ``v_dim`` wide. The
+    matrices' rows are grouped by part, not by head (the op's docstring).
+    Where ``nope_dim == v_dim`` is a multiple of 128 and the program is
+    lowered for a TPU, the softmax is the fused kernels of
+    ``ops.attn_kernel``; ``block`` is the plain form's, as in
+    ``GQAttention``."""
+
+    def __init__(self, in_units, num_heads, nope_dim=128, rope_dim=64,
+                 v_dim=128, latent_dim=512, rope_theta=10000.0,
+                 epsilon=1e-5, block=1024, **kwargs):
+        super().__init__(**kwargs)
+        self._attrs = {"num_heads": num_heads, "nope_dim": nope_dim,
+                       "rope_dim": rope_dim, "v_dim": v_dim,
+                       "latent_dim": latent_dim,
+                       "rope_theta": float(rope_theta), "eps": epsilon,
+                       "block": block}
+        with self.name_scope():
+            get = self.params.get
+            self.q_weight = get(
+                "q_weight", shape=(num_heads * (nope_dim + rope_dim),
+                                   in_units))
+            self.kv_down_weight = get(
+                "kv_down_weight", shape=(latent_dim + rope_dim, in_units))
+            self.kv_norm_weight = get("kv_norm_weight", shape=(latent_dim,),
+                                      init="ones")
+            self.kv_up_weight = get(
+                "kv_up_weight", shape=(num_heads * (nope_dim + v_dim),
+                                       latent_dim))
+            self.o_weight = get("o_weight",
+                                shape=(in_units, num_heads * v_dim))
+
+    def hybrid_forward(self, F, x, q_weight, kv_down_weight, kv_norm_weight,
+                       kv_up_weight, o_weight):
+        return F.LatentAttention(x, q_weight, kv_down_weight, kv_norm_weight,
+                                 kv_up_weight, o_weight, **self._attrs)
 
 
 class GQAttention(HybridBlock):

@@ -22,6 +22,23 @@ diagonal are never computed, nor fetched (their index is clamped to the
 last block that is, and an unchanged index moves nothing). A length that
 is no multiple of the block is padded with zero rows: a padded key lies
 past every true query's diagonal, and a padded query's gradient is zero.
+
+A second part of the score (``extra``), for heads whose queries and keys
+are wider than their values and share a part of the key (multi-head
+latent attention: a rotary key of 64 beside 16 heads' own 128): ``q2``
+(B, L, Hq * D2), a second query part a head, and ``k2`` (B, L, D2), one
+key part that every head reads. ``q2 k2^T`` is added to the same float32
+score block before the scale, the mask and the softmax, forward and in
+both backward kernels, which then also give ``dq2`` and ``dk2``; ``k2``
+is never repeated a head, and its gradient is summed over all heads in
+the ``dK, dV`` kernel's grid, whose last axis then runs over every
+head's query blocks in turn (a key/value head's ``dK`` and ``dV`` leave
+when its heads are through, ``dk2`` at the end). D2 need be no lane
+tile: Mosaic takes a block whose last dimension is the whole array's, so
+``k2`` is read as it lies and ``q2`` goes in head-major, (B, Hq, L, D2),
+one transposition of a narrow array that XLA fuses into the rotation
+that produced it; the gradient comes back the same way. Without
+``extra`` the kernels are, to the instruction, what they were.
 """
 from __future__ import annotations
 
@@ -57,12 +74,18 @@ def _lanes(x, width):
     return x if width == _LANES else jnp.tile(x, (1, width // _LANES))
 
 
-def _scores(rows, cols, i, j, blk, scale, diagonal, transposed=False):
+def _scores(rows, cols, i, j, blk, scale, diagonal, transposed=False,
+            rows2=None, cols2=None):
     """The scaled float32 scores of query block ``i`` against key block
-    ``j``, ``rows @ cols.T``: (queries, keys), or (keys, queries)
+    ``j``, ``rows @ cols.T``, with ``rows2 @ cols2.T`` added where a
+    second part is given: (queries, keys), or (keys, queries)
     ``transposed``; on the ``diagonal`` block masked by the causal
     order."""
-    s = lax.dot_general(rows, cols, _NT, preferred_element_type=_F32) * scale
+    s = lax.dot_general(rows, cols, _NT, preferred_element_type=_F32)
+    if rows2 is not None:
+        s = s + lax.dot_general(rows2, cols2, _NT,
+                                preferred_element_type=_F32)
+    s = s * scale
     if not diagonal:
         return s
     query = i * blk + lax.broadcasted_iota(jnp.int32, s.shape,
@@ -75,8 +98,9 @@ def _scores(rows, cols, i, j, blk, scale, diagonal, transposed=False):
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                *, scale, blk):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, blk):
+    q2_ref, k2_ref = refs[:-5] or (None, None)
+    o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[-5:]
     i, j = pl.program_id(2), pl.program_id(3)
     width = acc_ref.shape[-1]
 
@@ -88,7 +112,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 
     def block(diagonal):
         v = v_ref[...]
-        s = _scores(q_ref[...], k_ref[...], i, j, blk, scale, diagonal)
+        s = _scores(q_ref[...], k_ref[...], i, j, blk, scale, diagonal,
+                    **_second(q2_ref, k2_ref))
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
         p = jnp.exp(s - _lanes(m_next, blk))
@@ -108,6 +133,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         # a row's number sits in every lane: turned, one row of it is the
         # block's log-sum-exp along the lanes
         lse_ref[...] = (m_ref[...] + jnp.log(l)).T[:1]
+
+
+def _second(rows2_ref, cols2_ref):
+    """The second part's blocks as ``_scores`` takes them; nothing where
+    there is none."""
+    return {} if rows2_ref is None else {"rows2": rows2_ref[...],
+                                         "cols2": cols2_ref[...]}
 
 
 def _head_spec(blk, dim, index):
@@ -150,10 +182,34 @@ def _padded(x, axis, length):
     return jnp.pad(x, pad) if pad[axis][1] else x
 
 
-def forward(q, k, v, num_heads, num_kv_heads, scale, interpret=False):
+def _extra_specs(blk, d2, q2_at, k2_at):
+    """Blocks of the second part: a head's (block, D2) of the head-major
+    ``q2`` (B, Hq, L, D2), and (block, D2) of ``k2`` (B, L, D2)."""
+    return [pl.BlockSpec((None, None, blk, d2), q2_at),
+            pl.BlockSpec((None, blk, d2), k2_at)]
+
+
+def _q2_at(b, h, i, j):
+    return b, h, i, 0
+
+
+def _k2_at(b, h, i, j):
+    return b, jnp.minimum(j, i), 0
+
+
+def _head_major(q2, hq, padded):
+    """``q2`` (B, L, Hq * D2) as (B, Hq, padded L, D2)."""
+    bsz, length, _ = q2.shape
+    return _padded(q2.reshape(bsz, length, hq, -1).transpose(0, 2, 1, 3), 2,
+                   padded)
+
+
+def forward(q, k, v, num_heads, num_kv_heads, scale, interpret=False,
+            extra=None):
     """``(out, lse)``: the attention's output (B, L, Hq * D) in ``q``'s
     dtype and every row's float32 log-sum-exp of its scaled, masked
-    scores (B, Hq, L)."""
+    scores (B, Hq, L). ``extra``: ``(q2 (B, L, Hq * D2), k2 (B, L, D2))``,
+    the scores' second part."""
     hq, group = int(num_heads), int(num_heads) // int(num_kv_heads)
     bsz, length, _ = q.shape
     dim = q.shape[-1] // hq
@@ -161,40 +217,56 @@ def forward(q, k, v, num_heads, num_kv_heads, scale, interpret=False):
     q, k, v = (_padded(t, 1, padded) for t in (q, k, v))
     n = padded // blk
     kv_at = functools.partial(_kv_at, group)
+    operands, specs = [q, k, v], [_head_spec(blk, dim, _q_at),
+                                  _head_spec(blk, dim, kv_at),
+                                  _head_spec(blk, dim, kv_at)]
+    if extra is not None:
+        q2, k2 = extra
+        operands += [_head_major(q2, hq, padded), _padded(k2, 1, padded)]
+        specs += _extra_specs(blk, k2.shape[-1], _q2_at, _k2_at)
     out, lse = _call(
         functools.partial(_fwd_kernel, scale=scale, blk=blk),
         "attn_fwd_kernel", (bsz, hq, n, n), interpret,
-        in_specs=[_head_spec(blk, dim, _q_at), _head_spec(blk, dim, kv_at),
-                  _head_spec(blk, dim, kv_at)],
+        in_specs=specs,
         out_specs=[_head_spec(blk, dim, _q_at), _row_spec(blk, _row_at)],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((bsz, hq, 1, padded), _F32)],
         scratch_shapes=[pltpu.VMEM((blk, _LANES), _F32),
                         pltpu.VMEM((blk, _LANES), _F32),
-                        pltpu.VMEM((blk, dim), _F32)])(q, k, v)
+                        pltpu.VMEM((blk, dim), _F32)])(*operands)
     return out[:, :length], lse[:, :, 0, :length]
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, scale, blk):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+               scale, blk):
+    if len(refs) == 2:
+        (dq_ref, acc_ref), q2_ref, k2_ref = refs, None, None
+    else:
+        q2_ref, k2_ref, dq_ref, dq2_ref, acc_ref, acc2_ref = refs
     i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(j == 0)
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        if q2_ref is not None:
+            acc2_ref[...] = jnp.zeros_like(acc2_ref)
 
     def block(diagonal):
         k, v = k_ref[...], v_ref[...]
-        s = _scores(q_ref[...], k, i, j, blk, scale, diagonal)
+        s = _scores(q_ref[...], k, i, j, blk, scale, diagonal,
+                    **_second(q2_ref, k2_ref))
         p = jnp.exp(s - jnp.expand_dims(lse_ref[0], -1))
         dp = lax.dot_general(do_ref[...], v, _NT,
                              preferred_element_type=_F32)
         ds = p * (dp - jnp.expand_dims(delta_ref[0], -1))
         acc_ref[...] += jnp.dot(ds.astype(k.dtype), k,
                                 preferred_element_type=_F32)
+        if q2_ref is not None:
+            acc2_ref[...] += jnp.dot(ds.astype(k.dtype), k2_ref[...],
+                                     preferred_element_type=_F32)
 
     pl.when(j < i)(functools.partial(block, False))
 
@@ -202,24 +274,37 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _():
         block(True)
         dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+        if q2_ref is not None:
+            dq2_ref[...] = (acc2_ref[...] * scale).astype(dq2_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, dk_acc, dv_acc, *, scale, blk, n):
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                scale, blk, n, span=None):
     # keys along the sublanes, queries along the lanes: a query's
-    # log-sum-exp and delta are rows as they lie
+    # log-sum-exp and delta are rows as they lie. ``span``: with a second
+    # part the last grid axis runs over all heads, ``span`` steps to a
+    # key/value head
+    if span is None:
+        (dk_ref, dv_ref, dk_acc, dv_acc), q2_ref, k2_ref = refs, None, None
+    else:
+        q2_ref, k2_ref, dk_ref, dv_ref, dk2_ref, dk_acc, dv_acc, dk2_acc = refs
     j, t = pl.program_id(2), pl.program_id(3)
     i = t % n
 
-    @pl.when(t == 0)
+    @pl.when(t == 0 if span is None else t % span == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    if span is not None:
+        @pl.when(t == 0)
+        def _():
+            dk2_acc[...] = jnp.zeros_like(dk2_acc)
+
     def block(diagonal):
         q, do = q_ref[...], do_ref[...]
         st = _scores(k_ref[...], q, i, j, blk, scale, diagonal,
-                     transposed=True)
+                     transposed=True, **_second(k2_ref, q2_ref))
         pt = jnp.exp(st - lse_ref[...])
         dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
                                preferred_element_type=_F32)
@@ -228,20 +313,29 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dst = pt * (dpt - delta_ref[...])
         dk_acc[...] += jnp.dot(dst.astype(q.dtype), q,
                                preferred_element_type=_F32)
+        if span is not None:
+            dk2_acc[...] += jnp.dot(dst.astype(q.dtype), q2_ref[...],
+                                    preferred_element_type=_F32)
 
     pl.when(i > j)(functools.partial(block, False))
     pl.when(i == j)(functools.partial(block, True))
 
-    @pl.when(t == pl.num_programs(3) - 1)
+    @pl.when(t == pl.num_programs(3) - 1 if span is None
+             else t % span == span - 1)
     def _():
         dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
+    if span is not None:
+        @pl.when(t == pl.num_programs(3) - 1)
+        def _():
+            dk2_ref[...] = (dk2_acc[...] * scale).astype(dk2_ref.dtype)
+
 
 def backward(q, k, v, out, lse, dout, num_heads, num_kv_heads, scale,
-             interpret=False):
+             interpret=False, extra=None):
     """``(dq, dk, dv)`` from what ``forward`` took and gave and the
-    output's cotangent."""
+    output's cotangent; with ``extra``, ``(dq, dk, dv, dq2, dk2)``."""
     hq, hk = int(num_heads), int(num_kv_heads)
     group = hq // hk
     bsz, length, _ = q.shape
@@ -255,6 +349,14 @@ def backward(q, k, v, out, lse, dout, num_heads, num_kv_heads, scale,
     kv_at = functools.partial(_kv_at, group)
     head, row = functools.partial(_head_spec, blk, dim), \
         functools.partial(_row_spec, blk)
+    if extra is not None:
+        dq, dk, dv, dq2, dk2 = _backward_extra(
+            (q, k, v, dout, lse, delta), extra, hq, group, dim, blk, n,
+            scale, interpret)
+        dq2 = dq2[:, :, :length].transpose(0, 2, 1, 3).reshape(
+            bsz, length, -1)
+        return dq[:, :length], dk[:, :length], dv[:, :length], dq2, \
+            dk2[:, :length]
     dq = _call(
         functools.partial(_dq_kernel, scale=scale, blk=blk),
         "attn_bwd_dq_kernel", (bsz, hq, n, n), interpret,
@@ -289,6 +391,67 @@ def backward(q, k, v, out, lse, dout, num_heads, num_kv_heads, scale,
                         pltpu.VMEM((blk, dim), _F32)])(
             q, k, v, dout, lse, delta)
     return dq[:, :length], dk[:, :length], dv[:, :length]
+
+
+def _backward_extra(operands, extra, hq, group, dim, blk, n, scale,
+                    interpret):
+    """``backward``'s two kernels with the scores' second part, over its
+    padded operands ``(q, k, v, dout, lse, delta)``: ``(dq, dk, dv, dq2
+    head-major, dk2)``, padded."""
+    q, k, v = operands[:3]
+    bsz, padded, _ = q.shape
+    q2, k2 = extra
+    d2 = k2.shape[-1]
+    q2, k2 = _head_major(q2, hq, padded), _padded(k2, 1, padded)
+    kv_at = functools.partial(_kv_at, group)
+    head, row = functools.partial(_head_spec, blk, dim), \
+        functools.partial(_row_spec, blk)
+    second = _extra_specs(blk, d2, _q2_at, _k2_at)
+    dq, dq2 = _call(
+        functools.partial(_dq_kernel, scale=scale, blk=blk),
+        "attn_bwd_dq_kernel", (bsz, hq, n, n), interpret,
+        in_specs=[head(_q_at), head(kv_at), head(kv_at), head(_q_at),
+                  row(_row_at), row(_row_at)] + second,
+        out_specs=[head(_q_at), second[0]],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(q2.shape, q2.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk, dim), _F32),
+                        pltpu.VMEM((blk, d2), _F32)])(*operands, q2, k2)
+
+    # grid (batch, 1, key block j, t): a key block meets every query head
+    # in turn, key/value head by key/value head, each at the query blocks
+    # at or after it, so that ``dk2`` adds up over all of them
+    span = group * n
+
+    def k_at(b, _, j, t):
+        return b, j, t // span
+
+    def q_at(b, _, j, t):
+        return b, jnp.maximum(t % n, j), t // n
+
+    def row_at(b, _, j, t):
+        return b, t // n, 0, jnp.maximum(t % n, j)
+
+    def q2_at(b, _, j, t):
+        return b, t // n, jnp.maximum(t % n, j), 0
+
+    def k2_at(b, _, j, t):
+        return b, j, 0
+
+    second = _extra_specs(blk, d2, q2_at, k2_at)
+    dk, dv, dk2 = _call(
+        functools.partial(_dkv_kernel, scale=scale, blk=blk, n=n, span=span),
+        "attn_bwd_dkv_kernel", (bsz, 1, n, hq * n), interpret,
+        in_specs=[head(q_at), head(k_at), head(k_at), head(q_at),
+                  row(row_at), row(row_at)] + second,
+        out_specs=[head(k_at), head(k_at), second[1]],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(k2.shape, k2.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk, dim), _F32),
+                        pltpu.VMEM((blk, dim), _F32),
+                        pltpu.VMEM((blk, d2), _F32)])(*operands, q2, k2)
+    return dq, dk, dv, dq2, dk2
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,11 @@
 """Sequence-model operators: RMSNorm, the Mamba-2 mixer (the chunked
-state-space scan, SSD), a latent mixture of experts that is told which
-experts it holds, causal grouped-query attention in blocks (fused
-kernels where the program is lowered for a TPU, ``ops.attn_kernel``) with
-or without rotary position encoding, a gated MLP, and the exit gate and
-exit-weighted loss of a stack that is run several times.
+state-space scan, SSD), a mixture of experts that is told which experts
+it holds (one routing path, two expert bodies: relu2 in a latent, or
+gated SiLU on the full hidden vector), causal grouped-query attention in
+blocks (fused kernels where the program is lowered for a TPU,
+``ops.attn_kernel``) with or without rotary position encoding, multi-head
+latent attention over the same kernels, a gated MLP, and the exit gate
+and exit-weighted loss of a stack that is run several times.
 
 Every op here is one chip's share of a layer: it is told how many heads
 and groups it holds and which experts, computes with what it holds, and
@@ -13,8 +15,8 @@ rest; ``tests/test_seq_ops.py`` adds the shares up to the uncut layer.
 The step's device time is a function of shapes alone: the expert layer's
 receive buffer is static and every row of it is computed, filled or not.
 
-Named scopes (``mx_ssd_*``, ``mx_moe_*``, ``mx_attn_*``, ``mx_rope``,
-``mx_gated_mlp``, ``mx_exit_head``, ``mx_exit_gate``) mark each
+Named scopes (``mx_ssd_*``, ``mx_moe_*``, ``mx_attn_*``, ``mx_mla_*``,
+``mx_rope``, ``mx_gated_mlp``, ``mx_exit_head``, ``mx_exit_gate``) mark each
 mechanism in the compiled program; ``telemetry.trace.hlo_scopes`` maps
 the program's instructions back to them. The expert layer's matrix
 products have scopes of their own (``mx_moe_score``: the router's;
@@ -203,7 +205,9 @@ def grouped_product(buf, w1, w2):
     ``w2`` (E, ff, latent). The whole buffer is computed, whether a row
     holds a token or nothing: the work is the same for every routing, and
     a product that skipped empty tiles would make a step's time depend on
-    the seed. Do not write one."""
+    the seed. Do not write one. (The other expert body, gated on the full
+    hidden vector: ``pooled_gated_product``, under the same scopes and
+    the same rule.)"""
     with jax.named_scope("mx_moe_gmm_up"):
         hid = _relu2(kept(jnp.einsum("erd,edf->erf", buf, w1,
                                      preferred_element_type=_F32))
@@ -212,6 +216,28 @@ def grouped_product(buf, w1, w2):
         return kept(jnp.einsum("erf,efd->erd", hid, w2,
                                preferred_element_type=_F32
                                ).astype(buf.dtype))
+
+
+def pooled_gated_product(buf, w1, w3, w2, sizes):
+    """Every held expert's ``(silu(rows W1) * rows W3) W2`` over its rows
+    of the pool: ``buf`` (rows, hidden) sorted by expert, ``sizes`` (E,)
+    int32 the rows each expert has there, summing to ``rows``; ``w1``,
+    ``w3`` (E, hidden, ff), ``w2`` (E, ff, hidden); the activation and the
+    gating in float32 on the products as the compute dtype holds them
+    (``gated_mlp``'s arithmetic). Three ragged products
+    (``lax.ragged_dot``: on a TPU XLA's own grouped kernel, elsewhere
+    its plain form). Every row of the pool is some expert's and is
+    computed, whether it holds a token or nothing: ``grouped_product``'s
+    rule."""
+    with jax.named_scope("mx_moe_gmm_up"):
+        gate, up = (kept(lax.ragged_dot(buf, w, sizes,
+                                        preferred_element_type=buf.dtype))
+                    for w in (w1, w3))
+        hid = (jax.nn.silu(gate.astype(_F32)) * up.astype(_F32)
+               ).astype(buf.dtype)
+    with jax.named_scope("mx_moe_gmm_down"):
+        return kept(lax.ragged_dot(hid, w2, sizes,
+                                   preferred_element_type=buf.dtype))
 
 
 def route(scores_in, router_weight, router_bias, top_k, scaling,
@@ -275,14 +301,34 @@ def latent_moe(data, router_weight, router_bias, down_weight, up_weight,
     the caller to keep for the next call; no gradient reaches it."""
     bsz, length, hidden = data.shape
     u = data.reshape(bsz * length, hidden)
-    t = u.shape[0]
-    ids = jnp.asarray(tuple(int(e) for e in expert_ids), jnp.int32)
-    n_held = ids.shape[0]
-    cap = int(buffer_rows) // n_held
     gate_all, chosen_all = route(u, router_weight, router_bias, top_k,
                                  scaling, norm_topk)
     with jax.named_scope("mx_moe_latent"):
         v = _mm(u, down_weight)                         # (T, latent)
+    buf, token, row_gate, load, count, cap = _dispatch(
+        v, gate_all, chosen_all, expert_ids, buffer_rows)
+    routed = _combine(grouped_product(buf, w1, w2), row_gate, token,
+                      u.shape[0])
+    with jax.named_scope("mx_moe_latent"):
+        routed = _mm(kept(routed.astype(data.dtype)), up_weight)
+    with jax.named_scope("mx_moe_shared"):
+        shared = _mm(_relu2(kept(_mm(u, shared_w1))), shared_w2)
+    stats = _moe_stats(load, count, cap, counters)
+    return (routed + shared).reshape(bsz, length, hidden), stats, \
+        lax.stop_gradient(balanced_bias(router_bias, load, bias_rate))
+
+
+def _dispatch(rows, gate_all, chosen_all, expert_ids, buffer_rows):
+    """Sort the (token, expert) pairs that chose a held expert into the
+    static buffer. ``rows`` (T, width): what an expert reads of a token.
+    Returns the buffer (E, cap, width), each of its rows' token (E, cap;
+    T where a row holds none) and gate (E, cap), every expert's load
+    (E_all,), the held experts' pair counts (E,) and ``cap``, the rows an
+    expert has."""
+    t = rows.shape[0]
+    ids = jnp.asarray(tuple(int(e) for e in expert_ids), jnp.int32)
+    n_held = ids.shape[0]
+    cap = int(buffer_rows) // n_held
     with jax.named_scope("mx_moe_dispatch"):
         load = jnp.sum(chosen_all, axis=0, dtype=_F32)
         gate = jnp.take(gate_all, ids, axis=1)          # (T, E)
@@ -297,28 +343,111 @@ def latent_moe(data, router_weight, router_bias, down_weight, up_weight,
         # what the sort gave and the rows it gathered, kept: no backward
         # pass sorts again, nor projects to the latent for the rows' sake
         token = kept(jnp.where(valid, order.T, t))      # (E, cap); t: none
-        buf = kept(jnp.take(v, token, axis=0, mode="fill", fill_value=0))
+        buf = kept(jnp.take(rows, token, axis=0, mode="fill", fill_value=0))
         pair = jnp.where(valid, jnp.arange(n_held)[:, None] * t + token,
                          n_held * t)
         row_gate = kept(jnp.take(gate.T.reshape(-1), pair, mode="fill",
                                  fill_value=0))
-    out_buf = grouped_product(buf, w1, w2)
+    return buf, token, row_gate, load, count, cap
+
+
+def _dispatch_pooled(rows, gate_all, chosen_all, expert_ids, buffer_rows):
+    """Sort the (token, expert) pairs that chose a held expert, expert by
+    expert, into ONE pool of ``buffer_rows`` rows that the held experts
+    share: an expert takes as many rows as it drew pairs, so a pair is
+    beyond the buffer only when the held experts' pairs together are more
+    than its rows (``_dispatch`` gives every expert a slice of its own,
+    and one expert's slice overflows while its neighbours' stand empty).
+    Returns the pool (rows, width), each row's token (T where it holds
+    none) and gate, every expert's load (E_all,), the held experts' pair
+    counts (E,) and the rows each has in the pool (E,) int32: the rows no
+    pair took are zeros and go to the last expert, so the sizes sum to
+    ``buffer_rows`` whatever the routing."""
+    t = rows.shape[0]
+    ids = jnp.asarray(tuple(int(e) for e in expert_ids), jnp.int32)
+    n_held = ids.shape[0]
+    cap = int(buffer_rows)
+    with jax.named_scope("mx_moe_dispatch"):
+        load = jnp.sum(chosen_all, axis=0, dtype=_F32)
+        gate = jnp.take(gate_all, ids, axis=1)          # (T, E)
+        chosen = jnp.take(chosen_all, ids, axis=1)
+        count = jnp.sum(chosen, axis=0, dtype=jnp.int32)
+        # pairs expert-major, e * T + token: a stable sort brings the
+        # chosen first, expert by expert and each expert's in token order
+        order = jnp.argsort(jnp.logical_not(chosen.T.reshape(-1)),
+                            stable=True)[:cap]
+        if cap > n_held * t:
+            order = jnp.pad(order, (0, cap - n_held * t))
+        ends = jnp.minimum(jnp.cumsum(count), cap)
+        valid = jnp.arange(cap) < ends[-1]
+        # kept as ``_dispatch`` keeps them: no backward pass sorts again
+        token = kept(jnp.where(valid, order % t, t))    # (cap,); t: none
+        buf = kept(jnp.take(rows, token, axis=0, mode="fill", fill_value=0))
+        row_gate = kept(jnp.take(gate.T.reshape(-1),
+                                 jnp.where(valid, order, n_held * t),
+                                 mode="fill", fill_value=0))
+        sizes = jnp.diff(ends, prepend=0).at[-1].add(cap - ends[-1])
+    return buf, token, row_gate, load, count, sizes
+
+
+def _combine(out_buf, row_gate, token, t):
+    """The buffer's rows, each times its gate, added up by token: (T,
+    width) float32."""
     with jax.named_scope("mx_moe_combine"):
         weighted = out_buf.astype(_F32) * row_gate[..., None]
-        routed = jnp.zeros((t, v.shape[1]), _F32).at[token.reshape(-1)].add(
-            weighted.reshape(-1, v.shape[1]), mode="drop")
-    with jax.named_scope("mx_moe_latent"):
-        routed = _mm(kept(routed.astype(data.dtype)), up_weight)
-    with jax.named_scope("mx_moe_shared"):
-        shared = _mm(_relu2(kept(_mm(u, shared_w1))), shared_w2)
+        return jnp.zeros((t, out_buf.shape[-1]), _F32).at[
+            token.reshape(-1)].add(
+                weighted.reshape(-1, out_buf.shape[-1]), mode="drop")
+
+
+def _moe_stats(load, count, cap, counters):
+    """``nn.MOE_COUNTERS`` of one call (the pairs beyond the buffer added
+    to ``counters``' own, when given); no gradient reaches them."""
     placed = jnp.sum(jnp.minimum(count, cap)).astype(_F32)
     held = jnp.sum(count).astype(_F32)
     before = 0.0 if counters is None else counters[1].astype(_F32)
-    stats = jnp.stack([held, before + held - placed,
-                       jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
-                       placed / (n_held * cap)])
-    return (routed + shared).reshape(bsz, length, hidden), \
-        lax.stop_gradient(stats), \
+    return lax.stop_gradient(jnp.stack([
+        held, before + held - placed,
+        jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
+        placed / (count.shape[0] * cap)]))
+
+
+@register_op("GatedMoE", num_outputs=3)
+def gated_moe(data, router_weight, router_bias, w1, w3, w2,
+              shared_gate_up_weight, shared_down_weight, counters=None,
+              expert_ids=(0,), top_k=1, buffer_rows=0, scaling=1.0,
+              norm_topk=True, bias_rate=0.0, **kw):
+    """A mixture of gated experts on the full hidden vector, for the
+    experts held here: ``latent_moe``'s router, combine, counters and
+    balancing step (one copy of each: ``route``, ``_combine``,
+    ``_moe_stats``, ``balanced_bias``) around another expert body and
+    another buffer. An expert is ``W2 (silu(W1 u) * W3 u)``: ``w1``,
+    ``w3`` (E, hidden, ff), ``w2`` (E, ff, hidden), no latent projection
+    on either side (``pooled_gated_product``). The ``buffer_rows`` rows
+    are ONE pool the held experts share (``_dispatch_pooled``): the
+    family trains without dropping a token, and a slice of its own for
+    each expert overflowed on the chip while the pool stood a quarter
+    empty (PERF.md, PR 37), so a pair is beyond the buffer only when the
+    held experts' pairs together outnumber its rows; the counters read
+    the pool (``buffer_fill`` its filled share). The shared experts are
+    one gated MLP, ``shared_gate_up_weight`` (2 ff_s, hidden) and
+    ``shared_down_weight`` (hidden, ff_s), as ``gated_mlp`` takes them.
+    Arguments, counters and returns as ``latent_moe``'s."""
+    bsz, length, hidden = data.shape
+    u = data.reshape(bsz * length, hidden)
+    gate_all, chosen_all = route(u, router_weight, router_bias, top_k,
+                                 scaling, norm_topk)
+    buf, token, row_gate, load, count, sizes = _dispatch_pooled(
+        u, gate_all, chosen_all, expert_ids, buffer_rows)
+    routed = _combine(pooled_gated_product(buf, w1, w3, w2, sizes), row_gate,
+                      token, u.shape[0])
+    with jax.named_scope("mx_moe_shared"):
+        shared = gated_mlp(u, shared_gate_up_weight, shared_down_weight)
+    # one pool: the held experts' pairs together against all its rows
+    stats = _moe_stats(load, jnp.sum(count)[None], int(buffer_rows),
+                       counters)
+    return (routed.astype(data.dtype) + shared).reshape(
+        bsz, length, hidden), stats, \
         lax.stop_gradient(balanced_bias(router_bias, load, bias_rate))
 
 
@@ -401,50 +530,62 @@ def _blocked_attention(q, k, v, blk, scale, with_lse=False):
     return (out, jnp.concatenate(lses, axis=-1)) if with_lse else out
 
 
-def _blocked_rows(q, k, v, hq, hk, scale, blk):
+def _blocked_rows(q, k, v, hq, hk, scale, blk, extra=None):
     """``_blocked_attention`` over rows of heads: ``q`` (B, L, hq * D),
-    ``k``, ``v`` (B, L, hk * D). Returns the output as such rows in
-    ``q``'s dtype and the log-sum-exp (B, hq, L)."""
+    ``k``, ``v`` (B, L, hk * D); with ``extra = (q2 (B, L, hq * D2), k2
+    (B, L, D2))`` every head's query and key are ``[q | q2]`` and ``[k |
+    k2]``, ``k2`` the same for all heads. Returns the output as such rows
+    in ``q``'s dtype and the log-sum-exp (B, hq, L)."""
     bsz, length, _ = q.shape
     k, v = (jnp.repeat(t.reshape(bsz, length, hk, -1), hq // hk, axis=2)
             for t in (k, v))
-    out, lse = _blocked_attention(q.reshape(bsz, length, hq, -1), k, v, blk,
-                                  scale, with_lse=True)
-    return out.reshape(q.shape).astype(q.dtype), lse
+    qh = q.reshape(bsz, length, hq, -1)
+    if extra is not None:
+        q2, k2 = extra
+        qh = jnp.concatenate([qh, q2.reshape(bsz, length, hq, -1)], axis=-1)
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            k2[:, :, None], (bsz, length, hq, k2.shape[-1]))], axis=-1)
+    out, lse = _blocked_attention(qh, k, v, blk, scale, with_lse=True)
+    return out.reshape(bsz, length, -1).astype(q.dtype), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _fused_attention(q, k, v, hq, hk, scale, blk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _fused_attention(q, k, v, extra, hq, hk, scale, blk):
     """Attention over rows of heads whose program takes its form when it
     is lowered: for a TPU the kernels of ``ops.attn_kernel``, forward and
     backward, for any other platform the blocked recurrence and JAX's own
-    derivative of it. Either way the unit around it keeps the output and
-    one float32 log-sum-exp a row, and its backward pass runs no forward
-    a second time where the kernels are."""
-    return _fused_attention_fwd(q, k, v, hq, hk, scale, blk)[0]
+    derivative of it. ``extra``: nothing, or the scores' second part
+    ``(q2, k2)`` (``_blocked_rows``). Either way the unit around it keeps
+    the output and one float32 log-sum-exp a row, and its backward pass
+    runs no forward a second time where the kernels are."""
+    return _fused_attention_fwd(q, k, v, extra, hq, hk, scale, blk)[0]
 
 
-def _fused_attention_fwd(q, k, v, hq, hk, scale, blk):
+def _fused_attention_fwd(q, k, v, extra, hq, hk, scale, blk):
     with jax.named_scope("mx_attn_fwd"):
         out, lse = lax.platform_dependent(
-            q, k, v,
-            tpu=lambda q, k, v: attn_kernel.forward(
-                attn_kernel.counted_site(q), k, v, hq, hk, scale),
-            default=lambda q, k, v: _blocked_rows(q, k, v, hq, hk, scale,
-                                                  blk))
+            q, k, v, *(extra or ()),
+            tpu=lambda q, k, v, *extra: attn_kernel.forward(
+                attn_kernel.counted_site(q), k, v, hq, hk, scale,
+                extra=extra or None),
+            default=lambda q, k, v, *extra: _blocked_rows(
+                q, k, v, hq, hk, scale, blk, extra or None))
     out, lse = kept(out), kept(lse)
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, extra, out, lse)
 
 
 def _fused_attention_bwd(hq, hk, scale, blk, res, dout):
-    q, k, v, out, lse = res
+    q, k, v, extra, out, lse = res
     with jax.named_scope("mx_attn_fwd"):
-        return lax.platform_dependent(
-            q, k, v, out, lse, dout,
-            tpu=lambda *a: attn_kernel.backward(*a, hq, hk, scale),
-            default=lambda q, k, v, out, lse, dout: jax.vjp(
-                lambda *qkv: _blocked_rows(*qkv, hq, hk, scale, blk)[0],
-                q, k, v)[1](dout))
+        grads = lax.platform_dependent(
+            q, k, v, out, lse, dout, *(extra or ()),
+            tpu=lambda *a: attn_kernel.backward(*a[:6], hq, hk, scale,
+                                                extra=a[6:] or None),
+            default=lambda q, k, v, out, lse, dout, *extra: jax.vjp(
+                lambda q, k, v, *extra: _blocked_rows(
+                    q, k, v, hq, hk, scale, blk, extra or None)[0],
+                q, k, v, *extra)[1](dout))
+    return tuple(grads[:3]) + (tuple(grads[3:]) or None,)
 
 
 _fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
@@ -477,6 +618,9 @@ def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
     A recomputation unit around it keeps the packed rows, the output and,
     where ``head_dim`` is a multiple of 128, one float32 log-sum-exp a
     row; the rotation is computed again, the kernel's forward is not.
+    (Heads whose queries and keys are wider than their values and share a
+    part of the key go through the same ``_fused_attention`` with a
+    second score part: ``latent_attention``.)
 
     ``data``: (B, L, (num_heads + 2 num_kv_heads) * head_dim). Returns
     (B, L, num_heads * head_dim)."""
@@ -493,11 +637,74 @@ def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
     if dh % 128 == 0:
         return _fused_attention(
             q.reshape(bsz, length, hq * dh), k.reshape(bsz, length, hk * dh),
-            v.reshape(bsz, length, hk * dh), hq, hk, float(scale), blk)
+            v.reshape(bsz, length, hk * dh), None, hq, hk, float(scale), blk)
     k = jnp.repeat(k, hq // hk, axis=2)
     v = jnp.repeat(v, hq // hk, axis=2)
     out = _blocked_attention(q, k, v, blk, scale)
     return kept(out.reshape(bsz, length, hq * dh).astype(data.dtype))
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention
+# ---------------------------------------------------------------------------
+@register_op("LatentAttention")
+def latent_attention(data, q_weight, kv_down_weight, kv_norm_weight,
+                     kv_up_weight, o_weight, num_heads=1, nope_dim=128,
+                     rope_dim=64, v_dim=128, latent_dim=512,
+                     rope_theta=10000.0, eps=1e-5, block=1024, **kw):
+    """Causal multi-head latent attention over the ``num_heads`` heads
+    held here. Keys and values are expanded from one ``latent_dim``-wide
+    vector a token; a head's score is ``q_nope . k_nope + q_pe . k_pe``
+    with ``k_pe`` (``rope_dim`` wide, rotated by position as ``q_pe`` is)
+    one vector shared by all heads, scaled by ``(nope_dim + rope_dim) **
+    -0.5``; values are ``v_dim`` wide.
+
+    ``data`` (B, L, hidden). ``q_weight`` (H * (nope + rope), hidden),
+    rows ``[every head's q_nope | every head's q_pe]``;
+    ``kv_down_weight`` (latent + rope, hidden), rows ``[c | k_pe]``;
+    ``kv_norm_weight`` (latent,), an RMSNorm over ``c`` alone;
+    ``kv_up_weight`` (H * (nope + v), latent), rows ``[every head's
+    k_nope | every head's v]``; ``o_weight`` (hidden, H * v). Grouping
+    the rows by part and not by head is a layout: a fixed permutation of
+    the rows of a matrix that is stored head by head.
+
+    The softmax goes through ``_fused_attention`` with ``(q_pe, k_pe)``
+    as the scores' second part: lowered for a TPU with ``nope_dim ==
+    v_dim`` a multiple of 128, the kernels of ``ops.attn_kernel`` (no
+    score block in memory, ``k_pe`` not repeated a head, 192 not padded
+    to 256), everywhere else the blocked recurrence over the
+    concatenated heads. A recomputation unit keeps ``q``, the latent
+    before and after its norm with ``k_pe`` (576 + 512 wide), the
+    attention's output and its log-sum-exp; the up-projection to H *
+    (nope + v) is computed again (2.1 M multiply-accumulates a token at
+    16 heads, against 8 times the latent's bytes to hold).
+
+    Returns (B, L, hidden), this share's partial sum."""
+    h, dn, dr, dv = int(num_heads), int(nope_dim), int(rope_dim), int(v_dim)
+    bsz, length, _ = data.shape
+    with jax.named_scope("mx_mla_q"):
+        q = kept(_mm(data, q_weight))
+    with jax.named_scope("mx_mla_kv_down"):
+        ckv = kept(_mm(data, kv_down_weight))
+        c = kept(rms_norm(ckv[..., :latent_dim], kv_norm_weight, eps=eps))
+    with jax.named_scope("mx_mla_kv_up"):
+        kv = _mm(c, kv_up_weight)
+    with jax.named_scope("mx_mla_rope"):
+        q_pe = rope(q[..., h * dn:].reshape(bsz, length, h, dr), rope_theta)
+        k_pe = rope(ckv[..., latent_dim:].reshape(bsz, length, 1, dr),
+                    rope_theta)
+    blk = min(int(block), length)
+    scale = float((dn + dr) ** -0.5)
+    q_nope, k_nope, v = q[..., :h * dn], kv[..., :h * dn], kv[..., h * dn:]
+    extra = (q_pe.reshape(bsz, length, h * dr),
+             k_pe.reshape(bsz, length, dr))
+    if dn == dv and dn % 128 == 0:
+        out = _fused_attention(q_nope, k_nope, v, extra, h, h, scale, blk)
+    else:
+        out = kept(_blocked_rows(q_nope, k_nope, v, h, h, scale, blk,
+                                 extra)[0])
+    with jax.named_scope("mx_mla_out"):
+        return _mm(out, o_weight)
 
 
 # ---------------------------------------------------------------------------

@@ -117,6 +117,30 @@ CASES = {
         attrs=dict(expert_ids=(1, 4), top_k=2, buffer_rows=10,
                    scaling=2.5),
         grad_args=[0, 3, 4, 5, 6, 7, 8], tol=(8e-2, 8e-3)),
+    "GatedMoE": dict(
+        # ops/seq.py: LatentMoE's routing around gated experts on the full
+        # hidden vector: 6 experts, 2 held, top-2, w1/w3 (2, 8, 6), w2 (2,
+        # 6, 8), a gated shared MLP 3 wide; the choice is piecewise
+        # constant (tests/test_gated_moe.py pins the gradients against the
+        # plain reference); the bias takes no gradient
+        inputs=[_signed((1, 5, 8), 0), _signed((6, 8), 1),
+                _signed((6,), 2), 0.5 * _signed((2, 8, 6), 3),
+                0.5 * _signed((2, 8, 6), 4), 0.5 * _signed((2, 6, 8), 5),
+                0.5 * _signed((6, 8), 6), 0.5 * _signed((8, 3), 7)],
+        attrs=dict(expert_ids=(1, 4), top_k=2, buffer_rows=10,
+                   scaling=2.5),
+        grad_args=[0, 3, 4, 5, 6, 7], tol=(8e-2, 8e-3)),
+    "LatentAttention": dict(
+        # ops/seq.py: 2 heads, q/k 4 + 2 wide (the 2-wide rotary key
+        # shared by both heads), values 3 wide, a latent of 6, blocks of
+        # 2 over 5 steps; rows grouped by part: Wq [q_nope 8 | q_pe 4],
+        # Wkva [c 6 | k_pe 2], Wkvb [k_nope 8 | v 6]
+        inputs=[_signed((2, 5, 8), 0), 0.5 * _signed((12, 8), 1),
+                0.5 * _signed((8, 8), 2), _pos((6,), 3),
+                0.5 * _signed((14, 6), 4), 0.5 * _signed((8, 6), 5)],
+        attrs=dict(num_heads=2, nope_dim=4, rope_dim=2, v_dim=3,
+                   latent_dim=6, rope_theta=50.0, block=2),
+        tol=(6e-2, 6e-3)),
     "CausalGQAttention": dict(
         # ops/seq.py: packed [q | k | v], 2 query heads on 1 key/value
         # head of 3, blocks of 2 over 5 steps
