@@ -1,5 +1,5 @@
 """Causal grouped-query attention as fused kernels (Pallas on Mosaic):
-one forward kernel and the backward's two, for ``ops.seq
+one forward kernel and one backward kernel, for ``ops.seq
 .causal_gq_attention`` where its program is lowered for a TPU and the
 heads are whole lane tiles.
 
@@ -16,29 +16,55 @@ scores, ``-1e30`` for a masked score, the probabilities cast to ``v``'s
 dtype for the weighted sum, one division by the denominator at the end.
 The forward keeps a block's float32 scores, the running maximum, the
 running sum and the float32 accumulator in VMEM and writes the output and
-one float32 log-sum-exp a row; the backward forms ``P = exp(S - lse)``
-again from q, k and that log-sum-exp, block by block. Blocks past the
-diagonal are never computed, nor fetched (their index is clamped to the
-last block that is, and an unchanged index moves nothing). A length that
-is no multiple of the block is padded with zero rows: a padded key lies
-past every true query's diagonal, and a padded query's gradient is zero.
+one float32 log-sum-exp a row. Blocks past the diagonal are never
+computed, nor fetched (their index is clamped to the last block that is,
+and an unchanged index moves nothing). A length that is no multiple of
+the block is padded with zero rows: a padded key lies past every true
+query's diagonal, and a padded query's gradient is zero.
+
+The backward forms each score block once. Its grid is (batch, key/value
+head, key block ``j``, the group's query heads times the query blocks
+``i``): for a pair ``i >= j`` it forms ``S^T`` (keys along the sublanes,
+so a query's log-sum-exp and ``delta`` are rows as they lie), ``P^T =
+exp(S^T - lse)`` again from q, k and that log-sum-exp, ``dP^T = V dO^T``
+and ``dS^T = P^T (dP^T - delta)``, rounds ``dS^T`` to the compute dtype
+once, and takes all five products from them: ``dV += P^T dO`` and ``dK +=
+dS^T Q`` into one block's float32 sums, which leave when the key block's
+last query block is through, and ``dQ_i += dS K_j`` (``dS^T`` turned: a
+product over its first dimension) into float32 sums over all the rows of
+the group's heads, held in VMEM while the key/value head is worked. A
+query block's sum is complete at its diagonal, where it is scaled, cast
+once and written into the group's ``dQ`` block of all the rows, which
+leaves when the key/value head changes: nothing of ``dQ`` goes through
+memory in float32, and the order of addition is the key blocks'. ``delta
+= sum(dO * O)`` is XLA's, outside the kernel.
 
 A second part of the score (``extra``), for heads whose queries and keys
 are wider than their values and share a part of the key (multi-head
 latent attention: a rotary key of 64 beside 16 heads' own 128): ``q2``
 (B, L, Hq * D2), a second query part a head, and ``k2`` (B, L, D2), one
 key part that every head reads. ``q2 k2^T`` is added to the same float32
-score block before the scale, the mask and the softmax, forward and in
-both backward kernels, which then also give ``dq2`` and ``dk2``; ``k2``
-is never repeated a head, and its gradient is summed over all heads in
-the ``dK, dV`` kernel's grid, whose last axis then runs over every
-head's query blocks in turn (a key/value head's ``dK`` and ``dV`` leave
-when its heads are through, ``dk2`` at the end). D2 need be no lane
-tile: Mosaic takes a block whose last dimension is the whole array's, so
-``k2`` is read as it lies and ``q2`` goes in head-major, (B, Hq, L, D2),
-one transposition of a narrow array that XLA fuses into the rotation
-that produced it; the gradient comes back the same way. Without
-``extra`` the kernels are, to the instruction, what they were.
+score block before the scale, the mask and the softmax, forward and
+backward, and the same ``dS^T`` then also gives ``dk2 += dS^T q2`` and
+``dq2_i += dS k2_j``: ``dq2`` is held and written like ``dQ``, and
+``dk2``, which adds up over every head, is held over all the rows while
+a sequence is worked, so with a second part the key/value heads run in
+turn. ``k2`` is never repeated a head. D2 need be no lane tile: Mosaic
+takes a block whose last dimension is the whole array's, so ``k2`` is
+read as it lies and ``q2`` goes in head-major, (B, Hq, L, D2), one
+transposition of a narrow array that XLA fuses into the rotation that
+produced it; the gradient comes back the same way.
+
+What the fused backward holds for all the rows (``resident_bytes``: a
+group's ``dQ`` in float32 and its output block twice, 32 MiB for four
+heads of 128 at 8192 rows) has to fit beside a grid step's blocks under
+the kernels' VMEM limit. Where it does not (``_RESIDENT_LIMIT_BYTES``:
+arithmetic on the length, the group, D and D2 when the program is
+traced), the backward is two kernels that hold one block's sums whatever
+the length: one over the queries' side for ``dQ`` (and ``dq2``), one
+over the keys' side for ``dK``, ``dV`` (and ``dk2``), each forming the
+score block for itself. The gauge ``attn::fused_bwd_sites`` counts the
+sites that take the fused kernel, beside ``attn::kernel_sites``.
 """
 from __future__ import annotations
 
@@ -57,7 +83,11 @@ _NEG = -1e30
 _LANES = 128
 _BLOCKS = (1024, 512, 256, 128)
 _VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+# of them, what the fused backward may hold for all the rows, beside a
+# grid step's blocks and about 24 MB of float32 (block, block) temporaries
+_RESIDENT_LIMIT_BYTES = 64 * 1024 * 1024
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
 
 
 def block_size(length):
@@ -167,11 +197,12 @@ def _row_at(b, h, i, j):
     return b, h, 0, i
 
 
-def _call(kernel, name, grid, interpret, **specs):
+def _call(kernel, name, grid, interpret,
+          semantics=("parallel",) * 3 + ("arbitrary",), **specs):
     return pl.pallas_call(
         kernel, grid=grid, name=name, interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * 3 + ("arbitrary",),
+            dimension_semantics=semantics,
             vmem_limit_bytes=_VMEM_LIMIT_BYTES), **specs)
 
 
@@ -240,6 +271,227 @@ def forward(q, k, v, num_heads, num_kv_heads, scale, interpret=False,
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
+def _keys_side(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q2_ref,
+               k2_ref, dk_acc, dv_acc, i, j, blk, scale, diagonal):
+    """Key block ``j`` against query block ``i`` with the keys along the
+    sublanes and the queries along the lanes, so that a query's
+    log-sum-exp and delta are rows as they lie: ``P^T`` and ``dS^T``
+    formed once, ``dV += P^T dO`` and ``dK += dS^T Q`` added to the
+    block's sums. Returns ``dS^T`` rounded to the compute dtype."""
+    q, do = q_ref[...], do_ref[...]
+    st = _scores(k_ref[...], q, i, j, blk, scale, diagonal, transposed=True,
+                 **_second(k2_ref, q2_ref))
+    pt = jnp.exp(st - lse_ref[...])
+    dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
+                           preferred_element_type=_F32)
+    dpt = lax.dot_general(v_ref[...], do, _NT, preferred_element_type=_F32)
+    dst = (pt * (dpt - delta_ref[...])).astype(q.dtype)
+    dk_acc[...] += jnp.dot(dst, q, preferred_element_type=_F32)
+    return dst
+
+
+def _keys_side_at(n, head, kv_head):
+    """Index maps of a grid (batch, h, key block ``j``, ``t``) whose step
+    ``t`` brings query block ``t % n`` of query head ``head(h, t)`` to key
+    block ``j`` of key/value head ``kv_head(h, t)``: ``(q_at, k_at,
+    row_at, q2_at, k2_at)``. The steps before a key block's diagonal
+    fetch the diagonal's block again and compute nothing."""
+    def q_at(b, h, j, t):
+        return b, jnp.maximum(t % n, j), head(h, t)
+
+    def k_at(b, h, j, t):
+        return b, j, kv_head(h, t)
+
+    def row_at(b, h, j, t):
+        return b, head(h, t), 0, jnp.maximum(t % n, j)
+
+    def q2_at(b, h, j, t):
+        return b, head(h, t), jnp.maximum(t % n, j), 0
+
+    def k2_at(b, h, j, t):
+        return b, j, 0
+
+    return q_at, k_at, row_at, q2_at, k2_at
+
+
+def _like(t):
+    return jax.ShapeDtypeStruct(t.shape, t.dtype)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                scale, blk, n):
+    # grid (batch, key/value head, key block j, t): a key block meets, for
+    # each query head of its group in turn, the query blocks at or after it
+    if len(refs) == 6:
+        q2_ref = k2_ref = None
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    else:
+        (q2_ref, k2_ref, dq_ref, dk_ref, dv_ref, dq2_ref, dk2_ref,
+         dq_acc, dk_acc, dv_acc, dq2_acc, dk2_acc) = refs
+    h, j, t = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    g, i = t // n, t % n
+    group, dim = dq_acc.shape[0], dq_acc.shape[-1]
+    keys = pl.ds(pl.multiple_of(j * blk, blk), blk)
+    queries = pl.ds(pl.multiple_of(i * blk, blk), blk)
+
+    @pl.when(t == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)        # a query block's first key block
+    def _():
+        dq_acc[g, queries] = jnp.zeros((blk, dim), _F32)
+        if q2_ref is not None:
+            dq2_acc[g, queries] = jnp.zeros((blk, dq2_acc.shape[-1]), _F32)
+
+    if q2_ref is not None:
+        @pl.when((h == 0) & (t == 0))
+        def _():
+            dk2_acc[keys] = jnp.zeros((blk, dk2_acc.shape[-1]), _F32)
+
+    def block(diagonal):
+        dst = _keys_side(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         q2_ref, k2_ref, dk_acc, dv_acc, i, j, blk, scale,
+                         diagonal)
+        dq_acc[g, queries] += _turned_product(dst, k_ref[...])
+        if q2_ref is not None:
+            dk2_acc[keys] += _product(dst, q2_ref[...])
+            dq2_acc[g, queries] += _turned_product(dst, k2_ref[...])
+
+    pl.when(i > j)(functools.partial(block, False))
+
+    @pl.when(i == j)        # a query block's last key block
+    def _():
+        block(True)
+        dq = (dq_acc[g, queries] * scale).astype(dq_ref.dtype)
+        for head in range(group):
+            @pl.when(g == head)
+            def _():
+                dq_ref[queries, head * dim:(head + 1) * dim] = dq
+        if q2_ref is not None:
+            dq2_ref[g, queries] = (dq2_acc[g, queries] * scale).astype(
+                dq2_ref.dtype)
+
+    @pl.when(t == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        if q2_ref is not None:
+            @pl.when(h == pl.num_programs(1) - 1)
+            def _():
+                dk2_ref[keys] = (dk2_acc[keys] * scale).astype(dk2_ref.dtype)
+
+
+def _turned_product(dst, cols):
+    """``dst.T @ cols`` in float32: ``dS^T`` (keys, queries) against the
+    keys' (keys, D) gives the queries' (queries, D). Where ``cols`` is
+    narrower than a lane tile (the second part's 64) it would fill half
+    the MXU's columns: the product is then taken as ``(cols.T @ dst).T``,
+    which turns the two narrow sides and gives the MXU ``dst``'s columns."""
+    if cols.shape[-1] % _LANES:
+        return lax.dot_general(cols, dst, _TN, preferred_element_type=_F32).T
+    return lax.dot_general(dst, cols, _TN, preferred_element_type=_F32)
+
+
+def _product(dst, cols):
+    """``dst @ cols`` in float32; for narrow ``cols``, as in
+    ``_turned_product``, ``(cols.T @ dst.T).T``."""
+    if cols.shape[-1] % _LANES:
+        return lax.dot_general(cols, dst, (((0,), (1,)), ((), ())),
+                               preferred_element_type=_F32).T
+    return jnp.dot(dst, cols, preferred_element_type=_F32)
+
+
+def resident_bytes(padded, group, dim, d2, itemsize):
+    """What the fused backward holds in VMEM for all ``padded`` rows: the
+    float32 sums of a group's ``dQ`` (and ``dq2``, and ``dk2``), lanes
+    padded to whole tiles, and the two buffers of each of their output
+    blocks."""
+    lanes2 = -(-d2 // _LANES) * _LANES
+    return padded * (group * (dim + lanes2) + lanes2) * (4 + 2 * itemsize)
+
+
+def backward(q, k, v, out, lse, dout, num_heads, num_kv_heads, scale,
+             interpret=False, extra=None):
+    """``(dq, dk, dv)`` from what ``forward`` took and gave and the
+    output's cotangent; with ``extra``, ``(dq, dk, dv, dq2, dk2)``. One
+    fused kernel where a group's float32 ``dQ`` over all the rows fits in
+    VMEM (``resident_bytes`` against ``_RESIDENT_LIMIT_BYTES``), else the
+    kernel for the queries' side and the kernel for the keys' side."""
+    hq, group = int(num_heads), int(num_heads) // int(num_kv_heads)
+    bsz, length, _ = q.shape
+    dim = q.shape[-1] // hq
+    blk, padded = block_size(length)
+    delta = jnp.sum((dout.astype(_F32) * out.astype(_F32)).reshape(
+        bsz, length, hq, dim), axis=-1).transpose(0, 2, 1)
+    q, k, v, dout = (_padded(t, 1, padded) for t in (q, k, v, dout))
+    lse, delta = (_padded(t, 2, padded)[:, :, None] for t in (lse, delta))
+    if extra is not None:
+        extra = (_head_major(extra[0], hq, padded),
+                 _padded(extra[1], 1, padded))
+    d2 = 0 if extra is None else extra[1].shape[-1]
+    fused = resident_bytes(padded, group, dim, d2, q.dtype.itemsize) \
+        <= _RESIDENT_LIMIT_BYTES
+    if fused:
+        dout = counted_site(dout, FUSED_BWD_GAUGE)
+    grads = (_backward_fused if fused else _backward_by_side)(
+        (q, k, v, dout, lse, delta), extra, group, dim, blk, scale, interpret)
+    dq, dk, dv = (t[:, :length] for t in grads[:3])
+    if extra is None:
+        return dq, dk, dv
+    dq2 = grads[3][:, :, :length].transpose(0, 2, 1, 3).reshape(
+        bsz, length, -1)
+    return dq, dk, dv, dq2, grads[4][:, :length]
+
+
+def _backward_fused(operands, extra, group, dim, blk, scale, interpret):
+    """The fused kernel over ``backward``'s padded operands ``(q, k, v,
+    dout, lse, delta)`` and second part ``(q2 head-major, k2)``: ``(dq,
+    dk, dv)`` and with a second part ``dq2`` head-major and ``dk2``,
+    padded."""
+    q, k, v = operands[:3]
+    bsz, padded, _ = q.shape
+    n = padded // blk
+    head, row = functools.partial(_head_spec, blk, dim), \
+        functools.partial(_row_spec, blk)
+    q_at, k_at, row_at, q2_at, k2_at = _keys_side_at(
+        n, lambda h, t: h * group + t // n, lambda h, t: h)
+    in_specs = [head(q_at), head(k_at), head(k_at), head(q_at),
+                row(row_at), row(row_at)]
+    # a group's ``dQ`` is one block of all the rows, written a query
+    # block at a time and leaving when the key/value head changes
+    out_specs = [pl.BlockSpec((None, padded, group * dim),
+                              lambda b, h, j, t: (b, 0, h)),
+                 head(k_at), head(k_at)]
+    outs = [q, k, v]
+    scratch = [pltpu.VMEM((group, padded, dim), _F32),
+               pltpu.VMEM((blk, dim), _F32), pltpu.VMEM((blk, dim), _F32)]
+    if extra is not None:
+        d2 = extra[1].shape[-1]
+        in_specs += _extra_specs(blk, d2, q2_at, k2_at)
+        out_specs += [pl.BlockSpec((None, group, padded, d2),
+                                   lambda b, h, j, t: (b, h, 0, 0)),
+                      pl.BlockSpec((None, padded, d2),
+                                   lambda b, h, j, t: (b, 0, 0))]
+        outs += extra
+        scratch += [pltpu.VMEM((group, padded, d2), _F32),
+                    pltpu.VMEM((padded, d2), _F32)]
+    # ``dk2`` adds up over the heads, so with a second part they run in turn
+    return _call(
+        functools.partial(_bwd_kernel, scale=scale, blk=blk, n=n),
+        "attn_bwd_kernel", (bsz, k.shape[-1] // dim, n, group * n), interpret,
+        semantics=("parallel", "parallel" if extra is None else "arbitrary",
+                   "arbitrary", "arbitrary"),
+        in_specs=in_specs, out_specs=out_specs,
+        out_shape=[_like(t) for t in outs],
+        scratch_shapes=scratch)(*operands, *(extra or ()))
+
+
+# -- where all the rows' sums do not fit: a kernel for each side ----------------
+# Each forms the score block, its ``exp``, ``dP`` and ``dS`` for itself
+# (seven products and two passes of vector work where the fused kernel has
+# five and one), and holds one block's sums whatever the length.
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                scale, blk):
     if len(refs) == 2:
@@ -261,11 +513,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         p = jnp.exp(s - jnp.expand_dims(lse_ref[0], -1))
         dp = lax.dot_general(do_ref[...], v, _NT,
                              preferred_element_type=_F32)
-        ds = p * (dp - jnp.expand_dims(delta_ref[0], -1))
-        acc_ref[...] += jnp.dot(ds.astype(k.dtype), k,
-                                preferred_element_type=_F32)
+        ds = (p * (dp - jnp.expand_dims(delta_ref[0], -1))).astype(k.dtype)
+        acc_ref[...] += jnp.dot(ds, k, preferred_element_type=_F32)
         if q2_ref is not None:
-            acc2_ref[...] += jnp.dot(ds.astype(k.dtype), k2_ref[...],
+            acc2_ref[...] += jnp.dot(ds, k2_ref[...],
                                      preferred_element_type=_F32)
 
     pl.when(j < i)(functools.partial(block, False))
@@ -279,179 +530,85 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                scale, blk, n, span=None):
-    # keys along the sublanes, queries along the lanes: a query's
-    # log-sum-exp and delta are rows as they lie. ``span``: with a second
-    # part the last grid axis runs over all heads, ``span`` steps to a
-    # key/value head
-    if span is None:
+                scale, blk, n, span):
+    # grid (batch, 1, key block j, t): a key block meets every query head
+    # in turn, ``span`` steps to a key/value head, each at the query
+    # blocks at or after it
+    if len(refs) == 4:
         (dk_ref, dv_ref, dk_acc, dv_acc), q2_ref, k2_ref = refs, None, None
     else:
         q2_ref, k2_ref, dk_ref, dv_ref, dk2_ref, dk_acc, dv_acc, dk2_acc = refs
     j, t = pl.program_id(2), pl.program_id(3)
     i = t % n
 
-    @pl.when(t == 0 if span is None else t % span == 0)
+    @pl.when(t % span == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if span is not None:
+    if q2_ref is not None:
         @pl.when(t == 0)
         def _():
             dk2_acc[...] = jnp.zeros_like(dk2_acc)
 
     def block(diagonal):
-        q, do = q_ref[...], do_ref[...]
-        st = _scores(k_ref[...], q, i, j, blk, scale, diagonal,
-                     transposed=True, **_second(k2_ref, q2_ref))
-        pt = jnp.exp(st - lse_ref[...])
-        dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
-                               preferred_element_type=_F32)
-        dpt = lax.dot_general(v_ref[...], do, _NT,
-                              preferred_element_type=_F32)
-        dst = pt * (dpt - delta_ref[...])
-        dk_acc[...] += jnp.dot(dst.astype(q.dtype), q,
-                               preferred_element_type=_F32)
-        if span is not None:
-            dk2_acc[...] += jnp.dot(dst.astype(q.dtype), q2_ref[...],
+        dst = _keys_side(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         q2_ref, k2_ref, dk_acc, dv_acc, i, j, blk, scale,
+                         diagonal)
+        if q2_ref is not None:
+            dk2_acc[...] += jnp.dot(dst, q2_ref[...],
                                     preferred_element_type=_F32)
 
     pl.when(i > j)(functools.partial(block, False))
     pl.when(i == j)(functools.partial(block, True))
 
-    @pl.when(t == pl.num_programs(3) - 1 if span is None
-             else t % span == span - 1)
+    @pl.when(t % span == span - 1)
     def _():
         dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
-    if span is not None:
+    if q2_ref is not None:
         @pl.when(t == pl.num_programs(3) - 1)
         def _():
             dk2_ref[...] = (dk2_acc[...] * scale).astype(dk2_ref.dtype)
 
 
-def backward(q, k, v, out, lse, dout, num_heads, num_kv_heads, scale,
-             interpret=False, extra=None):
-    """``(dq, dk, dv)`` from what ``forward`` took and gave and the
-    output's cotangent; with ``extra``, ``(dq, dk, dv, dq2, dk2)``."""
-    hq, hk = int(num_heads), int(num_kv_heads)
-    group = hq // hk
-    bsz, length, _ = q.shape
-    dim = q.shape[-1] // hq
-    blk, padded = block_size(length)
-    delta = jnp.sum((dout.astype(_F32) * out.astype(_F32)).reshape(
-        bsz, length, hq, dim), axis=-1).transpose(0, 2, 1)
-    q, k, v, dout = (_padded(t, 1, padded) for t in (q, k, v, dout))
-    lse, delta = (_padded(t, 2, padded)[:, :, None] for t in (lse, delta))
-    n = padded // blk
+def _backward_by_side(operands, extra, group, dim, blk, scale, interpret):
+    """``_backward_fused``'s results from the two kernels."""
+    q, k, v = operands[:3]
+    bsz, padded, _ = q.shape
+    n, hq = padded // blk, q.shape[-1] // dim
     kv_at = functools.partial(_kv_at, group)
     head, row = functools.partial(_head_spec, blk, dim), \
         functools.partial(_row_spec, blk)
-    if extra is not None:
-        dq, dk, dv, dq2, dk2 = _backward_extra(
-            (q, k, v, dout, lse, delta), extra, hq, group, dim, blk, n,
-            scale, interpret)
-        dq2 = dq2[:, :, :length].transpose(0, 2, 1, 3).reshape(
-            bsz, length, -1)
-        return dq[:, :length], dk[:, :length], dv[:, :length], dq2, \
-            dk2[:, :length]
+    extra = extra or ()
+    d2 = extra[1].shape[-1] if extra else 0
+    sums2 = [pltpu.VMEM((blk, d2), _F32)] if extra else []
+    second = _extra_specs(blk, d2, _q2_at, _k2_at) if extra else []
     dq = _call(
         functools.partial(_dq_kernel, scale=scale, blk=blk),
         "attn_bwd_dq_kernel", (bsz, hq, n, n), interpret,
         in_specs=[head(_q_at), head(kv_at), head(kv_at), head(_q_at),
-                  row(_row_at), row(_row_at)],
-        out_specs=head(_q_at),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk, dim), _F32)])(
-            q, k, v, dout, lse, delta)
-
-    # grid (batch, key head, key block j, t): a key block meets, for each
-    # query head of its group in turn, the query blocks at or after it; the
-    # steps before those fetch the diagonal's block and compute nothing
-    def k_at(b, h, j, t):
-        return b, j, h
-
-    def q_at(b, h, j, t):
-        return b, jnp.maximum(t % n, j), h * group + t // n
-
-    def row_at(b, h, j, t):
-        return b, h * group + t // n, 0, jnp.maximum(t % n, j)
-
-    dk, dv = _call(
-        functools.partial(_dkv_kernel, scale=scale, blk=blk, n=n),
-        "attn_bwd_dkv_kernel", (bsz, hk, n, group * n), interpret,
-        in_specs=[head(q_at), head(k_at), head(k_at), head(q_at),
-                  row(row_at), row(row_at)],
-        out_specs=[head(k_at), head(k_at)],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk, dim), _F32),
-                        pltpu.VMEM((blk, dim), _F32)])(
-            q, k, v, dout, lse, delta)
-    return dq[:, :length], dk[:, :length], dv[:, :length]
-
-
-def _backward_extra(operands, extra, hq, group, dim, blk, n, scale,
-                    interpret):
-    """``backward``'s two kernels with the scores' second part, over its
-    padded operands ``(q, k, v, dout, lse, delta)``: ``(dq, dk, dv, dq2
-    head-major, dk2)``, padded."""
-    q, k, v = operands[:3]
-    bsz, padded, _ = q.shape
-    q2, k2 = extra
-    d2 = k2.shape[-1]
-    q2, k2 = _head_major(q2, hq, padded), _padded(k2, 1, padded)
-    kv_at = functools.partial(_kv_at, group)
-    head, row = functools.partial(_head_spec, blk, dim), \
-        functools.partial(_row_spec, blk)
-    second = _extra_specs(blk, d2, _q2_at, _k2_at)
-    dq, dq2 = _call(
-        functools.partial(_dq_kernel, scale=scale, blk=blk),
-        "attn_bwd_dq_kernel", (bsz, hq, n, n), interpret,
-        in_specs=[head(_q_at), head(kv_at), head(kv_at), head(_q_at),
                   row(_row_at), row(_row_at)] + second,
-        out_specs=[head(_q_at), second[0]],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(q2.shape, q2.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk, dim), _F32),
-                        pltpu.VMEM((blk, d2), _F32)])(*operands, q2, k2)
-
-    # grid (batch, 1, key block j, t): a key block meets every query head
-    # in turn, key/value head by key/value head, each at the query blocks
-    # at or after it, so that ``dk2`` adds up over all of them
+        out_specs=[head(_q_at)] + second[:1],
+        out_shape=[_like(q)] + [_like(t) for t in extra[:1]],
+        scratch_shapes=[pltpu.VMEM((blk, dim), _F32)] + sums2)(
+            *operands, *extra)
     span = group * n
-
-    def k_at(b, _, j, t):
-        return b, j, t // span
-
-    def q_at(b, _, j, t):
-        return b, jnp.maximum(t % n, j), t // n
-
-    def row_at(b, _, j, t):
-        return b, t // n, 0, jnp.maximum(t % n, j)
-
-    def q2_at(b, _, j, t):
-        return b, t // n, jnp.maximum(t % n, j), 0
-
-    def k2_at(b, _, j, t):
-        return b, j, 0
-
-    second = _extra_specs(blk, d2, q2_at, k2_at)
-    dk, dv, dk2 = _call(
+    q_at, k_at, row_at, q2_at, k2_at = _keys_side_at(
+        n, lambda _, t: t // n, lambda _, t: t // span)
+    second = _extra_specs(blk, d2, q2_at, k2_at) if extra else []
+    dkv = _call(
         functools.partial(_dkv_kernel, scale=scale, blk=blk, n=n, span=span),
         "attn_bwd_dkv_kernel", (bsz, 1, n, hq * n), interpret,
         in_specs=[head(q_at), head(k_at), head(k_at), head(q_at),
                   row(row_at), row(row_at)] + second,
-        out_specs=[head(k_at), head(k_at), second[1]],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct(k2.shape, k2.dtype)],
+        out_specs=[head(k_at), head(k_at)] + second[1:],
+        out_shape=[_like(k), _like(v)] + [_like(t) for t in extra[1:]],
         scratch_shapes=[pltpu.VMEM((blk, dim), _F32),
-                        pltpu.VMEM((blk, dim), _F32),
-                        pltpu.VMEM((blk, d2), _F32)])(*operands, q2, k2)
-    return dq, dk, dv, dq2, dk2
+                        pltpu.VMEM((blk, dim), _F32)] + sums2)(
+            *operands, *extra)
+    return dq[:1] + dkv[:2] + dq[1:] + dkv[2:]
 
 
 # ---------------------------------------------------------------------------
@@ -460,24 +617,26 @@ def _backward_extra(operands, extra, hq, group, dim, blk, n, scale,
 # Which form a call site takes is decided when its program is lowered
 # (``lax.platform_dependent``), so that is where a site is counted: an
 # identity whose lowering rule, reached only inside the TPU branch, adds
-# one to the gauge ``attn::kernel_sites``. ``TrainStep`` sets the gauge to
-# zero where it traces its step.
+# one to a gauge: ``attn::kernel_sites`` for a site that takes the
+# kernels, ``attn::fused_bwd_sites`` for one whose backward is the fused
+# kernel. ``TrainStep`` sets both to zero where it traces its step.
 GAUGE = "attn::kernel_sites"
+FUSED_BWD_GAUGE = "attn::fused_bwd_sites"
 
 _site_p = Primitive("mx_attn_kernel_site")
-_site_p.def_impl(lambda x: x)
-_site_p.def_abstract_eval(lambda x: x)
+_site_p.def_impl(lambda x, gauge: x)
+_site_p.def_abstract_eval(lambda x, gauge: x)
 
 
-def _site_lowering(ctx, x):
+def _site_lowering(ctx, x, gauge):
     from .. import telemetry
-    telemetry.gauge(GAUGE).inc()
+    telemetry.gauge(gauge).inc()
     return [x]
 
 
 mlir.register_lowering(_site_p, _site_lowering)
 
 
-def counted_site(x):
-    """``x``; lowering it counts one call site of the kernel."""
-    return _site_p.bind(x)
+def counted_site(x, gauge=GAUGE):
+    """``x``; lowering it adds one to ``gauge``."""
+    return _site_p.bind(x, gauge=gauge)

@@ -258,6 +258,7 @@ class TrainStep:
             # of the step traced last, like the units' gauges: the lowering
             # that follows counts the attention sites it gives the kernel
             _telemetry.gauge(_attn_kernel.GAUGE).set(0)
+            _telemetry.gauge(_attn_kernel.FUSED_BWD_GAUGE).set(0)
             key = jax.random.fold_in(base_key, t)
             if preprocess is not None:
                 x = preprocess(x)

@@ -79,6 +79,11 @@ def _value_and_grad(fn, data, weight):
 CASES = [(length, hq, hk, theta, dtype)
          for length in (256, 200) for hq, hk in ((4, 4), (4, 1))
          for theta in (None, 1e4) for dtype in ("float32", "bfloat16")]
+# three and five blocks of 128: a query block's gradient adds up over the
+# key blocks before it, a key block's over the query blocks after it
+CASES += [(length, hq, hk, None, dtype)
+          for length in (384, 640) for hq, hk in ((4, 4), (4, 1))
+          for dtype in ("float32", "bfloat16")]
 
 
 @pytest.mark.parametrize("length,hq,hk,theta,dtype", CASES)
@@ -97,6 +102,47 @@ def test_kernels_agree_with_dense_and_blocked(kernels_here, length, hq, hk,
         for g, w in zip(got, want):
             scale = np.abs(w).max()
             assert np.abs(g - w).max() <= tol * scale, other.__name__
+
+
+def test_dq_is_the_float32_sum_over_key_blocks_rounded_once():
+    """Three key blocks of 128 in bfloat16: ``dQ`` is each key block's
+    ``dS K`` added up in float32 and rounded once at the end, not a sum
+    of rounded parts (which this test tells apart)."""
+    hq, hk, length, blk = 4, 1, 384, 128
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    data = _packed(length, hq, hk, "bfloat16", seed=7)
+    q, k, v = (data[..., :hq * D], data[..., hq * D:(hq + hk) * D],
+               data[..., (hq + hk) * D:])
+    dout = jax.random.normal(jax.random.PRNGKey(8), q.shape, f32).astype(bf16)
+    scale = D ** -0.5
+    out, lse = attn_kernel.forward(q, k, v, hq, hk, scale, interpret=True)
+    got = np.asarray(attn_kernel.backward(
+        q, k, v, out, lse, dout, hq, hk, scale, interpret=True)[0].astype(f32))
+
+    heads = lambda t, h: t.reshape(2, length, h, D)  # noqa: E731
+    qh, doh, oh = heads(q, hq), heads(dout, hq), heads(out, hq)
+    kh, vh = (jnp.repeat(heads(t, hk), hq // hk, axis=2) for t in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", qh, kh,
+                   preferred_element_type=f32) * scale
+    s = jnp.where(jnp.tril(jnp.ones((length, length), bool)), s, -1e30)
+    p = jnp.exp(s - lse[..., None])
+    dp = jnp.einsum("bqhd,bkhd->bhqk", doh, vh, preferred_element_type=f32)
+    delta = jnp.sum(doh.astype(f32) * oh.astype(f32), -1).transpose(0, 2, 1)
+    ds = (p * (dp - delta[..., None])).astype(bf16)
+    parts = [jnp.einsum("bhqk,bkhd->bqhd", ds[..., j:j + blk],
+                        kh[:, j:j + blk], preferred_element_type=f32)
+             for j in range(0, length, blk)]
+
+    def rows(t):
+        return np.asarray(t.astype(f32)).reshape(2, length, hq * D)
+
+    once = rows((sum(parts) * scale).astype(bf16))
+    each = rows(sum((part * scale).astype(bf16) for part in parts))
+    # the same float32 sums but for the order inside a product: a value
+    # in ten thousand lands on the other side of a rounding
+    assert (got != once).mean() < 1e-3
+    assert np.abs(got - once).max() <= 2.0 ** -9 * np.abs(once).max()
+    assert (each != once).mean() > 0.05     # rounded parts would show
 
 
 @pytest.mark.parametrize("hq,hk", [(4, 4), (4, 1)])
@@ -162,7 +208,8 @@ def test_a_unit_s_backward_runs_no_second_forward_kernel(hq, hk, theta):
     plain = _kernel_calls(jax.make_jaxpr(jax.grad(unit))(data).jaxpr)
     kept = _kernel_calls(jax.make_jaxpr(jax.grad(
         jax.checkpoint(unit, policy=remat.POLICY)))(data).jaxpr)
-    want = ["attn_fwd_kernel", "attn_bwd_dq_kernel", "attn_bwd_dkv_kernel"]
+    # one backward kernel a site: it forms each score block once
+    want = ["attn_fwd_kernel", "attn_bwd_kernel"]
     assert plain == want
     assert kept == want, kept
     # with nothing kept, the unit computes its forward again: that is
@@ -192,10 +239,15 @@ def test_a_unit_keeps_the_log_sum_exp_beside_what_it_kept(hq, hk):
 
 # -- where the kernels are taken -------------------------------------------------
 def _lowered_for(platform, fn, *args):
-    mx.telemetry.gauge(attn_kernel.GAUGE).set(0)
+    """The text lowered for ``platform`` and what the lowering counted:
+    ``(attn::kernel_sites, attn::fused_bwd_sites)``."""
+    gauges = [mx.telemetry.gauge(g) for g in (attn_kernel.GAUGE,
+                                              attn_kernel.FUSED_BWD_GAUGE)]
+    for gauge in gauges:
+        gauge.set(0)
     text = jax.jit(fn).trace(*args).lower(
         lowering_platforms=(platform,)).as_text()
-    return text, mx.telemetry.gauge(attn_kernel.GAUGE).get()
+    return text, tuple(gauge.get() for gauge in gauges)
 
 
 @pytest.mark.parametrize("dim,platform,sites", [
@@ -209,12 +261,67 @@ def test_kernel_sites_follow_the_platform_lowered_for_and_the_head(
         return jnp.sum(_op(d, 2, 1, 1e4, dim=dim).astype(jnp.float32))
 
     text, counted = _lowered_for(platform, jax.grad(loss), data)
-    assert counted == sites
+    assert counted == (sites, sites)
     calls = re.findall(r"tpu_custom_call", text)
-    assert len(calls) == 3 * sites
+    assert len(calls) == 2 * sites
     # a (heads, block, block) float32 score value is in the text where
     # the plain form is, and nowhere where the kernels are
     assert ("tensor<2x2x256x256xf32>" in text) == (not sites)
+
+
+PAST_THE_RULE = [(384, 4, 2, 64, "float32"), (384, 4, 1, 0, "float32"),
+                 (200, 4, 4, 64, "bfloat16"), (640, 4, 2, 0, "bfloat16")]
+
+
+@pytest.mark.parametrize("length,hq,hk,d2,dtype", PAST_THE_RULE)
+def test_past_the_rule_two_kernels_give_the_fused_kernel_s_gradients(
+        monkeypatch, length, hq, hk, d2, dtype):
+    """Where a group's float32 ``dQ`` over all the rows would not fit in
+    VMEM the backward is a kernel for each side; the rule is arithmetic
+    on the shape (``resident_bytes``), here met by lowering the limit.
+    The same sums in the same order: ``dK`` and ``dV`` to the bit, and
+    in float32 ``dQ`` too; the rest to a rounding, since the fused kernel
+    forms ``dQ``'s score block turned and takes the second part's narrow
+    products with their narrow sides turned (another order inside a
+    product)."""
+    keys = jax.random.split(jax.random.PRNGKey(9), 6)
+    widths = (hq * D, hk * D, hk * D, hq * D) + ((hq * d2, d2) if d2 else ())
+    q, k, v, dout, *extra = (
+        jax.random.normal(key, (2, length, w), jnp.float32).astype(dtype)
+        for key, w in zip(keys, widths))
+    extra = tuple(extra) or None
+    out, lse = attn_kernel.forward(q, k, v, hq, hk, 0.08, interpret=True,
+                                   extra=extra)
+
+    def grads():
+        return [np.asarray(g.astype(jnp.float32)) for g in
+                attn_kernel.backward(q, k, v, out, lse, dout, hq, hk, 0.08,
+                                     interpret=True, extra=extra)]
+
+    assert attn_kernel.resident_bytes(length, hq // hk, D, d2, 4) \
+        <= attn_kernel._RESIDENT_LIMIT_BYTES
+    fused = grads()
+    monkeypatch.setattr(attn_kernel, "_RESIDENT_LIMIT_BYTES", 0)
+    for name, a, b in zip(("dq", "dk", "dv", "dq2", "dk2"), fused, grads()):
+        if name in ("dk", "dv") or (dtype, name) == ("float32", "dq"):
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        else:       # the turned score block; the narrow products turned
+            assert (a != b).mean() < (1e-3 if dtype == "bfloat16" else 1), \
+                name
+            assert np.abs(a - b).max() <= (2.0 ** -8 if dtype == "bfloat16"
+                                           else 1e-6) * np.abs(b).max(), name
+
+
+def test_past_the_rule_the_gauge_counts_no_fused_backward(monkeypatch):
+    data = _packed(256, 2, 1, "bfloat16")
+
+    def loss(d):
+        return jnp.sum(_op(d, 2, 1, 1e4).astype(jnp.float32))
+
+    monkeypatch.setattr(attn_kernel, "_RESIDENT_LIMIT_BYTES", 0)
+    text, counted = _lowered_for("tpu", jax.grad(loss), data)
+    assert counted == (1, 0)
+    assert len(re.findall(r"tpu_custom_call", text)) == 3
 
 
 def test_train_step_publishes_the_sites_of_the_step_it_traced():
@@ -229,18 +336,20 @@ def test_train_step_publishes_the_sites_of_the_step_it_traced():
                      compute_dtype="bfloat16", remat="layer")
     x = jnp.zeros((1, 256), jnp.int32)
     y = jnp.zeros((256,), jnp.int32)
-    mx.telemetry.gauge(attn_kernel.GAUGE).set(7)
+    gauges = [mx.telemetry.gauge(g) for g in (attn_kernel.GAUGE,
+                                              attn_kernel.FUSED_BWD_GAUGE)]
+    for gauge in gauges:
+        gauge.set(7)
     step(x, y)                                  # traced and lowered here
-    assert mx.telemetry.gauge(attn_kernel.GAUGE).get() == 0
+    assert [gauge.get() for gauge in gauges] == [0, 0]
     args = (step._pvals, step._opt_state, x, y, step._t_dev,
             jnp.asarray(0.1, jnp.float32))
-    mx.telemetry.gauge(attn_kernel.GAUGE).set(0)
     text = step._step_jit.trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
     # two attention layers in the one scanned body: call sites of one
-    # shape share one lowered program, which is what the gauge counts
-    assert mx.telemetry.gauge(attn_kernel.GAUGE).get() == 1
-    assert len(re.findall(r"tpu_custom_call", text)) == 3
+    # shape share one lowered program, which is what the gauges count
+    assert [gauge.get() for gauge in gauges] == [1, 1]
+    assert len(re.findall(r"tpu_custom_call", text)) == 2
     kept = mx.telemetry.snapshot(prefix="remat::saved_bytes::")
     per_layer = {k: v["value"] for k, v in kept.items() if "_l0_" in k}
     # rows (2 + 2) heads wide, the output 2 heads wide, the log-sum-exp,

@@ -5,8 +5,8 @@ second part (a second query part a head, one key part shared by all
 heads), interpreted here on the CPU, against the blocked recurrence over
 the concatenated heads, forward and the three-plus-two gradients; where
 the op takes the kernels; what a recomputation unit keeps; and the
-kernels' lowered text at heads of 128 with no second part, which is the
-parent's to the instruction. Nothing here is a time."""
+kernels' lowered text at heads of 128 at the three cells' shapes, pinned.
+Nothing here is a time."""
 import base64
 import functools
 import hashlib
@@ -238,7 +238,7 @@ def test_kernel_sites_follow_the_platform_and_the_head(nope, platform, sites):
 
     text, counted = _lowered_for(platform, jax.grad(loss), w, x)
     assert counted == sites
-    assert len(re.findall(r"tpu_custom_call", text)) == 3 * sites
+    assert len(re.findall(r"tpu_custom_call", text)) == 2 * sites
     # a (heads, block, block) float32 score value is in the text where the
     # plain form is, and nowhere where the kernels are
     assert ("tensor<1x2x256x256xf32>" in text) == (not sites)
@@ -280,36 +280,57 @@ def _without_locations(text):
     return _BODY.sub("BODY", text) + "\n".join(bodies)
 
 
-#: sha256 of ``jax.grad`` of ``causal_gq_attention`` at heads of 128,
-#: lowered for a TPU (three Mosaic calls), locations stripped, computed
-#: with the function below on the parent of the PR that gave the kernels
-#: their second part (PR 37; commit 19fb4be): Ouro's heads (16 of 16 at
-#: 4096, rotary), Nemotron's (4 on 1 at 8192) and a padded length
+#: sha256 of ``jax.grad`` of the attention at heads of 128, lowered for a
+#: TPU (two Mosaic calls: the forward kernel and the fused backward),
+#: locations stripped, computed with the function below. PR 38 changed
+#: the text (the backward's two kernels became one; the forward kernel's
+#: body is what PR 37's parent pinned) and re-anchored it here: Ouro's
+#: heads (16 of 16 at 4096, rotary), Nemotron's (4 on 1 at 8192), a padded
+#: length, and Moonlight's (16 heads at 8192 with a second part 64 wide),
+#: so that each cell's own kernels are guarded outside the harness's tests
 KERNEL_TEXT_SHA256 = {
-    (4096, 16, 16, 1e6):
-        "6fa2c5d0bc38f647b0ee09b313ae0a741b764387182f1ebe1d67a93dc585856e",
-    (8192, 4, 1, None):
-        "03bb5d1dfcf19199cd2273e6e24a3f719ffb200c20b86e9bc98cad157c72adf5",
-    (200, 4, 2, None):
-        "87ffcde320babed57a2d1a2f594b59080a65f13637ef0bd46a74923468189c27",
+    (4096, 16, 16, 1e6, 0):
+        "bf98d2b5c543dbc0b907b06e9d4fde3ac92e5d4517e93365ffb05f2c19c90a59",
+    (8192, 4, 1, None, 0):
+        "73868229bf765dbc8f013038c2cb9e7a6d65c7dc8726c71c8fccc9e7225387dd",
+    (200, 4, 2, None, 0):
+        "585fb073a6e4335cf8c90299e4741f052012caf06475f6e56cfb20176af76e4c",
+    (8192, 16, 16, None, 64):
+        "942b13d4667adb433b25b08cbe4a3a8f834b6ba0d031d48218e09df680b64e2f",
 }
 
 
-@pytest.mark.parametrize("length,hq,hk,theta", list(KERNEL_TEXT_SHA256))
+def _lowered_attention(length, hq, hk, theta, d2):
+    """``jax.grad`` of the attention over bfloat16 rows of one sequence,
+    lowered for a TPU: ``causal_gq_attention``, or with a second part
+    ``_fused_attention`` over ``(q2, k2)`` ``d2`` wide."""
+    def rows(width):
+        return jax.ShapeDtypeStruct((1, length, width), jnp.bfloat16)
+
+    if not d2:
+        def loss(d):
+            return jnp.sum(seq.causal_gq_attention(
+                d, num_heads=hq, num_kv_heads=hk, head_dim=D,
+                rope_theta=theta).astype(jnp.float32))
+        args = (rows((hq + 2 * hk) * D),)
+    else:
+        def loss(q, k, v, q2, k2):
+            return jnp.sum(seq._fused_attention(
+                q, k, v, (q2, k2), hq, hk, (D + d2) ** -0.5,
+                1024).astype(jnp.float32))
+        args = (rows(hq * D), rows(hk * D), rows(hk * D), rows(hq * d2),
+                rows(d2))
+    return jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))).trace(
+        *args).lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("length,hq,hk,theta,d2", list(KERNEL_TEXT_SHA256))
 def test_kernels_at_heads_of_128_are_the_program_they_were(length, hq, hk,
-                                                           theta):
-    """The rehearsal sizes of both ``PatternLM`` cells have heads of 16
+                                                           theta, d2):
+    """The rehearsal sizes of the ``PatternLM`` cells have heads of 16
     and never reach the kernels, so their pinned steps do not guard them:
     this does, at the cells' own shapes."""
-    data = jax.ShapeDtypeStruct((1, length, (hq + 2 * hk) * D), jnp.bfloat16)
-
-    def loss(d):
-        return jnp.sum(seq.causal_gq_attention(
-            d, num_heads=hq, num_kv_heads=hk, head_dim=D,
-            rope_theta=theta).astype(jnp.float32))
-
-    text = jax.jit(jax.grad(loss)).trace(data).lower(
-        lowering_platforms=("tpu",)).as_text()
-    assert len(_BODY.findall(text)) == 3
+    text = _lowered_attention(length, hq, hk, theta, d2)
+    assert len(_BODY.findall(text)) == 2
     assert hashlib.sha256(_without_locations(text).encode()).hexdigest() \
-        == KERNEL_TEXT_SHA256[length, hq, hk, theta]
+        == KERNEL_TEXT_SHA256[length, hq, hk, theta, d2]

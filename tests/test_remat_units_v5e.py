@@ -209,19 +209,21 @@ def test_attention_is_the_kernel_forward_and_backward(one_chip, no_jax_cache,
     """Compiled for the described v5e from this CPU host, the step holds
     the attention as Mosaic calls under ``mx_attn_fwd``: one forward
     kernel a layer (the unit keeps its output and log-sum-exp, so the
-    backward loop holds none), one ``dQ`` and one ``dK, dV`` kernel a
-    layer, and no float32 (heads, block, block) score value anywhere."""
+    backward loop holds none), one fused backward kernel a layer, which
+    the gauge ``attn::fused_bwd_sites`` counts, and no float32 (heads,
+    block, block) score value anywhere."""
     import mxnet_tpu as mx
     from mxnet_tpu.ops import attn_kernel
     from mxnet_tpu.telemetry.trace import hlo_scopes
     text = _compiled_step(name, one_chip)[2].as_text()
     calls = {k: _kernel_calls(text, k) for k in
-             ("attn_fwd_kernel", "attn_bwd_dq_kernel", "attn_bwd_dkv_kernel")}
+             ("attn_fwd_kernel", "attn_bwd_kernel")}
     counts = {k: len(v) for k, v in calls.items()}
     print(f"{name}: {counts}, attn::kernel_sites "
           f"{mx.telemetry.gauge(attn_kernel.GAUGE).get()}")
     # like layers share one lowered program: the gauge counts programs
     assert mx.telemetry.gauge(attn_kernel.GAUGE).get() == 1
+    assert mx.telemetry.gauge(attn_kernel.FUSED_BWD_GAUGE).get() == 1
     assert counts["attn_fwd_kernel"] >= 1
     assert len(set(counts.values())) == 1, counts   # no second forward
     scopes = hlo_scopes(text, path=True)
@@ -254,4 +256,48 @@ def test_mosaic_takes_the_kernels_at_other_shapes(one_chip, no_jax_cache,
     data = jax.ShapeDtypeStruct((2, length, (hq + 2 * hk) * dim), dtype,
                                 sharding=one_chip)
     text = jax.jit(jax.grad(loss)).lower(data).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+@pytest.mark.parametrize("length,hq,hk,d2,fused", [
+    (8192, 16, 16, 64, True),    # Moonlight's: a head's dQ and dq2, and dk2
+    (8192, 4, 1, 0, True),       # Nemotron's: a group of four heads' dQ
+    (8192, 8, 1, 0, True),       # 64 MiB for all the rows: the rule's edge
+    (16384, 8, 1, 0, False),     # twice that: a kernel for each side
+    (16384, 2, 2, 64, True),     # a second part on the fused side ...
+    (65536, 2, 2, 64, False)])   # ... and past the rule
+def test_the_backward_fits_vmem_on_both_sides_of_the_rule(
+        one_chip, no_jax_cache, length, hq, hk, d2, fused):
+    """The fused backward holds a group's float32 ``dQ`` over all the rows
+    in VMEM: at the cells' shapes and up to the rule's edge Mosaic takes
+    it under the kernels' VMEM limit; past the edge the backward is the
+    two kernels that hold one block's sums, and the gauge says which."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops import attn_kernel
+    bf16 = jnp.bfloat16
+
+    def rows(width, dtype=bf16):
+        return jax.ShapeDtypeStruct((1, length, width), dtype,
+                                    sharding=one_chip)
+
+    args = [rows(hq * 128), rows(hk * 128), rows(hk * 128), rows(hq * 128),
+            jax.ShapeDtypeStruct((1, hq, length), jnp.float32,
+                                 sharding=one_chip), rows(hq * 128)]
+    extra = [rows(hq * d2), rows(d2)] if d2 else []
+    assert (attn_kernel.resident_bytes(length, hq // hk, 128, d2, 2)
+            <= attn_kernel._RESIDENT_LIMIT_BYTES) == fused
+
+    def backward(q, k, v, out, lse, dout, *extra):
+        return attn_kernel.backward(q, k, v, out, lse, dout, hq, hk, 0.08,
+                                    extra=extra or None)
+
+    mx.telemetry.gauge(attn_kernel.FUSED_BWD_GAUGE).set(0)
+    text = jax.jit(backward).lower(*args, *extra).compile().as_text()
+    assert mx.telemetry.gauge(attn_kernel.FUSED_BWD_GAUGE).get() == fused
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert [k for k in ("attn_bwd_kernel", "attn_bwd_dq_kernel",
+                        "attn_bwd_dkv_kernel")
+            for line in calls if f"/{k}/" in line] \
+        == (["attn_bwd_kernel"] if fused
+            else ["attn_bwd_dq_kernel", "attn_bwd_dkv_kernel"])
