@@ -90,12 +90,15 @@ def total_implicit_loss(loss_specs, head_inputs, outs, head_grads):
     """Scalar training loss: each implicit head's loss over its INPUT
     values plus sum(out * head_grad) for explicit heads — the quantity
     whose gradient is the reference backward."""
+    import jax
     import jax.numpy as jnp
     total = jnp.zeros((), jnp.float32)
     implicit = {i for i, _, _ in loss_specs}
     for (i, node, attrs), ins in zip(loss_specs, head_inputs):
-        total = total + _IMPLICIT_LOSS[node.op](
-            *ins, **attrs).astype(jnp.float32)
+        # the heads' gradient (p - y and its kin) carries this scope
+        with jax.named_scope("mx_loss"):
+            total = total + _IMPLICIT_LOSS[node.op](
+                *ins, **attrs).astype(jnp.float32)
     for i, o in enumerate(outs):
         if i not in implicit and head_grads is not None and \
                 head_grads[i] is not None:

@@ -21,6 +21,8 @@ partial sums go on to the next layer as they are.
 """
 from __future__ import annotations
 
+import jax
+
 from ...ndarray.ndarray import _wrap
 from ..block import HybridBlock
 from .. import nn
@@ -102,7 +104,9 @@ class PatternLM(HybridBlock):
     def hybrid_forward(self, F, x):
         h = self.embed(x)
         if self.gate is None:
-            return self.head(self.stack.last(h)).reshape((-1, self._vocab))
+            h = self.stack.last(h)
+            with jax.named_scope("mx_head"):    # forward and backward
+                return self.head(h).reshape((-1, self._vocab))
         hidden = self.stack(h).reshape((0, -1, self._units))
         # the weight's array of this trace, not the Parameter's own holder
         return hidden, self.gate(hidden), _wrap(self.head.weight.data()._data)

@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
+
 from ... import autograd
 from ..block import Block, HybridBlock, stateful_write
 from .activations import Activation
@@ -92,7 +94,9 @@ class HybridSequential(HybridBlock):
 class Dense(HybridBlock):
     """Fully-connected layer: ``act(dot(x, W^T) + b)``
     (reference: basic_layers.py:128). Weight layout (units, in_units) matches
-    the reference so checkpoints interchange."""
+    the reference so checkpoints interchange. In a compiled program the
+    layer's product, forward and backward, carries the scope ``mx_dense``
+    (``telemetry.trace.scope_table``)."""
 
     def __init__(self, units, activation=None, use_bias=True, flatten=True,
                  dtype="float32", weight_initializer=None,
@@ -123,8 +127,10 @@ class Dense(HybridBlock):
             self.bias._infer_shape((self._units,))
 
     def hybrid_forward(self, F, x, weight, bias=None):
-        out = F.FullyConnected(x, weight, bias, no_bias=bias is None,
-                               num_hidden=self._units, flatten=self._flatten)
+        with jax.named_scope("mx_dense"):
+            out = F.FullyConnected(x, weight, bias, no_bias=bias is None,
+                                   num_hidden=self._units,
+                                   flatten=self._flatten)
         if self.act is not None:
             out = self.act(out)
         return out
@@ -303,7 +309,9 @@ class Embedding(HybridBlock):
         pass
 
     def hybrid_forward(self, F, x, weight):
-        return F.Embedding(x, weight, **self._kwargs)
+        # the lookup, and backward the scatter of its gradient
+        with jax.named_scope("mx_embed"):
+            return F.Embedding(x, weight, **self._kwargs)
 
     def __repr__(self):
         return (f"{self.__class__.__name__}({self._input_dim} -> "

@@ -9,6 +9,8 @@ a share returns that share's partial sum (``ops/seq.py``).
 """
 from __future__ import annotations
 
+import jax
+
 from ... import autograd
 from ...ndarray.ndarray import _wrap
 from ..block import HybridBlock, _TraceState, stateful_write
@@ -270,9 +272,14 @@ class GQAttention(HybridBlock):
                 "o_weight", shape=(in_units, num_heads * head_dim))
 
     def hybrid_forward(self, F, x, qkv_weight, o_weight):
-        qkv = F.FullyConnected(x, qkv_weight, no_bias=True, flatten=False)
+        # the two products beside the attention's own scope
+        with jax.named_scope("mx_attn_proj"):
+            qkv = F.FullyConnected(x, qkv_weight, no_bias=True,
+                                   flatten=False)
         out = F.CausalGQAttention(qkv, **self._attrs)
-        return F.FullyConnected(out, o_weight, no_bias=True, flatten=False)
+        with jax.named_scope("mx_attn_proj"):
+            return F.FullyConnected(out, o_weight, no_bias=True,
+                                    flatten=False)
 
 
 class GatedMLP(HybridBlock):
@@ -326,7 +333,6 @@ class HybridLoop(HybridBlock):
 
     def _scan(self, x):
         """``(last, every)`` of ``loops`` scanned trips from ``x``."""
-        import jax
         outer = _TraceState.active()
         units = getattr(_TraceState._current, "remat_units", None)
 

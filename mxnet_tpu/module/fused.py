@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from ..base import MXNetError
 from ..executor import build_graph_fns
 from ..parallel import functional_opt
+from ..telemetry import trace as _trace
 
 __all__ = ["FusedSymbolStep"]
 
@@ -129,6 +130,7 @@ class FusedSymbolStep:
         self._metric_detach_epoch = 0   # bumped by detach_metrics
         self._t_dev = None
         self._step_jit = None
+        self._program = None    # telemetry.trace's record of the step
         self._programs = {}     # feed signature -> compiled executable
         self._program_costs = {}  # feed signature -> XLA cost dict
         self._program_exes = {}   # feed signature -> raw executable
@@ -438,8 +440,10 @@ class FusedSymbolStep:
         cdt = self.compute_dtype
 
         def _cast(v):
-            return v.astype(cdt) if cdt is not None and \
-                v.dtype == jnp.float32 else v
+            if cdt is None or v.dtype != jnp.float32:
+                return v
+            with jax.named_scope("mx_cast"):
+                return v.astype(cdt)
 
         metric_rules = self._metric_rules or []
         out_names = self.symbol.list_outputs()
@@ -587,38 +591,41 @@ class FusedSymbolStep:
                 for i, (p, g, s, tr) in enumerate(
                         zip(pvals, grads_big, opt_state, trainable)):
                     if tr:
-                        if isinstance(g, RowSparseRows):
-                            # lazy rows-only update: momentum/moments
-                            # and weight decay advance on touch only
-                            np_, ns_ = fopt.row_update(
-                                p, g.ids, g.rows, s, lr * lr_mults[i],
-                                t + 1, wd_eff[i])
-                        elif zero_big[i]:
-                            np_, ns_ = _zero_update(
-                                p, g, s, opt_specs[i], lr, t,
-                                lr_mults[i], wd_eff[i])
-                        else:
-                            pkey = jax.random.fold_in(
-                                jax.random.fold_in(key, 0x6F707469), i) \
-                                if fopt.needs_key else None
-                            np_, ns_ = fopt.update(
-                                p, g, s, lr * lr_mults[i],
-                                t + 1, wd_eff[i], key=pkey)
-                        new_p.append(np_.astype(p.dtype))
+                        with jax.named_scope("mx_opt_update"):
+                            if isinstance(g, RowSparseRows):
+                                # lazy rows-only update: momentum/moments
+                                # and weight decay advance on touch only
+                                np_, ns_ = fopt.row_update(
+                                    p, g.ids, g.rows, s, lr * lr_mults[i],
+                                    t + 1, wd_eff[i])
+                            elif zero_big[i]:
+                                np_, ns_ = _zero_update(
+                                    p, g, s, opt_specs[i], lr, t,
+                                    lr_mults[i], wd_eff[i])
+                            else:
+                                pkey = jax.random.fold_in(
+                                    jax.random.fold_in(key, 0x6F707469),
+                                    i) if fopt.needs_key else None
+                                np_, ns_ = fopt.update(
+                                    p, g, s, lr * lr_mults[i],
+                                    t + 1, wd_eff[i], key=pkey)
+                            new_p.append(np_.astype(p.dtype))
                         new_s.append(ns_)
                     else:
                         new_p.append(p)
                         new_s.append(s)
                 if has_flat:
-                    if zero_flat:
-                        nf, nfs = _zero_update(
-                            flat_p, grad_flat, flat_state, flat_specs,
-                            lr, t, flat_lrm, flat_wd)
-                    else:
-                        nf, nfs = fopt.update(
-                            flat_p, grad_flat, flat_state,
-                            lr * flat_lrm, t + 1, flat_wd)
-                    new_flat, new_flat_s = nf.astype(jnp.float32), nfs
+                    with jax.named_scope("mx_opt_update"):
+                        if zero_flat:
+                            nf, nfs = _zero_update(
+                                flat_p, grad_flat, flat_state, flat_specs,
+                                lr, t, flat_lrm, flat_wd)
+                        else:
+                            nf, nfs = fopt.update(
+                                flat_p, grad_flat, flat_state,
+                                lr * flat_lrm, t + 1, flat_wd)
+                        new_flat = nf.astype(jnp.float32)
+                    new_flat_s = nfs
                 else:
                     new_flat, new_flat_s = flat_p, flat_state
                 new_aux_big = tuple(
@@ -644,11 +651,12 @@ class FusedSymbolStep:
                     pred_map = dict(zip(out_names, outs))
                     label_map = {n: feed_vals[input_pos[n]]
                                  for n in self.input_names}
-                    new_m = tuple(
-                        fn(s, [label_map[n] for n in lnames],
-                           [pred_map[n] for n in pnames])
-                        for (init, lnames, pnames, fn), s
-                        in zip(metric_rules, mstate))
+                    with jax.named_scope("mx_metric"):
+                        new_m = tuple(
+                            fn(s, [label_map[n] for n in lnames],
+                               [pred_map[n] for n in pnames])
+                            for (init, lnames, pnames, fn), s
+                            in zip(metric_rules, mstate))
                 else:
                     new_m = mstate
                 return (tuple(new_p), tuple(new_s), new_flat, new_flat_s,
@@ -1001,12 +1009,41 @@ class FusedSymbolStep:
         exe, source = compile_mod.load_or_compile(key, _lower)
         compile_mod.note_entry_point(key.name, key, sig)
         self._note_cost(sig, exe)
+        self._note_program(source, exe, _trace.shapes_of(args))
         if source != "cache":
             return exe
         jit_fn = self._step_jit
         return compile_mod.guarded_loaded_program(
             exe, jit_fn, "fused step",
             on_reject=lambda: self._programs.__setitem__(sig, jit_fn))
+
+    def _note_program(self, source, exe, shapes):
+        """The one reference :meth:`scope_table` is built from when
+        somebody asks: the executable, and the traced program. After a
+        fresh compile the jit finds the trace it just took; an
+        executable loaded from an AOT entry was never traced in this
+        process, so that trace is taken only when the table is asked
+        for, and only while this step is alive."""
+        from ..ops.pallas_fused import mesh_scope
+
+        def trace_of(step):
+            with mesh_scope(step.mesh, step.data_axis):
+                return step._step_jit.trace(*shapes)
+
+        if source == "cache":
+            ref = weakref.ref(self)
+            traced = lambda: None if ref() is None else trace_of(ref())  # noqa: E731
+        else:
+            traced = trace_of(self)
+        self._program = _trace.note_program("jit_mx_fused_step", traced,
+                                            exe)
+
+    def scope_table(self):
+        """``{HLO instruction name: scope path}`` of the step program
+        acquired last (``telemetry.trace.scope_table``), or None before
+        the first step. Built when first asked for; a step costs nothing
+        for it."""
+        return None if self._program is None else self._program.table()
 
     def _note_cost(self, sig, exe):
         """Record XLA cost analysis of an already-compiled step program

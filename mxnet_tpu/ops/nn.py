@@ -596,7 +596,7 @@ def _run_layer(xs, mode, wx, wh, bx, bh, h0, c0=None, reverse=False):
     return _recurrence(_RNN_CELLS[mode], reverse, gx, wh, bh, init)
 
 
-@register_op("RNN", num_outputs=-1)
+@register_op("RNN", num_outputs=-1, names_its_parts=True)
 def rnn(data, parameters, state, state_cell=None, state_size=None,
         num_layers=1, mode="lstm", bidirectional=False, p=0.0,
         state_outputs=False, lstm_state_clip_min=None, lstm_state_clip_max=None,

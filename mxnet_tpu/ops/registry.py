@@ -23,16 +23,19 @@ _OPS: Dict[str, "OpDef"] = {}
 
 
 class OpDef:
-    __slots__ = ("name", "fn", "aliases", "no_grad", "num_outputs", "attr_types")
+    __slots__ = ("name", "fn", "aliases", "no_grad", "num_outputs",
+                 "attr_types", "names_its_parts")
 
     def __init__(self, name: str, fn: Callable, aliases=(), no_grad=False,
-                 num_outputs: int = 1, attr_types: Optional[dict] = None):
+                 num_outputs: int = 1, attr_types: Optional[dict] = None,
+                 names_its_parts: bool = False):
         self.name = name
         self.fn = fn
         self.aliases = tuple(aliases)
         self.no_grad = no_grad
         self.num_outputs = num_outputs
         self.attr_types = attr_types or {}
+        self.names_its_parts = names_its_parts
 
     def __call__(self, *args, **kwargs):
         return self.fn(*args, **kwargs)
@@ -42,11 +45,18 @@ class OpDef:
 
 
 def register_op(name: str, aliases: Sequence[str] = (), no_grad: bool = False,
-                num_outputs: int = 1):
-    """Register an operator implementation under its MXNet name(s)."""
+                num_outputs: int = 1, names_its_parts: bool = False):
+    """Register an operator implementation under its MXNet name(s).
+
+    ``names_its_parts``: the operator's body opens ``jax.named_scope``s
+    for its own parts (``mx_rnn_scan``, ``mx_attn_fwd``). A Symbol
+    graph's walk (``Symbol._apply_node_op``) gives such an operator no
+    ``mx_op_<name>`` scope, which would enclose them: the readers of a
+    device trace anchor their patterns at the start of a scope's path."""
 
     def _reg(fn):
-        opdef = OpDef(name, fn, aliases, no_grad, num_outputs)
+        opdef = OpDef(name, fn, aliases, no_grad, num_outputs,
+                      names_its_parts=names_its_parts)
         _OPS[name] = opdef
         for a in aliases:
             _OPS[a] = opdef

@@ -15,10 +15,12 @@ rest; ``tests/test_seq_ops.py`` adds the shares up to the uncut layer.
 The step's device time is a function of shapes alone: the expert layer's
 receive buffer is static and every row of it is computed, filled or not.
 
-Named scopes (``mx_ssd_*``, ``mx_moe_*``, ``mx_attn_*``, ``mx_mla_*``,
-``mx_rope``, ``mx_gated_mlp``, ``mx_exit_head``, ``mx_exit_gate``) mark each
-mechanism in the compiled program; ``telemetry.trace.hlo_scopes`` maps
-the program's instructions back to them. The expert layer's matrix
+Named scopes (``mx_norm``, ``mx_mamba_proj``, ``mx_ssd_*``, ``mx_moe_*``,
+``mx_attn_*``, ``mx_mla_*``, ``mx_rope``, ``mx_gated_mlp``,
+``mx_exit_head``, ``mx_exit_gate``) mark each mechanism in the compiled
+program, and each operator's registration lists its own;
+``telemetry.trace.scope_table`` maps the program's instructions back to
+them. The expert layer's matrix
 products have scopes of their own (``mx_moe_score``: the router's;
 ``mx_moe_latent``: both latent projections; ``mx_moe_gmm_*``;
 ``mx_moe_shared``), so that ``mx_moe_route``, ``mx_moe_dispatch`` and
@@ -69,12 +71,20 @@ def _relu2(x):
 # ---------------------------------------------------------------------------
 # RMSNorm
 # ---------------------------------------------------------------------------
-@register_op("RMSNorm")
+@register_op("RMSNorm", names_its_parts=True)
 def rms_norm(data, gamma, eps=1e-5, num_groups=1, keep_input=False, **kw):
     """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, or over
     each of ``num_groups`` equal slices of it; computed in float32.
     ``keep_input``: the unit around this norm holds its input (a norm
-    *after* a sublayer reads that sublayer's last product)."""
+    *after* a sublayer reads that sublayer's last product). Scope
+    ``mx_norm``; the two norms that are a part of another mechanism (the
+    Mamba-2 gate's, the key/value latent's) are ``_rms_norm`` inside
+    that mechanism's scope."""
+    with jax.named_scope("mx_norm"):
+        return _rms_norm(data, gamma, eps, num_groups, keep_input)
+
+
+def _rms_norm(data, gamma, eps=1e-5, num_groups=1, keep_input=False):
     if keep_input:
         data = kept(data)
     shape = data.shape
@@ -160,7 +170,7 @@ def ssd_chunked(x, dt, a, b, c, chunk):
     return y.reshape(bsz, nc * q, h, p)[:, :length]
 
 
-@register_op("Mamba2Mixer")
+@register_op("Mamba2Mixer", names_its_parts=True)
 def mamba2_mixer(data, in_proj_weight, conv_weight, conv_bias, dt_bias,
                  a_log, d, norm_weight, out_proj_weight, num_heads=1,
                  head_dim=64, state_size=128, num_groups=1, chunk_size=128,
@@ -177,7 +187,10 @@ def mamba2_mixer(data, in_proj_weight, conv_weight, conv_bias, dt_bias,
         int(num_groups)
     bsz, length, _ = data.shape
     d_in = h * p
-    zxbcdt = kept(_mm(data, in_proj_weight))
+    # not mx_ssd_*: the in- and out-projections are no part of what
+    # ssd_time_share.train has read under that prefix since PR 29
+    with jax.named_scope("mx_mamba_proj"):
+        zxbcdt = kept(_mm(data, in_proj_weight))
     z = zxbcdt[..., :d_in]
     xbc = zxbcdt[..., d_in:2 * d_in + 2 * g * n]
     dt = zxbcdt[..., 2 * d_in + 2 * g * n:]
@@ -192,8 +205,9 @@ def mamba2_mixer(data, in_proj_weight, conv_weight, conv_bias, dt_bias,
     with jax.named_scope("mx_ssd_gate"):
         y = y + d.astype(_F32)[:, None] * x.astype(_F32)
         y = y.reshape(bsz, length, d_in) * jax.nn.silu(z.astype(_F32))
-        y = rms_norm(y, norm_weight, eps=eps, num_groups=g)
-    return _mm(y.astype(data.dtype), out_proj_weight)
+        y = _rms_norm(y, norm_weight, eps=eps, num_groups=g)
+    with jax.named_scope("mx_mamba_proj"):
+        return _mm(y.astype(data.dtype), out_proj_weight)
 
 
 # ---------------------------------------------------------------------------
@@ -274,7 +288,7 @@ def balanced_bias(router_bias, load, rate):
         + rate * jnp.sign(jnp.mean(load) - load)
 
 
-@register_op("LatentMoE", num_outputs=3)
+@register_op("LatentMoE", num_outputs=3, names_its_parts=True)
 def latent_moe(data, router_weight, router_bias, down_weight, up_weight,
                w1, w2, shared_w1, shared_w2, counters=None, expert_ids=(0,),
                top_k=1, buffer_rows=0, scaling=1.0, norm_topk=True,
@@ -412,7 +426,7 @@ def _moe_stats(load, count, cap, counters):
         placed / (count.shape[0] * cap)]))
 
 
-@register_op("GatedMoE", num_outputs=3)
+@register_op("GatedMoE", num_outputs=3, names_its_parts=True)
 def gated_moe(data, router_weight, router_bias, w1, w3, w2,
               shared_gate_up_weight, shared_down_weight, counters=None,
               expert_ids=(0,), top_k=1, buffer_rows=0, scaling=1.0,
@@ -469,7 +483,7 @@ def _attention_block(q, k, v, bias, scale):
     return o, m, jnp.sum(p, axis=-1)
 
 
-@register_op("RoPE")
+@register_op("RoPE", names_its_parts=True)
 def rope(data, theta=10000.0, **kw):
     """Rotary position encoding over the whole head, in the
     ``rotate_half`` convention: with ``x = [x1 | x2]`` the two halves of
@@ -591,7 +605,7 @@ def _fused_attention_bwd(hq, hk, scale, blk, res, dout):
 _fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
 
 
-@register_op("CausalGQAttention")
+@register_op("CausalGQAttention", names_its_parts=True)
 def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
                         block=1024, scale=None, rope_theta=None, **kw):
     """Causal attention over packed ``[q | k | v]`` rows, ``num_heads``
@@ -647,7 +661,7 @@ def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
 # ---------------------------------------------------------------------------
 # multi-head latent attention
 # ---------------------------------------------------------------------------
-@register_op("LatentAttention")
+@register_op("LatentAttention", names_its_parts=True)
 def latent_attention(data, q_weight, kv_down_weight, kv_norm_weight,
                      kv_up_weight, o_weight, num_heads=1, nope_dim=128,
                      rope_dim=64, v_dim=128, latent_dim=512,
@@ -686,7 +700,7 @@ def latent_attention(data, q_weight, kv_down_weight, kv_norm_weight,
         q = kept(_mm(data, q_weight))
     with jax.named_scope("mx_mla_kv_down"):
         ckv = kept(_mm(data, kv_down_weight))
-        c = kept(rms_norm(ckv[..., :latent_dim], kv_norm_weight, eps=eps))
+        c = kept(_rms_norm(ckv[..., :latent_dim], kv_norm_weight, eps=eps))
     with jax.named_scope("mx_mla_kv_up"):
         kv = _mm(c, kv_up_weight)
     with jax.named_scope("mx_mla_rope"):
@@ -710,7 +724,7 @@ def latent_attention(data, q_weight, kv_down_weight, kv_norm_weight,
 # ---------------------------------------------------------------------------
 # gated MLP
 # ---------------------------------------------------------------------------
-@register_op("GatedMLP")
+@register_op("GatedMLP", names_its_parts=True)
 def gated_mlp(data, gate_up_weight, down_weight, **kw):
     """``(silu(u W_gate) * (u W_up)) W_down``. ``gate_up_weight``: (2 f,
     hidden), rows ``[gate | up]``, one product for both; ``down_weight``:
@@ -728,7 +742,7 @@ def gated_mlp(data, gate_up_weight, down_weight, **kw):
 # ---------------------------------------------------------------------------
 # a stack run several times: the exit gate and the exit-weighted loss
 # ---------------------------------------------------------------------------
-@register_op("ExitGate", num_outputs=2)
+@register_op("ExitGate", num_outputs=2, names_its_parts=True)
 def exit_gate(data, weight, bias, **kw):
     """The gates of a stack run ``T`` times. ``data``: (T, ..., hidden),
     the stack's output after each pass. Returns ``(logits (T - 1, N),

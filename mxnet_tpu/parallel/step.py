@@ -41,12 +41,19 @@ def softmax_ce_loss(logits, labels):
     where the projection left them. A label outside ``[0, classes)`` picks
     nothing: that row's loss is the log-sum-exp of its logits less their
     maximum, and its gradient the softmax (a gather gave NaN past the end).
+    Scope ``mx_loss``, opened by the named losses themselves: a loss the
+    caller brings names its own parts (``exit_weighted_loss``'s are
+    ``mx_exit_head`` and ``mx_exit_gate``), and a scope around it would
+    enclose them.
     """
-    return jnp.mean(softmax_ce_rows(logits, labels))
+    with jax.named_scope("mx_loss"):
+        return jnp.mean(softmax_ce_rows(logits, labels))
 
 
 def l2_loss(pred, target):
-    return 0.5 * jnp.mean(jnp.square(pred - target.reshape(pred.shape)))
+    with jax.named_scope("mx_loss"):
+        return 0.5 * jnp.mean(
+            jnp.square(pred - target.reshape(pred.shape)))
 
 
 def exit_weighted_loss(beta=0.0):
@@ -176,6 +183,7 @@ class TrainStep:
         self._pvals = None
         self._opt_state = None
         self._step_jit = None
+        self._program = None     # telemetry.trace's record of the step
         self._trace_id = None    # every call's span is on one trace
         # declarative alternative to param_spec_fn: regex -> PartitionSpec
         # rules (parallel/partition.py). Explicit param_spec_fn wins; with
@@ -266,12 +274,15 @@ class TrainStep:
             def fwd(pv):
                 pv_c = pv
                 if compute_dtype is not None:
-                    pv_c = tuple(
-                        v.astype(compute_dtype)
-                        if v.dtype == jnp.float32 and tr else v
-                        for v, tr in zip(pv, trainable))
-                    x_c = x.astype(compute_dtype) \
-                        if x.dtype == jnp.float32 else x
+                    # the masters' copies in the compute dtype, and
+                    # backward the gradients' way back to float32
+                    with jax.named_scope("mx_cast"):
+                        pv_c = tuple(
+                            v.astype(compute_dtype)
+                            if v.dtype == jnp.float32 and tr else v
+                            for v, tr in zip(pv, trainable))
+                        x_c = x.astype(compute_dtype) \
+                            if x.dtype == jnp.float32 else x
                 else:
                     x_c = x
                 outs, writes = staged(pv_c, (x_c,), key)
@@ -281,7 +292,9 @@ class TrainStep:
 
             (loss, writes), grads = jax.value_and_grad(
                 fwd, has_aux=True)(pvals)
-            # optimizer update on trainable params only
+            # optimizer update on trainable params only. A fusion carries
+            # one name: a weight gradient that XLA fuses with its update
+            # reads as one of the two (on a v5e as the product: PERF.md)
             new_p, new_s = [], []
             for i, (p, g, s, tr) in enumerate(
                     zip(pvals, grads, opt_state, trainable)):
@@ -292,10 +305,11 @@ class TrainStep:
                     pkey = jax.random.fold_in(
                         jax.random.fold_in(key, 0x6F707469), i) \
                         if fopt.needs_key else None
-                    np_, ns_ = fopt.update(p, g, s, lr * lr_mults[i],
-                                           t + 1, wd_base * wd_mults[i],
-                                           key=pkey)
-                    new_p.append(np_.astype(p.dtype))
+                    with jax.named_scope("mx_opt_update"):
+                        np_, ns_ = fopt.update(p, g, s, lr * lr_mults[i],
+                                               t + 1, wd_base * wd_mults[i],
+                                               key=pkey)
+                        new_p.append(np_.astype(p.dtype))
                     new_s.append(ns_)
                 else:
                     new_p.append(p)
@@ -380,9 +394,14 @@ class TrainStep:
             # persistent cache: a plain jax.jit, so the compile registry
             # is told of the acquisition, it does not make it
             from ..compile import registry as _creg
+            shapes = _trace.shapes_of(args)     # the call donates args
             with _trace.span("compile", "step", on=on), \
                     _creg.jit_acquire("mx_train_step", "train_step", args):
                 out = self._step_jit(*args)
+            # the one reference scope_table() is built from when somebody
+            # asks: the call's own trace, found again (no second trace)
+            self._program = _trace.note_program(
+                "jit_mx_train_step", self._step_jit.trace(*shapes))
         else:
             # on the host an enqueue; once the runtime's limit of
             # executions in flight is reached it blocks for a device step
@@ -413,6 +432,13 @@ class TrainStep:
             self.param_list = self.net._get_param_list()
             self._trainable = [p.grad_req != "null"
                                for p in self.param_list]
+
+    def scope_table(self):
+        """``{HLO instruction name: scope path}`` of this step's compiled
+        program (``telemetry.trace.scope_table``), or None before the
+        first call. Built when first asked for; a step costs nothing for
+        it."""
+        return None if self._program is None else self._program.table()
 
     def sync_params(self):
         """Nothing to do: the net's Parameters hold the step's buffers

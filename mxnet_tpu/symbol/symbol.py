@@ -13,6 +13,7 @@ reference-format JSON round-trips.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence
@@ -305,14 +306,21 @@ class Symbol:
         return dmap
 
     @staticmethod
-    def _apply_node_op(node, ins, training, rng_key):
+    def _apply_node_op(node, ins, training, rng_key, scoped=True):
         """Dispatch ONE op node on resolved input values — the single
         place that parses attrs and injects training flags / per-node
         RNG keys. Shared by the eager walk (eval_arrays_ex) and the
         segmented walk (_make_segment_fn): the two must stay
         bit-identical (same uid fold salt, same BN semantics) or the
         Monitor's tapped pass diverges from training. Returns
-        (outs tuple, parsed attrs)."""
+        (outs tuple, parsed attrs).
+
+        The one site, too, that names a graph's work in a compiled
+        program: with ``scoped`` the operator runs under the scope
+        ``mx_op_<node.op>`` (``telemetry.trace.scope_table``), unless its
+        registration says that it names its own parts (a scope around
+        them would change the paths their readers match). The eager walk
+        of a group2ctx Executor, which runs every step, asks for none."""
         import jax
         from ..ops.registry import get_op
         attrs = {k: parse_attr(v) for k, v in node.attrs.items()
@@ -329,11 +337,14 @@ class Symbol:
             # so forward and backward see identical dropout masks
             attrs["key"] = jax.random.fold_in(base, node.uid % (2 ** 31))
         innames = node.attrs.get("__input_names__")
-        if innames:
-            res = opdef.fn(**dict(zip(parse_attr(innames), ins)),
-                           **attrs)
-        else:
-            res = opdef.fn(*ins, **attrs)
+        with jax.named_scope("mx_op_" + node.op) \
+                if scoped and not opdef.names_its_parts \
+                else contextlib.nullcontext():
+            if innames:
+                res = opdef.fn(**dict(zip(parse_attr(innames), ins)),
+                               **attrs)
+            else:
+                res = opdef.fn(*ins, **attrs)
         return (res if isinstance(res, tuple) else (res,)), attrs
 
     @staticmethod
@@ -409,8 +420,8 @@ class Symbol:
                 dev = device_map.get(node.name)
                 if dev is not None:
                     ins = [jax.device_put(v, dev) for v in ins]
-            outs, attrs = Symbol._apply_node_op(node, ins, training,
-                                                rng_key)
+            outs, attrs = Symbol._apply_node_op(
+                node, ins, training, rng_key, scoped=device_map is None)
             for i, o in enumerate(outs):
                 cache[(id(node), i)] = o
                 if internals is not None:

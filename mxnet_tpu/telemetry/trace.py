@@ -45,6 +45,13 @@ a ``jax.profiler`` trace is running in this process. ``fit()`` and
   pipeline's worker threads and ``data:wait`` (wait) on the consumer's,
   linked to the fit root via :meth:`DataPipeline.set_trace`.
 
+The device's half of "where did this step spend its time" is the step
+program's own: ``jax.named_scope("mx_...")`` where the work is emitted
+(the vocabulary is in ``docs/observability.md``), and :func:`scope_table`
+puts those names on a compiled program's instructions, which is what a
+profiler trace calls its device events. Scopes run while a step is
+traced, never per step; the table is built when somebody asks.
+
 Hot-path contract (the same one the metrics layer keeps): recording a
 completed span is one tuple write into a preallocated ring under a
 short lock — no I/O, no syncs, no unbounded growth (``MXTPU_TRACE_RING``
@@ -60,18 +67,21 @@ finds the window's spans in :func:`spans` afterwards.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
 import re
 import threading
 import time
+import weakref
 
 from . import registry
 
 __all__ = ["enabled", "trace_dir", "new_trace_id", "new_span_id",
            "span", "current", "record_span", "spans", "export_trace",
-           "trace_files", "read_trace", "reset", "hlo_scopes"]
+           "trace_files", "read_trace", "reset", "hlo_scopes",
+           "scope_table", "note_program", "shapes_of", "XLA_NAMED"]
 
 # the clock of every span; record_span's callers (intervals measured
 # across threads) read their t0 from it too
@@ -435,9 +445,20 @@ def reset():
 
 _HLO_INSTRUCTION = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name=\"([^\"]*)\"", re.M)
+_HLO_LOOP = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? while\(", re.M)
+
+#: instructions XLA names itself, dropping the ``op_name`` they were
+#: traced under (``lax.ragged_dot`` lowered for a TPU is XLA's own grouped
+#: kernel: ``ragged-dot-none.2``, ``ragged-dot-metadata``), by the start
+#: of their name, and the scope this program files them under: its one
+#: ragged product is the pooled experts' (``ops.seq.pooled_gated_product``,
+#: inside ``mx_moe_gmm_up`` / ``mx_moe_gmm_down``). The compiled text
+#: keeps nothing of their call site, so this is a stated fallback.
+XLA_NAMED = {"ragged-dot": "mx_moe_gmm_ragged"}
 
 
-def hlo_scopes(hlo_text, prefix="mx_", path=False):
+def hlo_scopes(hlo_text, prefix="mx_", path=False, loops=True,
+               xla_named=None):
     """From a compiled program's HLO text, ``{instruction name: scope}``
     for every instruction whose ``op_name`` passes through a
     ``jax.named_scope`` that starts with ``prefix`` (the innermost such
@@ -446,7 +467,13 @@ def hlo_scopes(hlo_text, prefix="mx_", path=False):
     from the outermost in, joined by ``/`` (``mx_loop_body/mx_attn_fwd``
     for the attention inside a scanned body). The device trace names its
     events by instruction, so this is what puts the program's own names
-    on them."""
+    on them.
+
+    ``loops=False`` leaves the ``while`` instructions out: a trace holds
+    an event for a loop and one for each operation of its every trip, so
+    a reader that adds up or unites events wants the trips alone.
+    ``xla_named`` (``{start of an instruction's name: scope}``, as
+    :data:`XLA_NAMED`) files the instructions XLA names itself."""
     # a path component of its own or inside jvp(...)/transpose(...); the
     # jitted function's own name, "jit(mx_train_step)", is no scope
     scope = re.compile(r"(?<!jit\()\b(" + re.escape(prefix) + r"\w+)")
@@ -455,4 +482,169 @@ def hlo_scopes(hlo_text, prefix="mx_", path=False):
         found = scope.findall(op_name)
         if found:
             out[name] = "/".join(found) if path else found[-1]
+        elif xla_named:
+            for start, filed in xla_named.items():
+                if name.startswith(start):
+                    out[name] = filed
+    if not loops:
+        for name in _HLO_LOOP.findall(hlo_text):
+            out.pop(name, None)
     return out
+
+
+# ---------------------------------------------------------------------------
+# the scope table of an acquired program
+# ---------------------------------------------------------------------------
+_programs = {}       # XLA module name -> _Program, the one acquired last
+
+
+@contextlib.contextmanager
+def _no_compile_cache():
+    """JAX's persistent compilation cache neither read nor written
+    inside (the ``.mxprog`` entries of ``compile/cache.py`` are not on a
+    ``Lowered.compile()``'s way at all)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()     # the cache is decided on once
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        compilation_cache.reset_cache()
+
+
+class _Program:
+    """What the scope table of one acquired program is built from, held
+    from the acquisition until the table is built: the traced program
+    (``jit(f).trace(*shapes)``, or a function that gives it: the jaxpr
+    and the arguments' shapes, no buffer and no Python closure) and,
+    weakly, the executable where the step holds one. The record of the
+    program acquired last under a name outlives its step, so that a
+    trace can be read after the step was closed."""
+
+    def __init__(self, name, traced, executable=None):
+        self.name = name
+        self._traced = traced
+        self._executable = None if executable is None \
+            else weakref.ref(executable)
+        self._table = None
+        # the executable was another tree's; None: not known
+        self.stale = None
+
+    def table(self):
+        """``{HLO instruction name: scope path}`` (see
+        :func:`scope_table`), built at the first call."""
+        if self._table is None:
+            t0 = _now()
+            self._table = self._build()
+            self._traced = self._executable = None
+            registry.gauge("trace::scope_table_s").set(_now() - t0)
+        return self._table
+
+    def _build(self):
+        traced = self._traced if hasattr(self._traced, "lower") \
+            else self._traced()
+        exe = self._executable and self._executable()
+        if traced is None:      # an AOT entry nobody can trace again
+            return _table_of(exe.as_text()) if exe is not None else {}
+        lowered = traced.lower()
+        if exe is None:
+            # the lowering the jit's own call compiled, which still holds
+            # that executable; else JAX's cache may
+            exe = lowered.compile()
+        text = exe.as_text()
+        # op_name is metadata, which JAX strips before it hashes a program
+        # for its cache: an executable found there may have been compiled
+        # from another tree, before a scope of this one existed. A
+        # program's text lists the Python frames it was traced through
+        # (files, functions, lines); where the executable's list is not
+        # this lowering's, or either text shows none, its names are taken
+        # to be somebody else's
+        mine = _frames(lowered.as_text(dialect="hlo", debug_info=True))
+        self.stale = mine is None or _frames(text) != mine
+        if not self.stale:
+            return _table_of(text)
+        # compile the same program once more beside the caches: the
+        # optimised program is the same, so its instructions have the
+        # cached one's names, which is what a trace's events are matched
+        # by
+        registry.gauge("trace::scope_table_recompiles").inc()
+        with _no_compile_cache():
+            fresh = lowered.compile(compiler_options=_COMPILE_AGAIN)
+        return _table_of(fresh.as_text())
+
+
+#: ``Lowered.compile()`` hands back the executable it has, and JAX keeps the
+#: executable of an equal module in memory; given an option it compiles.
+#: This one is at its default and dumps nothing (no ``xla_dump_to``).
+_COMPILE_AGAIN = {"xla_dump_hlo_as_text": False}
+
+_FRAMES = re.compile(r"^FileNames\n.*?^StackFrames\n.*?\n\n", re.M | re.S)
+_FRAME_FILE = re.compile(r'^(\d+) ".*?([^/"]+)"$', re.M)
+
+
+def _frames(hlo_text):
+    """The frame tables at the head of a program's HLO text (``FileNames``
+    to ``StackFrames``), each file by its last component: two checkouts
+    of one tree are one source. None where the text shows no such table
+    (another XLA's format): the caller then trusts no name."""
+    found = _FRAMES.search(hlo_text)
+    return _FRAME_FILE.sub(r'\1 "\2"', found.group(0)) if found else None
+
+
+def _table_of(text):
+    return hlo_scopes(text, path=True, loops=False, xla_named=XLA_NAMED)
+
+
+def shapes_of(args):
+    """``args`` with every array replaced by its shape and dtype
+    (``jax.ShapeDtypeStruct``): what a program can be traced and lowered
+    from again once its arguments' buffers are donated. No sharding: a
+    program over a mesh states its own (``in_shardings``), and one
+    without lowers for the default device exactly as its first call did,
+    so that JAX's cache finds that call's executable."""
+    import jax
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+        if hasattr(a, "shape") and hasattr(a, "dtype") else a, args)
+
+
+def note_program(name, traced, executable=None):
+    """The one stored reference of an acquisition: ``name`` is the XLA
+    module's (``jit_mx_train_step``), the rest :class:`_Program`'s.
+    Returns the record whose ``table()`` the step object hands out; the
+    record acquired last under a name answers :func:`scope_table`."""
+    prog = _programs[name] = _Program(name, traced, executable)
+    return prog
+
+
+def scope_table(name):
+    """``{HLO instruction name: scope path}`` of the program acquired
+    last under the XLA module name ``name`` (``jit_mx_train_step``:
+    ``parallel.TrainStep``; ``jit_mx_fused_step``: ``Module``'s fused
+    step), or None where none was: every ``mx_*`` scope of an
+    instruction from the outermost in, joined by ``/``, without the
+    ``while`` instructions and with XLA's own grouped kernel under
+    :data:`XLA_NAMED`'s name. A profiler trace names its device events by
+    instruction, so this table puts the program's names on them
+    (``TrainStep.scope_table()`` / ``FusedSymbolStep.scope_table()`` are
+    the same table by the object).
+
+    Built when first asked for, never on a step's path: the program is
+    lowered again from the acquisition's traced form (no trace is taken
+    twice) and its executable's text read, compiled or loaded from
+    JAX's cache where the step holds none. **The table knows whose
+    names it carries**: JAX strips names before it hashes a program for
+    its cache, so an executable found there may have been compiled from
+    another tree, before a scope of this one existed. Where the Python
+    frames listed at the head of the executable's text (files,
+    functions, lines) are not this lowering's, or a text lists none,
+    the table is read from one compile beside the caches instead
+    (counted by the gauge
+    ``trace::scope_table_recompiles``; seconds to a minute, once a
+    process). The gauge ``trace::scope_table_s`` holds the seconds the
+    table built last took."""
+    prog = _programs.get(name)
+    return None if prog is None else prog.table()

