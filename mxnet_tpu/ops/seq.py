@@ -1,11 +1,13 @@
 """Sequence-model operators: RMSNorm, the Mamba-2 mixer (the chunked
 state-space scan, SSD), a mixture of experts that is told which experts
 it holds (one routing path, two expert bodies: relu2 in a latent, or
-gated SiLU on the full hidden vector), causal grouped-query attention in
-blocks (fused kernels where the program is lowered for a TPU,
-``ops.attn_kernel``) with or without rotary position encoding, multi-head
-latent attention over the same kernels, a gated MLP, and the exit gate
-and exit-weighted loss of a stack that is run several times.
+gated SiLU on the full hidden vector, its grouped products this repo's
+kernels where the program is lowered for a TPU, ``ops.gmm_kernel``),
+causal grouped-query attention in blocks (fused kernels where the program
+is lowered for a TPU, ``ops.attn_kernel``) with or without rotary
+position encoding, multi-head latent attention over the same kernels, a
+gated MLP, and the exit gate and exit-weighted loss of a stack that is
+run several times.
 
 Every op here is one chip's share of a layer: it is told how many heads
 and groups it holds and which experts, computes with what it holds, and
@@ -49,7 +51,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import attn_kernel
+from . import attn_kernel, gmm_kernel
 from .registry import register_op
 from .remat import kept
 
@@ -232,26 +234,124 @@ def grouped_product(buf, w1, w2):
                                ).astype(buf.dtype))
 
 
+def _gating(gate, up):
+    """``silu(gate) * up`` in float32, in the products' dtype."""
+    return (jax.nn.silu(gate.astype(_F32)) * up.astype(_F32)
+            ).astype(gate.dtype)
+
+
+def _ragged(rows, w, sizes):
+    return lax.ragged_dot(rows, w, sizes, preferred_element_type=rows.dtype)
+
+
 def pooled_gated_product(buf, w1, w3, w2, sizes):
     """Every held expert's ``(silu(rows W1) * rows W3) W2`` over its rows
     of the pool: ``buf`` (rows, hidden) sorted by expert, ``sizes`` (E,)
     int32 the rows each expert has there, summing to ``rows``; ``w1``,
     ``w3`` (E, hidden, ff), ``w2`` (E, ff, hidden); the activation and the
     gating in float32 on the products as the compute dtype holds them
-    (``gated_mlp``'s arithmetic). Three ragged products
-    (``lax.ragged_dot``: on a TPU XLA's own grouped kernel, elsewhere
-    its plain form). Every row of the pool is some expert's and is
-    computed, whether it holds a token or nothing: ``grouped_product``'s
-    rule."""
+    (``gated_mlp``'s arithmetic), every product summed in float32 and
+    rounded once.
+
+    Two forms of the three grouped products, chosen by what the program
+    can see, not by the caller. Where the kernels' tiling rule takes the
+    shapes (``gmm_kernel.tile_rows``: both widths whole lane tiles, the
+    pool whole tiles of rows, the blocks within VMEM) and the program is
+    lowered for a TPU, this repo's own kernels under one ``custom_vjp``
+    (``ops.gmm_kernel``): the pool walked in tiles, group by group, an
+    expert's matrices in VMEM while its rows pass, forward, the rows'
+    gradients (contracted over the weights' stored minor dimension: no
+    weight is turned) and the weights' gradients (a group's float32 sum
+    held in VMEM over its tiles). Everywhere else (other shapes, another
+    backend) three ``lax.ragged_dot`` and JAX's derivative of them.
+
+    A recomputation unit around it keeps both up-products and the
+    down-product and, where the kernels may run, ``sizes`` and the walk
+    (a few hundred bytes); ``hid`` is formed again, the products are not.
+
+    Every row of the pool is some expert's and is computed, whether it
+    holds a token or nothing: ``grouped_product``'s rule."""
+    tile = gmm_kernel.tile_rows(buf.shape[0], buf.shape[1], w1.shape[2],
+                                buf.dtype)
+    if tile is not None:
+        return _pooled_kernels(buf, w1, w3, w2, sizes, tile)
     with jax.named_scope("mx_moe_gmm_up"):
-        gate, up = (kept(lax.ragged_dot(buf, w, sizes,
-                                        preferred_element_type=buf.dtype))
-                    for w in (w1, w3))
-        hid = (jax.nn.silu(gate.astype(_F32)) * up.astype(_F32)
-               ).astype(buf.dtype)
+        hid = _gating(*(kept(_ragged(buf, w, sizes)) for w in (w1, w3)))
     with jax.named_scope("mx_moe_gmm_down"):
-        return kept(lax.ragged_dot(hid, w2, sizes,
-                                   preferred_element_type=buf.dtype))
+        return kept(_ragged(hid, w2, sizes))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _pooled_kernels(buf, w1, w3, w2, sizes, tile):
+    """``pooled_gated_product`` whose program takes its form when it is
+    lowered: for a TPU the kernels of ``ops.gmm_kernel`` over tiles of
+    ``tile`` rows, forward and backward, for any other platform the
+    ragged products and JAX's derivative of each. Either way the unit
+    around it keeps ``gate``, ``up`` and the result, and its backward pass
+    runs no forward product a second time."""
+    return _pooled_kernels_fwd(buf, w1, w3, w2, sizes, tile)[0]
+
+
+def _pooled_kernels_fwd(buf, w1, w3, w2, sizes, tile):
+    sizes = kept(sizes)
+    with jax.named_scope("mx_moe_gmm_up"):
+        walk = tuple(kept(t) for t in gmm_kernel.visits(
+            sizes, buf.shape[0], tile))
+        gate, up, hid = lax.platform_dependent(
+            buf, w1, w3, sizes, *walk,
+            tpu=lambda buf, w1, w3, sizes, *walk: gmm_kernel.up(
+                attn_kernel.counted_site(buf, gmm_kernel.GAUGE), w1, w3,
+                walk, tile),
+            default=lambda buf, w1, w3, sizes, *walk: _ragged_up(
+                buf, w1, w3, sizes))
+        gate, up = kept(gate), kept(up)
+    with jax.named_scope("mx_moe_gmm_down"):
+        out = kept(lax.platform_dependent(
+            hid, w2, sizes, *walk,
+            tpu=lambda hid, w2, sizes, *walk: gmm_kernel.down(
+                hid, w2, walk, tile),
+            default=lambda hid, w2, sizes, *walk: _ragged(hid, w2, sizes)))
+    return out, (buf, w1, w3, w2, sizes, walk, gate, up)
+
+
+def _ragged_up(buf, w1, w3, sizes):
+    gate, up = (_ragged(buf, w, sizes) for w in (w1, w3))
+    return gate, up, _gating(gate, up)
+
+
+def _ragged_down_backward(d_out, gate, up, w2, sizes):
+    hid, gating_vjp = jax.vjp(_gating, gate, up)
+    d_hid, dw2 = jax.vjp(lambda h, w: _ragged(h, w, sizes), hid, w2)[1](d_out)
+    return gating_vjp(d_hid) + (dw2,)
+
+
+def _ragged_up_backward(buf, d_gate, d_up, w1, w3, sizes):
+    (d_buf, dw1), (d_buf3, dw3) = (
+        jax.vjp(lambda b, w: _ragged(b, w, sizes), buf, w)[1](d)
+        for w, d in ((w1, d_gate), (w3, d_up)))
+    return d_buf + d_buf3, dw1, dw3
+
+
+def _pooled_kernels_bwd(tile, res, d_out):
+    buf, w1, w3, w2, sizes, walk, gate, up = res
+    with jax.named_scope("mx_moe_gmm_down"):
+        d_gate, d_up, dw2 = lax.platform_dependent(
+            d_out, gate, up, w2, sizes, *walk,
+            tpu=lambda d_out, gate, up, w2, sizes, *walk:
+                gmm_kernel.down_backward(d_out, gate, up, w2, walk, tile),
+            default=lambda d_out, gate, up, w2, sizes, *walk:
+                _ragged_down_backward(d_out, gate, up, w2, sizes))
+    with jax.named_scope("mx_moe_gmm_up"):
+        d_buf, dw1, dw3 = lax.platform_dependent(
+            buf, d_gate, d_up, w1, w3, sizes, *walk,
+            tpu=lambda buf, d_gate, d_up, w1, w3, sizes, *walk:
+                gmm_kernel.up_backward(buf, d_gate, d_up, w1, w3, walk, tile),
+            default=lambda buf, d_gate, d_up, w1, w3, sizes, *walk:
+                _ragged_up_backward(buf, d_gate, d_up, w1, w3, sizes))
+    return d_buf, dw1, dw3, dw2, None
+
+
+_pooled_kernels.defvjp(_pooled_kernels_fwd, _pooled_kernels_bwd)
 
 
 def route(scores_in, router_weight, router_bias, top_k, scaling,
@@ -437,7 +537,10 @@ def gated_moe(data, router_weight, router_bias, w1, w3, w2,
     ``_moe_stats``, ``balanced_bias``) around another expert body and
     another buffer. An expert is ``W2 (silu(W1 u) * W3 u)``: ``w1``,
     ``w3`` (E, hidden, ff), ``w2`` (E, ff, hidden), no latent projection
-    on either side (``pooled_gated_product``). The ``buffer_rows`` rows
+    on either side (``pooled_gated_product``: the grouped-matmul kernels
+    of ``ops.gmm_kernel`` where the program is lowered for a TPU and the
+    widths are whole lane tiles, ``lax.ragged_dot`` everywhere else; the
+    gauge ``moe::gmm_kernel_sites`` says which). The ``buffer_rows`` rows
     are ONE pool the held experts share (``_dispatch_pooled``): the
     family trains without dropping a token, and a slice of its own for
     each expert overflowed on the chip while the pool stood a quarter

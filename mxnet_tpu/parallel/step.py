@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import telemetry as _telemetry
 from ..ops import attn_kernel as _attn_kernel
+from ..ops import gmm_kernel as _gmm_kernel
 from ..ndarray.ndarray import NDArray, _wrap
 from ..ops.seq import exit_weighted_ce, softmax_ce_rows
 from ..telemetry import trace as _trace
@@ -264,9 +265,10 @@ class TrainStep:
 
         def mx_train_step(pvals, opt_state, x, y, t, lr):
             # of the step traced last, like the units' gauges: the lowering
-            # that follows counts the attention sites it gives the kernel
+            # that follows counts the sites it gives the kernels
             _telemetry.gauge(_attn_kernel.GAUGE).set(0)
             _telemetry.gauge(_attn_kernel.FUSED_BWD_GAUGE).set(0)
+            _telemetry.gauge(_gmm_kernel.GAUGE).set(0)
             key = jax.random.fold_in(base_key, t)
             if preprocess is not None:
                 x = preprocess(x)

@@ -452,8 +452,9 @@ _HLO_LOOP = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? while\(", re.M)
 #: kernel: ``ragged-dot-none.2``, ``ragged-dot-metadata``), by the start
 #: of their name, and the scope this program files them under: its one
 #: ragged product is the pooled experts' (``ops.seq.pooled_gated_product``,
-#: inside ``mx_moe_gmm_up`` / ``mx_moe_gmm_down``). The compiled text
-#: keeps nothing of their call site, so this is a stated fallback.
+#: inside ``mx_moe_gmm_up`` / ``mx_moe_gmm_down``, at the shapes its own
+#: kernels do not take). The compiled text keeps nothing of their call
+#: site, so this is a stated fallback.
 XLA_NAMED = {"ragged-dot": "mx_moe_gmm_ragged"}
 
 
