@@ -4,7 +4,8 @@ grouped-query attention (the ``hybrid_override_pattern`` of the
 ``nemotron_h`` family's configurations), ``G`` a gated MLP, ``L``
 multi-head latent attention, ``F`` a mixture of gated experts on the full
 hidden vector (``deepseek_v3``'s two sublayers: ``LG`` a dense layer,
-``LF`` an expert layer). Pre-norm
+``LF`` an expert layer), ``D`` a Gated DeltaNet mixer (``qwen3_next``'s
+linear-attention layers: ``DFDFDF*F`` a period). Pre-norm
 residual throughout, ``x <- x + Mixer_l(RMSNorm_l(x))``, or with
 ``post_norm`` a norm on either side of the mixer, ``x <- x +
 RMSNorm'_l(Mixer_l(RMSNorm_l(x)))``; one final RMSNorm, an untied head,
@@ -35,13 +36,15 @@ class _Layer(HybridBlock):
     unit that ``TrainStep(remat="layer")`` recomputes."""
     _remat_unit = True
 
-    def __init__(self, units, mixer, epsilon, post_norm=False, **kwargs):
+    def __init__(self, units, mixer, epsilon, post_norm=False,
+                 unit_offset=False, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
-            self.norm = nn.RMSNorm(units, epsilon)
+            self.norm = nn.RMSNorm(units, epsilon, unit_offset=unit_offset)
             self.mixer = mixer()
             # reads the mixer's last product, which the unit then keeps
-            self.post_norm = nn.RMSNorm(units, epsilon, keep_input=True) \
+            self.post_norm = nn.RMSNorm(units, epsilon, keep_input=True,
+                                        unit_offset=unit_offset) \
                 if post_norm else None
 
     def hybrid_forward(self, F, x):
@@ -55,9 +58,12 @@ class PatternLM(HybridBlock):
     ``latent_attention``, ``experts``: the keyword arguments of
     ``nn.Mamba2Mixer``, ``nn.LatentMoE``, ``nn.GQAttention``,
     ``nn.GatedMLP``, ``nn.LatentAttention`` and ``nn.GatedMoE`` after
-    ``in_units`` (what each layer of that kind holds). ``post_norm``: a second norm in every layer, after its mixer.
-    ``loops``: how often the stack and the final norm run, each pass on
-    the one before's output.
+    ``in_units`` (what each layer of that kind holds), and
+    ``linear_attention`` those of ``nn.GatedDeltaNet``. ``post_norm``: a
+    second norm in every layer, after its mixer. ``norm_unit_offset``:
+    every layer's norm and the final norm scale by ``1 + w`` from ``w =
+    0``. ``loops``: how often the stack and the final norm run, each pass
+    on the one before's output.
 
     Input (B, L) token ids below ``vocab``; output (B * L, vocab) logits
     of the last pass. With ``exit_gate``, three outputs for a loss over
@@ -70,7 +76,8 @@ class PatternLM(HybridBlock):
     def __init__(self, pattern, vocab, units, mamba=None, moe=None,
                  attention=None, mlp=None, epsilon=1e-5, post_norm=False,
                  loops=1, exit_gate=False, latent_attention=None,
-                 experts=None, **kwargs):
+                 experts=None, linear_attention=None,
+                 norm_unit_offset=False, **kwargs):
         super().__init__(**kwargs)
         make = {"M": lambda: nn.Mamba2Mixer(units, epsilon=epsilon,
                                             **mamba),
@@ -79,7 +86,9 @@ class PatternLM(HybridBlock):
                 "G": lambda: nn.GatedMLP(units, **mlp),
                 "L": lambda: nn.LatentAttention(units, epsilon=epsilon,
                                                 **latent_attention),
-                "F": lambda: nn.GatedMoE(units, **experts)}
+                "F": lambda: nn.GatedMoE(units, **experts),
+                "D": lambda: nn.GatedDeltaNet(units, epsilon=epsilon,
+                                              **linear_attention)}
         self._vocab, self._units = vocab, units
         with self.name_scope():
             self.embed = nn.Embedding(vocab, units)
@@ -88,10 +97,10 @@ class PatternLM(HybridBlock):
             for i, kind in enumerate(pattern):
                 if kind not in make:
                     raise ValueError(f"layer kind {kind!r} in {pattern!r}: "
-                                     "M, E, *, G, L and F are known")
+                                     "M, E, *, G, L, F and D are known")
                 self.stack.add(_Layer(units, make[kind], epsilon, post_norm,
-                                      prefix=f"l{i}_"))
-            final = nn.RMSNorm(units, epsilon)
+                                      norm_unit_offset, prefix=f"l{i}_"))
+            final = nn.RMSNorm(units, epsilon, unit_offset=norm_unit_offset)
             # a scan holds for its backward pass whatever its body computes
             # outside a unit as autodiff leaves it (of this norm, three
             # float32 copies of its rows a pass): a unit holds its input

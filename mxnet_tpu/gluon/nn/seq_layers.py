@@ -1,5 +1,6 @@
-"""Sequence-model blocks: ``RMSNorm``, ``Mamba2Mixer``, ``LatentMoE``,
-``GatedMoE``, ``GQAttention``, ``LatentAttention``, ``GatedMLP``, the
+"""Sequence-model blocks: ``RMSNorm``, ``Mamba2Mixer``, ``GatedDeltaNet``,
+``LatentMoE``, ``GatedMoE``, ``GQAttention``, ``LatentAttention``,
+``GatedMLP``, the
 ``HybridLoop`` container that runs its children several times as one
 scanned body, and ``ExitGate``, over the ops of ``ops/seq.py``.
 
@@ -11,18 +12,27 @@ from __future__ import annotations
 
 import jax
 
-from ... import autograd
+from ... import autograd, initializer
 from ...ndarray.ndarray import _wrap
 from ..block import HybridBlock, _TraceState, stateful_write
 
-__all__ = ["RMSNorm", "Mamba2Mixer", "LatentMoE", "GatedMoE", "GQAttention",
-           "LatentAttention", "GatedMLP", "HybridLoop", "ExitGate",
+__all__ = ["RMSNorm", "Mamba2Mixer", "GatedDeltaNet", "LatentMoE", "GatedMoE",
+           "GQAttention", "LatentAttention", "GatedMLP", "HybridLoop",
+           "ExitGate",
            "MOE_COUNTERS", "publish_moe_counters", "publish_loop_counters"]
 
 #: what an expert layer's ``counters`` hold, in order: the gauges
 #: ``moe::<name>::<block>`` of ``publish_moe_counters``
 MOE_COUNTERS = ("pairs_held", "overflow_pairs", "load_max_over_mean",
                 "buffer_fill")
+
+
+class _Start(initializer.Constant):
+    """A parameter's own start value, whatever the initializers' choice by
+    name would make of it (a ``gamma`` is set to 1 and a ``bias`` to 0 by
+    every initializer): a norm's ``gamma`` that starts at 0 under ``1 +
+    gamma``, a ``dt_bias`` that starts at 1."""
+    _init_gamma = _init_bias = initializer.Constant._init_weight
 
 
 def publish_moe_counters(net):
@@ -49,17 +59,21 @@ def publish_moe_counters(net):
 class RMSNorm(HybridBlock):
     """``keep_input``: inside a recomputation unit, hold the norm's input
     for the backward pass (a norm after a sublayer, whose input is that
-    sublayer's last product)."""
+    sublayer's last product). ``unit_offset``: the scale is ``1 +
+    gamma`` and ``gamma`` starts at zero."""
 
     def __init__(self, in_channels, epsilon=1e-5, keep_input=False,
-                 **kwargs):
+                 unit_offset=False, **kwargs):
         super().__init__(**kwargs)
         self._attrs = {"eps": epsilon}
         if keep_input:
             self._attrs["keep_input"] = True
+        if unit_offset:
+            self._attrs["unit_offset"] = True
         with self.name_scope():
-            self.gamma = self.params.get("gamma", shape=(in_channels,),
-                                         init="ones")
+            self.gamma = self.params.get(
+                "gamma", shape=(in_channels,),
+                init=_Start(0.0) if unit_offset else "ones")
 
     def hybrid_forward(self, F, x, gamma):
         return F.RMSNorm(x, gamma, **self._attrs)
@@ -99,6 +113,47 @@ class Mamba2Mixer(HybridBlock):
         return F.Mamba2Mixer(x, in_proj_weight, conv_weight, conv_bias,
                              dt_bias, a_log, d, gate_norm_weight,
                              out_proj_weight, **self._attrs)
+
+
+class GatedDeltaNet(HybridBlock):
+    """The Gated DeltaNet mixer, linear attention by the gated delta rule
+    (``ops.seq.gated_delta_net``), over the ``num_k_heads`` key heads,
+    ``key_dim`` wide, and the ``num_v_heads`` value heads, ``value_dim``
+    wide, held here: ``in_units -> [q k v z], [b a] -> conv over q k v ->
+    the rule in chunks -> gated head norm -> in_units``. The state a
+    value head carries is ``key_dim x value_dim``, whatever the
+    length."""
+
+    def __init__(self, in_units, num_k_heads, num_v_heads, key_dim=128,
+                 value_dim=128, conv_kernel=4, chunk_size=64, epsilon=1e-6,
+                 **kwargs):
+        super().__init__(**kwargs)
+        conv = 2 * num_k_heads * key_dim + num_v_heads * value_dim
+        self._attrs = {"num_k_heads": num_k_heads,
+                       "num_v_heads": num_v_heads, "key_dim": key_dim,
+                       "value_dim": value_dim, "chunk_size": chunk_size,
+                       "eps": epsilon}
+        with self.name_scope():
+            get = self.params.get
+            self.qkvz_weight = get(
+                "qkvz_weight", shape=(conv + num_v_heads * value_dim,
+                                      in_units))
+            self.ba_weight = get("ba_weight",
+                                 shape=(2 * num_v_heads, in_units))
+            self.conv_weight = get("conv_weight", shape=(conv, conv_kernel))
+            self.dt_bias = get("dt_bias", shape=(num_v_heads,),
+                               init=_Start(1.0))
+            self.a_log = get("a_log", shape=(num_v_heads,), init="zeros")
+            self.gate_norm_weight = get("gate_norm_weight",
+                                        shape=(value_dim,), init="ones")
+            self.out_weight = get("out_weight",
+                                  shape=(in_units, num_v_heads * value_dim))
+
+    def hybrid_forward(self, F, x, qkvz_weight, ba_weight, conv_weight,
+                       dt_bias, a_log, gate_norm_weight, out_weight):
+        return F.GatedDeltaNet(x, qkvz_weight, ba_weight, conv_weight,
+                               dt_bias, a_log, gate_norm_weight, out_weight,
+                               **self._attrs)
 
 
 class LatentMoE(HybridBlock):
@@ -161,11 +216,15 @@ class GatedMoE(HybridBlock):
     rows (a pair is beyond the buffer only when the held experts' pairs
     together outnumber its rows; ``buffer_fill`` is the pool's filled
     share); the shared experts are one gated MLP ``shared_units`` wide,
-    whole."""
+    whole. ``scoring``: the router's scores, each expert's ``sigmoid`` or
+    a ``softmax`` over all experts. ``shared_gate``: the shared experts'
+    output goes through ``sigmoid(u . shared_gate_weight)``, a gate of
+    its own a token."""
 
     def __init__(self, in_units, num_experts, expert_ids, top_k,
                  expert_units, shared_units, buffer_rows, scaling=1.0,
-                 norm_topk=True, bias_update_rate=0.0, **kwargs):
+                 norm_topk=True, bias_update_rate=0.0, scoring="sigmoid",
+                 shared_gate=False, **kwargs):
         super().__init__(**kwargs)
         held = len(expert_ids)
         self._attrs = {"expert_ids": tuple(int(e) for e in expert_ids),
@@ -173,6 +232,8 @@ class GatedMoE(HybridBlock):
                        "scaling": float(scaling),
                        "norm_topk": bool(norm_topk),
                        "bias_rate": float(bias_update_rate)}
+        if scoring != "sigmoid":
+            self._attrs["scoring"] = scoring
         with self.name_scope():
             get = self.params.get
             self.router_weight = get("router_weight",
@@ -188,12 +249,17 @@ class GatedMoE(HybridBlock):
                                           shape=(in_units, shared_units))
             self.counters = get("counters", shape=(len(MOE_COUNTERS),),
                                 init="zeros", grad_req="null")
+            if shared_gate:
+                self.shared_gate_weight = get("shared_gate_weight",
+                                              shape=(1, in_units))
 
     def hybrid_forward(self, F, x, router_weight, router_bias, w1, w3, w2,
-                       shared_gate_up_weight, shared_down_weight, counters):
+                       shared_gate_up_weight, shared_down_weight, counters,
+                       shared_gate_weight=None):
+        more = () if shared_gate_weight is None else (shared_gate_weight,)
         out, new, bias = F.GatedMoE(
             x, router_weight, router_bias, w1, w3, w2,
-            shared_gate_up_weight, shared_down_weight, counters,
+            shared_gate_up_weight, shared_down_weight, counters, *more,
             **self._attrs)
         stateful_write(self.counters, new)
         if self._attrs["bias_rate"] and autograd.is_training():
@@ -255,28 +321,56 @@ class GQAttention(HybridBlock):
     ``block`` rows, which is all ``block`` means (no result depends on
     it). As a recomputation unit the layer keeps the packed rows, the
     attention's output and, with the kernels, a float32 log-sum-exp a
-    row."""
+    row.
+
+    ``rotary_dim``: the rotation takes a head's first ``rotary_dim``
+    elements alone. ``qk_norm``: an RMSNorm of a head's width on every
+    query head and every key head before the rotation
+    (``q_norm_weight``, ``k_norm_weight``; ``epsilon``, and with
+    ``norm_unit_offset`` the scale ``1 + w`` from ``w = 0``). ``gated``:
+    the projection is ``[q | k | v | gate]``, the gate as wide as the
+    queries, and every head's output is multiplied by ``sigmoid`` of its
+    gate before the output projection."""
 
     def __init__(self, in_units, num_heads, num_kv_heads, head_dim=128,
-                 block=1024, rope_theta=None, **kwargs):
+                 block=1024, rope_theta=None, rotary_dim=None,
+                 qk_norm=False, gated=False, epsilon=1e-6,
+                 norm_unit_offset=False, **kwargs):
         super().__init__(**kwargs)
         self._attrs = {"num_heads": num_heads, "num_kv_heads": num_kv_heads,
                        "head_dim": head_dim, "block": block}
         if rope_theta is not None:
             self._attrs["rope_theta"] = float(rope_theta)
+        if rotary_dim is not None:
+            self._attrs["rotary_dim"] = int(rotary_dim)
+        if gated:
+            self._attrs["gated"] = True
+        if qk_norm:
+            self._attrs.update(eps=epsilon,
+                               unit_offset=bool(norm_unit_offset))
+        rows = (num_heads * (2 if gated else 1) + 2 * num_kv_heads) \
+            * head_dim
         with self.name_scope():
-            self.qkv_weight = self.params.get(
-                "qkv_weight",
-                shape=((num_heads + 2 * num_kv_heads) * head_dim, in_units))
+            self.qkv_weight = self.params.get("qkv_weight",
+                                              shape=(rows, in_units))
             self.o_weight = self.params.get(
                 "o_weight", shape=(in_units, num_heads * head_dim))
+            if qk_norm:
+                init = "zeros" if norm_unit_offset else "ones"
+                self.q_norm_weight = self.params.get(
+                    "q_norm_weight", shape=(head_dim,), init=init)
+                self.k_norm_weight = self.params.get(
+                    "k_norm_weight", shape=(head_dim,), init=init)
 
-    def hybrid_forward(self, F, x, qkv_weight, o_weight):
+    def hybrid_forward(self, F, x, qkv_weight, o_weight, q_norm_weight=None,
+                       k_norm_weight=None):
         # the two products beside the attention's own scope
         with jax.named_scope("mx_attn_proj"):
             qkv = F.FullyConnected(x, qkv_weight, no_bias=True,
                                    flatten=False)
-        out = F.CausalGQAttention(qkv, **self._attrs)
+        norms = () if q_norm_weight is None else (q_norm_weight,
+                                                  k_norm_weight)
+        out = F.CausalGQAttention(qkv, *norms, **self._attrs)
         with jax.named_scope("mx_attn_proj"):
             return F.FullyConnected(out, o_weight, no_bias=True,
                                     flatten=False)

@@ -1,5 +1,6 @@
 """Sequence-model operators: RMSNorm, the Mamba-2 mixer (the chunked
-state-space scan, SSD), a mixture of experts that is told which experts
+state-space scan, SSD), the Gated DeltaNet mixer (the gated delta rule in
+chunks), a mixture of experts that is told which experts
 it holds (one routing path, two expert bodies: relu2 in a latent, or
 gated SiLU on the full hidden vector, its grouped products this repo's
 kernels where the program is lowered for a TPU, ``ops.gmm_kernel``),
@@ -17,8 +18,8 @@ rest; ``tests/test_seq_ops.py`` adds the shares up to the uncut layer.
 The step's device time is a function of shapes alone: the expert layer's
 receive buffer is static and every row of it is computed, filled or not.
 
-Named scopes (``mx_norm``, ``mx_mamba_proj``, ``mx_ssd_*``, ``mx_moe_*``,
-``mx_attn_*``, ``mx_mla_*``, ``mx_rope``, ``mx_gated_mlp``,
+Named scopes (``mx_norm``, ``mx_mamba_proj``, ``mx_ssd_*``, ``mx_gdn_*``,
+``mx_moe_*``, ``mx_attn_*``, ``mx_mla_*``, ``mx_rope``, ``mx_gated_mlp``,
 ``mx_exit_head``, ``mx_exit_gate``) mark each mechanism in the compiled
 program, and each operator's registration lists its own;
 ``telemetry.trace.scope_table`` maps the program's instructions back to
@@ -37,7 +38,9 @@ the length), the threshold of the routing's choice, what the dispatch's
 sort gave, the convolution's, the scan's and the attention's outputs,
 the attention's log-sum-exp a row where its kernels run, and a norm's sum
 of squares. Activations, gates, decay masks, casts,
-rotated heads and the scaled rows of a norm are computed again. An
+rotated heads, the scaled rows of a norm and the whole of the delta rule
+(its chain's backward wants every chunk's entering state) are computed
+again. An
 exit's logits are never held: each exit's head and cross entropy is a
 unit of its own that keeps its hidden state (``exit_weighted_ce``).
 """
@@ -74,19 +77,24 @@ def _relu2(x):
 # RMSNorm
 # ---------------------------------------------------------------------------
 @register_op("RMSNorm", names_its_parts=True)
-def rms_norm(data, gamma, eps=1e-5, num_groups=1, keep_input=False, **kw):
+def rms_norm(data, gamma, eps=1e-5, num_groups=1, keep_input=False,
+             unit_offset=False, **kw):
     """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis, or over
     each of ``num_groups`` equal slices of it; computed in float32.
     ``keep_input``: the unit around this norm holds its input (a norm
-    *after* a sublayer reads that sublayer's last product). Scope
+    *after* a sublayer reads that sublayer's last product).
+    ``unit_offset``: the scale is ``1 + gamma``, a weight that starts at
+    zero (the ``qwen3_next`` family's norms). Scope
     ``mx_norm``; the two norms that are a part of another mechanism (the
     Mamba-2 gate's, the key/value latent's) are ``_rms_norm`` inside
     that mechanism's scope."""
     with jax.named_scope("mx_norm"):
-        return _rms_norm(data, gamma, eps, num_groups, keep_input)
+        return _rms_norm(data, gamma, eps, num_groups, keep_input,
+                         unit_offset)
 
 
-def _rms_norm(data, gamma, eps=1e-5, num_groups=1, keep_input=False):
+def _rms_norm(data, gamma, eps=1e-5, num_groups=1, keep_input=False,
+              unit_offset=False):
     if keep_input:
         data = kept(data)
     shape = data.shape
@@ -94,7 +102,10 @@ def _rms_norm(data, gamma, eps=1e-5, num_groups=1, keep_input=False):
     x = data.astype(_F32).reshape(shape[:-1] + (g, shape[-1] // g))
     # the reduction's result, a float a row, is kept; the scaling is not
     x = x * lax.rsqrt(kept(jnp.mean(jnp.square(x), -1, keepdims=True) + eps))
-    return (x.reshape(shape) * gamma.astype(_F32)).astype(data.dtype)
+    x = x.reshape(shape)
+    if unit_offset:
+        return (x * (1.0 + gamma.astype(_F32))).astype(data.dtype)
+    return (x * gamma.astype(_F32)).astype(data.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -102,14 +113,16 @@ def _rms_norm(data, gamma, eps=1e-5, num_groups=1, keep_input=False):
 # ---------------------------------------------------------------------------
 def causal_conv1d(x, weight, bias):
     """Depthwise causal convolution over time. ``x``: (B, L, C);
-    ``weight``: (C, K), its last tap on the current step; ``bias``: (C,)."""
+    ``weight``: (C, K), its last tap on the current step; ``bias``: (C,),
+    or None for a convolution without one."""
     k = weight.shape[1]
     length = x.shape[1]
     pad = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     w = weight.astype(x.dtype)
-    out = bias.astype(x.dtype)
+    out = None if bias is None else bias.astype(x.dtype)
     for j in range(k):
-        out = out + pad[:, j:j + length, :] * w[:, j]
+        tap = pad[:, j:j + length, :] * w[:, j]
+        out = tap if out is None else out + tap
     return out
 
 
@@ -210,6 +223,157 @@ def mamba2_mixer(data, in_proj_weight, conv_weight, conv_bias, dt_bias,
         y = _rms_norm(y, norm_weight, eps=eps, num_groups=g)
     with jax.named_scope("mx_mamba_proj"):
         return _mm(y.astype(data.dtype), out_proj_weight)
+
+
+# ---------------------------------------------------------------------------
+# Gated DeltaNet
+# ---------------------------------------------------------------------------
+def _l2_norm(x, eps):
+    """``x / sqrt(sum(x^2) + eps)`` over the last axis, float32."""
+    x = x.astype(_F32)
+    return x * lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + eps)
+
+
+def gated_delta_rule(q, k, v, beta, g, chunk=64):
+    """The gated delta rule from a zero state, ``S' = exp(g_t) S_{t-1}``,
+    ``u_t = beta_t (v_t - S'^T k_t)``, ``S_t = S' + k_t u_t^T``, ``o_t =
+    S_t^T q_t``, in chunks. What a step writes depends on what the state
+    holds for its key, so the steps of a chunk are tied by a unit lower
+    triangular system: with ``G_i`` the sum of ``g`` up to step ``i`` of
+    the chunk, ``A_ij = beta_i (k_i . k_j) exp(G_i - G_j)`` for ``j < i``
+    and ``(I + A) [W | U] = [beta k exp(G) | beta v]`` solved by forward
+    substitution (``lax.linalg.triangular_solve``), a chunk entered with
+    state ``S`` writes ``V' = U - W S``, reads ``O = (q exp(G)) S +
+    tril[(q_i . k_j) exp(G_i - G_j)] V'`` and leaves ``exp(G_last) S + (k
+    exp(G_last - G))^T V'``. The system and every product that does not
+    read the state are formed for all chunks at once; the chunks are then
+    chained by a ``lax.scan`` that carries the state.
+
+    ``q``, ``k``: (B, L, G, N), as the rule reads them (normalised, ``q``
+    scaled); ``v``: (B, L, H, P) with ``H % G == 0``, key head ``j``
+    serving value heads ``j H/G`` to ``(j + 1) H/G - 1``; ``beta``, ``g``:
+    (B, L, H) float32, ``g <= 0``. The decays, the solve, the state and
+    every sum in float32, the products' operands in ``v``'s dtype. Any
+    ``L``: the tail is padded with steps of ``beta = 0`` and ``g = 0``,
+    which write nothing and decay nothing. Returns (B, L, H, P) float32."""
+    bsz, length, h, p = v.shape
+    gk, n = k.shape[2], k.shape[3]
+    r = h // gk
+    c = int(chunk)
+    pad = (-length) % c
+    if pad:
+        q, k, v, beta, g = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, beta, g))
+    nc = (length + pad) // c
+    dtype = v.dtype
+
+    def heads(t):       # (B, L, H) -> (B, nc, G, r, c)
+        return t.astype(_F32).reshape(bsz, nc, c, gk, r).transpose(
+            0, 1, 3, 4, 2)
+
+    def dot(spec, a, b):
+        return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                          preferred_element_type=_F32)
+
+    beta, cs = heads(beta), jnp.cumsum(heads(g), axis=-1)
+    q = q.reshape(bsz, nc, c, gk, n).transpose(0, 1, 3, 2, 4)
+    k = k.reshape(bsz, nc, c, gk, n).transpose(0, 1, 3, 2, 4)
+    v = v.reshape(bsz, nc, c, gk, r, p).transpose(0, 1, 3, 4, 2, 5)
+    seg = cs[..., :, None] - cs[..., None, :]           # (B,nc,G,r,i,j)
+    row = jnp.arange(c)[:, None]
+    col = jnp.arange(c)[None, :]
+    upto = jnp.exp(jnp.where(row >= col, seg, -jnp.inf))
+    below = jnp.where(row > col, upto, 0.0)
+    kk = dot("zcgin,zcgjn->zcgij", k, k)[:, :, :, None]
+    system = beta[..., None] * kk * below               # strictly lower
+    k32 = k.astype(_F32)[:, :, :, None]                 # (B,nc,G,1,c,N)
+    rhs = jnp.concatenate(
+        [k32 * (beta * jnp.exp(cs))[..., None],
+         v.astype(_F32) * beta[..., None]], axis=-1)
+    wu = lax.linalg.triangular_solve(system, rhs, left_side=True, lower=True,
+                                     unit_diagonal=True)
+    w, u = wu[..., :n], wu[..., n:]
+    inside = dot("zcgin,zcgjn->zcgij", q, k)[:, :, :, None] * upto
+    q_in = q.astype(_F32)[:, :, :, None] * jnp.exp(cs)[..., None]
+    to_end = jnp.exp(cs[..., -1:] - cs)                 # (B,nc,G,r,c)
+    k_out = k32 * to_end[..., None]
+    leave = jnp.exp(cs[..., -1])                        # (B,nc,G,r)
+
+    def one_chunk(state, xs):
+        w, u, inside, q_in, k_out, leave = xs
+        written = u - dot("zgrin,zgrnp->zgrip", w, state)
+        out = dot("zgrin,zgrnp->zgrip", q_in, state) \
+            + dot("zgrij,zgrjp->zgrip", inside, written)
+        state = leave[..., None, None] * state \
+            + dot("zgrin,zgrip->zgrnp", k_out, written)
+        return state, out
+
+    _, out = lax.scan(
+        one_chunk, jnp.zeros((bsz, gk, r, n, p), _F32),
+        tuple(jnp.moveaxis(t, 1, 0)
+              for t in (w, u, inside, q_in, k_out, leave)))
+    # (nc, B, G, r, c, P) -> (B, L, H, P)
+    return out.transpose(1, 0, 4, 2, 3, 5).reshape(bsz, nc * c, h, p)[
+        :, :length]
+
+
+@register_op("GatedDeltaNet", names_its_parts=True)
+def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, dt_bias,
+                    a_log, norm_weight, out_weight, num_k_heads=1,
+                    num_v_heads=1, key_dim=128, value_dim=128, chunk_size=64,
+                    eps=1e-6, **kw):
+    """The Gated DeltaNet mixer (linear attention by the gated delta
+    rule) over the ``num_k_heads`` key heads, ``key_dim`` wide, and the
+    ``num_v_heads`` value heads, ``value_dim`` wide, held here.
+
+    ``data``: (B, L, hidden). ``qkvz_weight``: (2 Hk key_dim + 2 Hv
+    value_dim, hidden), rows ``[q | k | v | z]`` grouped by part (a fixed
+    permutation of a layout that interleaves them by key head);
+    ``ba_weight``: (2 Hv, hidden), rows ``[b | a]``; ``conv_weight``: (2
+    Hk key_dim + Hv value_dim, K), a causal depthwise convolution
+    without bias over ``[q | k | v]``, not over the gate ``z``;
+    ``dt_bias``, ``a_log``: (Hv,); ``norm_weight``: (value_dim,), the
+    gated norm's, one for all heads; ``out_weight``: (hidden, Hv
+    value_dim).
+
+    ``[q | k | v] = silu(conv(.))``; a value head's ``beta =
+    sigmoid(b)`` and log decay ``g = -exp(a_log) softplus(a + dt_bias)``
+    in float32; ``q`` and ``k`` L2-normalised over a head, ``q`` scaled by
+    ``key_dim ** -0.5``; ``gated_delta_rule`` in chunks of
+    ``chunk_size``; ``rmsnorm(o) * norm_weight * silu(z)`` a head in
+    float32; the output product. Scopes: ``mx_gdn_proj`` (the three
+    products), ``mx_gdn_conv``, ``mx_gdn_rule`` (normalisation, solve and
+    chain), ``mx_gdn_gate``. A recomputation unit around it keeps both
+    input products; the convolution and the rule are computed again (the
+    chain's own backward wants every chunk's entering state, which no
+    unit holds between its passes).
+
+    Returns (B, L, hidden), this share's partial sum."""
+    hk, hv, dk, dv = int(num_k_heads), int(num_v_heads), int(key_dim), \
+        int(value_dim)
+    bsz, length, _ = data.shape
+    with jax.named_scope("mx_gdn_proj"):
+        qkvz = kept(_mm(data, qkvz_weight))
+        ba = kept(_mm(data, ba_weight))
+    conv = 2 * hk * dk + hv * dv
+    with jax.named_scope("mx_gdn_conv"):
+        qkv = jax.nn.silu(causal_conv1d(qkvz[..., :conv], conv_weight, None))
+    with jax.named_scope("mx_gdn_rule"):
+        beta = jax.nn.sigmoid(ba[..., :hv].astype(_F32))
+        g = -jnp.exp(a_log.astype(_F32)) * jax.nn.softplus(
+            ba[..., hv:].astype(_F32) + dt_bias.astype(_F32))
+        q, k = (_l2_norm(t.reshape(bsz, length, hk, dk), 1e-6)
+                for t in (qkv[..., :hk * dk], qkv[..., hk * dk:2 * hk * dk]))
+        v = qkv[..., 2 * hk * dk:].reshape(bsz, length, hv, dv)
+        o = gated_delta_rule((q * dk ** -0.5).astype(data.dtype),
+                             k.astype(data.dtype), v, beta, g, chunk_size)
+    with jax.named_scope("mx_gdn_gate"):
+        z = qkvz[..., conv:].astype(_F32).reshape(bsz, length, hv, dv)
+        y = _rms_norm(o, norm_weight, eps=eps) * jax.nn.silu(z)
+    with jax.named_scope("mx_gdn_proj"):
+        return _mm(y.reshape(bsz, length, hv * dv).astype(data.dtype),
+                   out_weight)
 
 
 # ---------------------------------------------------------------------------
@@ -355,18 +519,20 @@ _pooled_kernels.defvjp(_pooled_kernels_fwd, _pooled_kernels_bwd)
 
 
 def route(scores_in, router_weight, router_bias, top_k, scaling,
-          norm_topk=True):
-    """Sigmoid scores over every expert of the model, the ``top_k`` by
-    ``score + bias`` chosen, the chosen scores normalised to sum 1 and
-    scaled. Returns ``(gate (T, E_all) float32, zero where not chosen;
-    chosen (T, E_all) bool)``. Ties at the ``top_k``-th place are all
-    taken (between floats they do not occur)."""
+          norm_topk=True, scoring="sigmoid"):
+    """Scores over every expert of the model (``scoring``: each expert's
+    ``sigmoid``, or a ``softmax`` over all of them, float32), the
+    ``top_k`` by ``score + bias`` chosen, the chosen scores normalised to
+    sum 1 and scaled. Returns ``(gate (T, E_all) float32, zero where not
+    chosen; chosen (T, E_all) bool)``. Ties at the ``top_k``-th place are
+    all taken (between floats they do not occur)."""
     with jax.named_scope("mx_moe_score"):
         logits = kept(lax.dot_general(
             scores_in, router_weight, (((1,), (1,)), ((), ())),
             preferred_element_type=_F32))
     with jax.named_scope("mx_moe_route"):
-        s = jax.nn.sigmoid(logits)
+        s = {"sigmoid": jax.nn.sigmoid,
+             "softmax": jax.nn.softmax}[scoring](logits)
         biased = s + router_bias.astype(_F32)
         # the threshold alone stands for the choice: a unit that keeps it
         # (T floats) finds gate and mask again without a second top_k
@@ -529,8 +695,9 @@ def _moe_stats(load, count, cap, counters):
 @register_op("GatedMoE", num_outputs=3, names_its_parts=True)
 def gated_moe(data, router_weight, router_bias, w1, w3, w2,
               shared_gate_up_weight, shared_down_weight, counters=None,
-              expert_ids=(0,), top_k=1, buffer_rows=0, scaling=1.0,
-              norm_topk=True, bias_rate=0.0, **kw):
+              shared_gate_weight=None, expert_ids=(0,), top_k=1,
+              buffer_rows=0, scaling=1.0, norm_topk=True, bias_rate=0.0,
+              scoring="sigmoid", **kw):
     """A mixture of gated experts on the full hidden vector, for the
     experts held here: ``latent_moe``'s router, combine, counters and
     balancing step (one copy of each: ``route``, ``_combine``,
@@ -548,18 +715,25 @@ def gated_moe(data, router_weight, router_bias, w1, w3, w2,
     held experts' pairs together outnumber its rows; the counters read
     the pool (``buffer_fill`` its filled share). The shared experts are
     one gated MLP, ``shared_gate_up_weight`` (2 ff_s, hidden) and
-    ``shared_down_weight`` (hidden, ff_s), as ``gated_mlp`` takes them.
+    ``shared_down_weight`` (hidden, ff_s), as ``gated_mlp`` takes them;
+    with ``shared_gate_weight`` (1, hidden) a token's share of them is
+    ``sigmoid(u . w)``, a gate of their own. ``scoring``: ``route``'s.
     Arguments, counters and returns as ``latent_moe``'s."""
     bsz, length, hidden = data.shape
     u = data.reshape(bsz * length, hidden)
     gate_all, chosen_all = route(u, router_weight, router_bias, top_k,
-                                 scaling, norm_topk)
+                                 scaling, norm_topk, scoring)
     buf, token, row_gate, load, count, sizes = _dispatch_pooled(
         u, gate_all, chosen_all, expert_ids, buffer_rows)
     routed = _combine(pooled_gated_product(buf, w1, w3, w2, sizes), row_gate,
                       token, u.shape[0])
     with jax.named_scope("mx_moe_shared"):
         shared = gated_mlp(u, shared_gate_up_weight, shared_down_weight)
+        if shared_gate_weight is not None:
+            open_ = jax.nn.sigmoid(lax.dot_general(
+                u, shared_gate_weight, (((1,), (1,)), ((), ())),
+                preferred_element_type=_F32))
+            shared = (shared.astype(_F32) * open_).astype(shared.dtype)
     # one pool: the held experts' pairs together against all its rows
     stats = _moe_stats(load, jnp.sum(count)[None], int(buffer_rows),
                        counters)
@@ -587,13 +761,19 @@ def _attention_block(q, k, v, bias, scale):
 
 
 @register_op("RoPE", names_its_parts=True)
-def rope(data, theta=10000.0, **kw):
+def rope(data, theta=10000.0, rotary_dim=None, **kw):
     """Rotary position encoding over the whole head, in the
     ``rotate_half`` convention: with ``x = [x1 | x2]`` the two halves of
     a head, position ``t`` and ``angle_i = t * theta^(-2i/D)`` for ``i <
-    D/2``, ``[x1 cos - x2 sin | x2 cos + x1 sin]``. ``data``: (B, L, H,
-    D), position = index along ``L``; the angles and the rotation in
-    float32, the result in ``data``'s dtype."""
+    D/2``, ``[x1 cos - x2 sin | x2 cos + x1 sin]``. With ``rotary_dim``
+    below ``D`` the head's first ``rotary_dim`` elements are rotated so,
+    as a head of that width, and the rest go through as they are.
+    ``data``: (B, L, H, D), position = index along ``L``; the angles and
+    the rotation in float32, the result in ``data``'s dtype."""
+    if rotary_dim is not None and int(rotary_dim) < data.shape[-1]:
+        r = int(rotary_dim)
+        return jnp.concatenate([rope(data[..., :r], theta), data[..., r:]],
+                               axis=-1)
     length, d = data.shape[1], data.shape[-1]
     half = d // 2
     with jax.named_scope("mx_rope"):
@@ -709,21 +889,34 @@ _fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
 
 
 @register_op("CausalGQAttention", names_its_parts=True)
-def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
-                        block=1024, scale=None, rope_theta=None, **kw):
+def causal_gq_attention(data, q_norm_weight=None, k_norm_weight=None,
+                        num_heads=1, num_kv_heads=1, head_dim=128,
+                        block=1024, scale=None, rope_theta=None,
+                        rotary_dim=None, gated=False, eps=1e-6,
+                        unit_offset=False, **kw):
     """Causal attention over packed ``[q | k | v]`` rows, ``num_heads``
     query heads sharing ``num_kv_heads`` key/value heads; with
     ``rope_theta`` the queries and keys are rotated by their position
-    first (``rope``), without it there is no positional encoding. The
+    first (``rope``; over a head's first ``rotary_dim`` elements alone
+    where that is given), without it there is no positional encoding. The
     scores and every sum in float32, the probabilities in ``data``'s
     dtype for the weighted sum, scale ``head_dim ** -0.5`` unless given.
+
+    With ``q_norm_weight`` and ``k_norm_weight`` (head_dim,) every query
+    head and every key head goes through an RMSNorm of its own width
+    before the rotation (``eps``, ``unit_offset``: ``rms_norm``'s; scope
+    ``mx_attn_qk_norm``). ``gated``: the rows are ``[q | k | v | gate]``,
+    the gate as wide as the queries, and each head's output is multiplied
+    by ``sigmoid`` of its gate, in float32 (scope ``mx_attn_gate``).
 
     Two forms of one recurrence (blocks of queries against the blocks of
     keys at or before them, a running maximum and denominator, blocks
     past the diagonal never formed), chosen by what the program can see,
     not by the caller. Where ``head_dim`` is a multiple of 128 and the
-    program is lowered for a TPU, one fused kernel forward and two
-    backward (``ops.attn_kernel``): no block of scores reaches memory,
+    program is lowered for a TPU, one fused kernel forward and, backward,
+    one fused kernel or, where a group's float32 ``dQ`` over all the rows
+    would not fit in VMEM (``attn_kernel.resident_bytes``), two by side
+    (``ops.attn_kernel``): no block of scores reaches memory,
     the heads are read where the projection wrote them, grouped heads
     share keys and values through the block index, and the block size is
     the kernel's (``attn_kernel.block_size``; ``block`` is not read).
@@ -734,31 +927,44 @@ def causal_gq_attention(data, num_heads=1, num_kv_heads=1, head_dim=128,
 
     A recomputation unit around it keeps the packed rows, the output and,
     where ``head_dim`` is a multiple of 128, one float32 log-sum-exp a
-    row; the rotation is computed again, the kernel's forward is not.
+    row; the head norms, the rotation and the output's gating are
+    computed again, the kernel's forward is not.
     (Heads whose queries and keys are wider than their values and share a
     part of the key go through the same ``_fused_attention`` with a
     second score part: ``latent_attention``.)
 
-    ``data``: (B, L, (num_heads + 2 num_kv_heads) * head_dim). Returns
-    (B, L, num_heads * head_dim)."""
+    ``data``: (B, L, (num_heads + 2 num_kv_heads) * head_dim), and
+    ``num_heads * head_dim`` more where ``gated``. Returns (B, L,
+    num_heads * head_dim)."""
     hq, hk, dh = int(num_heads), int(num_kv_heads), int(head_dim)
     bsz, length, _ = data.shape
     data = kept(data)       # the projection's output, named where it is read
     q = data[..., :hq * dh].reshape(bsz, length, hq, dh)
     k = data[..., hq * dh:(hq + hk) * dh].reshape(bsz, length, hk, dh)
-    v = data[..., (hq + hk) * dh:].reshape(bsz, length, hk, dh)
+    v = data[..., (hq + hk) * dh:(hq + 2 * hk) * dh].reshape(
+        bsz, length, hk, dh)
+    if q_norm_weight is not None:
+        with jax.named_scope("mx_attn_qk_norm"):
+            q = _rms_norm(q, q_norm_weight, eps, unit_offset=unit_offset)
+            k = _rms_norm(k, k_norm_weight, eps, unit_offset=unit_offset)
     if rope_theta is not None:
-        q, k = rope(q, rope_theta), rope(k, rope_theta)
+        q, k = (rope(t, rope_theta, rotary_dim) for t in (q, k))
     blk = min(int(block), length)
     scale = scale if scale is not None else dh ** -0.5
     if dh % 128 == 0:
-        return _fused_attention(
+        out = _fused_attention(
             q.reshape(bsz, length, hq * dh), k.reshape(bsz, length, hk * dh),
             v.reshape(bsz, length, hk * dh), None, hq, hk, float(scale), blk)
-    k = jnp.repeat(k, hq // hk, axis=2)
-    v = jnp.repeat(v, hq // hk, axis=2)
-    out = _blocked_attention(q, k, v, blk, scale)
-    return kept(out.reshape(bsz, length, hq * dh).astype(data.dtype))
+    else:
+        k = jnp.repeat(k, hq // hk, axis=2)
+        v = jnp.repeat(v, hq // hk, axis=2)
+        out = _blocked_attention(q, k, v, blk, scale)
+        out = kept(out.reshape(bsz, length, hq * dh).astype(data.dtype))
+    if gated:
+        with jax.named_scope("mx_attn_gate"):
+            gate = jax.nn.sigmoid(data[..., (hq + 2 * hk) * dh:].astype(_F32))
+            out = (out.astype(_F32) * gate).astype(out.dtype)
+    return out
 
 
 # ---------------------------------------------------------------------------

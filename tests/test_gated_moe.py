@@ -261,3 +261,158 @@ def test_latent_moe_keeps_its_names_and_its_scopes():
                   "mx_moe_shared"):
         assert scope in text, scope
     assert "mx_moe_latent" not in text
+
+
+# -- a softmax router and a shared expert behind a gate of its own ------------
+QSZ = {"hidden_size": 32, "moe_intermediate_size": 24,
+       "shared_expert_intermediate_size": 20, "router_experts": 8,
+       "num_experts": 8, "num_experts_per_tok": 3, "norm_topk_prob": True,
+       "reference_row_block": 16}
+
+
+def _qwen_reference():
+    return harness.load_module(os.path.join(
+        ROOT, "benchmark", "configs", "qwen3-next-80b-a3b.py"))
+
+
+def _qwen_weights(seed=0):
+    d, ff, fs = 32, 24, 20
+    shapes = {"router_weight": (8, d), "w1": (8, d, ff), "w3": (8, d, ff),
+              "w2": (8, ff, d), "shared_gate_up_weight": (2 * fs, d),
+              "shared_down_weight": (d, fs), "shared_gate_weight": (1, d)}
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
+    return {k: 0.3 * jax.random.normal(key, s, jnp.float32)
+            for key, (k, s) in zip(keys, shapes.items())}
+
+
+def _qwen_layer(w, x, ids, counters=None):
+    held = jnp.asarray(ids)
+    return seq.gated_moe(
+        x, w["router_weight"], jnp.zeros((8,)), w["w1"][held], w["w3"][held],
+        w["w2"][held], w["shared_gate_up_weight"], w["shared_down_weight"],
+        counters, w["shared_gate_weight"], expert_ids=tuple(ids), top_k=3,
+        buffer_rows=3 * TOKENS, norm_topk=True, scoring="softmax")
+
+
+def _qwen_ref_layer(w, x, ids=None):
+    sz = dict(QSZ) if ids is None else dict(QSZ, expert_ids=list(ids))
+    held = jnp.arange(8) if ids is None else jnp.asarray(ids)
+    p = {"l0_" + k: (v[held] if k in ("w1", "w3", "w2") else v)
+         for k, v in w.items()}
+    return _qwen_reference().moe_layer(sz, p, 0, x.reshape(-1, 32),
+                                       "float32")
+
+
+def test_softmax_router_takes_the_largest_and_normalises_them():
+    w = _qwen_weights(1)
+    u = jax.random.normal(jax.random.PRNGKey(2), (TOKENS, 32))
+    gate, chosen = seq.route(u, w["router_weight"], jnp.zeros((8,)), 3, 1.0,
+                             True, "softmax")
+    p = jax.nn.softmax(u @ w["router_weight"].T, axis=-1)
+    top = jnp.argsort(-p, axis=-1)[:, :3]
+    want = jnp.zeros_like(p).at[jnp.arange(TOKENS)[:, None], top].set(
+        jnp.take_along_axis(p, top, axis=-1))
+    np.testing.assert_allclose(gate, want / want.sum(-1, keepdims=True),
+                               rtol=1e-5)
+    assert (np.asarray(chosen).sum(-1) == 3).all()
+    np.testing.assert_allclose(gate.sum(-1), 1.0, rtol=1e-5)
+    ref_gate, ref_chosen = _qwen_reference().router(
+        QSZ, {"l0_router_weight": w["router_weight"]}, 0, u, "float32")
+    np.testing.assert_allclose(gate, ref_gate, rtol=1e-5)
+    np.testing.assert_array_equal(chosen, ref_chosen)
+    # the sigmoid router of the same weights chooses by other scores
+    other, _ = seq.route(u, w["router_weight"], jnp.zeros((8,)), 3, 1.0, True)
+    assert float(jnp.max(jnp.abs(other - gate))) > 1e-3
+
+
+def test_shared_expert_goes_through_a_gate_of_its_own():
+    w = _qwen_weights(3)
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, TOKENS, 32))
+    none = dict(w, w2=jnp.zeros_like(w["w2"]))      # the routed part zero
+    with jax.default_matmul_precision("highest"):
+        got = _qwen_layer(none, x, range(8))[0][0]
+        mlp = seq.gated_mlp(x, w["shared_gate_up_weight"],
+                            w["shared_down_weight"])[0]
+        want = jax.nn.sigmoid(x[0] @ w["shared_gate_weight"].T) * mlp
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def test_qwen_shares_add_up_to_the_uncut_layer():
+    """The routed parts of 4 shares of 2 of 8 experts (ids 0-1, 2-3, 4-5,
+    6-7, each with its shared expert's weights zero), plus the gated
+    shared expert once, are the reference's layer over all 8 experts."""
+    w = _qwen_weights(5)
+    x = jax.random.normal(jax.random.PRNGKey(6), (1, TOKENS, 32))
+    no_shared = dict(w, shared_down_weight=jnp.zeros_like(
+        w["shared_down_weight"]))
+    with jax.default_matmul_precision("highest"):
+        want = _qwen_ref_layer(w, x)
+        total = jnp.zeros((TOKENS, 32))
+        held_pairs = 0.0
+        for share in range(4):
+            ids = [2 * share, 2 * share + 1]
+            out, stats, _ = _qwen_layer(no_shared, x, ids)
+            assert float(stats[1]) == 0          # no pair beyond the pool
+            held_pairs += float(stats[0])
+            total = total + out[0]
+            # one share is its own reference's share too
+            np.testing.assert_allclose(
+                out[0], _qwen_ref_layer(no_shared, x, ids), atol=2e-5)
+        whole = dict(w, w2=jnp.zeros_like(w["w2"]))
+        total = total + _qwen_layer(whole, x, range(8))[0][0]
+    np.testing.assert_allclose(total, want, atol=5e-5)
+    # every (token, expert) pair was held by exactly one share
+    assert held_pairs == TOKENS * 3
+
+
+def test_qwen_layer_s_gradients_are_the_reference_s():
+    w = _qwen_weights(7)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, TOKENS // 2, 32))
+    cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    ids = (1, 4, 6)
+    held = jnp.asarray(ids)
+
+    def got(w, x):
+        return jnp.sum(_qwen_layer(w, x, list(ids))[0] * cot)
+
+    def want(w, x):
+        return jnp.sum(_qwen_ref_layer(w, x, ids).reshape(x.shape) * cot)
+
+    with jax.default_matmul_precision("highest"):
+        a = jax.grad(got, (0, 1))(w, x)
+        b = jax.grad(want, (0, 1))(w, x)
+    for name in w:
+        ga, gb = a[0][name], b[0][name]
+        if name in ("w1", "w3", "w2"):      # the held experts' alone
+            assert not np.asarray(ga)[np.setdiff1d(np.arange(8), ids)].any()
+            ga, gb = ga[held], gb[held]
+        np.testing.assert_allclose(ga, gb, atol=3e-5 * max(float(
+            jnp.max(jnp.abs(gb))), 1e-3), err_msg=name)
+    np.testing.assert_allclose(a[1], b[1], atol=3e-5)
+
+
+def test_block_s_options_leave_the_old_block_as_it_was():
+    old = nn.GatedMoE(32, 8, [0, 1], 3, 24, 20, 64)
+    assert "scoring" not in old._attrs
+    assert not any(n.endswith("shared_gate_weight")
+                   for n in old.collect_params())
+    new = nn.GatedMoE(32, 8, [0, 1], 3, 24, 20, 3 * TOKENS, scoring="softmax",
+                      shared_gate=True)
+    new.initialize(mx.init.Zero())
+    assert new._attrs["scoring"] == "softmax"
+    params = {n.split("_", 1)[1]: p for n, p in new.collect_params().items()}
+    assert params["shared_gate_weight"].shape == (1, 32)
+    rng = np.random.default_rng(2)
+    for name, p in params.items():
+        if name not in ("counters", "router_bias"):
+            p.set_data(mx.nd.array(0.3 * rng.normal(size=p.shape)
+                                   .astype(np.float32)))
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, TOKENS, 32))
+    w = {k: p.data()._data for k, p in params.items()}
+    want = seq.gated_moe(
+        x, w["router_weight"], w["router_bias"], w["w1"], w["w3"], w["w2"],
+        w["shared_gate_up_weight"], w["shared_down_weight"], None,
+        w["shared_gate_weight"], expert_ids=(0, 1), top_k=3,
+        buffer_rows=3 * TOKENS, scoring="softmax")[0]
+    np.testing.assert_allclose(new(mx.nd.array(x)).asnumpy(), want,
+                               rtol=1e-5, atol=1e-6)

@@ -2,11 +2,13 @@
 (``jax.named_scope("mx_...")``), and the table that puts them on a
 compiled program's instructions (``telemetry.trace.scope_table``).
 
-Small forms of the five steps the benchmark times (each cell at its
+Small forms of the steps the benchmark times (each cell at its
 rehearsal sizes, on the CPU): the scopes that existed before ISSUE 39
 keep their paths, the new ones name instructions, and the lowered text of
-every step, debug information stripped, is the text it had: scopes are
-metadata. Nothing here is a time."""
+every step that existed then, debug information stripped, is the text it
+had: scopes are metadata, and an option a later cell's layers brought
+(ISSUE 41's) changes nothing where it is not taken. Nothing here is a
+time."""
 import hashlib
 import os
 import re
@@ -150,6 +152,10 @@ def test_the_old_scopes_keep_their_paths(step):
     ("moonlight-16b-a3b-train-8k",
      ["mx_opt_update", "mx_loss", "mx_head/mx_dense", "mx_embed",
       "mx_norm"]),
+    ("qwen3-next-80b-a3b-train-8k",
+     ["mx_opt_update", "mx_loss", "mx_head/mx_dense", "mx_embed", "mx_norm",
+      "mx_attn_proj", "mx_gdn_proj", "mx_gdn_conv", "mx_gdn_rule",
+      "mx_gdn_gate", "mx_attn_qk_norm", "mx_attn_gate"]),
     ("resnet50-train",
      ["mx_opt_update", "mx_loss", "mx_metric", "mx_op_Convolution",
       "mx_op_BatchNorm", "mx_op_Activation", "mx_op_Pooling",
@@ -158,6 +164,35 @@ def test_the_old_scopes_keep_their_paths(step):
 def test_the_new_scopes_name_instructions(cell, scopes):
     paths = set(_step_of(cell)[1].values())
     assert not [s for s in scopes if s not in paths]
+
+
+#: the scopes ISSUE 41 added (a delta-rule layer's four parts, the gated
+#: attention's head norms and gate), and the scopes its cell shares with
+#: the older cells, whose paths are theirs
+ADDED_41 = {"mx_gdn_proj", "mx_gdn_conv", "mx_gdn_rule", "mx_gdn_gate",
+            "mx_attn_qk_norm", "mx_attn_gate"}
+SHARED_41 = {"mx_attn_fwd", "mx_rope", "mx_moe_combine", "mx_moe_dispatch",
+             "mx_moe_gmm_down", "mx_moe_gmm_up", "mx_moe_route",
+             "mx_moe_score", "mx_moe_shared", "mx_moe_shared/mx_gated_mlp"}
+
+
+def test_the_newest_cell_s_scopes_stand_beside_the_shared_ones():
+    """``qwen3-next-80b-a3b-train-8k`` has no parent to be compared with:
+    the scopes it shares with the older cells have the paths the accepted
+    readers match there, its own scopes enclose none of them and stand
+    inside none, and no older cell's step holds one of its scopes."""
+    paths = set(_step_of("qwen3-next-80b-a3b-train-8k")[1].values())
+    old = {p for p in paths if not any(
+        _is_new(s) or s in ADDED_41 for s in p.split("/"))}
+    assert old == SHARED_41
+    for path in paths:
+        parts = path.split("/")
+        if ADDED_41 & set(parts):
+            assert set(parts) <= ADDED_41, path
+    assert ADDED_41 <= paths
+    for name in PARENT:
+        assert not [p for p in _step_of(name)[1].values()
+                    if ADDED_41 & set(p.split("/"))], name
 
 
 def _rnn_then_fc():
@@ -177,9 +212,9 @@ def test_an_operator_that_names_its_parts_gets_no_operator_scope():
     operator's registration says that it names its own parts:
     ``mx_op_RNN`` would enclose ``mx_rnn_scan``."""
     from mxnet_tpu.ops import registry
-    for op in ("RNN", "RMSNorm", "Mamba2Mixer", "LatentMoE", "GatedMoE",
-               "CausalGQAttention", "LatentAttention", "GatedMLP",
-               "RoPE", "ExitGate"):
+    for op in ("RNN", "RMSNorm", "Mamba2Mixer", "GatedDeltaNet", "LatentMoE",
+               "GatedMoE", "CausalGQAttention", "LatentAttention",
+               "GatedMLP", "RoPE", "ExitGate"):
         assert registry.get_op(op).names_its_parts, op
     for op in ("Convolution", "BatchNorm", "FullyConnected", "Embedding"):
         assert not registry.get_op(op).names_its_parts, op

@@ -105,6 +105,21 @@ CASES = {
         attrs=dict(num_heads=2, head_dim=4, state_size=4, num_groups=1,
                    chunk_size=4),
         grad_args=[0, 1, 2, 3, 4, 5, 6, 7, 8], tol=(6e-2, 6e-3)),
+    "GatedDeltaNet": dict(
+        # ops/seq.py: 1 key head of 4 serving 2 value heads of 3, chunk 4
+        # over 6 steps (a padded tail); qkvz rows [q 4 | k 4 | v 6 | z 6],
+        # ba rows [b 2 | a 2], a 4-tap convolution over the 14 q, k, v
+        # channels; small weights so that the decays' exponentials and the
+        # chunk's triangular solve stay in FD's reach, and an eps under the
+        # gated norm's root that its small rows do not vanish beside (at
+        # 1e-6 the norm is too curved for a step of 1e-2)
+        inputs=[_signed((2, 6, 8), 0), 0.3 * _signed((20, 8), 1),
+                0.3 * _signed((4, 8), 2), 0.5 * _signed((14, 4), 3),
+                _signed((2,), 4), 0.3 * _signed((2,), 5), _pos((3,), 6),
+                _signed((8, 6), 7)],
+        attrs=dict(num_k_heads=1, num_v_heads=2, key_dim=4, value_dim=3,
+                   chunk_size=4, eps=1e-2),
+        tol=(6e-2, 6e-3)),
     "LatentMoE": dict(
         # ops/seq.py: 6 experts, 2 of 3 held, top-2, a buffer with room;
         # the choice is piecewise constant, so FD sees the smooth part
