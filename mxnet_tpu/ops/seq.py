@@ -1,6 +1,7 @@
 """Sequence-model operators: RMSNorm, the Mamba-2 mixer (the chunked
 state-space scan, SSD), the Gated DeltaNet mixer (the gated delta rule in
-chunks), a mixture of experts that is told which experts
+chunks, this repo's kernels where the program is lowered for a TPU,
+``ops.gdn_kernel``), a mixture of experts that is told which experts
 it holds (one routing path, two expert bodies: relu2 in a latent, or
 gated SiLU on the full hidden vector, its grouped products this repo's
 kernels where the program is lowered for a TPU, ``ops.gmm_kernel``),
@@ -54,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import attn_kernel, gmm_kernel
+from . import attn_kernel, gdn_kernel, gmm_kernel
 from .registry import register_op
 from .remat import kept
 
@@ -255,11 +256,34 @@ def gated_delta_rule(q, k, v, beta, g, chunk=64):
     (B, L, H) float32, ``g <= 0``. The decays, the solve, the state and
     every sum in float32, the products' operands in ``v``'s dtype. Any
     ``L``: the tail is padded with steps of ``beta = 0`` and ``g = 0``,
-    which write nothing and decay nothing. Returns (B, L, H, P) float32."""
+    which write nothing and decay nothing. Returns (B, L, H, P) float32.
+
+    Two forms of one algorithm, chosen by what the program can see, not by
+    the caller. Where the kernels' rule of shapes takes them
+    (``gdn_kernel.takes``: ``N`` and ``P`` whole lane tiles of 128, the
+    chunk whole sublane tiles and at most 128, one dtype for ``q``, ``k``
+    and ``v``, the blocks within VMEM) and the program is lowered for a
+    TPU, this repo's own kernels under one ``custom_vjp``
+    (``ops.gdn_kernel``): a head's state and a chunk's system and inverse
+    in VMEM, forward and backward, the heads read where the mixer wrote
+    them. Everywhere else (other shapes, another backend) the plain form
+    below, ``_solve_then_scan``, differentiated by JAX. The gauge
+    ``gdn::kernel_sites`` says which."""
+    n, p, c = k.shape[3], v.shape[3], int(chunk)
+    if q.dtype == k.dtype == v.dtype and gdn_kernel.takes(
+            n, p, c, v.dtype, v.shape[2] // k.shape[2]):
+        return _rule_kernels(q, k, v, beta, g, c)
+    return _solve_then_scan(q, k, v, beta, g, c)
+
+
+def _solve_then_scan(q, k, v, beta, g, chunk):
+    """``gated_delta_rule`` in plain JAX: the system and every product
+    that does not read the state for all chunks at once, the chunks then
+    chained by a ``lax.scan``."""
     bsz, length, h, p = v.shape
     gk, n = k.shape[2], k.shape[3]
     r = h // gk
-    c = int(chunk)
+    c = chunk
     pad = (-length) % c
     if pad:
         q, k, v, beta, g = (
@@ -318,6 +342,53 @@ def gated_delta_rule(q, k, v, beta, g, chunk=64):
         :, :length]
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule_kernels(q, k, v, beta, g, chunk):
+    """``gated_delta_rule`` whose program takes its form when it is
+    lowered: for a TPU the kernels of ``ops.gdn_kernel``, forward and
+    backward, for any other platform ``_solve_then_scan`` and JAX's own
+    derivative of it. Nothing of it is handed to a recomputation unit:
+    what the backward kernel reads of the forward (every chunk's entering
+    state and inverse) lives from the rule's forward to its backward
+    inside the unit's backward pass, one layer at a time."""
+    return _rule_kernels_fwd(q, k, v, beta, g, chunk)[0]
+
+
+def _rule_kernels_fwd(q, k, v, beta, g, chunk):
+    bsz, length, h, p = v.shape
+    n, chunks = k.shape[3], gdn_kernel.steps(length, chunk)[0] // chunk
+
+    def plain(*a):
+        # the kernels' kept values have no part in this form's derivative
+        return (_solve_then_scan(*a, chunk),
+                jnp.zeros((bsz, h, chunks, n, p), _F32),
+                jnp.zeros((bsz, h, chunks, chunk, chunk), _F32))
+
+    out, states, inverses = lax.platform_dependent(
+        q, k, v, beta, g,
+        tpu=lambda *a: gdn_kernel.forward(
+            attn_kernel.counted_site(a[0], gdn_kernel.GAUGE), *a[1:],
+            chunk=chunk),
+        default=plain)
+    return out, (q, k, v, beta, g, states, inverses)
+
+
+def _rule_kernels_bwd(chunk, res, d_out):
+    # no scope of its own: the backward rule carries the scope its forward
+    # was called under (``gated_delta_net``'s ``mx_gdn_rule``), and one
+    # opened here would file the kernel under ``mx_gdn_rule/mx_gdn_rule``,
+    # outside what ``^mx_gdn_rule$`` reads
+    return lax.platform_dependent(
+        *res, d_out,
+        tpu=lambda *a: gdn_kernel.backward(*a, chunk=chunk),
+        default=lambda q, k, v, beta, g, states, inverses, d_out:
+            jax.vjp(lambda *a: _solve_then_scan(*a, chunk),
+                    q, k, v, beta, g)[1](d_out))
+
+
+_rule_kernels.defvjp(_rule_kernels_fwd, _rule_kernels_bwd)
+
+
 @register_op("GatedDeltaNet", names_its_parts=True)
 def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, dt_bias,
                     a_log, norm_weight, out_weight, num_k_heads=1,
@@ -342,12 +413,18 @@ def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, dt_bias,
     in float32; ``q`` and ``k`` L2-normalised over a head, ``q`` scaled by
     ``key_dim ** -0.5``; ``gated_delta_rule`` in chunks of
     ``chunk_size``; ``rmsnorm(o) * norm_weight * silu(z)`` a head in
-    float32; the output product. Scopes: ``mx_gdn_proj`` (the three
-    products), ``mx_gdn_conv``, ``mx_gdn_rule`` (normalisation, solve and
-    chain), ``mx_gdn_gate``. A recomputation unit around it keeps both
+    float32; the output product. The rule is ``gated_delta_rule``'s two
+    forms: at heads that are whole lane tiles, in a program lowered for a
+    TPU, the kernels of ``ops.gdn_kernel`` (the heads read where the
+    convolution wrote them, a head's state and a chunk's system in VMEM),
+    everywhere else the solve and the scan in plain JAX. Scopes:
+    ``mx_gdn_proj`` (the three products), ``mx_gdn_conv``,
+    ``mx_gdn_rule`` (normalisation, decays and the rule, kernels or
+    plain), ``mx_gdn_gate``. A recomputation unit around it keeps both
     input products; the convolution and the rule are computed again (the
     chain's own backward wants every chunk's entering state, which no
-    unit holds between its passes).
+    unit holds between its passes: the kernels write them in the unit's
+    recomputation and read them in its backward).
 
     Returns (B, L, hidden), this share's partial sum."""
     hk, hv, dk, dv = int(num_k_heads), int(num_v_heads), int(key_dim), \

@@ -1,0 +1,248 @@
+"""The gated delta rule's kernels (``ops/gdn_kernel.py``) against
+``ops.seq.gated_delta_rule``'s plain form and JAX's own derivative of it,
+interpreted on the CPU; the rule of shapes they are taken by; and which
+form ``gated_delta_rule`` takes: the kernels where the rule takes the
+shapes and the program is lowered for a TPU, the plain form everywhere
+else, with the gauge ``gdn::kernel_sites`` counting the sites. Nothing
+here is a time."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu.ops import gdn_kernel, seq
+
+N = P = 128
+CHUNK = 16
+
+
+def _operands(length, dtype, group=1, bsz=1, seed=0, decay=1.0, n=N, p=P):
+    """``(q, k, v, beta, g)`` as the mixer hands them over: unit keys,
+    scaled unit queries, ``beta`` in (0, 1), ``g <= 0``; one key head,
+    ``group`` value heads."""
+    rng = np.random.default_rng(seed)
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    q = unit(rng.normal(size=(bsz, length, 1, n))) * n ** -0.5
+    k = unit(rng.normal(size=(bsz, length, 1, n)))
+    v = rng.normal(size=(bsz, length, group, p))
+    beta = 1 / (1 + np.exp(-rng.normal(size=(bsz, length, group))))
+    g = -rng.uniform(0, decay, size=(bsz, length, group))
+    return tuple(jnp.asarray(t, d) for t, d in zip(
+        (q, k, v, beta, g), (dtype, dtype, dtype, jnp.float32, jnp.float32)))
+
+
+def _plain(args, cot, chunk=CHUNK):
+    """The plain form's value and gradients, float32 products at full
+    precision."""
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(
+            lambda *a: seq._solve_then_scan(*a, chunk), *args)
+        return out, vjp(cot)
+
+
+def _kernels(args, cot, chunk=CHUNK):
+    out, states, inverses = gdn_kernel.forward(*args, chunk=chunk,
+                                               interpret=True)
+    return out, gdn_kernel.backward(*args, states, inverses, cot,
+                                    chunk=chunk, interpret=True)
+
+
+def _close(got, want, tol, name):
+    assert got.dtype == want.dtype and got.shape == want.shape, name
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    np.testing.assert_allclose(got, want, rtol=tol,
+                               atol=tol * np.abs(want).max(), err_msg=name)
+
+
+@pytest.mark.parametrize("length", [32, 40])    # whole chunks; a padded tail
+@pytest.mark.parametrize("group", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_kernels_are_the_plain_form_and_its_derivative(dtype, group, length):
+    """``out`` and the gradients for ``q``, ``k``, ``v``, ``beta`` and
+    ``g``: in float32 to 1e-5 of the largest value, in bfloat16 within the
+    rounding of one output. One grid step of two chunks, and one of three
+    whose last is half padding."""
+    args = _operands(length, jnp.dtype(dtype), group, seed=length + group)
+    cot = jnp.asarray(np.random.default_rng(9).normal(
+        size=(1, length, group, P)), jnp.float32)
+    want, want_d = _plain(args, cot)
+    got, got_d = _kernels(args, cot)
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -7
+    _close(got, want, tol, "out")
+    for name, a, b in zip("q k v beta g".split(), got_d, want_d):
+        _close(a, b, tol, "d" + name)
+
+
+def test_the_state_crosses_grid_steps_forward_and_backward():
+    """130 rows in chunks of 16 are two grid steps of eight chunks (the
+    second mostly padding): the state leaves the first step in the VMEM
+    scratch and ``dS`` comes back through it."""
+    assert gdn_kernel.steps(130, CHUNK) == (256, 128)
+    args = _operands(130, jnp.bfloat16, 1, seed=11, decay=0.1)
+    cot = jnp.asarray(np.random.default_rng(4).normal(
+        size=(1, 130, 1, P)), jnp.float32)
+    want, want_d = _plain(args, cot)
+    got, got_d = _kernels(args, cot)
+    _close(got, want, 2.0 ** -7, "out")
+    for name, a, b in zip("q k v beta g".split(), got_d, want_d):
+        _close(a, b, 2.0 ** -7, "d" + name)
+    # the second step's rows read what the first step wrote
+    assert float(jnp.max(jnp.abs(got[:, 128:]))) > 0
+
+
+def test_a_strong_decay_underflows_to_zero_and_not_to_nan():
+    """``g`` near -20 a step, -300 over a chunk: ``exp`` of differences
+    ``G_i - G_j <= 0`` only, never a quotient of two underflowed
+    numbers."""
+    args = _operands(40, jnp.float32, 2, seed=5, decay=25.0)
+    cot = jnp.ones((1, 40, 2, P), jnp.float32)
+    want, want_d = _plain(args, cot)
+    got, got_d = _kernels(args, cot)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    _close(got, want, 1e-5, "out")
+    for name, a, b in zip("q k v beta g".split(), got_d, want_d):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        _close(a, b, 1e-5, "d" + name)
+
+
+def test_a_second_sequence_does_not_see_the_first_one_s_state():
+    """The states' scratch is set to zero where a sequence begins: the
+    second sequence of a batch gives what it gives alone, to the bit."""
+    both = _operands(32, jnp.bfloat16, 1, bsz=2, seed=3)
+    alone = tuple(t[1:] for t in both)
+    cot = jnp.ones((2, 32, 1, P), jnp.float32)
+    got, got_d = _kernels(both, cot)
+    want, want_d = _kernels(alone, cot[1:])
+    np.testing.assert_array_equal(got[1:], want)
+    for a, b in zip(got_d, want_d):
+        np.testing.assert_array_equal(np.asarray(a[1:], np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_a_padded_tail_writes_nothing():
+    """The same first 20 outputs whether 20 steps are given (padded to
+    two chunks) or 32; beside the output, every chunk's entering state
+    and inverse for the backward kernel."""
+    args = _operands(32, jnp.float32, 1, seed=1)
+    whole, states, inverses = gdn_kernel.forward(*args, chunk=CHUNK,
+                                                 interpret=True)
+    short = gdn_kernel.forward(*(t[:, :20] for t in args), chunk=CHUNK,
+                               interpret=True)[0]
+    np.testing.assert_allclose(short, whole[:, :20], atol=1e-6)
+    assert states.shape == (1, 1, 2, N, P) and inverses.shape == (
+        1, 1, 2, CHUNK, CHUNK)
+    assert not np.asarray(states[:, :, 0]).any()    # from a zero state
+
+
+def test_every_level_of_the_inverse_at_the_cell_s_chunk():
+    """Chunks of 64, the Qwen3-Next cell's: diagonal blocks of 8 merged
+    three times, the matrices twice side by side in a lane tile."""
+    args = _operands(100, jnp.bfloat16, 2, seed=13, decay=0.05)
+    cot = jnp.asarray(np.random.default_rng(6).normal(
+        size=(1, 100, 2, P)), jnp.float32)
+    want, want_d = _plain(args, cot, 64)
+    got, got_d = _kernels(args, cot, 64)
+    _close(got, want, 2.0 ** -7, "out")
+    for name, a, b in zip("q k v beta g".split(), got_d, want_d):
+        _close(a, b, 2.0 ** -7, "d" + name)
+
+
+def test_the_rule_of_shapes_reads_shapes_alone():
+    """``N`` and ``P`` whole lane tiles, the chunk whole sublane tiles of
+    the dtype and at most 128, the blocks under the VMEM budget: the
+    Qwen3-Next cell's shapes are taken; heads that are no lane tile, a
+    chunk over 128 or off the tiles, and states that would not fit are
+    not."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert gdn_kernel.takes(128, 128, 64, bf16, group=2)
+    assert gdn_kernel.takes(256, 128, 64, bf16)
+    assert gdn_kernel.takes(128, 128, 128, bf16)
+    assert gdn_kernel.takes(128, 128, 8, f32)
+    assert not gdn_kernel.takes(128, 128, 8, bf16)      # half a bf16 tile
+    assert not gdn_kernel.takes(128, 128, 48, bf16)     # 8 doubled: no
+    assert not gdn_kernel.takes(128, 128, 256, bf16)
+    assert not gdn_kernel.takes(8, 128, 64, bf16)
+    assert not gdn_kernel.takes(128, 6, 64, bf16)
+    assert not gdn_kernel.takes(128, 192, 64, bf16)
+    assert not gdn_kernel.takes(128, 128, 64, jnp.int8)
+    assert not gdn_kernel.takes(128, 128, 64, jnp.float16)
+    assert not gdn_kernel.takes(1024, 1024, 64, bf16, group=4)
+    held = max(gdn_kernel.forward_bytes(128, 128, 64, 2, 2),
+               gdn_kernel.backward_bytes(128, 128, 64, 2, 2))
+    assert 3e6 < held < gdn_kernel._BUDGET_BYTES \
+        < gdn_kernel._VMEM_LIMIT_BYTES
+    # a long sequence in steps of whole lane tiles of rows, a short one in
+    # one step of all its chunks
+    assert gdn_kernel.steps(8192, 64) == (8192, 256)
+    assert gdn_kernel.steps(8200, 64) == (8448, 256)
+    assert gdn_kernel.steps(200, 64) == (256, 256)
+    assert gdn_kernel.steps(100, 64) == (128, 128)
+    assert gdn_kernel.steps(130, 16) == (256, 128)
+    assert gdn_kernel.steps(40, 16) == (48, 48)
+
+
+def _lowered(head, platform, dtype=jnp.bfloat16):
+    """The text of ``gated_delta_rule``'s value and gradients lowered for
+    ``platform`` at heads ``head`` wide, and what the gauge counted."""
+    args = _operands(32, dtype, 2, n=head, p=head)
+
+    def loss(*a):
+        return jnp.sum(seq.gated_delta_rule(*a, chunk=CHUNK) ** 2)
+
+    mx.telemetry.gauge(gdn_kernel.GAUGE).set(0)
+    text = jax.jit(jax.value_and_grad(loss, argnums=range(5))).trace(
+        *args).lower(lowering_platforms=(platform,)).as_text()
+    return text, mx.telemetry.gauge(gdn_kernel.GAUGE).get()
+
+
+@pytest.mark.parametrize("head,platform,sites,calls", [
+    (128, "tpu", 1, 2),     # the kernels: one forward, one backward
+    (128, "cpu", 0, 0),     # another platform: the plain form
+    (8, "tpu", 0, 0)])      # heads the rule of shapes refuses: the same
+def test_kernel_sites_follow_the_platform_and_the_rule_of_shapes(
+        head, platform, sites, calls):
+    text, counted = _lowered(head, platform)
+    assert counted == sites
+    assert text.count("tpu_custom_call") == calls
+    assert ("gdn_fwd_kernel" in text) == ("gdn_bwd_kernel" in text) \
+        == bool(calls)
+    # the plain form's chain of chunks is a loop; the kernels' is their grid
+    assert ("stablehlo.while" in text) == (calls == 0)
+
+
+def test_operands_of_two_dtypes_stay_the_plain_form():
+    """``q`` and ``k`` in another dtype than ``v`` are rounded by the plain
+    form where it multiplies them, not before: no kernel."""
+    q, k, v, beta, g = _operands(32, jnp.bfloat16, 2)
+    mx.telemetry.gauge(gdn_kernel.GAUGE).set(0)
+    text = jax.jit(lambda *a: seq.gated_delta_rule(*a, chunk=CHUNK)).trace(
+        q.astype(jnp.float32), k.astype(jnp.float32), v, beta, g).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in text
+    assert mx.telemetry.gauge(gdn_kernel.GAUGE).get() == 0
+
+
+def test_off_a_tpu_the_program_is_the_plain_form_to_the_bit():
+    """Where the rule takes the shapes and the platform is not a TPU,
+    value and gradients are ``_solve_then_scan``'s as JAX differentiates
+    it."""
+    args = _operands(40, jnp.bfloat16, 2, bsz=2, seed=7)
+    cot = jnp.asarray(np.random.default_rng(2).normal(
+        size=(2, 40, 2, P)), jnp.float32)
+
+    def through(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a) * cot), argnums=range(5)))(*args)
+
+    assert gdn_kernel.takes(N, P, CHUNK, jnp.bfloat16, 2)
+    got = through(lambda *a: seq.gated_delta_rule(*a, chunk=CHUNK))
+    want = through(lambda *a: seq._solve_then_scan(*a, CHUNK))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

@@ -1,0 +1,194 @@
+"""The step of ``qwen3-next-80b-a3b-train-8k`` compiled for a v5e that is
+described and not attached, at the sizes the cell times: Mosaic takes the
+gated delta rule's kernels (``ops/gdn_kernel.py``) at the cell's shapes
+(16 key heads, 32 value heads, 128 x 128, chunks of 64, bfloat16), forward
+and backward, three calls a linear-attention layer, each under the scope
+path ``mx_gdn_rule`` and no longer one; no ``triangular-solve`` expansion
+and no ``while`` is left for the rule; the step fits what one chip gives a
+program, and a delta-rule unit keeps what it kept before the kernels. The
+attention and grouped-product kernels are there by name and count as
+``tests/bench_harness/test_bench_qwen3_next_compile.py`` found them
+(that file compares the whole set of custom calls for equality and counts
+three ``while`` a linear layer, so it is red since the rule's kernels;
+ROADMAP D0 c). Nothing runs here, so nothing here is a time or a result.
+The topology is described inside a fixture only (one process at a time
+may load the TPU's library: the on-chip-measurement guide, section 2)."""
+import collections
+import os
+import re
+import sys
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+import harness  # noqa: E402
+
+CELL = "qwen3-next-80b-a3b-train-8k"
+#: what one v5e gives a program: ``bytes_limit`` of the device's memory
+#: statistics (a chip run of PR 41), 15.75 GiB
+CHIP_BYTES = 16_909_336_064
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compiled_step(one_chip):
+    """``(sizes, compiled, gauges, kept)``: the cell's step compiled for
+    the described chip from shapes alone, what the kernels' gauges
+    counted at its lowering, and the bytes each recomputation unit
+    keeps."""
+    from jax.experimental.compilation_cache import compilation_cache
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops import attn_kernel, gdn_kernel, gmm_kernel
+    from mxnet_tpu.parallel import TrainStep
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cell = harness.load_cell(CELL)
+        sizes = cell.sizes
+        net = cell.model._net(sizes)
+        net.initialize(mx.init.Zero())
+        opt = dict(cell.config["optimizer"])
+        step = TrainStep(net, loss="softmax_ce", optimizer=opt.pop("name"),
+                         optimizer_params=opt,
+                         compute_dtype=cell.config["compute_dtype"],
+                         remat="layer")
+
+        def spec(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        pvals = tuple(spec(p.shape) for p in step.param_list)
+        state = tuple((spec(p.shape),) * 2 if t else ()
+                      for p, t in zip(step.param_list, step._trainable))
+        tokens = sizes["batch"] * sizes["seq_len"]
+        step._build_step()
+        compiled = step._step_jit.lower(
+            pvals, state, spec((sizes["batch"], sizes["seq_len"]), jnp.int32),
+            spec((tokens,), jnp.int32), spec((), jnp.uint32),
+            spec(())).compile()
+        gauges = {g: mx.telemetry.gauge(g).get() for g in (
+            attn_kernel.GAUGE, attn_kernel.FUSED_BWD_GAUGE, gmm_kernel.GAUGE,
+            gdn_kernel.GAUGE)}
+        kept = {k.rsplit("::", 1)[1]: v["value"] for k, v in
+                mx.telemetry.snapshot(prefix="remat::saved_bytes::").items()}
+        return sizes, compiled, gauges, kept
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before)
+        compilation_cache.reset_cache()
+
+
+def _custom_calls(hlo):
+    """``{instruction name: kernel name}`` of a compiled program's Mosaic
+    calls."""
+    return {name: name.rsplit(".", 1)[0] for name in re.findall(
+        r'%?([\w.\-]+) = [^\n]*?custom_call_target="tpu_custom_call"', hlo)}
+
+
+def _linear_layers(sizes):
+    layers = sizes["num_hidden_layers"]
+    return layers - layers // sizes["full_attention_interval"]
+
+
+def test_mosaic_takes_the_rule_s_kernels_three_a_linear_layer(compiled_step):
+    """Forward, the unit's recomputation (whose states and inverses the
+    backward reads) and backward: six ``gdn_fwd_kernel`` and three
+    ``gdn_bwd_kernel`` for three layers of one shape, which share one
+    lowered program (the gauge reads 1)."""
+    from mxnet_tpu.ops import attn_kernel, gdn_kernel, gmm_kernel
+    sizes, compiled, gauges, _ = compiled_step
+    assert gauges == {attn_kernel.GAUGE: 1, attn_kernel.FUSED_BWD_GAUGE: 0,
+                      gmm_kernel.GAUGE: 1, gdn_kernel.GAUGE: 1}
+    calls = collections.Counter(_custom_calls(compiled.as_text()).values())
+    linear, layers = _linear_layers(sizes), sizes["num_hidden_layers"]
+    assert calls == {
+        "gdn_fwd_kernel": 2 * linear, "gdn_bwd_kernel": linear,
+        "attn_fwd_kernel": 1, "attn_bwd_dq_kernel": 1,
+        "attn_bwd_dkv_kernel": 1,
+        **{f"moe_gmm_{side}{part}_kernel": layers
+           for side in ("up", "down") for part in ("", "_rows", "_weights")}}
+
+
+def test_no_solve_and_no_loop_is_left_for_the_rule(compiled_step):
+    """The plain form's ``triangular_solve`` expansion and its three
+    ``while`` a linear layer (forward, recomputation, backward) are gone,
+    and nothing else in this step loops; no grouped product fell back to
+    XLA's either."""
+    _, compiled, _, _ = compiled_step
+    hlo = compiled.as_text()
+    assert not re.findall(r" while\(", hlo)
+    assert "triangular-solve" not in hlo and "triangular_solve" not in hlo
+    assert "ragged-dot" not in hlo
+
+
+def test_every_kernel_of_the_rule_is_under_mx_gdn_rule_and_no_longer_path(
+        compiled_step):
+    """``gdn_roofline.train`` reads ``^mx_gdn_rule$``: a call under
+    ``mx_gdn_rule/mx_gdn_rule`` (a scope opened twice) or under another
+    part's scope would stand outside it and flatter the rule."""
+    from mxnet_tpu.telemetry import trace
+    _, compiled, _, _ = compiled_step
+    hlo = compiled.as_text()
+    paths = trace.hlo_scopes(hlo, path=True)
+    mine = {name: paths.get(name) for name, kernel
+            in _custom_calls(hlo).items() if kernel.startswith("gdn_")}
+    assert len(mine) == 9
+    assert set(mine.values()) == {"mx_gdn_rule"}, mine
+    # and no instruction of the step has the scope twice in its path (what
+    # XLA fuses or copies across the rule's border with the gated norm
+    # carries both parts' names, ``mx_gdn_gate/mx_gdn_rule``: its choice)
+    assert not [p for p in set(paths.values())
+                if p.split("/").count("mx_gdn_rule") > 1]
+
+
+def test_step_fits_one_v5e_and_a_unit_keeps_nothing_of_the_rule(
+        compiled_step):
+    """625.7 M parameters with Adam's moments, 8192 tokens, recomputation
+    by layer: arguments, outputs and temporaries on one described v5e,
+    under the 14.5 GB the step had before the kernels; a delta-rule unit
+    keeps both input products and the gated norm's statistics, not the
+    convolution, not the rule's output, states or inverses."""
+    sizes, compiled, _, kept = compiled_step
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    print(f"qwen3-next-80b-a3b step: {peak / 1e9:.2f} GB "
+          f"({m.argument_size_in_bytes / 1e9:.2f} of state, "
+          f"{m.temp_size_in_bytes / 1e9:.2f} of temporaries), "
+          f"{sum(kept.values()) / 1e9:.3f} GB kept by {len(kept)} units")
+    hbm = harness.peaks_for("TPU v5 lite")["hbm_bytes"]
+    assert 0.25 * hbm < peak < 14.5e9 < CHIP_BYTES, peak
+    assert m.alias_size_in_bytes >= 0.99 * m.argument_size_in_bytes
+    tokens = sizes["batch"] * sizes["seq_len"]
+    (first,) = [v for k, v in kept.items() if k.endswith("_l0_")]
+    assert first == tokens * 2 * (12288 + 64) + tokens * 32 * 4 + tokens * 4
+
+
+def test_what_the_kernels_hold_in_vmem_is_under_their_budget():
+    """At the cell's shapes, by the module's own statement: the blocks
+    twice, the states' scratch and a block's values before the chain."""
+    from mxnet_tpu.ops import gdn_kernel
+    cell = harness.load_cell(CELL)
+    sz = cell.sizes
+    n, p = sz["linear_key_head_dim"], sz["linear_value_head_dim"]
+    group = sz["linear_num_value_heads"] // sz["linear_num_key_heads"]
+    assert (n, p, group) == (128, 128, 2)
+    assert gdn_kernel.takes(n, p, 64, jnp.bfloat16, group)
+    for held in (gdn_kernel.forward_bytes(n, p, 64, group, 2),
+                 gdn_kernel.backward_bytes(n, p, 64, group, 2)):
+        assert 2e6 < held < gdn_kernel._BUDGET_BYTES
