@@ -9,7 +9,10 @@ linear-attention layers: ``DFDFDF*F`` a period). Pre-norm
 residual throughout, ``x <- x + Mixer_l(RMSNorm_l(x))``, or with
 ``post_norm`` a norm on either side of the mixer, ``x <- x +
 RMSNorm'_l(Mixer_l(RMSNorm_l(x)))``; one final RMSNorm, an untied head,
-no bias but the convolution's.
+no bias but the convolution's. With ``residual_streams`` the residual is
+several streams a token and ``x <- H_res x + H_post^T Mixer_l(RMSNorm_l(
+H_pre x))``, the three maps computed from the streams by every layer
+(manifold-constrained hyper-connections).
 
 With ``loops`` above 1 the whole stack, final norm included, runs that
 many times over its own output with the same weights, as one scanned body
@@ -22,24 +25,58 @@ partial sums go on to the next layer as they are.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 
 from ...ndarray.ndarray import _wrap
-from ..block import HybridBlock
+from ..block import HybridBlock, stateful_write
 from .. import nn
+from ..nn.seq_layers import _Start
 
 __all__ = ["PatternLM"]
 
 
 class _Layer(HybridBlock):
     """``x + mixer(norm(x))``, or ``x + post_norm(mixer(norm(x)))``: the
-    unit that ``TrainStep(remat="layer")`` recomputes."""
+    unit that ``TrainStep(remat="layer")`` recomputes.
+
+    With ``streams`` = n the input is a token's n residual streams side
+    by side, (B, L, n * units), and the residual is a manifold-constrained
+    hyper-connection (arXiv:2512.24880; ``ops.seq.mhc_maps``): three maps
+    computed from the streams (``hc_weight`` the paper's phi, (n (n + 2),
+    n * units), rows ``[pre | post | res]``; ``hc_alpha`` a scalar a map;
+    ``hc_bias``), the sublayer reads ``u = sum_j H_pre[j] X_j`` and the
+    streams become ``H_res X + H_post^T f(u)`` with ``H_res`` doubly
+    stochastic by ``hyper_connections["iters"]`` Sinkhorn iterations.
+    ``hc_dev`` (no gradient, written every forward) holds what the
+    iterations leave (``nn.publish_mhc_counters``). The start values keep
+    the streams apart and hand the sublayer their mean: ``H_res`` close to
+    the identity, ``H_pre`` 1 / n, ``H_post`` 1; with one stream that is
+    ``x + f(x)``."""
     _remat_unit = True
 
     def __init__(self, units, mixer, epsilon, post_norm=False,
-                 unit_offset=False, **kwargs):
+                 unit_offset=False, streams=None, hyper_connections=None,
+                 **kwargs):
         super().__init__(**kwargs)
+        self._hc = None
         with self.name_scope():
+            if streams is not None:
+                n = int(streams)
+                self._hc = dict(hyper_connections or {}, streams=n)
+                get = self.params.get
+                self.hc_weight = get("hc_weight",
+                                     shape=(n * (n + 2), n * units))
+                self.hc_alpha = get("hc_alpha", shape=(3,),
+                                    init=_Start(0.01))
+                start = [-math.log(n - 1) if n > 1 else 30.0] * n \
+                    + [0.0] * n + [8.0 * (i == j) for i in range(n)
+                                   for j in range(n)]
+                self.hc_bias = get("hc_bias", shape=(n * (n + 2),),
+                                   init=_Start(start))
+                self.hc_dev = get("hc_dev", shape=(1,), init="zeros",
+                                  grad_req="null")
             self.norm = nn.RMSNorm(units, epsilon, unit_offset=unit_offset)
             self.mixer = mixer()
             # reads the mixer's last product, which the unit then keeps
@@ -47,9 +84,32 @@ class _Layer(HybridBlock):
                                         unit_offset=unit_offset) \
                 if post_norm else None
 
+    def _sublayer(self, u):
+        out = self.mixer(self.norm(u))
+        return out if self.post_norm is None else self.post_norm(out)
+
+    def hybrid_forward(self, F, x, hc_weight=None, hc_alpha=None,
+                       hc_bias=None, hc_dev=None):
+        if self._hc is None:
+            return x + self._sublayer(x)
+        pre, post, res, dev = F.HyperConnectionMaps(
+            x, hc_weight, hc_alpha, hc_bias, **self._hc)
+        stateful_write(self.hc_dev, dev)
+        out = self._sublayer(F.HyperConnectionPre(x, pre))
+        return F.HyperConnectionPost(x, out, res, post)
+
+
+class _Streams(HybridBlock):
+    """Where the stack's hidden state becomes ``streams`` residual streams
+    (``ops.seq.mhc_spread``: the vector copied into each) and where they
+    become one again (``mhc_merge``: their sum)."""
+
+    def __init__(self, op, streams, **kwargs):
+        super().__init__(**kwargs)
+        self._op, self._streams = op, int(streams)
+
     def hybrid_forward(self, F, x):
-        out = self.mixer(self.norm(x))
-        return x + (out if self.post_norm is None else self.post_norm(out))
+        return getattr(F, self._op)(x, streams=self._streams)
 
 
 class PatternLM(HybridBlock):
@@ -63,7 +123,14 @@ class PatternLM(HybridBlock):
     second norm in every layer, after its mixer. ``norm_unit_offset``:
     every layer's norm and the final norm scale by ``1 + w`` from ``w =
     0``. ``loops``: how often the stack and the final norm run, each pass
-    on the one before's output.
+    on the one before's output. ``residual_streams``: every token's
+    residual is that many streams, mixed around every layer by a
+    manifold-constrained hyper-connection (``_Layer``): the embedding is
+    copied into each (scope ``mx_mhc_in``), a layer reads a learned mix of
+    them and writes back through a doubly stochastic matrix, and their sum
+    (``mx_mhc_out``) goes to the final norm; ``hyper_connections`` holds
+    ``ops.seq.mhc_maps``' ``iters``, ``eps`` and ``clamp``. Not with
+    ``loops`` above 1.
 
     Input (B, L) token ids below ``vocab``; output (B * L, vocab) logits
     of the last pass. With ``exit_gate``, three outputs for a loss over
@@ -77,8 +144,13 @@ class PatternLM(HybridBlock):
                  attention=None, mlp=None, epsilon=1e-5, post_norm=False,
                  loops=1, exit_gate=False, latent_attention=None,
                  experts=None, linear_attention=None,
-                 norm_unit_offset=False, **kwargs):
+                 norm_unit_offset=False, residual_streams=None,
+                 hyper_connections=None, **kwargs):
         super().__init__(**kwargs)
+        if residual_streams is not None and loops > 1:
+            raise ValueError("residual_streams with loops > 1: a layer "
+                             "writes what its Sinkhorn iterations leave, "
+                             "which a looped body may not")
         make = {"M": lambda: nn.Mamba2Mixer(units, epsilon=epsilon,
                                             **mamba),
                 "E": lambda: nn.LatentMoE(units, **moe),
@@ -94,12 +166,19 @@ class PatternLM(HybridBlock):
             self.embed = nn.Embedding(vocab, units)
             # the container takes no part in its children's names
             self.stack = nn.HybridLoop(loops, prefix="")
+            if residual_streams is not None:
+                self.stack.add(_Streams("HyperConnectionSpread",
+                                        residual_streams))
             for i, kind in enumerate(pattern):
                 if kind not in make:
                     raise ValueError(f"layer kind {kind!r} in {pattern!r}: "
                                      "M, E, *, G, L, F and D are known")
                 self.stack.add(_Layer(units, make[kind], epsilon, post_norm,
-                                      norm_unit_offset, prefix=f"l{i}_"))
+                                      norm_unit_offset, residual_streams,
+                                      hyper_connections, prefix=f"l{i}_"))
+            if residual_streams is not None:
+                self.stack.add(_Streams("HyperConnectionMerge",
+                                        residual_streams))
             final = nn.RMSNorm(units, epsilon, unit_offset=norm_unit_offset)
             # a scan holds for its backward pass whatever its body computes
             # outside a unit as autodiff leaves it (of this norm, three

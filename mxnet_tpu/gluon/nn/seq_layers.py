@@ -19,7 +19,8 @@ from ..block import HybridBlock, _TraceState, stateful_write
 __all__ = ["RMSNorm", "Mamba2Mixer", "GatedDeltaNet", "LatentMoE", "GatedMoE",
            "GQAttention", "LatentAttention", "GatedMLP", "HybridLoop",
            "ExitGate",
-           "MOE_COUNTERS", "publish_moe_counters", "publish_loop_counters"]
+           "MOE_COUNTERS", "publish_moe_counters", "publish_loop_counters",
+           "publish_mhc_counters"]
 
 #: what an expert layer's ``counters`` hold, in order: the gauges
 #: ``moe::<name>::<block>`` of ``publish_moe_counters``
@@ -35,6 +36,15 @@ class _Start(initializer.Constant):
     _init_gamma = _init_bias = initializer.Constant._init_weight
 
 
+def _blocks_under(net):
+    """``net`` and every block below it."""
+    todo = [net]
+    while todo:
+        block = todo.pop()
+        todo.extend(block._children.values())
+        yield block
+
+
 def publish_moe_counters(net):
     """Read the counters of every expert layer (``LatentMoE``,
     ``GatedMoE``) under ``net`` (what the
@@ -43,16 +53,31 @@ def publish_moe_counters(net):
     the step. Returns ``{gauge: value}``."""
     from ... import telemetry
     out = {}
-    todo = [net]
-    while todo:
-        block = todo.pop()
-        todo.extend(block._children.values())
+    for block in _blocks_under(net):
         if isinstance(block, (LatentMoE, GatedMoE)):
             name = block.counters.name.rsplit("_", 1)[0]
             for counter, value in zip(MOE_COUNTERS,
                                       block.counters.data().asnumpy()):
                 out[f"moe::{counter}::{name}"] = float(value)
                 telemetry.gauge(f"moe::{counter}::{name}").set(float(value))
+    return out
+
+
+def publish_mhc_counters(net):
+    """Read ``hc_dev`` of every hyper-connected sublayer under ``net``
+    (``PatternLM(residual_streams=...)``'s layers: what the last forward,
+    or ``TrainStep`` call, wrote beside its output) and set the gauges
+    ``mhc::res_sum_dev::<layer>``: the largest ``|row or column sum of
+    H_res - 1|`` over the call's tokens, what the Sinkhorn iterations
+    leave. Returns ``{gauge: value}``."""
+    from ... import telemetry
+    out = {}
+    for block in _blocks_under(net):
+        dev = getattr(block, "hc_dev", None)
+        if dev is not None:
+            name = f"mhc::res_sum_dev::{dev.name.rsplit('_hc_', 1)[0]}"
+            out[name] = float(dev.data().asnumpy()[0])
+            telemetry.gauge(name).set(out[name])
     return out
 
 
@@ -270,10 +295,20 @@ class GatedMoE(HybridBlock):
 class LatentAttention(HybridBlock):
     """Causal multi-head latent attention (``ops.seq.latent_attention``)
     over the ``num_heads`` heads held here: queries ``nope_dim +
-    rope_dim`` wide straight from the input (no query latent), keys and
+    rope_dim`` wide, straight from the input or, with ``q_latent_dim``,
+    through a normed latent of that width (``q_down_weight``,
+    ``q_norm_weight``, then ``q_weight`` from the latent); keys and
     values expanded from a ``latent_dim``-wide normed latent, one rotary
     key of ``rope_dim`` shared by all heads, values ``v_dim`` wide. The
     matrices' rows are grouped by part, not by head (the op's docstring).
+    ``rope_scaling``: a configuration's YaRN group (``type: yarn``,
+    ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    ``beta_slow``, ``mscale``, ``mscale_all_dim``): the rotation's
+    frequencies are ``ops.seq.rope_frequencies``' and, where
+    ``mscale_all_dim`` is given, the softmax scale is ``(nope_dim +
+    rope_dim) ** -0.5`` times ``yarn_mscale(factor, mscale_all_dim)``
+    squared; cos and sin are not scaled, so ``mscale`` has to equal
+    ``mscale_all_dim``.
     Where ``nope_dim == v_dim`` is a multiple of 128 and the program is
     lowered for a TPU, the softmax is the fused kernels of
     ``ops.attn_kernel``; ``block`` is the plain form's, as in
@@ -281,18 +316,27 @@ class LatentAttention(HybridBlock):
 
     def __init__(self, in_units, num_heads, nope_dim=128, rope_dim=64,
                  v_dim=128, latent_dim=512, rope_theta=10000.0,
-                 epsilon=1e-5, block=1024, **kwargs):
+                 epsilon=1e-5, block=1024, q_latent_dim=None,
+                 rope_scaling=None, **kwargs):
         super().__init__(**kwargs)
         self._attrs = {"num_heads": num_heads, "nope_dim": nope_dim,
                        "rope_dim": rope_dim, "v_dim": v_dim,
                        "latent_dim": latent_dim,
                        "rope_theta": float(rope_theta), "eps": epsilon,
                        "block": block}
+        if rope_scaling is not None:
+            self._attrs.update(_yarn_attrs(rope_scaling,
+                                           nope_dim + rope_dim))
         with self.name_scope():
             get = self.params.get
+            if q_latent_dim is not None:
+                self.q_down_weight = get("q_down_weight",
+                                         shape=(q_latent_dim, in_units))
+                self.q_norm_weight = get("q_norm_weight",
+                                         shape=(q_latent_dim,), init="ones")
             self.q_weight = get(
                 "q_weight", shape=(num_heads * (nope_dim + rope_dim),
-                                   in_units))
+                                   q_latent_dim or in_units))
             self.kv_down_weight = get(
                 "kv_down_weight", shape=(latent_dim + rope_dim, in_units))
             self.kv_norm_weight = get("kv_norm_weight", shape=(latent_dim,),
@@ -304,9 +348,35 @@ class LatentAttention(HybridBlock):
                                 shape=(in_units, num_heads * v_dim))
 
     def hybrid_forward(self, F, x, q_weight, kv_down_weight, kv_norm_weight,
-                       kv_up_weight, o_weight):
+                       kv_up_weight, o_weight, q_down_weight=None,
+                       q_norm_weight=None):
+        more = () if q_down_weight is None else (q_down_weight,
+                                                 q_norm_weight)
         return F.LatentAttention(x, q_weight, kv_down_weight, kv_norm_weight,
-                                 kv_up_weight, o_weight, **self._attrs)
+                                 kv_up_weight, o_weight, *more,
+                                 **self._attrs)
+
+
+def _yarn_attrs(group, score_dim):
+    """``latent_attention``'s ``yarn`` and ``scale`` from a
+    configuration's ``rope_scaling`` group."""
+    from ...ops.seq import yarn_mscale
+    if group.get("type", group.get("rope_type")) != "yarn":
+        raise ValueError(f"rope_scaling {group!r}: only type yarn is known")
+    factor = group["factor"]
+    all_dim = group.get("mscale_all_dim", 0)
+    if yarn_mscale(factor, group.get("mscale", 1)) \
+            != yarn_mscale(factor, all_dim or 1):
+        raise ValueError("rope_scaling: cos and sin are not scaled here, "
+                         "so mscale has to equal mscale_all_dim")
+    attrs = {"yarn": (float(factor),
+                      float(group["original_max_position_embeddings"]),
+                      float(group.get("beta_fast", 32)),
+                      float(group.get("beta_slow", 1)))}
+    if all_dim:
+        attrs["scale"] = float(score_dim ** -0.5
+                               * yarn_mscale(factor, all_dim) ** 2)
+    return attrs
 
 
 class GQAttention(HybridBlock):
@@ -500,10 +570,7 @@ def publish_loop_counters(net):
     ``{gauge: value}``."""
     from ... import telemetry
     out = {}
-    todo = [net]
-    while todo:
-        block = todo.pop()
-        todo.extend(block._children.values())
+    for block in _blocks_under(net):
         if isinstance(block, HybridLoop):
             out["loop::trips"] = float(block._loops)
             out["loop::stack_traces"] = float(block.body_traces)

@@ -76,7 +76,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.extend.core import Primitive
-from jax.interpreters import mlir
+from jax.interpreters import ad, mlir
 
 _F32 = jnp.float32
 _NEG = -1e30
@@ -635,6 +635,9 @@ def _site_lowering(ctx, x, gauge):
 
 
 mlir.register_lowering(_site_p, _site_lowering)
+# an identity to differentiation too: a site may stand where JAX
+# differentiates (the hyper-connection's maps); the tangent is not counted
+ad.defjvp(_site_p, lambda t, x, gauge: t)
 
 
 def counted_site(x, gauge=GAUGE):

@@ -20,9 +20,9 @@ The step's device time is a function of shapes alone: the expert layer's
 receive buffer is static and every row of it is computed, filled or not.
 
 Named scopes (``mx_norm``, ``mx_mamba_proj``, ``mx_ssd_*``, ``mx_gdn_*``,
-``mx_moe_*``, ``mx_attn_*``, ``mx_mla_*``, ``mx_rope``, ``mx_gated_mlp``,
-``mx_exit_head``, ``mx_exit_gate``) mark each mechanism in the compiled
-program, and each operator's registration lists its own;
+``mx_moe_*``, ``mx_attn_*``, ``mx_mla_*``, ``mx_rope``, ``mx_mhc_*``,
+``mx_gated_mlp``, ``mx_exit_head``, ``mx_exit_gate``) mark each mechanism
+in the compiled program, and each operator's registration lists its own;
 ``telemetry.trace.scope_table`` maps the program's instructions back to
 them. The expert layer's matrix
 products have scopes of their own (``mx_moe_score``: the router's;
@@ -837,25 +837,61 @@ def _attention_block(q, k, v, bias, scale):
     return o, m, jnp.sum(p, axis=-1)
 
 
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention factor ``0.1 mscale ln(factor) + 1`` (1 for a
+    factor of 1 or less): its square scales the softmax where a
+    configuration gives ``mscale_all_dim`` (``nn.LatentAttention``)."""
+    factor = float(factor)
+    return 0.1 * float(mscale) * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_frequencies(dim, theta=10000.0, yarn=None):
+    """The ``dim // 2`` rotary frequencies of a ``dim``-wide head, float32:
+    ``f_i = theta^(-2i/dim)``. With ``yarn = (factor, original length,
+    beta_fast, beta_slow)`` the YaRN rule (arXiv:2309.00071, "NTK by
+    parts"): a pair that turns more than ``beta_fast`` times over the
+    original length keeps ``f_i``, one that turns fewer than ``beta_slow``
+    times gets ``f_i / factor``, and between the two pairs ``low =
+    floor(d(beta_fast))`` and ``high = ceil(d(beta_slow))``, ``d(beta) =
+    dim ln(original / (2 pi beta)) / (2 ln theta)``, the two are mixed by
+    ``ramp_i = clip((i - low) / (high - low), 0, 1)``: ``f_i (1 - ramp_i)
+    + f_i / factor * ramp_i``."""
+    i = np.arange(0, dim, 2)
+    f = 1.0 / float(theta) ** (i / dim)
+    if yarn is not None:
+        factor, original, fast, slow = (float(v) for v in yarn)
+
+        def pair(beta):
+            return dim * np.log(original / (2 * np.pi * beta)) \
+                / (2 * np.log(float(theta)))
+
+        low = max(np.floor(pair(fast)), 0)
+        high = min(np.ceil(pair(slow)), dim - 1)
+        ramp = np.clip((i / 2 - low) / max(high - low, 1e-3), 0, 1)
+        f = f * (1 - ramp) + f / factor * ramp
+    return np.asarray(f, np.float32)
+
+
 @register_op("RoPE", names_its_parts=True)
-def rope(data, theta=10000.0, rotary_dim=None, **kw):
+def rope(data, theta=10000.0, rotary_dim=None, yarn=None, **kw):
     """Rotary position encoding over the whole head, in the
     ``rotate_half`` convention: with ``x = [x1 | x2]`` the two halves of
     a head, position ``t`` and ``angle_i = t * theta^(-2i/D)`` for ``i <
     D/2``, ``[x1 cos - x2 sin | x2 cos + x1 sin]``. With ``rotary_dim``
     below ``D`` the head's first ``rotary_dim`` elements are rotated so,
-    as a head of that width, and the rest go through as they are.
-    ``data``: (B, L, H, D), position = index along ``L``; the angles and
-    the rotation in float32, the result in ``data``'s dtype."""
+    as a head of that width, and the rest go through as they are. With
+    ``yarn = (factor, original length, beta_fast, beta_slow)`` the
+    frequencies are YaRN's (``rope_frequencies``); cos and sin are not
+    scaled. ``data``: (B, L, H, D), position = index along ``L``; the
+    angles and the rotation in float32, the result in ``data``'s dtype."""
     if rotary_dim is not None and int(rotary_dim) < data.shape[-1]:
         r = int(rotary_dim)
-        return jnp.concatenate([rope(data[..., :r], theta), data[..., r:]],
-                               axis=-1)
+        return jnp.concatenate([rope(data[..., :r], theta, yarn=yarn),
+                                data[..., r:]], axis=-1)
     length, d = data.shape[1], data.shape[-1]
     half = d // 2
     with jax.named_scope("mx_rope"):
-        inv = np.asarray(1.0 / float(theta) ** (np.arange(0, d, 2) / d),
-                         np.float32)
+        inv = rope_frequencies(d, theta, yarn)
         ang = jnp.arange(length, dtype=_F32)[:, None] * inv[None, :]
         cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
         x = data.astype(_F32)
@@ -1049,15 +1085,28 @@ def causal_gq_attention(data, q_norm_weight=None, k_norm_weight=None,
 # ---------------------------------------------------------------------------
 @register_op("LatentAttention", names_its_parts=True)
 def latent_attention(data, q_weight, kv_down_weight, kv_norm_weight,
-                     kv_up_weight, o_weight, num_heads=1, nope_dim=128,
+                     kv_up_weight, o_weight, q_down_weight=None,
+                     q_norm_weight=None, num_heads=1, nope_dim=128,
                      rope_dim=64, v_dim=128, latent_dim=512,
-                     rope_theta=10000.0, eps=1e-5, block=1024, **kw):
+                     rope_theta=10000.0, eps=1e-5, block=1024, scale=None,
+                     yarn=None, **kw):
     """Causal multi-head latent attention over the ``num_heads`` heads
     held here. Keys and values are expanded from one ``latent_dim``-wide
     vector a token; a head's score is ``q_nope . k_nope + q_pe . k_pe``
     with ``k_pe`` (``rope_dim`` wide, rotated by position as ``q_pe`` is)
-    one vector shared by all heads, scaled by ``(nope_dim + rope_dim) **
-    -0.5``; values are ``v_dim`` wide.
+    one vector shared by all heads, scaled by ``scale``, ``(nope_dim +
+    rope_dim) ** -0.5`` unless given (a model whose positions are
+    stretched multiplies it by ``yarn_mscale`` squared); values are
+    ``v_dim`` wide. ``yarn``: the rotation's frequencies are YaRN's
+    (``rope_frequencies``).
+
+    With ``q_down_weight`` (q_latent, hidden) and ``q_norm_weight``
+    (q_latent,) the queries come through a latent of their own: ``c_q =
+    RMSNorm(x W_qa)``, ``q = c_q W_qb``, and ``q_weight`` is (H * (nope +
+    rope), q_latent); the three are one scope, ``mx_mla_q``, and a unit
+    keeps both products. A share of the heads holds both latents whole
+    and its heads' rows of ``q_weight`` and ``kv_up_weight`` and columns
+    of ``o_weight``: the shares' outputs add up to the uncut layer's.
 
     ``data`` (B, L, hidden). ``q_weight`` (H * (nope + rope), hidden),
     rows ``[every head's q_nope | every head's q_pe]``;
@@ -1083,18 +1132,24 @@ def latent_attention(data, q_weight, kv_down_weight, kv_norm_weight,
     h, dn, dr, dv = int(num_heads), int(nope_dim), int(rope_dim), int(v_dim)
     bsz, length, _ = data.shape
     with jax.named_scope("mx_mla_q"):
-        q = kept(_mm(data, q_weight))
+        if q_down_weight is None:
+            q = kept(_mm(data, q_weight))
+        else:
+            c_q = _rms_norm(kept(_mm(data, q_down_weight)), q_norm_weight,
+                            eps=eps)
+            q = kept(_mm(c_q, q_weight))
     with jax.named_scope("mx_mla_kv_down"):
         ckv = kept(_mm(data, kv_down_weight))
         c = kept(_rms_norm(ckv[..., :latent_dim], kv_norm_weight, eps=eps))
     with jax.named_scope("mx_mla_kv_up"):
         kv = _mm(c, kv_up_weight)
     with jax.named_scope("mx_mla_rope"):
-        q_pe = rope(q[..., h * dn:].reshape(bsz, length, h, dr), rope_theta)
+        q_pe = rope(q[..., h * dn:].reshape(bsz, length, h, dr), rope_theta,
+                    yarn=yarn)
         k_pe = rope(ckv[..., latent_dim:].reshape(bsz, length, 1, dr),
-                    rope_theta)
+                    rope_theta, yarn=yarn)
     blk = min(int(block), length)
-    scale = float((dn + dr) ** -0.5)
+    scale = float((dn + dr) ** -0.5 if scale is None else scale)
     q_nope, k_nope, v = q[..., :h * dn], kv[..., :h * dn], kv[..., h * dn:]
     extra = (q_pe.reshape(bsz, length, h * dr),
              k_pe.reshape(bsz, length, dr))
@@ -1105,6 +1160,130 @@ def latent_attention(data, q_weight, kv_down_weight, kv_norm_weight,
                                  extra)[0])
     with jax.named_scope("mx_mla_out"):
         return _mm(out, o_weight)
+
+
+# ---------------------------------------------------------------------------
+# several residual streams: manifold-constrained hyper-connections
+# ---------------------------------------------------------------------------
+#: one for every sublayer whose maps a program lowers (and one more where
+#: a recomputation unit lowers them again); ``TrainStep`` sets it to zero
+#: where it traces its step
+MHC_GAUGE = "mhc::sites"
+
+
+def _sum_over(m, axis):
+    """The sum over a short axis as additions of its slices: elementwise
+    work that fuses with what surrounds it, where a ``reduce`` a
+    normalisation would cut the 20 iterations into 40 programs."""
+    parts = [lax.index_in_dim(m, i, axis, keepdims=True)
+             for i in range(m.shape[axis])]
+    return functools.reduce(lambda a, b: a + b, parts)
+
+
+@register_op("HyperConnectionMaps", num_outputs=4, names_its_parts=True)
+def mhc_maps(data, phi, alpha, bias, streams=4, iters=20, eps=1e-6,
+             clamp=(-30.0, 30.0), **kw):
+    """The three maps of a hyper-connected sublayer (manifold-constrained
+    hyper-connections, arXiv:2512.24880) from a token's ``n = streams``
+    residual streams, ``data`` (B, L, n * C), stream ``j`` the columns ``j
+    C`` to ``(j + 1) C``:
+
+    ``x^ = vec(X) / sqrt(mean(vec(X)^2) + eps)``; ``H~ = alpha * (x^ phi)
+    + bias`` in three parts, ``phi`` (n (n + 2), n C) rows ``[pre (n) |
+    post (n) | res (n n, row-major)]``, ``alpha`` (3,) one scalar a part,
+    ``bias`` (n (n + 2),); ``H_pre = sigmoid(H~_pre)``, ``H_post = 2
+    sigmoid(H~_post)``, ``H_res`` the matrix ``exp(clip(H~_res, clamp))``
+    after ``iters`` Sinkhorn iterations, each its columns divided by
+    their sums (+ ``eps``) and then its rows by theirs: doubly stochastic
+    to what the iterations leave.
+
+    Everything in float32 and with the tokens minor: the product is
+    ``phi X^T`` (n (n + 2), T), divided by the streams' root mean square
+    after it (the streams are read for both at once), and a token's 4 x 4
+    matrix is 16 rows of a (16, T) array, never a padded tile of its
+    own. A recomputation unit keeps the product and the mean square (n
+    (n + 2) + 1 floats a token); sigmoids and iterations are computed
+    again. Scope ``mx_mhc_maps``.
+
+    Returns ``(H_pre (n, B, L), H_post (n, B, L), H_res (n, n, B, L),
+    dev (1,))``, float32; ``dev`` is the largest ``|row or column sum of
+    H_res - 1|`` over the tokens, and no gradient reaches it."""
+    n, lo, hi = int(streams), float(clamp[0]), float(clamp[1])
+    bsz, length, width = data.shape
+    with jax.named_scope("mx_mhc_maps"):
+        x = attn_kernel.counted_site(
+            data.reshape(bsz * length, width), MHC_GAUGE)
+        raw = kept(lax.dot_general(phi.astype(x.dtype), x,
+                                   (((1,), (1,)), ((), ())),
+                                   preferred_element_type=_F32))
+        mean_sq = kept(jnp.mean(jnp.square(x.astype(_F32)), axis=-1))
+        part = np.repeat(np.arange(3), [n, n, n * n])
+        h = raw * lax.rsqrt(mean_sq + eps)[None, :] \
+            * alpha.astype(_F32)[part][:, None] \
+            + bias.astype(_F32)[:, None]
+        pre = jax.nn.sigmoid(h[:n])
+        post = 2.0 * jax.nn.sigmoid(h[n:2 * n])
+        m = jnp.exp(jnp.clip(h[2 * n:], lo, hi)).reshape(n, n, -1)
+        for _ in range(int(iters)):
+            m = m / (_sum_over(m, 0) + eps)       # columns
+            m = m / (_sum_over(m, 1) + eps)       # rows
+        dev = lax.stop_gradient(jnp.maximum(
+            jnp.max(jnp.abs(_sum_over(m, 0) - 1.0)),
+            jnp.max(jnp.abs(_sum_over(m, 1) - 1.0))))
+        return (pre.reshape(n, bsz, length), post.reshape(n, bsz, length),
+                m.reshape(n, n, bsz, length), dev.reshape(1))
+
+
+def _streams_of(data, n):
+    width = data.shape[-1] // n
+    return [data[..., j * width:(j + 1) * width].astype(_F32)
+            for j in range(n)]
+
+
+@register_op("HyperConnectionPre", names_its_parts=True)
+def mhc_pre(data, pre, **kw):
+    """What a hyper-connected sublayer reads: ``u = sum_j H_pre[j] X_j``,
+    ``data`` (B, L, n * C) and ``pre`` (n, B, L) -> (B, L, C) in
+    ``data``'s dtype, the sum in float32. A unit computes it again (it
+    is as wide as a stream). Scope ``mx_mhc_pre``."""
+    with jax.named_scope("mx_mhc_pre"):
+        xs = _streams_of(data, pre.shape[0])
+        u = sum(pre[j][..., None] * x for j, x in enumerate(xs))
+        return u.astype(data.dtype)
+
+
+@register_op("HyperConnectionPost", names_its_parts=True)
+def mhc_post(data, out, res, post, **kw):
+    """What a hyper-connected sublayer writes: ``X'_i = sum_j H_res[i, j]
+    X_j + H_post[i] y``, ``data`` (B, L, n * C), ``out`` = ``y`` (B, L,
+    C), ``res`` (n, n, B, L), ``post`` (n, B, L) -> (B, L, n * C) in
+    ``data``'s dtype, the sums in float32. A unit keeps ``y``, the
+    mixer's last product, which ``H_post``'s gradient reads. Scope
+    ``mx_mhc_post``."""
+    with jax.named_scope("mx_mhc_post"):
+        n = post.shape[0]
+        xs = _streams_of(data, n)
+        y = kept(out).astype(_F32)
+        return jnp.concatenate(
+            [(sum(res[i, j][..., None] * x for j, x in enumerate(xs))
+              + post[i][..., None] * y).astype(data.dtype)
+             for i in range(n)], axis=-1)
+
+
+@register_op("HyperConnectionSpread", names_its_parts=True)
+def mhc_spread(data, streams=4, **kw):
+    """A token's vector copied into each of its ``streams`` residual
+    streams: (B, L, C) -> (B, L, n * C). Scope ``mx_mhc_in``."""
+    with jax.named_scope("mx_mhc_in"):
+        return jnp.tile(data, (1, 1, int(streams)))
+
+
+@register_op("HyperConnectionMerge", names_its_parts=True)
+def mhc_merge(data, streams=4, **kw):
+    """The sum of a token's residual streams, in float32: (B, L, n * C) ->
+    (B, L, C) in ``data``'s dtype. Scope ``mx_mhc_out``."""
+    with jax.named_scope("mx_mhc_out"):
+        return sum(_streams_of(data, int(streams))).astype(data.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -195,6 +195,36 @@ def test_the_newest_cell_s_scopes_stand_beside_the_shared_ones():
                     if ADDED_41 & set(p.split("/"))], name
 
 
+#: the scopes ISSUE 43 added (several residual streams: a layer's maps, the
+#: mix it reads, the mix it writes, the copy into streams and their sum),
+#: and the scopes its cell shares with Moonlight's, whose paths are theirs
+ADDED_43 = {"mx_mhc_maps", "mx_mhc_pre", "mx_mhc_post", "mx_mhc_in",
+            "mx_mhc_out"}
+
+
+def test_the_streams_scopes_stand_beside_moonlight_s():
+    """``xing4.0-29b-a4b-train-4k`` has no parent to be compared with: the
+    paths made of older scopes alone are exactly Moonlight's (a query
+    latent's two products and norm stay under ``mx_mla_q``; the YaRN
+    frequencies open nothing), its own scopes enclose none of them and
+    stand inside none, and no older cell's step holds one of its
+    scopes."""
+    paths = set(_step_of("xing4.0-29b-a4b-train-4k")[1].values())
+    old = {p for p in paths if not any(
+        _is_new(s) or s in ADDED_43 for s in p.split("/"))}
+    assert old == PARENT["moonlight-16b-a3b-train-8k"][1]
+    for path in paths:
+        parts = path.split("/")
+        if ADDED_43 & set(parts):
+            assert len(parts) == 1, path
+    assert ADDED_43 <= paths
+    assert {"mx_opt_update", "mx_loss", "mx_head/mx_dense", "mx_embed",
+            "mx_norm", "mx_cast"} <= paths
+    for name in list(PARENT) + ["qwen3-next-80b-a3b-train-8k"]:
+        assert not [p for p in _step_of(name)[1].values()
+                    if ADDED_43 & set(p.split("/"))], name
+
+
 def _rnn_then_fc():
     seq = mx.sym.RNN(mx.sym.Variable("data"), mx.sym.Variable("p"),
                      mx.sym.Variable("s"), mx.sym.Variable("c"),
@@ -214,7 +244,9 @@ def test_an_operator_that_names_its_parts_gets_no_operator_scope():
     from mxnet_tpu.ops import registry
     for op in ("RNN", "RMSNorm", "Mamba2Mixer", "GatedDeltaNet", "LatentMoE",
                "GatedMoE", "CausalGQAttention", "LatentAttention",
-               "GatedMLP", "RoPE", "ExitGate"):
+               "GatedMLP", "RoPE", "ExitGate", "HyperConnectionMaps",
+               "HyperConnectionPre", "HyperConnectionPost",
+               "HyperConnectionSpread", "HyperConnectionMerge"):
         assert registry.get_op(op).names_its_parts, op
     for op in ("Convolution", "BatchNorm", "FullyConnected", "Embedding"):
         assert not registry.get_op(op).names_its_parts, op

@@ -165,6 +165,23 @@ CASES = {
         # ops/seq.py: (B, L, H, D) with an even D; a small theta so that
         # every pair turns visibly within 5 positions
         inputs=[_signed((2, 5, 2, 4), 0)], attrs=dict(theta=50.0)),
+    "HyperConnectionMaps": dict(
+        # ops/seq.py: n = 2 streams of 3 (n C = 6), phi (8, 6) rows [pre 2 |
+        # post 2 | res 4], one alpha a map and a bias of order 1, so that
+        # no map is near the identity; the fourth output (what the
+        # iterations leave) takes no gradient
+        inputs=[_signed((2, 5, 6), 0), _signed((8, 6), 1),
+                _pos((3,), 2), _signed((8,), 3)],
+        attrs=dict(streams=2, iters=20, eps=1e-6), outputs=3),
+    "HyperConnectionPre": dict(
+        inputs=[_signed((2, 5, 6), 0), _pos((2, 2, 5), 1)]),
+    "HyperConnectionPost": dict(
+        inputs=[_signed((2, 5, 6), 0), _signed((2, 5, 3), 1),
+                _pos((2, 2, 2, 5), 2), _pos((2, 2, 5), 3)]),
+    "HyperConnectionSpread": dict(
+        inputs=[_signed((2, 5, 3), 0)], attrs=dict(streams=2)),
+    "HyperConnectionMerge": dict(
+        inputs=[_signed((2, 5, 6), 0)], attrs=dict(streams=2)),
     "GatedMLP": dict(
         # ops/seq.py: [gate | up] rows of one (2 f, hidden) weight, f = 4
         inputs=[_signed((2, 5, 6), 0), 0.5 * _signed((8, 6), 1),
