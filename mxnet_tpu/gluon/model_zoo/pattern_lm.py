@@ -92,11 +92,10 @@ class _Layer(HybridBlock):
                        hc_bias=None, hc_dev=None):
         if self._hc is None:
             return x + self._sublayer(x)
-        pre, post, res, dev = F.HyperConnectionMaps(
+        x, u, post, res, dev = F.HyperConnectionRead(
             x, hc_weight, hc_alpha, hc_bias, **self._hc)
         stateful_write(self.hc_dev, dev)
-        out = self._sublayer(F.HyperConnectionPre(x, pre))
-        return F.HyperConnectionPost(x, out, res, post)
+        return F.HyperConnectionPost(x, self._sublayer(u), res, post)
 
 
 class _Streams(HybridBlock):

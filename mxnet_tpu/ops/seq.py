@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import attn_kernel, gdn_kernel, gmm_kernel
+from . import attn_kernel, gdn_kernel, gmm_kernel, mhc_kernel
 from .registry import register_op
 from .remat import kept
 
@@ -1180,6 +1180,37 @@ def _sum_over(m, axis):
     return functools.reduce(lambda a, b: a + b, parts)
 
 
+def _product(x, phi):
+    """``phi X^T`` (n (n + 2), T) float32 of the streams ``x`` (T, n C)."""
+    return lax.dot_general(phi.astype(x.dtype), x, (((1,), (1,)), ((), ())),
+                           preferred_element_type=_F32)
+
+
+def _mean_square(x):
+    return jnp.mean(jnp.square(x.astype(_F32)), axis=-1)
+
+
+def _maps_of(raw, mean_sq, alpha, bias, n, iters, eps, clamp):
+    """The per-token arithmetic of ``mhc_maps`` on its product and mean
+    square, tokens minor: ``(H_pre (n, T), H_post (n, T), H_res (n, n, T),
+    dev ())``."""
+    part = np.repeat(np.arange(3), [n, n, n * n])
+    h = raw * lax.rsqrt(mean_sq + eps)[None, :] \
+        * alpha.astype(_F32)[part][:, None] \
+        + bias.astype(_F32)[:, None]
+    pre = jax.nn.sigmoid(h[:n])
+    post = 2.0 * jax.nn.sigmoid(h[n:2 * n])
+    m = jnp.exp(jnp.clip(h[2 * n:], float(clamp[0]),
+                         float(clamp[1]))).reshape(n, n, -1)
+    for _ in range(int(iters)):
+        m = m / (_sum_over(m, 0) + eps)       # columns
+        m = m / (_sum_over(m, 1) + eps)       # rows
+    dev = lax.stop_gradient(jnp.maximum(
+        jnp.max(jnp.abs(_sum_over(m, 0) - 1.0)),
+        jnp.max(jnp.abs(_sum_over(m, 1) - 1.0))))
+    return pre, post, m, dev
+
+
 @register_op("HyperConnectionMaps", num_outputs=4, names_its_parts=True)
 def mhc_maps(data, phi, alpha, bias, streams=4, iters=20, eps=1e-6,
              clamp=(-30.0, 30.0), **kw):
@@ -1205,31 +1236,23 @@ def mhc_maps(data, phi, alpha, bias, streams=4, iters=20, eps=1e-6,
     (n + 2) + 1 floats a token); sigmoids and iterations are computed
     again. Scope ``mx_mhc_maps``.
 
+    This op is the plain form on every platform, one pass of XLA's over
+    the streams for the product and one for the mean square. A layer
+    calls ``mhc_read``, which is this op and ``mhc_pre`` in one and, in a
+    program lowered for a TPU, reads the streams once for both.
+
     Returns ``(H_pre (n, B, L), H_post (n, B, L), H_res (n, n, B, L),
     dev (1,))``, float32; ``dev`` is the largest ``|row or column sum of
     H_res - 1|`` over the tokens, and no gradient reaches it."""
-    n, lo, hi = int(streams), float(clamp[0]), float(clamp[1])
+    n = int(streams)
     bsz, length, width = data.shape
     with jax.named_scope("mx_mhc_maps"):
         x = attn_kernel.counted_site(
             data.reshape(bsz * length, width), MHC_GAUGE)
-        raw = kept(lax.dot_general(phi.astype(x.dtype), x,
-                                   (((1,), (1,)), ((), ())),
-                                   preferred_element_type=_F32))
-        mean_sq = kept(jnp.mean(jnp.square(x.astype(_F32)), axis=-1))
-        part = np.repeat(np.arange(3), [n, n, n * n])
-        h = raw * lax.rsqrt(mean_sq + eps)[None, :] \
-            * alpha.astype(_F32)[part][:, None] \
-            + bias.astype(_F32)[:, None]
-        pre = jax.nn.sigmoid(h[:n])
-        post = 2.0 * jax.nn.sigmoid(h[n:2 * n])
-        m = jnp.exp(jnp.clip(h[2 * n:], lo, hi)).reshape(n, n, -1)
-        for _ in range(int(iters)):
-            m = m / (_sum_over(m, 0) + eps)       # columns
-            m = m / (_sum_over(m, 1) + eps)       # rows
-        dev = lax.stop_gradient(jnp.maximum(
-            jnp.max(jnp.abs(_sum_over(m, 0) - 1.0)),
-            jnp.max(jnp.abs(_sum_over(m, 1) - 1.0))))
+        raw = kept(_product(x, phi))
+        mean_sq = kept(_mean_square(x))
+        pre, post, m, dev = _maps_of(raw, mean_sq, alpha, bias, n, iters,
+                                     eps, clamp)
         return (pre.reshape(n, bsz, length), post.reshape(n, bsz, length),
                 m.reshape(n, n, bsz, length), dev.reshape(1))
 
@@ -1240,16 +1263,163 @@ def _streams_of(data, n):
             for j in range(n)]
 
 
+def _mix(data, pre):
+    """``sum_j pre[j] X_j`` in float32, rounded to ``data``'s dtype."""
+    xs = _streams_of(data, pre.shape[0])
+    u = sum(pre[j][..., None] * x for j, x in enumerate(xs))
+    return u.astype(data.dtype)
+
+
 @register_op("HyperConnectionPre", names_its_parts=True)
 def mhc_pre(data, pre, **kw):
     """What a hyper-connected sublayer reads: ``u = sum_j H_pre[j] X_j``,
     ``data`` (B, L, n * C) and ``pre`` (n, B, L) -> (B, L, C) in
     ``data``'s dtype, the sum in float32. A unit computes it again (it
-    is as wide as a stream). Scope ``mx_mhc_pre``."""
+    is as wide as a stream). Scope ``mx_mhc_pre``. The plain form on
+    every platform, a pass of its own over the streams; a layer calls
+    ``mhc_read``."""
     with jax.named_scope("mx_mhc_pre"):
-        xs = _streams_of(data, pre.shape[0])
-        u = sum(pre[j][..., None] * x for j, x in enumerate(xs))
-        return u.astype(data.dtype)
+        return _mix(data, pre)
+
+
+def _read_plain(x, phi, alpha_pre, bias_pre, n, eps):
+    """``mhc_kernel.read`` in plain JAX: the streams' product and mean
+    square and the mix under ``H_pre``, which is ``_maps_of``'s expression
+    on the product's first rows."""
+    raw, mean_sq = _product(x, phi), _mean_square(x)
+    pre = jax.nn.sigmoid(raw[:n] * lax.rsqrt(mean_sq + eps)[None, :]
+                         * alpha_pre + bias_pre[:, None])
+    return raw, mean_sq, _mix(x, pre)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _read_kernels(x, phi, alpha_pre, bias_pre, n, eps):
+    """``(x, raw, mean_sq, u)``: a sublayer's read side whose program takes
+    its form when it is lowered: for a TPU ``mhc_kernel.read`` and
+    ``read_backward``, for any other platform ``_read_plain`` and JAX's
+    derivative of it. The streams go through unchanged so that what the
+    write side's backward leaves for them comes back here as a cotangent
+    and is added where the one ``dX`` is summed, not in a pass of its
+    own."""
+    return _read_kernels_fwd(x, phi, alpha_pre, bias_pre, n, eps)[0]
+
+
+def _read_kernels_fwd(x, phi, alpha_pre, bias_pre, n, eps):
+    raw, mean_sq, u = lax.platform_dependent(
+        x, phi, alpha_pre, bias_pre,
+        tpu=lambda x, *a: mhc_kernel.read(
+            attn_kernel.counted_site(x, mhc_kernel.GAUGE), *a, n=n, eps=eps),
+        default=lambda *a: _read_plain(*a, n, eps))
+    return (x, raw, mean_sq, u), (x, phi, alpha_pre, bias_pre, raw, mean_sq)
+
+
+def _read_kernels_bwd(n, eps, res, cts):
+    # no scope of its own: the backward rule carries the scope its forward
+    # was called under (``mx_mhc_pre``)
+    def kernels(x, phi, alpha_pre, bias_pre, raw, mean_sq, dxp, d_raw, d_ms,
+                du):
+        dx, d_phi, d_logits = mhc_kernel.read_backward(
+            x, dxp, du, raw, mean_sq, d_raw, d_ms, phi, alpha_pre, bias_pre,
+            n=n, eps=eps)
+        scaled = raw[:n] * lax.rsqrt(mean_sq + eps)[None, :]
+        return (dx, d_phi.astype(phi.dtype), jnp.sum(d_logits * scaled),
+                jnp.sum(d_logits, axis=1))
+
+    def plain(x, phi, alpha_pre, bias_pre, raw, mean_sq, dxp, *cts):
+        dx, *rest = jax.vjp(lambda *a: _read_plain(*a, n, eps),
+                            x, phi, alpha_pre, bias_pre)[1](cts)
+        return (dxp + dx, *rest)
+
+    return lax.platform_dependent(*res, *cts, tpu=kernels, default=plain)
+
+
+_read_kernels.defvjp(_read_kernels_fwd, _read_kernels_bwd)
+
+
+@register_op("HyperConnectionRead", num_outputs=5, names_its_parts=True)
+def mhc_read(data, phi, alpha, bias, streams=4, iters=20, eps=1e-6,
+             clamp=(-30.0, 30.0), **kw):
+    """A hyper-connected sublayer's read side: ``mhc_maps`` and ``mhc_pre``
+    in one. ``data`` (B, L, n * C), ``phi``, ``alpha``, ``bias`` and the
+    keywords as ``mhc_maps`` takes them.
+
+    Two forms. Where ``mhc_kernel.takes`` the shapes (2 to 8 streams of
+    whole lane tiles, the tokens whole blocks of 128, bfloat16 or
+    float32) and the program is lowered for a TPU, the streams are read
+    once: ``mhc_kernel.read`` gives the product, the mean square and ``u``
+    (``H_pre`` formed in the kernel), under ``mx_mhc_pre``; the
+    per-token arithmetic of ``mhc_maps`` stays XLA's, on the product and
+    the mean square the unit keeps, under ``mx_mhc_maps``; the backward
+    pass is ``mhc_kernel.read_backward``, which writes ONE cotangent of
+    the streams. Everywhere else ``mhc_maps`` then ``mhc_pre``, the plain
+    form as JAX differentiates it. Shapes and the platform choose; no
+    option does. ``mhc::kernel_sites`` counts the sites that took the
+    kernels, ``mhc::sites`` every site's maps.
+
+    Returns ``(data, u (B, L, C), H_post (n, B, L), H_res (n, n, B, L),
+    dev (1,))``: the streams as they came, for ``mhc_post`` to read (so
+    that its backward's share of their cotangent passes through this
+    op's), what the sublayer reads, and ``mhc_maps``' last three."""
+    n = int(streams)
+    bsz, length, width = data.shape
+    if not mhc_kernel.takes(bsz * length, n, width, data.dtype):
+        pre, post, res, dev = mhc_maps(data, phi, alpha, bias, streams=n,
+                                       iters=iters, eps=eps, clamp=clamp)
+        return data, mhc_pre(data, pre), post, res, dev
+    with jax.named_scope("mx_mhc_pre"):
+        x, raw, mean_sq, u = _read_kernels(
+            data.reshape(bsz * length, width), phi.astype(data.dtype),
+            alpha.astype(_F32)[0], bias.astype(_F32)[:n], n, float(eps))
+    with jax.named_scope("mx_mhc_maps"):
+        raw = attn_kernel.counted_site(raw, MHC_GAUGE)
+        _, post, res, dev = _maps_of(kept(raw), kept(mean_sq), alpha, bias,
+                                     n, iters, eps, clamp)
+    return (x.reshape(data.shape), u.reshape(bsz, length, width // n),
+            post.reshape(n, bsz, length), res.reshape(n, n, bsz, length),
+            dev.reshape(1))
+
+
+def _post_plain(data, y, res, post, keep=False):
+    """``X'_i = sum_j res[i, j] X_j + post[i] y``, the sums in float32;
+    ``keep``: the unit around it holds ``y``."""
+    xs = _streams_of(data, post.shape[0])
+    y = (kept(y) if keep else y).astype(_F32)
+    return jnp.concatenate(
+        [(sum(res[i, j][..., None] * x for j, x in enumerate(xs))
+          + post[i][..., None] * y).astype(data.dtype)
+         for i in range(post.shape[0])], axis=-1)
+
+
+@jax.custom_vjp
+def _post_kernels(x, y, res, post):
+    """``mhc_post`` on (T, n C) streams, ``res`` (n n, T) and ``post`` (n,
+    T), whose program takes its form when it is lowered: for a TPU
+    ``mhc_kernel.post`` and ``post_backward``, for any other platform
+    ``_post_plain`` and JAX's derivative of it."""
+    return _post_kernels_fwd(x, y, res, post)[0]
+
+
+def _post_rows(x, y, res, post):
+    n = post.shape[0]
+    return _post_plain(x, y, res.reshape(n, n, -1), post)
+
+
+def _post_kernels_fwd(x, y, res, post):
+    out = lax.platform_dependent(x, y, res, post, tpu=mhc_kernel.post,
+                                 default=_post_rows)
+    return out, (x, y, res, post)
+
+
+def _post_kernels_bwd(saved, g):
+    # under the scope of its forward too (``mx_mhc_post``)
+    return lax.platform_dependent(
+        *saved, g,
+        tpu=lambda x, y, res, post, g: mhc_kernel.post_backward(
+            g, x, y, res, post),
+        default=lambda *a: jax.vjp(_post_rows, *a[:4])[1](a[4]))
+
+
+_post_kernels.defvjp(_post_kernels_fwd, _post_kernels_bwd)
 
 
 @register_op("HyperConnectionPost", names_its_parts=True)
@@ -1259,15 +1429,26 @@ def mhc_post(data, out, res, post, **kw):
     C), ``res`` (n, n, B, L), ``post`` (n, B, L) -> (B, L, n * C) in
     ``data``'s dtype, the sums in float32. A unit keeps ``y``, the
     mixer's last product, which ``H_post``'s gradient reads. Scope
-    ``mx_mhc_post``."""
+    ``mx_mhc_post``.
+
+    Two forms, by ``mhc_kernel.takes`` and the platform the program is
+    lowered for, as ``mhc_read``'s: on a TPU ``mhc_kernel.post`` reads
+    ``data`` and ``y`` once and writes the streams once, and its backward,
+    ``mhc_kernel.post_backward``, reads the cotangent, ``data`` and ``y``
+    once for the streams' partial cotangent, ``dy`` and both maps'
+    gradients a token; everywhere else the plain sums."""
     with jax.named_scope("mx_mhc_post"):
         n = post.shape[0]
-        xs = _streams_of(data, n)
-        y = kept(out).astype(_F32)
-        return jnp.concatenate(
-            [(sum(res[i, j][..., None] * x for j, x in enumerate(xs))
-              + post[i][..., None] * y).astype(data.dtype)
-             for i in range(n)], axis=-1)
+        bsz, length, width = data.shape
+        tokens = bsz * length
+        if not mhc_kernel.takes(tokens, n, width, data.dtype) \
+                or out.dtype != data.dtype:
+            return _post_plain(data, out, res, post, keep=True)
+        return _post_kernels(
+            data.reshape(tokens, width),
+            kept(out).reshape(tokens, width // n),
+            res.reshape(n * n, tokens), post.reshape(n, tokens)
+        ).reshape(data.shape)
 
 
 @register_op("HyperConnectionSpread", names_its_parts=True)

@@ -173,6 +173,12 @@ CASES = {
         inputs=[_signed((2, 5, 6), 0), _signed((8, 6), 1),
                 _pos((3,), 2), _signed((8,), 3)],
         attrs=dict(streams=2, iters=20, eps=1e-6), outputs=3),
+    "HyperConnectionRead": dict(
+        # ops/seq.py: the maps and the mix in one, the same fixture; the
+        # fifth output (what the iterations leave) takes no gradient
+        inputs=[_signed((2, 5, 6), 0), _signed((8, 6), 1),
+                _pos((3,), 2), _signed((8,), 3)],
+        attrs=dict(streams=2, iters=20, eps=1e-6), outputs=4),
     "HyperConnectionPre": dict(
         inputs=[_signed((2, 5, 6), 0), _pos((2, 2, 5), 1)]),
     "HyperConnectionPost": dict(
