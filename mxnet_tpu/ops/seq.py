@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import attn_kernel, gdn_kernel, gmm_kernel, mhc_kernel
+from . import attn_kernel, gdn_conv_kernel, gdn_kernel, gmm_kernel, mhc_kernel
 from .registry import register_op
 from .remat import kept
 
@@ -389,6 +389,77 @@ def _rule_kernels_bwd(chunk, res, d_out):
 _rule_kernels.defvjp(_rule_kernels_fwd, _rule_kernels_bwd)
 
 
+def _convolved(qkvz, conv_weight, heads):
+    """``silu(conv([q | k | v]))`` of the packed projection, (B, L, 2 G N
+    + H P)."""
+    conv = 2 * heads.keys * heads.n + heads.values * heads.p
+    return jax.nn.silu(causal_conv1d(qkvz[..., :conv], conv_weight, None))
+
+
+def _normalised(qkv, heads, dtype):
+    """The rule's ``(q, k, v)`` from the convolved heads: ``q`` and ``k``
+    (B, L, G, N) L2-normalised a head in float32, ``q`` scaled by ``N **
+    -0.5``, in ``dtype``; ``v`` (B, L, H, P) as it is."""
+    bsz, length, _ = qkv.shape
+    wide = heads.keys * heads.n
+    q, k = (_l2_norm(t.reshape(bsz, length, heads.keys, heads.n), 1e-6)
+            for t in (qkv[..., :wide], qkv[..., wide:2 * wide]))
+    v = qkv[..., 2 * wide:].reshape(bsz, length, heads.values, heads.p)
+    return (q * heads.n ** -0.5).astype(dtype), k.astype(dtype), v
+
+
+def _operands_plain(qkvz, conv_weight, heads):
+    """The rule's ``(q, k, v)`` from the packed projection, plain JAX."""
+    return _normalised(_convolved(qkvz, conv_weight, heads), heads,
+                       qkvz.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _operand_kernels(qkvz, conv_weight, heads):
+    """The rule's ``(q, k, v)`` from the packed projection, a program that
+    takes its form when it is lowered: for a TPU the kernels of
+    ``ops.gdn_conv_kernel``, which read ``[q | k | v]`` where the
+    projection wrote them and convolve, activate and normalise in one
+    pass, forward and backward; for any other platform ``_convolved`` and
+    ``_normalised`` and JAX's own derivative of them. A recomputation
+    unit keeps nothing of it: both passes read the kept projection."""
+    return _operand_kernels_fwd(qkvz, conv_weight, heads)[0]
+
+
+def _operand_kernels_fwd(qkvz, conv_weight, heads):
+    lead = qkvz.shape[:-1]
+
+    def kernels(x, w):
+        q, k, v = gdn_conv_kernel.forward(
+            attn_kernel.counted_site(x, gdn_conv_kernel.GAUGE), w, heads)
+        return (q.reshape(lead + (heads.keys, heads.n)),
+                k.reshape(lead + (heads.keys, heads.n)),
+                v.reshape(lead + (heads.values, heads.p)))
+
+    out = lax.platform_dependent(
+        qkvz, conv_weight, tpu=kernels,
+        default=lambda x, w: _operands_plain(x, w, heads))
+    return out, (qkvz, conv_weight)
+
+
+def _operand_kernels_bwd(heads, res, cts):
+    # no scope of its own: the backward rule carries ``mx_gdn_conv`` from
+    # its forward's call site (``_rule_kernels_bwd``)
+    def kernels(x, w, *cts):
+        d_rows, d_w = gdn_conv_kernel.backward(
+            x, w, *(d.reshape(x.shape[:-1] + (-1,)) for d in cts), heads)
+        gate = jnp.zeros(x.shape[:-1] + (x.shape[-1] - w.shape[0],), x.dtype)
+        return jnp.concatenate(d_rows + (gate,), axis=-1), d_w
+
+    return lax.platform_dependent(
+        *res, *cts, tpu=kernels,
+        default=lambda x, w, *d: jax.vjp(
+            lambda x, w: _operands_plain(x, w, heads), x, w)[1](d))
+
+
+_operand_kernels.defvjp(_operand_kernels_fwd, _operand_kernels_bwd)
+
+
 @register_op("GatedDeltaNet", names_its_parts=True)
 def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, dt_bias,
                     a_log, norm_weight, out_weight, num_k_heads=1,
@@ -434,17 +505,21 @@ def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, dt_bias,
         qkvz = kept(_mm(data, qkvz_weight))
         ba = kept(_mm(data, ba_weight))
     conv = 2 * hk * dk + hv * dv
+    heads = gdn_conv_kernel.Heads(hk, dk, hv, dv)
+    fused = qkvz.dtype == data.dtype and gdn_conv_kernel.takes(
+        heads, conv_weight.shape[1], qkvz.dtype, conv_weight.dtype)
     with jax.named_scope("mx_gdn_conv"):
-        qkv = jax.nn.silu(causal_conv1d(qkvz[..., :conv], conv_weight, None))
+        if fused:
+            q, k, v = _operand_kernels(qkvz, conv_weight, heads)
+        else:
+            qkv = _convolved(qkvz, conv_weight, heads)
     with jax.named_scope("mx_gdn_rule"):
         beta = jax.nn.sigmoid(ba[..., :hv].astype(_F32))
         g = -jnp.exp(a_log.astype(_F32)) * jax.nn.softplus(
             ba[..., hv:].astype(_F32) + dt_bias.astype(_F32))
-        q, k = (_l2_norm(t.reshape(bsz, length, hk, dk), 1e-6)
-                for t in (qkv[..., :hk * dk], qkv[..., hk * dk:2 * hk * dk]))
-        v = qkv[..., 2 * hk * dk:].reshape(bsz, length, hv, dv)
-        o = gated_delta_rule((q * dk ** -0.5).astype(data.dtype),
-                             k.astype(data.dtype), v, beta, g, chunk_size)
+        if not fused:
+            q, k, v = _normalised(qkv, heads, data.dtype)
+        o = gated_delta_rule(q, k, v, beta, g, chunk_size)
     with jax.named_scope("mx_gdn_gate"):
         z = qkvz[..., conv:].astype(_F32).reshape(bsz, length, hv, dv)
         y = _rms_norm(o, norm_weight, eps=eps) * jax.nn.silu(z)

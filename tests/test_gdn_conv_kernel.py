@@ -1,0 +1,405 @@
+"""The Gated DeltaNet mixer's operand kernels (``ops/gdn_conv_kernel.py``:
+the convolution over ``[q | k | v]``, SiLU and the heads' L2 norm, read
+from the packed projection in place) against the plain form
+(``causal_conv1d``, ``silu``, ``_l2_norm``) and JAX's own derivative of it,
+interpreted on the CPU; the rule of shapes they are taken by; and which
+form ``gated_delta_net`` takes: the kernels where the rule takes the
+shapes and the program is lowered for a TPU, the plain form everywhere
+else, with the gauge ``gdn::conv_kernel_sites`` counting the sites.
+Nothing here is a time."""
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+import mxnet_tpu as mx
+from mxnet_tpu.ops import gdn_conv_kernel, seq
+
+N = P = 128
+TAPS = 4
+
+
+def _heads(group):
+    return gdn_conv_kernel.Heads(1, N, group, P)
+
+
+def _operands(bsz, length, group, dtype, seed=0, taps=TAPS, keys=1):
+    """``(qkvz, weight)``: the packed projection (B, L, [q | k | v | z])
+    and the taps, in ``dtype``."""
+    heads = gdn_conv_kernel.Heads(keys, N, keys * group, P)
+    conv = 2 * keys * N + heads.values * P
+    rng = np.random.default_rng(seed)
+    qkvz = rng.normal(size=(bsz, length, conv + heads.values * P)) * 0.7
+    weight = rng.normal(size=(conv, taps)) * 0.5
+    return jnp.asarray(qkvz, dtype), jnp.asarray(weight, dtype), heads
+
+
+def _flat(outs):
+    return tuple(o.reshape(o.shape[:2] + (-1,)) for o in outs)
+
+
+def _cots(outs, seed=9):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.normal(size=o.shape), o.dtype) for o in outs)
+
+
+def _plain(qkvz, weight, heads, cots):
+    """The plain form's ``(q, k, v)`` and JAX's gradients of it for the
+    projection and the taps, in float32 from the operands as given."""
+    def loss(x, w):
+        outs = _flat(seq._operands_plain(x, w, heads))
+        return sum(jnp.sum(o * c.astype(jnp.float32))
+                   for o, c in zip(outs, cots)), outs
+
+    (_, outs), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+        qkvz.astype(jnp.float32), weight.astype(jnp.float32))
+    return outs, grads
+
+
+def _close(got, want, tol, name):
+    assert got.shape == want.shape, name
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    np.testing.assert_allclose(got, want, rtol=tol,
+                               atol=tol * np.abs(want).max(), err_msg=name)
+
+
+# two blocks of 256 rows; one block and a row; shorter than the taps
+@pytest.mark.parametrize("length", [512, 257, 2])
+@pytest.mark.parametrize("group", [1, 2])
+@pytest.mark.parametrize("bsz", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_kernels_are_the_plain_form_and_its_derivative(dtype, bsz, group,
+                                                       length):
+    """``q``, ``k``, ``v`` and the gradients for the projection's
+    convolved columns and for the taps: in float32 to 1e-5 of the largest
+    value, in bfloat16 within the rounding of one output. A batch entry's
+    first rows see zeros before them, not the entry before; a block's
+    first rows see the block before through the halo, and its last rows'
+    cotangent the block after through the carried rows."""
+    qkvz, weight, heads = _operands(bsz, length, group, jnp.dtype(dtype),
+                                    seed=length + group)
+    got = gdn_conv_kernel.forward(qkvz, weight, tuple(heads), interpret=True)
+    cots = _cots(got)
+    want, (want_dx, want_dw) = _plain(qkvz, weight, heads, cots)
+    tol = 1e-5 if dtype == "float32" else 2.0 ** -7
+    for name, a, b in zip("qkv", got, want):
+        assert a.dtype == qkvz.dtype
+        _close(a, b, tol, name)
+    d_rows, d_weight = gdn_conv_kernel.backward(
+        qkvz, weight, *cots, tuple(heads), interpret=True)
+    assert d_weight.dtype == weight.dtype
+    conv = weight.shape[0]
+    assert not np.asarray(want_dx[..., conv:]).any()    # the gate's columns
+    _close(jnp.concatenate(d_rows, axis=-1), want_dx[..., :conv], tol, "dx")
+    _close(d_weight, want_dw, 4 * tol, "dw")
+
+
+def test_a_second_sequence_does_not_see_the_first_one_s_rows():
+    """The halo and the carried rows are zeros where a batch entry begins
+    and ends: the second entry gives what it gives alone, to the bit."""
+    qkvz, weight, heads = _operands(2, 300, 2, jnp.bfloat16, seed=3)
+    both = gdn_conv_kernel.forward(qkvz, weight, tuple(heads),
+                                   interpret=True)
+    alone = gdn_conv_kernel.forward(qkvz[1:], weight, tuple(heads),
+                                    interpret=True)
+    cots = _cots(both)
+    d_both, _ = gdn_conv_kernel.backward(qkvz, weight, *cots, tuple(heads),
+                                         interpret=True)
+    d_alone, _ = gdn_conv_kernel.backward(
+        qkvz[1:], weight, *(c[1:] for c in cots), tuple(heads),
+        interpret=True)
+    for a, b in zip(both + d_both, alone + d_alone):
+        np.testing.assert_array_equal(np.asarray(a[1:], np.float32),
+                                      np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("taps", [2, 3])
+def test_other_filters_than_four_taps(taps):
+    qkvz, weight, heads = _operands(1, 70, 1, jnp.float32, seed=taps,
+                                    taps=taps)
+    got = gdn_conv_kernel.forward(qkvz, weight, tuple(heads), interpret=True)
+    cots = _cots(got)
+    want, (want_dx, want_dw) = _plain(qkvz, weight, heads, cots)
+    for name, a, b in zip("qkv", got, want):
+        _close(a, b, 1e-5, name)
+    d_rows, d_weight = gdn_conv_kernel.backward(
+        qkvz, weight, *cots, tuple(heads), interpret=True)
+    _close(jnp.concatenate(d_rows, axis=-1), want_dx[..., :weight.shape[0]],
+           1e-5, "dx")
+    _close(d_weight, want_dw, 4e-5, "dw")
+
+
+def test_two_key_heads_are_normalised_each_over_its_own_lanes():
+    """Two heads of ``q`` side by side in one part: each row's norm is
+    over one head's 128 lanes, and ``q`` is scaled by ``N ** -0.5``."""
+    qkvz, weight, heads = _operands(1, 40, 2, jnp.float32, seed=8, keys=2)
+    q, k, v = gdn_conv_kernel.forward(qkvz, weight, tuple(heads),
+                                      interpret=True)
+    norms = np.linalg.norm(np.asarray(k).reshape(1, 40, 2, N), axis=-1)
+    np.testing.assert_allclose(norms, 1.0, atol=1e-4)
+    norms = np.linalg.norm(np.asarray(q).reshape(1, 40, 2, N), axis=-1)
+    np.testing.assert_allclose(norms, N ** -0.5, atol=1e-5)
+    want = _flat(seq._operands_plain(qkvz, weight, heads))
+    for name, a, b in zip("qkv", (q, k, v), want):
+        _close(a, b, 1e-5, name)
+
+
+def test_the_rule_of_shapes_reads_shapes_alone():
+    """Key and value heads whole lane tiles, 2 to 9 taps, one dtype for
+    the projection and the taps, column parts that exist and the blocks
+    under the VMEM budget: the Qwen3-Next cell's shapes are taken (two
+    parts of 4096 columns); heads that are no lane tile, mixed dtypes, a
+    long filter and rows that would not fit are not."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    heads = gdn_conv_kernel.Heads
+    cell = heads(16, 128, 32, 128)
+    assert gdn_conv_kernel.takes(cell, 4, bf16, bf16)
+    assert gdn_conv_kernel.parts(cell) == 2
+    assert gdn_conv_kernel.takes(heads(1, 128, 1, 128), 4, f32, f32)
+    assert gdn_conv_kernel.takes(heads(2, 256, 4, 128), 2, bf16, bf16)
+    assert not gdn_conv_kernel.takes(heads(2, 8, 4, 6), 4, bf16, bf16)
+    assert not gdn_conv_kernel.takes(heads(2, 128, 4, 6), 4, bf16, bf16)
+    assert not gdn_conv_kernel.takes(heads(2, 192, 4, 128), 4, bf16, bf16)
+    assert not gdn_conv_kernel.takes(cell, 4, bf16, f32)     # mixed dtypes
+    assert not gdn_conv_kernel.takes(cell, 4, jnp.float16, jnp.float16)
+    assert not gdn_conv_kernel.takes(cell, 1, bf16, bf16)
+    assert not gdn_conv_kernel.takes(cell, 10, bf16, bf16)
+    # ``v``'s window has to begin at a whole multiple of its width
+    assert gdn_conv_kernel.parts(heads(1, 128, 3, 128)) is None
+    assert not gdn_conv_kernel.takes(heads(1, 128, 3, 128), 4, bf16, bf16)
+    # one key head 4096 wide cannot be cut into parts: over the budget
+    assert not gdn_conv_kernel.takes(heads(1, 32768, 1, 32768), 4, f32, f32)
+    held = gdn_conv_kernel.held_bytes(cell, 2)
+    assert 8e6 < held < gdn_conv_kernel._BUDGET_BYTES \
+        < gdn_conv_kernel._VMEM_LIMIT_BYTES
+    # a long sequence in steps of 256 rows, a short one in one step of
+    # whole groups
+    assert gdn_conv_kernel.block_rows(8192) == (8192, 256)
+    assert gdn_conv_kernel.block_rows(257) == (512, 256)
+    assert gdn_conv_kernel.block_rows(100) == (128, 128)
+    assert gdn_conv_kernel.block_rows(2) == (32, 32)
+
+
+# ---------------------------------------------------------------------------
+# the mixer's two forms
+# ---------------------------------------------------------------------------
+class _LoweredForATpu:
+    """Stands where ``ops.seq`` names ``jax.lax``: every
+    ``platform_dependent`` takes its TPU branch, as a lowering for a TPU
+    would."""
+
+    def __getattr__(self, name):
+        return getattr(lax, name)
+
+    @staticmethod
+    def platform_dependent(*args, tpu, default):
+        return tpu(*args)
+
+
+def _mixer(head, dtype, length=40, bsz=2, seed=4):
+    """``loss(data, *weights)`` of a ``gated_delta_net`` with one key head
+    and two value heads ``head`` wide, and its arguments."""
+    hidden = 32
+    conv = 4 * head
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(rng.normal(size=shape) * scale, dtype)
+
+    args = (draw(bsz, length, hidden),
+            draw(conv + 2 * head, hidden, scale=hidden ** -0.5),
+            draw(4, hidden, scale=hidden ** -0.5), draw(conv, TAPS, scale=0.5),
+            draw(2), draw(2, scale=0.1), 1 + draw(head, scale=0.1),
+            draw(hidden, 2 * head, scale=head ** -0.5))
+    cot = jnp.asarray(rng.normal(size=(bsz, length, hidden)), jnp.float32)
+
+    def loss(*a):
+        out = seq.gated_delta_net(*a, num_k_heads=1, num_v_heads=2,
+                                  key_dim=head, value_dim=head, chunk_size=16)
+        return jnp.sum(out * cot)
+
+    return loss, args
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_mixer_through_the_kernels_is_the_plain_form_with_every_gradient(
+        dtype, monkeypatch):
+    """The TPU's branch of the mixer's ``custom_vjp`` on this CPU, the
+    kernels interpreted (the rule's stay the plain form): the value and
+    the gradients for the input and all seven weights, the projection's
+    cotangent put together from the kernels' three parts and zeros for
+    the gate's columns, which the gate's own path fills."""
+    loss, args = _mixer(N, jnp.dtype(dtype))
+    fn = jax.value_and_grad(loss, argnums=range(len(args)))
+    monkeypatch.setattr(seq.gdn_kernel, "takes", lambda *a: False)
+    with monkeypatch.context() as m:
+        m.setattr(gdn_conv_kernel, "takes", lambda *a: False)
+        want = fn(*args)
+    monkeypatch.setattr(seq, "lax", _LoweredForATpu())
+    for name in ("forward", "backward"):
+        monkeypatch.setattr(gdn_conv_kernel, name, functools.partial(
+            getattr(gdn_conv_kernel, name), interpret=True))
+    got = fn(*args)
+    tol = 2e-4 if dtype == "float32" else 2.0 ** -5
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype
+        _close(a, b, tol, "leaf")
+
+
+def _lowered(head, platform, dtype=jnp.bfloat16):
+    """The text of the mixer's value and gradients lowered for
+    ``platform`` at heads ``head`` wide, and what the gauge counted."""
+    loss, args = _mixer(head, dtype)
+    mx.telemetry.gauge(gdn_conv_kernel.GAUGE).set(0)
+    text = jax.jit(jax.value_and_grad(loss, argnums=range(len(args)))).trace(
+        *args).lower(lowering_platforms=(platform,)).as_text()
+    return text, mx.telemetry.gauge(gdn_conv_kernel.GAUGE).get()
+
+
+@pytest.mark.parametrize("head,platform,sites", [
+    (128, "tpu", 1),    # the kernels: one forward, one backward
+    (128, "cpu", 0),    # another platform: the plain form
+    (8, "tpu", 0)])     # heads the rule of shapes refuses: the same
+def test_kernel_sites_follow_the_platform_and_the_rule_of_shapes(
+        head, platform, sites):
+    text, counted = _lowered(head, platform)
+    assert counted == sites
+    assert ("gdn_conv_fwd_kernel" in text) \
+        == ("gdn_conv_bwd_kernel" in text) == bool(sites)
+    # the plain form pads the rows for its taps; the kernels read a halo
+    assert ("stablehlo.pad" in text) or sites
+
+
+def test_taps_of_another_dtype_stay_the_plain_form():
+    """A float32 filter over a bfloat16 projection is rounded by the
+    plain form where it multiplies: no kernel."""
+    loss, args = _mixer(N, jnp.bfloat16)
+    args = args[:3] + (args[3].astype(jnp.float32),) + args[4:]
+    mx.telemetry.gauge(gdn_conv_kernel.GAUGE).set(0)
+    text = jax.jit(loss).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "gdn_conv" not in text
+    assert mx.telemetry.gauge(gdn_conv_kernel.GAUGE).get() == 0
+
+
+def _parent_s_mixer(data, qkvz_weight, ba_weight, conv_weight, dt_bias, a_log,
+                    norm_weight, out_weight, hk, hv, dk, dv, chunk):
+    """``gated_delta_net`` as it stood before the kernels, line for
+    line."""
+    _F32 = jnp.float32
+    bsz, length, _ = data.shape
+    qkvz = seq.kept(seq._mm(data, qkvz_weight))
+    ba = seq.kept(seq._mm(data, ba_weight))
+    conv = 2 * hk * dk + hv * dv
+    qkv = jax.nn.silu(seq.causal_conv1d(qkvz[..., :conv], conv_weight, None))
+    beta = jax.nn.sigmoid(ba[..., :hv].astype(_F32))
+    g = -jnp.exp(a_log.astype(_F32)) * jax.nn.softplus(
+        ba[..., hv:].astype(_F32) + dt_bias.astype(_F32))
+    q, k = (seq._l2_norm(t.reshape(bsz, length, hk, dk), 1e-6)
+            for t in (qkv[..., :hk * dk], qkv[..., hk * dk:2 * hk * dk]))
+    v = qkv[..., 2 * hk * dk:].reshape(bsz, length, hv, dv)
+    o = seq.gated_delta_rule((q * dk ** -0.5).astype(data.dtype),
+                             k.astype(data.dtype), v, beta, g, chunk)
+    z = qkvz[..., conv:].astype(_F32).reshape(bsz, length, hv, dv)
+    y = seq._rms_norm(o, norm_weight, eps=1e-6) * jax.nn.silu(z)
+    return seq._mm(y.reshape(bsz, length, hv * dv).astype(data.dtype),
+                   out_weight)
+
+
+def _without_locations(text):
+    import re
+    return re.sub(r"\s*loc\([^\n]*\)|#loc[^\n]*\n", "", text)
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_refused_shapes_lower_to_the_parent_s_text(platform):
+    """At heads the rule of shapes refuses, the mixer's lowered text,
+    value and gradients, is the text of the lines it had before the
+    kernels, for either platform."""
+    loss, args = _mixer(8, jnp.bfloat16)
+    cot = jnp.ones(args[0].shape, jnp.float32)
+
+    def text(fn):
+        def loss(*a):
+            return jnp.sum(fn(*a) * cot)
+
+        return _without_locations(
+            jax.jit(jax.value_and_grad(loss, argnums=range(len(args)))).trace(
+                *args).lower(lowering_platforms=(platform,)).as_text(
+                    debug_info=False))
+
+    assert text(lambda *a: seq.gated_delta_net(
+        *a, num_k_heads=1, num_v_heads=2, key_dim=8, value_dim=8,
+        chunk_size=16)) == text(lambda *a: _parent_s_mixer(*a, 1, 2, 8, 8, 16))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_off_a_tpu_the_values_are_the_parent_s_to_the_bit(dtype):
+    """Where the rule takes the shapes and the platform is not a TPU, the
+    mixer's value and every gradient are what the lines it had before the
+    kernels give."""
+    loss, args = _mixer(N, jnp.dtype(dtype))
+    cot = jnp.asarray(np.random.default_rng(2).normal(size=args[0].shape),
+                      jnp.float32)
+    assert gdn_conv_kernel.takes(_heads(2), TAPS, jnp.dtype(dtype),
+                                 jnp.dtype(dtype))
+
+    def through(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a) * cot), argnums=range(len(args))))(
+                *args)
+
+    got = through(lambda *a: seq.gated_delta_net(
+        *a, num_k_heads=1, num_v_heads=2, key_dim=N, value_dim=N,
+        chunk_size=16))
+    want = through(lambda *a: _parent_s_mixer(*a, 1, 2, N, N, 16))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_a_unit_through_the_kernels_keeps_what_the_plain_form_keeps(
+        monkeypatch):
+    """Both input products and the gated norm's statistics, nothing of
+    the convolution: the kernels read the kept projection in both
+    passes."""
+    from mxnet_tpu.ops import remat
+    loss, args = _mixer(N, jnp.bfloat16)
+
+    def unit(data):
+        return seq.gated_delta_net(data, *args[1:], num_k_heads=1,
+                                   num_v_heads=2, key_dim=N, value_dim=N,
+                                   chunk_size=16)
+
+    got = remat.kept_bytes(jax.make_jaxpr(unit)(args[0]))
+    monkeypatch.setattr(gdn_conv_kernel, "takes", lambda *a: False)
+    want = remat.kept_bytes(jax.make_jaxpr(unit)(args[0]))
+    assert got == want > 0
+
+
+def test_a_train_step_sets_the_gauge_to_zero_where_it_traces():
+    """``TrainStep`` resets ``gdn::conv_kernel_sites`` beside the other
+    kernels' gauges, so that a step's reading is that step's: a model
+    with a delta-rule layer traced for this CPU reads 0 whatever stood
+    there."""
+    from mxnet_tpu.gluon.model_zoo import PatternLM
+    from mxnet_tpu.parallel import TrainStep
+    net = PatternLM("D", 31, 16,
+                    linear_attention=dict(num_k_heads=1, num_v_heads=2,
+                                          key_dim=8, value_dim=8,
+                                          chunk_size=8),
+                    mlp=dict(units=24))
+    net.initialize(mx.init.Normal(0.3))
+    step = TrainStep(net, loss="softmax_ce", optimizer="sgd",
+                     optimizer_params=dict(learning_rate=1e-2))
+    gauge = mx.telemetry.gauge(gdn_conv_kernel.GAUGE)
+    gauge.set(7)
+    step(mx.nd.array(np.zeros((2, 6), np.int32)),
+         mx.nd.array(np.zeros((12,), np.int32)))
+    assert gauge.get() == 0
