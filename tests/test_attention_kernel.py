@@ -5,7 +5,6 @@ the packed rows; padding; what a recomputation unit around the op keeps
 and what its backward pass runs; where the op takes the kernels
 (``head_dim`` a multiple of 128 and a program lowered for a TPU) and the
 gauge ``attn::kernel_sites`` that counts it. Nothing here is a time."""
-import functools
 import re
 
 import numpy as np
@@ -13,10 +12,12 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 import mxnet_tpu as mx
 from mxnet_tpu.ops import attn_kernel, remat, seq
+
+import numerics
+from numerics import Tol, kernels_here  # noqa: F401
 
 D = 128
 
@@ -58,22 +59,12 @@ def _blocked(data, hq, hk, rope_theta=None, block=128):
     return _op(data, hq, hk, rope_theta, block=block)
 
 
-@pytest.fixture()
-def kernels_here(monkeypatch):
-    """The op takes its TPU branch on this backend, kernels interpreted."""
-    monkeypatch.setattr(lax, "platform_dependent",
-                        lambda *args, tpu, default: tpu(*args))
-    for name in ("forward", "backward"):
-        monkeypatch.setattr(attn_kernel, name, functools.partial(
-            getattr(attn_kernel, name), interpret=True))
-
-
 def _value_and_grad(fn, data, weight):
-    def loss(d):
-        return jnp.sum(fn(d).astype(jnp.float32) * weight)
-    out = fn(data).astype(jnp.float32)
-    return np.asarray(out), np.asarray(jax.grad(loss)(data)
-                                       .astype(jnp.float32))
+    """``fn(data)`` and the gradient of its sum weighted by ``weight``, as
+    float32 arrays."""
+    out, grad = numerics.traced(lambda d: fn(d).astype(jnp.float32), (data,),
+                                weight, 0)
+    return np.asarray(out), np.asarray(grad.astype(jnp.float32))
 
 
 CASES = [(length, hq, hk, theta, dtype)
@@ -99,9 +90,7 @@ def test_kernels_agree_with_dense_and_blocked(kernels_here, length, hq, hk,
     for other in (_dense, _blocked):
         want = _value_and_grad(lambda d: other(d, hq, hk, theta), data,
                                weight)
-        for g, w in zip(got, want):
-            scale = np.abs(w).max()
-            assert np.abs(g - w).max() <= tol * scale, other.__name__
+        numerics.close(got, want, Tol(rtol=0.0, scaled=tol), other.__name__)
 
 
 def test_dq_is_the_float32_sum_over_key_blocks_rounded_once():

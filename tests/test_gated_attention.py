@@ -6,7 +6,6 @@ of the benchmark's reference (``benchmark/configs/qwen3-next-80b-a3b.py``),
 values and gradients; the fused kernels interpreted at heads of 256 with
 eight query heads on one key/value head; the backward's choice of its
 by-side form at that cell's 8192 rows. Nothing here is a time."""
-import functools
 import os
 import re
 import sys
@@ -16,7 +15,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 import mxnet_tpu as mx
 from mxnet_tpu.gluon import nn
@@ -25,7 +23,8 @@ from mxnet_tpu.ops import attn_kernel, remat, seq
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 import harness  # noqa: E402
-
+import numerics  # noqa: E402
+from numerics import Tol, kernels_here  # noqa: E402, F401
 
 
 @pytest.fixture(autouse=True)
@@ -84,16 +83,10 @@ def test_gated_attention_is_the_plain_one(length):
     w = _weights()
     x = jnp.asarray(np.random.default_rng(1).normal(size=(2, length, 24)),
                     jnp.float32)
-    np.testing.assert_allclose(_layer(w, x), _plain(w, x), rtol=2e-5,
-                               atol=2e-6)
     weight = jnp.asarray(np.random.default_rng(2).normal(
         size=(2, length, 24)), jnp.float32)
-    got = jax.grad(lambda w, x: jnp.sum(_layer(w, x) * weight), (0, 1))(w, x)
-    want = jax.grad(lambda w, x: jnp.sum(_plain(w, x) * weight), (0, 1))(w, x)
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(a, b, atol=3e-5 * float(jnp.max(
-            jnp.abs(b))))
+    numerics.agree(_layer, _plain, (w, x), weight, (0, 1),
+                   value=Tol(rtol=2e-5, atol=2e-6), grads=Tol(scaled=3e-5))
 
 
 def test_partial_rotation_turns_the_first_part_alone():
@@ -123,13 +116,17 @@ def test_head_norm_scales_by_one_plus_w_before_the_rotation():
     x = jnp.asarray(np.random.default_rng(4).normal(size=(1, 6, 24)),
                     jnp.float32)
     scaled = dict(zero, qkv_weight=zero["qkv_weight"].at[:32].multiply(3.0))
-    np.testing.assert_allclose(_layer(zero, x), _layer(scaled, x),
-                               rtol=1e-4, atol=1e-6)
+    numerics.agree(_layer, lambda w, x: _layer(scaled, x), (zero, x),
+                   value=Tol(rtol=1e-4, atol=1e-6))
     flat = dict(zero, k_norm_weight=-jnp.ones(8))
-    qkv = seq._mm(x, flat["qkv_weight"])[..., :64]      # no gate
-    out = seq.causal_gq_attention(
-        qkv, flat["q_norm_weight"], flat["k_norm_weight"], num_heads=4,
-        num_kv_heads=2, head_dim=8, block=4, unit_offset=True)
+
+    def uniform(w, x):
+        qkv = seq._mm(x, w["qkv_weight"])[..., :64]      # no gate
+        return qkv, seq.causal_gq_attention(
+            qkv, w["q_norm_weight"], w["k_norm_weight"], num_heads=4,
+            num_kv_heads=2, head_dim=8, block=4, unit_offset=True)
+
+    (qkv, out), _ = numerics.traced(uniform, (flat, x))
     v = qkv[..., 48:64].reshape(1, 6, 2, 8)
     means = jnp.cumsum(v, axis=1) / jnp.arange(1, 7)[None, :, None, None]
     np.testing.assert_allclose(out.reshape(1, 6, 4, 8),
@@ -140,12 +137,12 @@ def test_the_gate_is_read_behind_k_and_v_and_multiplies_each_head():
     w = _weights()
     x = jnp.asarray(np.random.default_rng(5).normal(size=(1, 6, 24)),
                     jnp.float32)
-    qkv = seq._mm(x, w["qkv_weight"])
     kw = dict(num_heads=4, num_kv_heads=2, head_dim=8, block=4)
-    plain = seq.causal_gq_attention(qkv[..., :64], **kw)
-    gated = seq.causal_gq_attention(qkv, gated=True, **kw)
-    np.testing.assert_allclose(
-        gated, plain * jax.nn.sigmoid(qkv[..., 64:]), rtol=1e-5)
+    numerics.agree(
+        lambda qkv: seq.causal_gq_attention(qkv, gated=True, **kw),
+        lambda qkv: seq.causal_gq_attention(qkv[..., :64], **kw)
+        * jax.nn.sigmoid(qkv[..., 64:]),
+        (seq._mm(x, w["qkv_weight"]),), value=Tol(rtol=1e-5))
 
 
 def test_block_holds_the_gate_and_both_head_norms():
@@ -164,8 +161,9 @@ def test_block_holds_the_gate_and_both_head_norms():
     x = mx.nd.array(np.random.default_rng(0).normal(size=(2, 7, 24))
                     .astype(np.float32))
     w = {k: p.data()._data for k, p in params.items()}
-    np.testing.assert_allclose(block(x).asnumpy(), _layer(w, x._data),
-                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        block(x).asnumpy(), numerics.traced(_layer, (w, x._data))[0],
+        rtol=1e-5, atol=1e-6)
     # without the options the block is the one it was
     plain = nn.GQAttention(24, 4, 2, head_dim=8)
     assert {n.split("_", 1)[1]: p.shape for n, p in
@@ -194,16 +192,6 @@ WIDE = dict(SZ, num_attention_heads=8, num_key_value_heads=1, head_dim=256,
             reference_attention_block=128)
 
 
-@pytest.fixture()
-def kernels_here(monkeypatch):
-    """The op takes its TPU branch on this backend, kernels interpreted."""
-    monkeypatch.setattr(lax, "platform_dependent",
-                        lambda *args, tpu, default: tpu(*args))
-    for name in ("forward", "backward"):
-        monkeypatch.setattr(attn_kernel, name, functools.partial(
-            getattr(attn_kernel, name), interpret=True))
-
-
 @pytest.mark.parametrize("length,limit", [(256, None), (200, 0)])
 def test_kernels_at_heads_of_256_with_eight_on_one(kernels_here, monkeypatch,
                                                    length, limit):
@@ -217,15 +205,11 @@ def test_kernels_at_heads_of_256_with_eight_on_one(kernels_here, monkeypatch,
                     jnp.float32)
     weight = jnp.asarray(np.random.default_rng(8).normal(
         size=(1, length, 64)), jnp.float32)
-    fns = (lambda w, x: _layer(w, x, WIDE, block=128),
-           lambda w, x: _plain(w, x, WIDE))
-    got, want = (jax.value_and_grad(
-        lambda w, x: jnp.sum(fn(w, x) * weight), (0, 1))(w, x) for fn in fns)
-    np.testing.assert_allclose(got[0], want[0], rtol=2e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
-                    jax.tree_util.tree_leaves(want[1])):
-        np.testing.assert_allclose(a, b, atol=5e-5 * float(jnp.max(
-            jnp.abs(b))))
+    # the weighted sum of the outputs, and its gradients
+    numerics.agree(
+        lambda w, x: jnp.sum(_layer(w, x, WIDE, block=128) * weight),
+        lambda w, x: jnp.sum(_plain(w, x, WIDE) * weight), (w, x), 1.0,
+        (0, 1), value=Tol(rtol=2e-5), grads=Tol(scaled=5e-5))
 
 
 def test_the_backward_is_by_side_at_the_cell_s_rows_and_fused_below():

@@ -21,7 +21,8 @@ from mxnet_tpu.ops import remat, seq
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 import harness  # noqa: E402
-
+import numerics  # noqa: E402
+from numerics import Tol  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -81,10 +82,10 @@ def _rule_inputs(length, seed=0, decay=3.0):
                                           (5, 8), (130, 64)])
 def test_chunked_rule_is_the_recurrence(length, chunk):
     args = _rule_inputs(length, seed=length)
-    got = seq.gated_delta_rule(*args, chunk=chunk)
-    want = _recurrence(*args)
-    assert got.shape == want.shape and got.dtype == jnp.float32
-    np.testing.assert_allclose(got, want, atol=2e-6)
+    got, _ = numerics.agree(
+        lambda *a: seq.gated_delta_rule(*a, chunk=chunk), _recurrence, args,
+        value=Tol(atol=2e-6))
+    assert got.dtype == jnp.float32
 
 
 @pytest.mark.parametrize("length,chunk", [(32, 16), (27, 8)])
@@ -92,13 +93,10 @@ def test_chunked_rule_s_gradients_are_the_recurrence_s(length, chunk):
     args = _rule_inputs(length, seed=3)
     weight = jnp.asarray(np.random.default_rng(9).normal(
         size=(2, length, 4, 6)), jnp.float32)
-    got = jax.grad(lambda *a: jnp.sum(
-        seq.gated_delta_rule(*a, chunk=chunk) * weight), range(5))(*args)
-    want = jax.grad(lambda *a: jnp.sum(_recurrence(*a) * weight),
-                    range(5))(*args)
+    got, want = (numerics.traced(fn, args, weight, range(5))[1] for fn in (
+        lambda *a: seq.gated_delta_rule(*a, chunk=chunk), _recurrence))
     for a, b, name in zip(got, want, "q k v beta g".split()):
-        np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.max(
-            jnp.abs(b))), err_msg=name)
+        numerics.close(a, b, Tol(scaled=2e-5), name)
 
 
 def test_a_strong_decay_underflows_to_zero_and_not_to_nan():
@@ -106,9 +104,10 @@ def test_a_strong_decay_underflows_to_zero_and_not_to_nan():
     near -20 a step, -1300 over a chunk) reads ``exp`` of differences
     only, never a quotient of two underflowed numbers."""
     args = _rule_inputs(40, seed=5, decay=25.0)
-    got, grads = jax.value_and_grad(lambda *a: jnp.sum(jnp.square(
-        seq.gated_delta_rule(*a, chunk=16))), range(5))(*args)
-    want = jnp.sum(jnp.square(_recurrence(*args)))
+    got, grads = numerics.traced(lambda *a: jnp.sum(jnp.square(
+        seq.gated_delta_rule(*a, chunk=16))), args, 1.0, range(5))
+    want, _ = numerics.traced(
+        lambda *a: jnp.sum(jnp.square(_recurrence(*a))), args)
     assert np.isfinite(float(got))
     np.testing.assert_allclose(got, want, rtol=1e-5)
     assert all(bool(jnp.all(jnp.isfinite(g))) for g in grads)
@@ -118,9 +117,10 @@ def test_a_padded_tail_writes_nothing():
     """The same first 20 outputs whether 20 steps are given (padded to
     two chunks) or 32."""
     args = _rule_inputs(32, seed=1)
-    whole = seq.gated_delta_rule(*args, chunk=16)
-    short = seq.gated_delta_rule(*(t[:, :20] for t in args), chunk=16)
-    np.testing.assert_allclose(short, whole[:, :20], atol=1e-6)
+    numerics.agree(
+        lambda *a: seq.gated_delta_rule(*(t[:, :20] for t in a), chunk=16),
+        lambda *a: seq.gated_delta_rule(*a, chunk=16)[:, :20], args,
+        value=Tol(atol=1e-6))
 
 
 # -- the mixer ----------------------------------------------------------------
@@ -161,11 +161,12 @@ def test_mixer_is_the_plain_mixer(length):
     sz, w = _mixer_weights()
     x = jnp.asarray(np.random.default_rng(2).normal(size=(2, length, 16)),
                     jnp.float32)
-    np.testing.assert_allclose(_mixer(w, x), _plain_mixer(sz, w, x),
-                               rtol=2e-5, atol=2e-5)
+    tol = Tol(rtol=2e-5, atol=2e-5)
+    numerics.agree(_mixer, lambda w, x: _plain_mixer(sz, w, x), (w, x),
+                   value=tol)
     # no result depends on the chunk
-    np.testing.assert_allclose(_mixer(w, x, chunk=4), _mixer(w, x, chunk=64),
-                               rtol=2e-5, atol=2e-5)
+    numerics.agree(lambda w, x: _mixer(w, x, chunk=4),
+                   lambda w, x: _mixer(w, x, chunk=64), (w, x), value=tol)
 
 
 def test_mixer_s_gradients_are_the_plain_mixer_s():
@@ -174,13 +175,9 @@ def test_mixer_s_gradients_are_the_plain_mixer_s():
                     jnp.float32)
     weight = jnp.asarray(np.random.default_rng(7).normal(size=(2, 13, 16)),
                          jnp.float32)
-    got = jax.grad(lambda w, x: jnp.sum(_mixer(w, x) * weight), (0, 1))(w, x)
-    want = jax.grad(lambda w, x: jnp.sum(_plain_mixer(sz, w, x) * weight),
-                    (0, 1))(w, x)
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(a, b, atol=3e-5 * float(jnp.max(
-            jnp.abs(b))) + 1e-7)
+    got, want = (numerics.traced(fn, (w, x), weight, (0, 1))[1] for fn in (
+        _mixer, lambda w, x: _plain_mixer(sz, w, x)))
+    numerics.close(got, want, Tol(atol=1e-7, scaled=3e-5))
 
 
 def test_the_gate_is_not_convolved_and_the_convolution_has_no_bias():
@@ -190,7 +187,7 @@ def test_the_gate_is_not_convolved_and_the_convolution_has_no_bias():
     _, w = _mixer_weights()
     w = dict(w, conv_weight=jnp.zeros_like(w["conv_weight"]))
     x = jnp.ones((1, 9, 16), jnp.float32)
-    assert float(jnp.max(jnp.abs(_mixer(w, x)))) == 0.0
+    assert float(jnp.max(jnp.abs(numerics.traced(_mixer, (w, x))[0]))) == 0.0
 
 
 def test_a_unit_keeps_both_input_products_and_nothing_of_the_rule():
@@ -218,8 +215,9 @@ def test_block_and_pattern_kind():
                     .astype(np.float32))
     w = {n.split("_", 1)[1]: p.data()._data
          for n, p in block.collect_params().items()}
-    np.testing.assert_allclose(block(x).asnumpy(), _mixer(w, x._data),
-                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        block(x).asnumpy(), numerics.traced(_mixer, (w, x._data))[0],
+        rtol=2e-5, atol=2e-5)
     net = PatternLM("D*", 32, 16,
                     linear_attention=dict(num_k_heads=2, num_v_heads=4,
                                           key_dim=8, value_dim=6,

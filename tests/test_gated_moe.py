@@ -23,6 +23,8 @@ from mxnet_tpu.ops import seq
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 import harness  # noqa: E402
+import numerics  # noqa: E402
+from numerics import Tol  # noqa: E402
 
 SZ = {"hidden_size": 32, "moe_intermediate_size": 24, "n_shared_experts": 2,
       "router_experts": 64, "n_routed_experts": 64, "num_experts_per_tok": 6,
@@ -90,14 +92,10 @@ def test_pooled_gated_product_is_a_loop_over_experts(sizes):
         return seq.pooled_gated_product(buf, w1, w3, w2,
                                         jnp.asarray(sizes, jnp.int32))
 
-    args = (buf, w["w1"], w["w3"], w["w2"])
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(pooled(*args), loop(*args), atol=1e-5)
-        got, want = (jax.grad(lambda *a: jnp.sum(f(*a) * cot),
-                              argnums=(0, 1, 2, 3))(*args)
-                     for f in (pooled, loop))
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(a, b, atol=2e-5)
+        numerics.agree(pooled, loop, (buf, w["w1"], w["w3"], w["w2"]), cot,
+                       (0, 1, 2, 3), value=Tol(atol=1e-5),
+                       grads=Tol(atol=2e-5))
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -108,21 +106,28 @@ def test_the_shares_add_up_to_the_uncut_layer():
     x = jax.random.normal(jax.random.PRNGKey(4), (1, TOKENS, 32))
     no_shared = dict(w, shared_down_weight=jnp.zeros_like(
         w["shared_down_weight"]))
+
+    def shares(w, no_shared, x):
+        """Each share's ``(out, stats, its reference's share)``."""
+        return [(*_layer(no_shared, x, ids, 8 * TOKENS)[:2],
+                 _ref_layer(no_shared, x, ids)[0])
+                for ids in (list(range(8 * share, 8 * share + 8))
+                            for share in range(8))], \
+            seq.gated_mlp(x, w["shared_gate_up_weight"],
+                          w["shared_down_weight"])[0], _ref_layer(w, x)
+
     with jax.default_matmul_precision("highest"):
-        want, load = _ref_layer(w, x)
-        total = jnp.zeros((TOKENS, 32))
-        held_pairs = 0.0
-        for share in range(8):
-            ids = list(range(8 * share, 8 * share + 8))
-            out, stats, _ = _layer(no_shared, x, ids, 8 * TOKENS)
-            assert float(stats[1]) == 0          # no pair beyond a buffer
-            held_pairs += float(stats[0])
-            total = total + out[0]
-            # one share is its own reference's share too
-            part, _ = _ref_layer(no_shared, x, ids)
-            np.testing.assert_allclose(out[0], part, atol=2e-5)
-        total = total + seq.gated_mlp(x, w["shared_gate_up_weight"],
-                                      w["shared_down_weight"])[0]
+        (parts, shared, (want, load)), _ = numerics.traced(
+            shares, (w, no_shared, x))
+    total = jnp.zeros((TOKENS, 32))
+    held_pairs = 0.0
+    for out, stats, part in parts:
+        assert float(stats[1]) == 0          # no pair beyond a buffer
+        held_pairs += float(stats[0])
+        total = total + out[0]
+        # one share is its own reference's share too
+        np.testing.assert_allclose(out[0], part, atol=2e-5)
+    total = total + shared
     np.testing.assert_allclose(total, want, atol=5e-5)
     # every (token, expert) pair was held by exactly one share
     assert held_pairs == TOKENS * 6 == float(load.sum())
@@ -142,8 +147,7 @@ def test_layer_and_gradients_are_the_reference_s(ids):
         return jnp.sum(_ref_layer(w, x, ids)[0].reshape(x.shape) * cot)
 
     with jax.default_matmul_precision("highest"):
-        a = jax.value_and_grad(got, argnums=(0, 1))(w, x)
-        b = jax.value_and_grad(want, argnums=(0, 1))(w, x)
+        a, b = (numerics.traced(fn, (w, x), 1.0, (0, 1)) for fn in (got, want))
     np.testing.assert_allclose(a[0], b[0], rtol=1e-5)
     for key in w:
         ga, gb = a[1][0][key], b[1][0][key]
@@ -197,18 +201,19 @@ def test_the_held_experts_share_one_pool():
     w["router_bias"] = w["router_bias"].at[0].set(10.0)
     x = jax.random.normal(jax.random.PRNGKey(12), (1, TOKENS, 32))
     ids = list(range(8))
+    w0 = dict(w, w2=w["w2"].at[1:8].set(0.0))
     with jax.default_matmul_precision("highest"):
-        want, _ = _ref_layer(w, x, ids)
-        out, stats, _ = _layer(w, x, ids, 8 * 12)
-        held = float(stats[0])
-        assert TOKENS < held <= 8 * 12 and float(stats[1]) == 0
-        np.testing.assert_allclose(out[0], want, atol=2e-5)
-        # a pool of 48 rows holds expert 0's pairs whole and no other
-        only, stats, _ = _layer(w, x, ids, TOKENS)
-        assert float(stats[1]) == held - TOKENS
-        w0 = dict(w, w2=w["w2"].at[1:8].set(0.0))
-        alone, _ = _ref_layer(w0, x, ids)
-        np.testing.assert_allclose(only[0], alone, atol=2e-5)
+        ((out, stats, _), want, (only, tight, _), alone), _ = numerics.traced(
+            lambda w, w0, x: (
+                _layer(w, x, ids, 8 * 12), _ref_layer(w, x, ids)[0],
+                # a pool of 48 rows holds expert 0's pairs whole and no other
+                _layer(w, x, ids, TOKENS), _ref_layer(w0, x, ids)[0]),
+            (w, w0, x))
+    held = float(stats[0])
+    assert TOKENS < held <= 8 * 12 and float(stats[1]) == 0
+    np.testing.assert_allclose(out[0], want, atol=2e-5)
+    assert float(tight[1]) == held - TOKENS
+    np.testing.assert_allclose(only[0], alone, atol=2e-5)
 
 
 def test_block_moves_its_bias_when_training_and_publishes_counters():
@@ -329,12 +334,15 @@ def test_shared_expert_goes_through_a_gate_of_its_own():
     w = _qwen_weights(3)
     x = jax.random.normal(jax.random.PRNGKey(4), (1, TOKENS, 32))
     none = dict(w, w2=jnp.zeros_like(w["w2"]))      # the routed part zero
-    with jax.default_matmul_precision("highest"):
-        got = _qwen_layer(none, x, range(8))[0][0]
+
+    def want(w, x):
         mlp = seq.gated_mlp(x, w["shared_gate_up_weight"],
                             w["shared_down_weight"])[0]
-        want = jax.nn.sigmoid(x[0] @ w["shared_gate_weight"].T) * mlp
-    np.testing.assert_allclose(got, want, atol=2e-6)
+        return jax.nn.sigmoid(x[0] @ w["shared_gate_weight"].T) * mlp
+
+    with jax.default_matmul_precision("highest"):
+        numerics.agree(lambda w, x: _qwen_layer(w, x, range(8))[0][0], want,
+                       (none, x), value=Tol(atol=2e-6))
 
 
 def test_qwen_shares_add_up_to_the_uncut_layer():
@@ -345,21 +353,28 @@ def test_qwen_shares_add_up_to_the_uncut_layer():
     x = jax.random.normal(jax.random.PRNGKey(6), (1, TOKENS, 32))
     no_shared = dict(w, shared_down_weight=jnp.zeros_like(
         w["shared_down_weight"]))
+    whole = dict(w, w2=jnp.zeros_like(w["w2"]))
+
+    def shares(w, no_shared, whole, x):
+        """Each share's ``(out, stats, its reference's share)``."""
+        return [(*_qwen_layer(no_shared, x, ids)[:2],
+                 _qwen_ref_layer(no_shared, x, ids))
+                for ids in ([2 * share, 2 * share + 1]
+                            for share in range(4))], \
+            _qwen_layer(whole, x, range(8))[0][0], _qwen_ref_layer(w, x)
+
     with jax.default_matmul_precision("highest"):
-        want = _qwen_ref_layer(w, x)
-        total = jnp.zeros((TOKENS, 32))
-        held_pairs = 0.0
-        for share in range(4):
-            ids = [2 * share, 2 * share + 1]
-            out, stats, _ = _qwen_layer(no_shared, x, ids)
-            assert float(stats[1]) == 0          # no pair beyond the pool
-            held_pairs += float(stats[0])
-            total = total + out[0]
-            # one share is its own reference's share too
-            np.testing.assert_allclose(
-                out[0], _qwen_ref_layer(no_shared, x, ids), atol=2e-5)
-        whole = dict(w, w2=jnp.zeros_like(w["w2"]))
-        total = total + _qwen_layer(whole, x, range(8))[0][0]
+        (parts, shared, want), _ = numerics.traced(
+            shares, (w, no_shared, whole, x))
+    total = jnp.zeros((TOKENS, 32))
+    held_pairs = 0.0
+    for out, stats, part in parts:
+        assert float(stats[1]) == 0          # no pair beyond the pool
+        held_pairs += float(stats[0])
+        total = total + out[0]
+        # one share is its own reference's share too
+        np.testing.assert_allclose(out[0], part, atol=2e-5)
+    total = total + shared
     np.testing.assert_allclose(total, want, atol=5e-5)
     # every (token, expert) pair was held by exactly one share
     assert held_pairs == TOKENS * 3
@@ -379,8 +394,8 @@ def test_qwen_layer_s_gradients_are_the_reference_s():
         return jnp.sum(_qwen_ref_layer(w, x, ids).reshape(x.shape) * cot)
 
     with jax.default_matmul_precision("highest"):
-        a = jax.grad(got, (0, 1))(w, x)
-        b = jax.grad(want, (0, 1))(w, x)
+        a, b = (numerics.traced(fn, (w, x), 1.0, (0, 1))[1]
+                for fn in (got, want))
     for name in w:
         ga, gb = a[0][name], b[0][name]
         if name in ("w1", "w3", "w2"):      # the held experts' alone

@@ -14,10 +14,12 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 import mxnet_tpu as mx
 from mxnet_tpu.ops import gdn_conv_kernel, seq
+
+import numerics
+from numerics import kernel_tol
 
 N = P = 128
 TAPS = 4
@@ -50,21 +52,20 @@ def _cots(outs, seed=9):
 def _plain(qkvz, weight, heads, cots):
     """The plain form's ``(q, k, v)`` and JAX's gradients of it for the
     projection and the taps, in float32 from the operands as given."""
-    def loss(x, w):
-        outs = _flat(seq._operands_plain(x, w, heads))
-        return sum(jnp.sum(o * c.astype(jnp.float32))
-                   for o, c in zip(outs, cots)), outs
-
-    (_, outs), grads = jax.value_and_grad(loss, (0, 1), has_aux=True)(
-        qkvz.astype(jnp.float32), weight.astype(jnp.float32))
-    return outs, grads
+    return numerics.traced(
+        lambda x, w: _flat(seq._operands_plain(x, w, heads)),
+        (qkvz.astype(jnp.float32), weight.astype(jnp.float32)), cots, (0, 1))
 
 
-def _close(got, want, tol, name):
-    assert got.shape == want.shape, name
-    got, want = (np.asarray(t, np.float32) for t in (got, want))
-    np.testing.assert_allclose(got, want, rtol=tol,
-                               atol=tol * np.abs(want).max(), err_msg=name)
+def _kernels(qkvz, weight, heads, seed=9):
+    """``(q, k, v)`` of the forward kernel, cotangents drawn for them, and
+    the backward kernel's ``(d_rows, d_weight)``, both interpreted."""
+    got, _ = numerics.traced(lambda x, w: gdn_conv_kernel.forward(
+        x, w, tuple(heads), interpret=True), (qkvz, weight))
+    cots = _cots(got, seed)
+    grads, _ = numerics.traced(lambda x, w, *c: gdn_conv_kernel.backward(
+        x, w, *c, tuple(heads), interpret=True), (qkvz, weight, *cots))
+    return got, cots, grads
 
 
 # two blocks of 256 rows; one block and a row; shorter than the taps
@@ -82,36 +83,30 @@ def test_kernels_are_the_plain_form_and_its_derivative(dtype, bsz, group,
     cotangent the block after through the carried rows."""
     qkvz, weight, heads = _operands(bsz, length, group, jnp.dtype(dtype),
                                     seed=length + group)
-    got = gdn_conv_kernel.forward(qkvz, weight, tuple(heads), interpret=True)
-    cots = _cots(got)
+    got, cots, (d_rows, d_weight) = _kernels(qkvz, weight, heads)
     want, (want_dx, want_dw) = _plain(qkvz, weight, heads, cots)
     tol = 1e-5 if dtype == "float32" else 2.0 ** -7
-    for name, a, b in zip("qkv", got, want):
-        assert a.dtype == qkvz.dtype
-        _close(a, b, tol, name)
-    d_rows, d_weight = gdn_conv_kernel.backward(
-        qkvz, weight, *cots, tuple(heads), interpret=True)
+    assert {a.dtype for a in got} == {qkvz.dtype}
+    numerics.close(got, want, kernel_tol(tol), "qkv")
     assert d_weight.dtype == weight.dtype
     conv = weight.shape[0]
     assert not np.asarray(want_dx[..., conv:]).any()    # the gate's columns
-    _close(jnp.concatenate(d_rows, axis=-1), want_dx[..., :conv], tol, "dx")
-    _close(d_weight, want_dw, 4 * tol, "dw")
+    numerics.close(jnp.concatenate(d_rows, axis=-1), want_dx[..., :conv],
+                   kernel_tol(tol), "dx")
+    numerics.close(d_weight, want_dw, kernel_tol(4 * tol), "dw")
 
 
 def test_a_second_sequence_does_not_see_the_first_one_s_rows():
     """The halo and the carried rows are zeros where a batch entry begins
     and ends: the second entry gives what it gives alone, to the bit."""
     qkvz, weight, heads = _operands(2, 300, 2, jnp.bfloat16, seed=3)
-    both = gdn_conv_kernel.forward(qkvz, weight, tuple(heads),
-                                   interpret=True)
-    alone = gdn_conv_kernel.forward(qkvz[1:], weight, tuple(heads),
-                                    interpret=True)
-    cots = _cots(both)
-    d_both, _ = gdn_conv_kernel.backward(qkvz, weight, *cots, tuple(heads),
-                                         interpret=True)
-    d_alone, _ = gdn_conv_kernel.backward(
-        qkvz[1:], weight, *(c[1:] for c in cots), tuple(heads),
-        interpret=True)
+    both, cots, (d_both, _) = _kernels(qkvz, weight, heads)
+    alone, _ = numerics.traced(lambda x, w: gdn_conv_kernel.forward(
+        x, w, tuple(heads), interpret=True), (qkvz[1:], weight))
+    (d_alone, _), _ = numerics.traced(
+        lambda x, w, *c: gdn_conv_kernel.backward(
+            x, w, *c, tuple(heads), interpret=True),
+        (qkvz[1:], weight, *(c[1:] for c in cots)))
     for a, b in zip(both + d_both, alone + d_alone):
         np.testing.assert_array_equal(np.asarray(a[1:], np.float32),
                                       np.asarray(b, np.float32))
@@ -121,16 +116,12 @@ def test_a_second_sequence_does_not_see_the_first_one_s_rows():
 def test_other_filters_than_four_taps(taps):
     qkvz, weight, heads = _operands(1, 70, 1, jnp.float32, seed=taps,
                                     taps=taps)
-    got = gdn_conv_kernel.forward(qkvz, weight, tuple(heads), interpret=True)
-    cots = _cots(got)
+    got, cots, (d_rows, d_weight) = _kernels(qkvz, weight, heads)
     want, (want_dx, want_dw) = _plain(qkvz, weight, heads, cots)
-    for name, a, b in zip("qkv", got, want):
-        _close(a, b, 1e-5, name)
-    d_rows, d_weight = gdn_conv_kernel.backward(
-        qkvz, weight, *cots, tuple(heads), interpret=True)
-    _close(jnp.concatenate(d_rows, axis=-1), want_dx[..., :weight.shape[0]],
-           1e-5, "dx")
-    _close(d_weight, want_dw, 4e-5, "dw")
+    numerics.close(got, want, kernel_tol(1e-5), "qkv")
+    numerics.close(jnp.concatenate(d_rows, axis=-1),
+                   want_dx[..., :weight.shape[0]], kernel_tol(1e-5), "dx")
+    numerics.close(d_weight, want_dw, kernel_tol(4e-5), "dw")
 
 
 def test_two_key_heads_are_normalised_each_over_its_own_lanes():
@@ -143,9 +134,8 @@ def test_two_key_heads_are_normalised_each_over_its_own_lanes():
     np.testing.assert_allclose(norms, 1.0, atol=1e-4)
     norms = np.linalg.norm(np.asarray(q).reshape(1, 40, 2, N), axis=-1)
     np.testing.assert_allclose(norms, N ** -0.5, atol=1e-5)
-    want = _flat(seq._operands_plain(qkvz, weight, heads))
-    for name, a, b in zip("qkv", (q, k, v), want):
-        _close(a, b, 1e-5, name)
+    numerics.close((q, k, v), _flat(seq._operands_plain(qkvz, weight, heads)),
+                   kernel_tol(1e-5), "qkv")
 
 
 def test_the_rule_of_shapes_reads_shapes_alone():
@@ -187,19 +177,6 @@ def test_the_rule_of_shapes_reads_shapes_alone():
 # ---------------------------------------------------------------------------
 # the mixer's two forms
 # ---------------------------------------------------------------------------
-class _LoweredForATpu:
-    """Stands where ``ops.seq`` names ``jax.lax``: every
-    ``platform_dependent`` takes its TPU branch, as a lowering for a TPU
-    would."""
-
-    def __getattr__(self, name):
-        return getattr(lax, name)
-
-    @staticmethod
-    def platform_dependent(*args, tpu, default):
-        return tpu(*args)
-
-
 def _mixer(head, dtype, length=40, bsz=2, seed=4):
     """``loss(data, *weights)`` of a ``gated_delta_net`` with one key head
     and two value heads ``head`` wide, and its arguments."""
@@ -234,21 +211,17 @@ def test_the_mixer_through_the_kernels_is_the_plain_form_with_every_gradient(
     cotangent put together from the kernels' three parts and zeros for
     the gate's columns, which the gate's own path fills."""
     loss, args = _mixer(N, jnp.dtype(dtype))
-    fn = jax.value_and_grad(loss, argnums=range(len(args)))
     monkeypatch.setattr(seq.gdn_kernel, "takes", lambda *a: False)
     with monkeypatch.context() as m:
         m.setattr(gdn_conv_kernel, "takes", lambda *a: False)
-        want = fn(*args)
-    monkeypatch.setattr(seq, "lax", _LoweredForATpu())
+        want = numerics.traced(loss, args, 1.0, range(len(args)))
+    monkeypatch.setattr(seq, "lax", numerics.LoweredForATpu())
     for name in ("forward", "backward"):
         monkeypatch.setattr(gdn_conv_kernel, name, functools.partial(
             getattr(gdn_conv_kernel, name), interpret=True))
-    got = fn(*args)
+    got = numerics.traced(loss, args, 1.0, range(len(args)))
     tol = 2e-4 if dtype == "float32" else 2.0 ** -5
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        assert a.dtype == b.dtype
-        _close(a, b, tol, "leaf")
+    numerics.close(got, want, kernel_tol(tol), same_dtype=True)
 
 
 def _lowered(head, platform, dtype=jnp.bfloat16):
@@ -348,20 +321,12 @@ def test_off_a_tpu_the_values_are_the_parent_s_to_the_bit(dtype):
                       jnp.float32)
     assert gdn_conv_kernel.takes(_heads(2), TAPS, jnp.dtype(dtype),
                                  jnp.dtype(dtype))
-
-    def through(fn):
-        return jax.jit(jax.value_and_grad(
-            lambda *a: jnp.sum(fn(*a) * cot), argnums=range(len(args))))(
-                *args)
-
-    got = through(lambda *a: seq.gated_delta_net(
-        *a, num_k_heads=1, num_v_heads=2, key_dim=N, value_dim=N,
-        chunk_size=16))
-    want = through(lambda *a: _parent_s_mixer(*a, 1, 2, N, N, 16))
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                      np.asarray(b, np.float32))
+    numerics.agree(
+        lambda *a: seq.gated_delta_net(
+            *a, num_k_heads=1, num_v_heads=2, key_dim=N, value_dim=N,
+            chunk_size=16),
+        lambda *a: _parent_s_mixer(*a, 1, 2, N, N, 16), args, cot,
+        range(len(args)), value=numerics.TO_THE_BIT)
 
 
 def test_a_unit_through_the_kernels_keeps_what_the_plain_form_keeps(

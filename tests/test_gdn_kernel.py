@@ -14,6 +14,8 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu.ops import gdn_kernel, seq
 
+import numerics
+
 N = P = 128
 CHUNK = 16
 
@@ -39,24 +41,29 @@ def _operands(length, dtype, group=1, bsz=1, seed=0, decay=1.0, n=N, p=P):
 def _plain(args, cot, chunk=CHUNK):
     """The plain form's value and gradients, float32 products at full
     precision."""
-    with jax.default_matmul_precision("highest"):
-        out, vjp = jax.vjp(
-            lambda *a: seq._solve_then_scan(*a, chunk), *args)
+    def both(*a):
+        out, vjp = jax.vjp(lambda *a: seq._solve_then_scan(*a, chunk), *a)
         return out, vjp(cot)
+
+    with jax.default_matmul_precision("highest"):
+        return numerics.traced(both, args)[0]
 
 
 def _kernels(args, cot, chunk=CHUNK):
-    out, states, inverses = gdn_kernel.forward(*args, chunk=chunk,
-                                               interpret=True)
-    return out, gdn_kernel.backward(*args, states, inverses, cot,
-                                    chunk=chunk, interpret=True)
+    def both(*a):
+        out, states, inverses = gdn_kernel.forward(*a, chunk=chunk,
+                                                   interpret=True)
+        return out, gdn_kernel.backward(*a, states, inverses, cot,
+                                        chunk=chunk, interpret=True)
+
+    return numerics.traced(both, args)[0]
 
 
-def _close(got, want, tol, name):
-    assert got.dtype == want.dtype and got.shape == want.shape, name
-    got, want = (np.asarray(t, np.float32) for t in (got, want))
-    np.testing.assert_allclose(got, want, rtol=tol,
-                               atol=tol * np.abs(want).max(), err_msg=name)
+def _same(got, want, tol):
+    """``(out, gradients)`` of the kernels to the plain form's, by name."""
+    got, want = (dict(zip("out dq dk dv dbeta dg".split(), (out, *grads)))
+                 for out, grads in (got, want))
+    numerics.close(got, want, numerics.kernel_tol(tol), same_dtype=True)
 
 
 @pytest.mark.parametrize("length", [32, 40])    # whole chunks; a padded tail
@@ -70,12 +77,8 @@ def test_kernels_are_the_plain_form_and_its_derivative(dtype, group, length):
     args = _operands(length, jnp.dtype(dtype), group, seed=length + group)
     cot = jnp.asarray(np.random.default_rng(9).normal(
         size=(1, length, group, P)), jnp.float32)
-    want, want_d = _plain(args, cot)
-    got, got_d = _kernels(args, cot)
-    tol = 1e-5 if dtype == "float32" else 2.0 ** -7
-    _close(got, want, tol, "out")
-    for name, a, b in zip("q k v beta g".split(), got_d, want_d):
-        _close(a, b, tol, "d" + name)
+    _same(_kernels(args, cot), _plain(args, cot),
+           1e-5 if dtype == "float32" else 2.0 ** -7)
 
 
 def test_the_state_crosses_grid_steps_forward_and_backward():
@@ -86,13 +89,10 @@ def test_the_state_crosses_grid_steps_forward_and_backward():
     args = _operands(130, jnp.bfloat16, 1, seed=11, decay=0.1)
     cot = jnp.asarray(np.random.default_rng(4).normal(
         size=(1, 130, 1, P)), jnp.float32)
-    want, want_d = _plain(args, cot)
-    got, got_d = _kernels(args, cot)
-    _close(got, want, 2.0 ** -7, "out")
-    for name, a, b in zip("q k v beta g".split(), got_d, want_d):
-        _close(a, b, 2.0 ** -7, "d" + name)
+    got = _kernels(args, cot)
+    _same(got, _plain(args, cot), 2.0 ** -7)
     # the second step's rows read what the first step wrote
-    assert float(jnp.max(jnp.abs(got[:, 128:]))) > 0
+    assert float(jnp.max(jnp.abs(got[0][:, 128:]))) > 0
 
 
 def test_a_strong_decay_underflows_to_zero_and_not_to_nan():
@@ -101,13 +101,10 @@ def test_a_strong_decay_underflows_to_zero_and_not_to_nan():
     numbers."""
     args = _operands(40, jnp.float32, 2, seed=5, decay=25.0)
     cot = jnp.ones((1, 40, 2, P), jnp.float32)
-    want, want_d = _plain(args, cot)
-    got, got_d = _kernels(args, cot)
-    assert bool(jnp.all(jnp.isfinite(got)))
-    _close(got, want, 1e-5, "out")
-    for name, a, b in zip("q k v beta g".split(), got_d, want_d):
-        assert bool(jnp.all(jnp.isfinite(a))), name
-        _close(a, b, 1e-5, "d" + name)
+    got = _kernels(args, cot)
+    assert all(bool(jnp.all(jnp.isfinite(a)))
+               for a in jax.tree_util.tree_leaves(got))
+    _same(got, _plain(args, cot), 1e-5)
 
 
 def test_a_second_sequence_does_not_see_the_first_one_s_state():
@@ -129,10 +126,11 @@ def test_a_padded_tail_writes_nothing():
     two chunks) or 32; beside the output, every chunk's entering state
     and inverse for the backward kernel."""
     args = _operands(32, jnp.float32, 1, seed=1)
-    whole, states, inverses = gdn_kernel.forward(*args, chunk=CHUNK,
-                                                 interpret=True)
-    short = gdn_kernel.forward(*(t[:, :20] for t in args), chunk=CHUNK,
-                               interpret=True)[0]
+    (whole, states, inverses), _ = numerics.traced(
+        lambda *a: gdn_kernel.forward(*a, chunk=CHUNK, interpret=True), args)
+    short = numerics.traced(
+        lambda *a: gdn_kernel.forward(*a, chunk=CHUNK, interpret=True)[0],
+        tuple(t[:, :20] for t in args))[0]
     np.testing.assert_allclose(short, whole[:, :20], atol=1e-6)
     assert states.shape == (1, 1, 2, N, P) and inverses.shape == (
         1, 1, 2, CHUNK, CHUNK)
@@ -145,11 +143,7 @@ def test_every_level_of_the_inverse_at_the_cell_s_chunk():
     args = _operands(100, jnp.bfloat16, 2, seed=13, decay=0.05)
     cot = jnp.asarray(np.random.default_rng(6).normal(
         size=(1, 100, 2, P)), jnp.float32)
-    want, want_d = _plain(args, cot, 64)
-    got, got_d = _kernels(args, cot, 64)
-    _close(got, want, 2.0 ** -7, "out")
-    for name, a, b in zip("q k v beta g".split(), got_d, want_d):
-        _close(a, b, 2.0 ** -7, "d" + name)
+    _same(_kernels(args, cot, 64), _plain(args, cot, 64), 2.0 ** -7)
 
 
 def test_the_rule_of_shapes_reads_shapes_alone():
@@ -234,15 +228,7 @@ def test_off_a_tpu_the_program_is_the_plain_form_to_the_bit():
     args = _operands(40, jnp.bfloat16, 2, bsz=2, seed=7)
     cot = jnp.asarray(np.random.default_rng(2).normal(
         size=(2, 40, 2, P)), jnp.float32)
-
-    def through(fn):
-        return jax.jit(jax.value_and_grad(
-            lambda *a: jnp.sum(fn(*a) * cot), argnums=range(5)))(*args)
-
     assert gdn_kernel.takes(N, P, CHUNK, jnp.bfloat16, 2)
-    got = through(lambda *a: seq.gated_delta_rule(*a, chunk=CHUNK))
-    want = through(lambda *a: seq._solve_then_scan(*a, CHUNK))
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                      np.asarray(b, np.float32))
+    numerics.agree(lambda *a: seq.gated_delta_rule(*a, chunk=CHUNK),
+                   lambda *a: seq._solve_then_scan(*a, CHUNK), args, cot,
+                   range(5), value=numerics.TO_THE_BIT)

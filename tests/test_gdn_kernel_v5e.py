@@ -15,94 +15,16 @@ attention and grouped-product kernels are there by name and count as
 (that file compares the whole set of custom calls for equality and counts
 three ``while`` a linear layer, so it is red since the rule's kernels;
 ROADMAP D0 c). Nothing runs here, so nothing here is a time or a result.
-The topology is described inside a fixture only (one process at a time
-may load the TPU's library: the on-chip-measurement guide, section 2)."""
+The chip is described and the step compiled, once a process, by
+``tests/described_v5e.py``."""
 import collections
-import os
 import re
-import sys
 
-import pytest
-
-import jax
 import jax.numpy as jnp
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(ROOT, "benchmark"))
-import harness  # noqa: E402
+from described_v5e import CHIP_BYTES, compiled_step, harness, peak_bytes
 
 CELL = "qwen3-next-80b-a3b-train-8k"
-#: what one v5e gives a program: ``bytes_limit`` of the device's memory
-#: statistics (a chip run of PR 41), 15.75 GiB
-CHIP_BYTES = 16_909_336_064
-
-
-@pytest.fixture(scope="module")
-def one_chip():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(scope="module")
-def compiled_step(one_chip):
-    """``(sizes, compiled, gauges, kept)``: the cell's step compiled for
-    the described chip from shapes alone, what the kernels' gauges
-    counted at its lowering, and the bytes each recomputation unit
-    keeps."""
-    from jax.experimental.compilation_cache import compilation_cache
-    import mxnet_tpu as mx
-    from mxnet_tpu.ops import (attn_kernel, gdn_conv_kernel, gdn_kernel,
-                               gmm_kernel)
-    from mxnet_tpu.parallel import TrainStep
-    before = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        cell = harness.load_cell(CELL)
-        sizes = cell.sizes
-        net = cell.model._net(sizes)
-        net.initialize(mx.init.Zero())
-        opt = dict(cell.config["optimizer"])
-        step = TrainStep(net, loss="softmax_ce", optimizer=opt.pop("name"),
-                         optimizer_params=opt,
-                         compute_dtype=cell.config["compute_dtype"],
-                         remat="layer")
-
-        def spec(shape, dtype=jnp.float32):
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-        pvals = tuple(spec(p.shape) for p in step.param_list)
-        state = tuple((spec(p.shape),) * 2 if t else ()
-                      for p, t in zip(step.param_list, step._trainable))
-        tokens = sizes["batch"] * sizes["seq_len"]
-        step._build_step()
-        compiled = step._step_jit.lower(
-            pvals, state, spec((sizes["batch"], sizes["seq_len"]), jnp.int32),
-            spec((tokens,), jnp.int32), spec((), jnp.uint32),
-            spec(())).compile()
-        gauges = {g: mx.telemetry.gauge(g).get() for g in (
-            attn_kernel.GAUGE, attn_kernel.FUSED_BWD_GAUGE, gmm_kernel.GAUGE,
-            gdn_kernel.GAUGE, gdn_conv_kernel.GAUGE)}
-        kept = {k.rsplit("::", 1)[1]: v["value"] for k, v in
-                mx.telemetry.snapshot(prefix="remat::saved_bytes::").items()}
-        return sizes, compiled, gauges, kept
-    finally:
-        jax.config.update("jax_enable_compilation_cache", before)
-        compilation_cache.reset_cache()
-
-
-def _custom_calls(hlo):
-    """``{instruction name: kernel name}`` of a compiled program's Mosaic
-    calls."""
-    return {name: name.rsplit(".", 1)[0] for name in re.findall(
-        r'%?([\w.\-]+) = [^\n]*?custom_call_target="tpu_custom_call"', hlo)}
 
 
 def _linear_layers(sizes):
@@ -110,7 +32,7 @@ def _linear_layers(sizes):
     return layers - layers // sizes["full_attention_interval"]
 
 
-def test_mosaic_takes_the_rule_s_kernels_three_a_linear_layer(compiled_step):
+def test_mosaic_takes_the_rule_s_kernels_three_a_linear_layer():
     """Forward, the unit's recomputation (whose states and inverses the
     backward reads) and backward: six ``gdn_fwd_kernel`` and three
     ``gdn_bwd_kernel`` for three layers of one shape, which share one
@@ -118,12 +40,14 @@ def test_mosaic_takes_the_rule_s_kernels_three_a_linear_layer(compiled_step):
     of its operands, six ``gdn_conv_fwd_kernel`` and three
     ``gdn_conv_bwd_kernel`` under a gauge of their own."""
     from mxnet_tpu.ops import (attn_kernel, gdn_conv_kernel, gdn_kernel,
-                               gmm_kernel)
-    sizes, compiled, gauges, _ = compiled_step
-    assert gauges == {attn_kernel.GAUGE: 1, attn_kernel.FUSED_BWD_GAUGE: 0,
+                               gmm_kernel, mhc_kernel, seq)
+    step = compiled_step(CELL)
+    sizes = step.sizes
+    assert step.gauges == {attn_kernel.GAUGE: 1, attn_kernel.FUSED_BWD_GAUGE: 0,
                       gmm_kernel.GAUGE: 1, gdn_kernel.GAUGE: 1,
-                      gdn_conv_kernel.GAUGE: 1}
-    calls = collections.Counter(_custom_calls(compiled.as_text()).values())
+                      gdn_conv_kernel.GAUGE: 1, mhc_kernel.GAUGE: 0,
+                      seq.MHC_GAUGE: 0}
+    calls = collections.Counter(step.calls.values())
     linear, layers = _linear_layers(sizes), sizes["num_hidden_layers"]
     assert calls == {
         "gdn_fwd_kernel": 2 * linear, "gdn_bwd_kernel": linear,
@@ -134,29 +58,25 @@ def test_mosaic_takes_the_rule_s_kernels_three_a_linear_layer(compiled_step):
            for side in ("up", "down") for part in ("", "_rows", "_weights")}}
 
 
-def test_no_solve_and_no_loop_is_left_for_the_rule(compiled_step):
+def test_no_solve_and_no_loop_is_left_for_the_rule():
     """The plain form's ``triangular_solve`` expansion and its three
     ``while`` a linear layer (forward, recomputation, backward) are gone,
     and nothing else in this step loops; no grouped product fell back to
     XLA's either."""
-    _, compiled, _, _ = compiled_step
-    hlo = compiled.as_text()
+    hlo = compiled_step(CELL).text
     assert not re.findall(r" while\(", hlo)
     assert "triangular-solve" not in hlo and "triangular_solve" not in hlo
     assert "ragged-dot" not in hlo
 
 
-def test_every_kernel_of_the_rule_is_under_mx_gdn_rule_and_no_longer_path(
-        compiled_step):
+def test_every_kernel_of_the_rule_is_under_mx_gdn_rule_and_no_longer_path():
     """``gdn_roofline.train`` reads ``^mx_gdn_rule$``: a call under
     ``mx_gdn_rule/mx_gdn_rule`` (a scope opened twice) or under another
     part's scope would stand outside it and flatter the rule."""
-    from mxnet_tpu.telemetry import trace
-    _, compiled, _, _ = compiled_step
-    hlo = compiled.as_text()
-    paths = trace.hlo_scopes(hlo, path=True)
+    step = compiled_step(CELL)
+    paths = step.paths
     mine = {name: paths.get(name) for name, kernel
-            in _custom_calls(hlo).items()
+            in step.calls.items()
             if kernel in ("gdn_fwd_kernel", "gdn_bwd_kernel")}
     assert len(mine) == 9
     assert set(mine.values()) == {"mx_gdn_rule"}, mine
@@ -167,18 +87,15 @@ def test_every_kernel_of_the_rule_is_under_mx_gdn_rule_and_no_longer_path(
                 if p.split("/").count("mx_gdn_rule") > 1]
 
 
-def test_every_kernel_of_the_operands_is_under_exactly_mx_gdn_conv(
-        compiled_step):
+def test_every_kernel_of_the_operands_is_under_exactly_mx_gdn_conv():
     """The backward rule opens no scope: it carries ``mx_gdn_conv`` from
     its forward's call site, so all nine calls stand under that path and
     none under ``mx_gdn_rule``, whose nine ``gdn_roofline.train``
     reads."""
-    from mxnet_tpu.telemetry import trace
-    _, compiled, _, _ = compiled_step
-    hlo = compiled.as_text()
-    paths = trace.hlo_scopes(hlo, path=True)
+    step = compiled_step(CELL)
+    paths = step.paths
     mine = {name: paths.get(name) for name, kernel
-            in _custom_calls(hlo).items() if kernel.startswith("gdn_conv_")}
+            in step.calls.items() if kernel.startswith("gdn_conv_")}
     assert len(mine) == 9
     assert set(mine.values()) == {"mx_gdn_conv"}, mine
 
@@ -190,17 +107,14 @@ def _entry(hlo):
                       hlo[hlo.index("\nENTRY"):])
 
 
-def test_no_pad_and_no_slice_of_the_projection_is_left_for_the_convolution(
-        compiled_step):
+def test_no_pad_and_no_slice_of_the_projection_is_left_for_the_convolution():
     """The plain form padded the 8192-wide slice of the kept projection
     for its taps and sliced ``v`` out again for the rule's kernels: 134 MB
     copies under ``mx_gdn_conv``. The kernels window the packed rows, so
     under that scope nothing is left but their calls (and what unpacks a
     call's results)."""
-    from mxnet_tpu.telemetry import trace
-    sizes, compiled, _, _ = compiled_step
-    hlo = compiled.as_text()
-    paths = trace.hlo_scopes(hlo, path=True)
+    step = compiled_step(CELL)
+    sizes, hlo, paths = step.sizes, step.text, step.paths
     under = [(name, shape, op) for name, shape, op in _entry(hlo)
              if "mx_gdn_conv" in (paths.get(name) or "")]
     assert under
@@ -214,17 +128,16 @@ def test_no_pad_and_no_slice_of_the_projection_is_left_for_the_convolution(
     assert not re.findall(r"pad[\w.\-]*fusion[^\n]*mx_gdn_conv", hlo)
 
 
-def test_step_fits_one_v5e_and_a_unit_keeps_nothing_of_the_rule(
-        compiled_step):
+def test_step_fits_one_v5e_and_a_unit_keeps_nothing_of_the_rule():
     """625.7 M parameters with Adam's moments, 8192 tokens, recomputation
     by layer: arguments, outputs and temporaries on one described v5e,
     under the 13.5 GB the chip's run is held to; a delta-rule unit
     keeps both input products and the gated norm's statistics, not the
     convolution, not the rule's output, states or inverses."""
-    sizes, compiled, _, kept = compiled_step
+    step = compiled_step(CELL)
+    sizes, compiled, kept = step.sizes, step.compiled, step.kept
     m = compiled.memory_analysis()
-    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
-            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    peak = peak_bytes(compiled)
     print(f"qwen3-next-80b-a3b step: {peak / 1e9:.2f} GB "
           f"({m.argument_size_in_bytes / 1e9:.2f} of state, "
           f"{m.temp_size_in_bytes / 1e9:.2f} of temporaries), "

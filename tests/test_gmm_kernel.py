@@ -13,6 +13,8 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu.ops import gmm_kernel, seq
 
+import numerics
+
 ROWS, HIDDEN, FF, EXPERTS, TILE = 512, 256, 128, 4, 128
 
 #: rows an expert has in the pool, by what the walk has to get right
@@ -48,33 +50,36 @@ def test_kernels_are_the_ragged_products_and_their_derivative(case, dtype):
     of one output."""
     sizes = jnp.asarray(SIZES[case], jnp.int32)
     buf, w1, w3, w2, cot = _operands(jnp.dtype(dtype))
-    walk = gmm_kernel.visits(sizes, ROWS, TILE)
-    gate, up, hid = gmm_kernel.up(buf, w1, w3, walk, TILE, interpret=True)
-    out = gmm_kernel.down(hid, w2, walk, TILE, interpret=True)
-    d_gate, d_up, dw2 = gmm_kernel.down_backward(cot, gate, up, w2, walk,
-                                                 TILE, interpret=True)
-    d_buf, dw1, dw3 = gmm_kernel.up_backward(buf, d_gate, d_up, w1, w3, walk,
-                                             TILE, interpret=True)
-    with jax.default_matmul_precision("highest"):
-        (want_gate, want_up, want_out), vjp = jax.vjp(
+
+    def kernels(buf, w1, w3, w2):
+        walk = gmm_kernel.visits(sizes, ROWS, TILE)
+        gate, up, hid = gmm_kernel.up(buf, w1, w3, walk, TILE, interpret=True)
+        out = gmm_kernel.down(hid, w2, walk, TILE, interpret=True)
+        d_gate, d_up, dw2 = gmm_kernel.down_backward(cot, gate, up, w2, walk,
+                                                     TILE, interpret=True)
+        d_buf, dw1, dw3 = gmm_kernel.up_backward(buf, d_gate, d_up, w1, w3,
+                                                 walk, TILE, interpret=True)
+        return dict(gate=gate, up=up, out=out, d_buf=d_buf, dW1=dw1, dW3=dw3,
+                    dW2=dw2)
+
+    def ragged(buf, w1, w3, w2):
+        (gate, up, out), vjp = jax.vjp(
             lambda *a: _ragged_parts(*a, sizes), buf, w1, w3, w2)
-        want_d = vjp((jnp.zeros_like(want_gate), jnp.zeros_like(want_up),
-                      cot))
-    tol = 1e-5 if dtype == "float32" else 2.0 ** -7
-    for name, got, want in zip(
-            ("gate", "up", "out", "d_buf", "dW1", "dW3", "dW2"),
-            (gate, up, out, d_buf, dw1, dw3, dw2),
-            (want_gate, want_up, want_out) + tuple(want_d)):
-        assert got.dtype == want.dtype and got.shape == want.shape, name
-        got, want = (np.asarray(t, np.float32) for t in (got, want))
-        np.testing.assert_allclose(got, want, rtol=tol,
-                                   atol=tol * np.abs(want).max(),
-                                   err_msg=name)
+        d_buf, dw1, dw3, dw2 = vjp((jnp.zeros_like(gate), jnp.zeros_like(up),
+                                    cot))
+        return dict(gate=gate, up=up, out=out, d_buf=d_buf, dW1=dw1, dW3=dw3,
+                    dW2=dw2)
+
+    got, _ = numerics.traced(kernels, (buf, w1, w3, w2))
+    with jax.default_matmul_precision("highest"):
+        want, _ = numerics.traced(ragged, (buf, w1, w3, w2))
+    numerics.close(got, want, numerics.kernel_tol(
+        1e-5 if dtype == "float32" else 2.0 ** -7), same_dtype=True)
     # an expert with no rows gets zeros, not what the memory held
     for e, rows in enumerate(SIZES[case]):
         if rows == 0:
-            for dw in (dw1, dw3, dw2):
-                assert not np.asarray(dw[e], np.float32).any()
+            for dw in ("dW1", "dW3", "dW2"):
+                assert not np.asarray(got[dw][e], np.float32).any()
 
 
 @pytest.mark.parametrize("sizes", list(SIZES.values()) + [
@@ -154,15 +159,6 @@ def test_the_cpu_form_is_the_ragged_products_to_the_bit():
     JAX differentiates them."""
     sizes = jnp.asarray(SIZES["an expert with no rows"], jnp.int32)
     buf, w1, w3, w2, cot = _operands(jnp.bfloat16, seed=3)
-
-    def through(fn):
-        return jax.jit(jax.value_and_grad(
-            lambda *a: jnp.sum((fn(*a) * cot).astype(jnp.float32)),
-            argnums=(0, 1, 2, 3)))(buf, w1, w3, w2)
-
-    got = through(lambda *a: seq.pooled_gated_product(*a, sizes))
-    want = through(lambda *a: _ragged_parts(*a, sizes)[2])
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_array_equal(np.asarray(a, np.float32),
-                                      np.asarray(b, np.float32))
+    numerics.agree(lambda *a: seq.pooled_gated_product(*a, sizes),
+                   lambda *a: _ragged_parts(*a, sizes)[2], (buf, w1, w3, w2),
+                   cot, (0, 1, 2, 3), value=numerics.TO_THE_BIT)

@@ -18,6 +18,9 @@ from mxnet_tpu.gluon.model_zoo import PatternLM
 from mxnet_tpu.gluon.model_zoo.pattern_lm import _Layer
 from mxnet_tpu.ops import remat, seq
 
+import numerics
+from numerics import Tol
+
 N, C = 4, 12
 EPS = 1e-6
 
@@ -83,22 +86,27 @@ def test_ops_are_the_per_token_form_with_their_gradients(n, iters):
                                      iters=iters)
         return jax.vmap(jax.vmap(one))(_streams(data, n)).reshape(data.shape)
 
-    args = (data, phi, alpha, bias, w)
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(got(*args), want(*args), atol=2e-5)
-        g_got = jax.grad(lambda *a: jnp.sum(got(*a) * cot),
-                         argnums=range(5))(*args)
-        g_want = jax.grad(lambda *a: jnp.sum(want(*a) * cot),
-                          argnums=range(5))(*args)
-    for a, b in zip(g_got, g_want):
-        np.testing.assert_allclose(a, b, atol=5e-5 * float(jnp.abs(b).max()))
+        numerics.agree(got, want, (data, phi, alpha, bias, w), cot, range(5),
+                       value=Tol(atol=2e-5), grads=Tol(scaled=5e-5))
 
 
 def test_maps_are_far_from_the_identity_and_h_res_is_doubly_stochastic():
     phi, alpha, bias = _params()
     data = jax.random.normal(jax.random.PRNGKey(8), (2, 9, N * C),
                              jnp.float32)
-    pre, post, res, dev = seq.mhc_maps(data, phi, alpha, bias, streams=N)
+    def maps(data, phi, alpha, bias):
+        *_, few = seq.mhc_maps(data, phi, alpha, bias, streams=N, iters=1)
+        *_, res_c, _ = seq.mhc_maps(data, phi, 40.0 * alpha, bias, streams=N,
+                                    clamp=(-2.0, 2.0))
+        want = jax.vmap(jax.vmap(lambda x: _token_maps(
+            x, phi, 40.0 * alpha, bias, N, clamp=(-2.0, 2.0))[2]))(
+                _streams(data, N))
+        return (seq.mhc_maps(data, phi, alpha, bias, streams=N), few, res_c,
+                want)
+
+    ((pre, post, res, dev), few, res_c, want), _ = numerics.traced(
+        maps, (data, phi, alpha, bias))
     assert pre.shape == (N, 2, 9) and post.shape == (N, 2, 9)
     assert res.shape == (N, N, 2, 9) and dev.shape == (1,)
     assert float(res.min()) > 0
@@ -108,15 +116,9 @@ def test_maps_are_far_from_the_identity_and_h_res_is_doubly_stochastic():
     assert left < 1e-3
     np.testing.assert_allclose(float(dev[0]), left, rtol=1e-6)
     # fewer iterations leave more: what the gauge is for
-    *_, few = seq.mhc_maps(data, phi, alpha, bias, streams=N, iters=1)
     assert float(few[0]) > 10 * left and float(few[0]) > 1e-2
     # the clamp is applied before the exponential
-    *_, res_c, _ = seq.mhc_maps(data, phi, 40.0 * alpha, bias, streams=N,
-                                clamp=(-2.0, 2.0))
     assert np.isfinite(np.asarray(res_c)).all()
-    want = jax.vmap(jax.vmap(lambda x: _token_maps(
-        x, phi, 40.0 * alpha, bias, N, clamp=(-2.0, 2.0))[2]))(
-            _streams(data, N))
     np.testing.assert_allclose(jnp.moveaxis(res_c, (0, 1), (2, 3)), want,
                                atol=1e-5)
 
@@ -124,8 +126,8 @@ def test_maps_are_far_from_the_identity_and_h_res_is_doubly_stochastic():
 def test_no_gradient_reaches_what_the_iterations_leave():
     phi, alpha, bias = _params()
     data = jax.random.normal(jax.random.PRNGKey(9), (1, 5, N * C))
-    g = jax.grad(lambda b: seq.mhc_maps(data, phi, alpha, b,
-                                        streams=N)[3][0])(bias)
+    _, g = numerics.traced(lambda b: seq.mhc_maps(
+        data, phi, alpha, b, streams=N)[3][0], (bias,), 1.0, 0)
     assert not np.asarray(g).any()
 
 
@@ -249,7 +251,9 @@ def test_pattern_lm_with_four_streams_is_the_plain_model():
     params = {k: v.data().asnumpy() for k, v in net.collect_params().items()}
     with jax.default_matmul_precision("highest"):
         got = net(mx.nd.array(tokens)).asnumpy()
-        want = _plain_model(params, jnp.asarray(tokens), "GGG", 4)
+        want, _ = numerics.traced(
+            lambda params, tokens: _plain_model(params, tokens, "GGG", 4),
+            (params, jnp.asarray(tokens)))
     assert got.shape == (12, 31)
     np.testing.assert_allclose(got, want, atol=3e-5)
     # and it is not the model with one stream: the maps do something

@@ -8,7 +8,6 @@ the op takes the kernels; what a recomputation unit keeps; and the
 kernels' lowered text at heads of 128 at the three cells' shapes, pinned.
 Nothing here is a time."""
 import base64
-import functools
 import hashlib
 import os
 import re
@@ -27,6 +26,8 @@ from mxnet_tpu.ops import attn_kernel, remat, seq
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 import harness  # noqa: E402
+import numerics  # noqa: E402
+from numerics import Tol, kernels_here  # noqa: E402, F401
 
 D = 128
 
@@ -84,15 +85,9 @@ def test_op_is_the_reference_s_equations(length, block):
             SMALL, p, 0, u, "float32"))(x)
 
     with jax.default_matmul_precision("highest"):
-        got = _op(SMALL, w, x, block)
-        np.testing.assert_allclose(got, want(w, x), atol=2e-5)
-        g_got = jax.grad(lambda w, x: jnp.sum(_op(SMALL, w, x, block) * cot),
-                         argnums=(0, 1))(w, x)
-        g_want = jax.grad(lambda w, x: jnp.sum(want(w, x) * cot),
-                          argnums=(0, 1))(w, x)
-    for a, b in zip(jax.tree_util.tree_leaves(g_got),
-                    jax.tree_util.tree_leaves(g_want)):
-        np.testing.assert_allclose(a, b, atol=3e-5 * float(jnp.abs(b).max()))
+        numerics.agree(
+            lambda w, x: _op(SMALL, w, x, block), want, (w, x), cot, (0, 1),
+            value=Tol(atol=2e-5), grads=Tol(scaled=3e-5))
 
 
 def test_the_rotary_key_is_one_vector_for_all_heads():
@@ -103,17 +98,23 @@ def test_the_rotary_key_is_one_vector_for_all_heads():
     w = _small_weights(SMALL)
     x = jax.random.normal(jax.random.PRNGKey(3), (1, 12, 48), jnp.float32)
     h, dn, r = 3, 16, 20
-    base = _op(SMALL, w, x)
-    moved = dict(w, kv_down_weight=w["kv_down_weight"].at[r:].multiply(2.0))
-    per_head = jnp.abs((_op(SMALL, dict(moved, o_weight=jnp.ones_like(
-        w["o_weight"])), x) - _op(SMALL, dict(w, o_weight=jnp.ones_like(
-            w["o_weight"])), x))).max()
+    ones = jnp.ones_like(w["o_weight"])
+
+    def forms(w, x):
+        base = _op(SMALL, w, x)
+        moved = dict(w, kv_down_weight=w["kv_down_weight"].at[r:].multiply(
+            2.0))
+        per_head = jnp.abs(_op(SMALL, dict(moved, o_weight=ones), x)
+                           - _op(SMALL, dict(w, o_weight=ones), x)).max()
+        flat = dict(w, q_weight=w["q_weight"].at[h * dn:].set(0.0),
+                    kv_down_weight=w["kv_down_weight"].at[r:].set(0.0))
+        a = _op(SMALL, flat, x)[0, -1]
+        b = _op(SMALL, flat, jnp.concatenate(
+            [x[:, :-1][:, ::-1], x[:, -1:]], axis=1))[0, -1]
+        return base, per_head, a, b
+
+    (base, per_head, a, b), _ = numerics.traced(forms, (w, x))
     assert per_head > 1e-3
-    flat = dict(w, q_weight=w["q_weight"].at[h * dn:].set(0.0),
-                kv_down_weight=w["kv_down_weight"].at[r:].set(0.0))
-    a = _op(SMALL, flat, x)[0, -1]
-    b = _op(SMALL, flat, jnp.concatenate(
-        [x[:, :-1][:, ::-1], x[:, -1:]], axis=1))[0, -1]
     np.testing.assert_allclose(a, b, atol=1e-5)
     assert jnp.abs(base - a).max() > 1e-3
 
@@ -146,24 +147,25 @@ def test_kernels_with_a_second_part_agree_with_the_blocked_form(
     k2]``; grouped key/value heads and a padded length among the cases."""
     q, k, v, q2, k2, cot = _parts(length, hq, hk, d2, dtype)
     scale = (D + d2) ** -0.5
-    out, lse = attn_kernel.forward(q, k, v, hq, hk, scale, interpret=True,
-                                   extra=(q2, k2))
-    want, want_lse = _blocked(q, k, v, q2, k2, hq, hk, scale)
     tol = 2e-5 if dtype == "float32" else 4e-2
-    f32 = lambda a: np.asarray(a.astype(jnp.float32))  # noqa: E731
-    assert np.abs(f32(out) - f32(want)).max() <= tol * np.abs(f32(want)).max()
-    np.testing.assert_allclose(lse, want_lse, atol=2e-5 if dtype == "float32"
-                               else 5e-2)
-    got = attn_kernel.backward(q, k, v, out, lse, cot, hq, hk, scale,
-                               interpret=True, extra=(q2, k2))
-    grads = jax.grad(lambda *a: jnp.sum(
-        _blocked(*a, hq, hk, scale)[0].astype(jnp.float32)
-        * cot.astype(jnp.float32)), argnums=(0, 1, 2, 3, 4))(q, k, v, q2, k2)
-    assert len(got) == 5
-    for name, a, b in zip(("dq", "dk", "dv", "dq2", "dk2"), got, grads):
-        assert a.shape == b.shape and a.dtype == b.dtype, name
-        assert np.abs(f32(a) - f32(b)).max() \
-            <= tol * np.abs(f32(b)).max(), name
+
+    def kernels(q, k, v, q2, k2):
+        out, lse = attn_kernel.forward(q, k, v, hq, hk, scale, interpret=True,
+                                       extra=(q2, k2))
+        return out, lse, attn_kernel.backward(
+            q, k, v, out, lse, cot, hq, hk, scale, interpret=True,
+            extra=(q2, k2))
+
+    (out, lse, got), _ = numerics.traced(kernels, (q, k, v, q2, k2))
+    (want, want_lse), grads = numerics.traced(
+        lambda *a: _blocked(*a, hq, hk, scale), (q, k, v, q2, k2),
+        (cot.astype(jnp.float32), 0.0), (0, 1, 2, 3, 4))
+    numerics.close(out, want, Tol(rtol=0.0, scaled=tol), "out")
+    numerics.close(lse, want_lse, Tol(atol=2e-5 if dtype == "float32"
+                                      else 5e-2), "lse")
+    # dq, dk, dv, dq2, dk2
+    numerics.close(got, grads, Tol(rtol=0.0, scaled=tol), "d",
+                   same_dtype=True)
 
 
 def test_without_a_second_part_the_kernels_return_what_they_did():
@@ -186,16 +188,6 @@ WIDE = {"hidden_size": 256, "num_attention_heads": 2, "qk_nope_head_dim": 128,
         "rope_theta": 50000, "rms_norm_eps": 1e-5}
 
 
-@pytest.fixture()
-def kernels_here(monkeypatch):
-    """The op takes its TPU branch on this backend, kernels interpreted."""
-    monkeypatch.setattr(lax, "platform_dependent",
-                        lambda *args, tpu, default: tpu(*args))
-    for name in ("forward", "backward"):
-        monkeypatch.setattr(attn_kernel, name, functools.partial(
-            getattr(attn_kernel, name), interpret=True))
-
-
 def _wide_case(nope=128, dtype=jnp.float32):
     sz = dict(WIDE, qk_nope_head_dim=nope, v_head_dim=nope)
     w = {k: v.astype(dtype) for k, v in _small_weights(sz, seed=5).items()}
@@ -210,15 +202,12 @@ def test_op_through_the_kernels_is_the_plain_form(kernels_here):
     def loss(w, x):
         return jnp.sum(_op(sz, w, x, block=128) ** 2)
 
-    got = jax.value_and_grad(loss, argnums=(0, 1))(w, x)
+    got = numerics.traced(loss, (w, x), 1.0, (0, 1))
     with pytest.MonkeyPatch.context() as plain:
         plain.setattr(lax, "platform_dependent",
                       lambda *args, tpu, default: default(*args))
-        want = jax.value_and_grad(loss, argnums=(0, 1))(w, x)
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(a, b, rtol=2e-4,
-                                   atol=2e-4 * float(jnp.abs(b).max()))
+        want = numerics.traced(loss, (w, x), 1.0, (0, 1))
+    numerics.close(got, want, numerics.kernel_tol(2e-4))
 
 
 def _lowered_for(platform, fn, *args):

@@ -25,6 +25,8 @@ from mxnet_tpu.ops import attn_kernel, remat, seq
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 import harness  # noqa: E402
+import numerics  # noqa: E402
+from numerics import Tol, kernels_here  # noqa: E402, F401
 
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
         "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
@@ -165,19 +167,14 @@ def test_op_with_a_query_latent_is_the_reference_s_equations(length, block):
             sz, p, 0, u, "float32"))(x)
 
     with jax.default_matmul_precision("highest"):
-        got = _op(sz, w, x, block)
-        np.testing.assert_allclose(got, want(w, x), atol=2e-5)
-        g_got = jax.grad(lambda w, x: jnp.sum(_op(sz, w, x, block) * cot),
-                         argnums=(0, 1))(w, x)
-        g_want = jax.grad(lambda w, x: jnp.sum(want(w, x) * cot),
-                          argnums=(0, 1))(w, x)
-    for a, b in zip(jax.tree_util.tree_leaves(g_got),
-                    jax.tree_util.tree_leaves(g_want)):
-        np.testing.assert_allclose(a, b, atol=3e-5 * float(jnp.abs(b).max()))
+        got, _ = numerics.agree(
+            lambda w, x: _op(sz, w, x, block), want, (w, x), cot, (0, 1),
+            value=Tol(atol=2e-5), grads=Tol(scaled=3e-5))
     # the scale and the frequencies both matter at these sizes
-    bare = seq.latent_attention(
+    bare, _ = numerics.traced(lambda w, x: seq.latent_attention(
         x, *(w[k] for k in LEAVES), num_heads=3, nope_dim=16, rope_dim=8,
-        v_dim=10, latent_dim=20, rope_theta=10000, eps=1e-6, block=block)
+        v_dim=10, latent_dim=20, rope_theta=10000, eps=1e-6, block=block),
+        (w, x))
     assert float(jnp.abs(bare - got).max()) > 1e-3
 
 
@@ -199,7 +196,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
                     part * h * w_ + (first + per) * w_]
              for part, w_ in zip(parts, width)])
 
-    with jax.default_matmul_precision("highest"):
+    def shares(w, x):
         whole = _op(sz, w, x)
         total = jnp.zeros_like(whole)
         for share in range(h // per):
@@ -216,6 +213,10 @@ def test_the_shares_add_up_to_the_uncut_layer():
                         o_weight=w["o_weight"][:, first * dv:
                                                (first + per) * dv])
             total = total + _op(sz, mine, x, heads=per)
+        return total, whole
+
+    with jax.default_matmul_precision("highest"):
+        (total, whole), _ = numerics.traced(shares, (w, x))
     np.testing.assert_allclose(total, whole, atol=3e-5)
     assert float(jnp.abs(whole).max()) > 1e-2
 
@@ -226,16 +227,6 @@ WIDE = dict(SMALL, hidden_size=256, num_attention_heads=4,
             kv_lora_rank=64, q_lora_rank=96)
 
 
-@pytest.fixture()
-def kernels_here(monkeypatch):
-    """The op takes its TPU branch on this backend, kernels interpreted."""
-    monkeypatch.setattr(lax, "platform_dependent",
-                        lambda *args, tpu, default: tpu(*args))
-    for name in ("forward", "backward"):
-        monkeypatch.setattr(attn_kernel, name, functools.partial(
-            getattr(attn_kernel, name), interpret=True))
-
-
 def test_op_through_the_kernels_at_four_heads_is_the_plain_form(
         kernels_here):
     w = _weights(WIDE, seed=5)
@@ -244,15 +235,12 @@ def test_op_through_the_kernels_at_four_heads_is_the_plain_form(
     def loss(w, x):
         return jnp.sum(_op(WIDE, w, x, block=128) ** 2)
 
-    got = jax.value_and_grad(loss, argnums=(0, 1))(w, x)
+    got = numerics.traced(loss, (w, x), 1.0, (0, 1))
     with pytest.MonkeyPatch.context() as plain:
         plain.setattr(lax, "platform_dependent",
                       lambda *args, tpu, default: default(*args))
-        want = jax.value_and_grad(loss, argnums=(0, 1))(w, x)
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(a, b, rtol=2e-4,
-                                   atol=2e-4 * float(jnp.abs(b).max()))
+        want = numerics.traced(loss, (w, x), 1.0, (0, 1))
+    numerics.close(got, want, numerics.kernel_tol(2e-4))
 
 
 def test_the_site_is_lowered_to_the_kernels_for_a_tpu():
@@ -293,7 +281,8 @@ def test_block_with_a_query_latent_is_the_op_and_without_it_the_old_block():
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 12, 48), jnp.float32)
     np.testing.assert_allclose(
         block(mx.nd.array(np.asarray(x))).asnumpy(),
-        _op(dict(SMALL, num_attention_heads=3), w, x), atol=1e-5)
+        numerics.traced(functools.partial(
+            _op, dict(SMALL, num_attention_heads=3)), (w, x))[0], atol=1e-5)
     old = nn.LatentAttention(48, 3, nope_dim=16, rope_dim=8, v_dim=10,
                              latent_dim=20)
     assert sorted(k.split("_", 1)[1] for k in old.collect_params()) \

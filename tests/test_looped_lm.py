@@ -19,6 +19,7 @@ import jax.numpy as jnp
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "reference"))
 import ouro as ref  # noqa: E402
+import numerics  # noqa: E402
 
 import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu import telemetry  # noqa: E402
@@ -192,7 +193,8 @@ def test_three_adam_steps_agree_with_the_reference(remat):
 def test_bfloat16_step_is_near_the_reference():
     """bfloat16 compute over float32 masters, as the cell runs it."""
     params, (x, y) = _params(), _batches(1)[0]
-    want = float(ref.reference_loss(SZ, params, x, y))
+    want = float(numerics.traced(
+        lambda p: ref.reference_loss(SZ, p, x, y), (params,))[0])
     step = _step(_load(_net(), params), compute_dtype="bfloat16")
     got = float(step(mx.nd.array(x), mx.nd.array(y)).asnumpy())
     assert abs(got - want) < 2e-2 * want
@@ -389,6 +391,7 @@ def test_gradient_against_finite_differences(name):
             gu = 0.5 * jax.random.normal(keys[1], (2 * 9, 6), jnp.float64)
             dn = 0.5 * jax.random.normal(keys[2], (6, 9), jnp.float64)
             fn = lambda a: jnp.sum(jnp.sin(seq.gated_mlp(a, gu, dn)))  # noqa
+        fn = jax.jit(fn)        # seven calls of one program
         grad = np.asarray(jax.grad(fn)(x))
         for key in jax.random.split(keys[3], 3):
             direction = np.asarray(jax.random.normal(key, x.shape))

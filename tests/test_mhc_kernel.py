@@ -18,6 +18,9 @@ from jax import lax
 import mxnet_tpu as mx
 from mxnet_tpu.ops import mhc_kernel, seq
 
+import numerics
+from numerics import kernel_tol
+
 EPS = 1e-6
 TOKENS = 256        # two blocks of 128
 
@@ -41,13 +44,6 @@ def _operands(n, c, dtype, tokens=TOKENS, seed=0):
         d_ms=jnp.asarray(rng.normal(size=(tokens,)), jnp.float32))
 
 
-def _close(got, want, tol, name):
-    assert got.dtype == want.dtype and got.shape == want.shape, name
-    got, want = (np.asarray(t, np.float32) for t in (got, want))
-    np.testing.assert_allclose(got, want, rtol=tol,
-                               atol=tol * np.abs(want).max(), err_msg=name)
-
-
 def _tol(dtype):
     # float32: sums in another order; bfloat16: the rounding of one output
     return 2e-5 if jnp.dtype(dtype) == jnp.float32 else 2.0 ** -7
@@ -62,22 +58,21 @@ DTYPES = pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @DTYPES
 def test_read_is_the_product_the_mean_square_and_the_mix(dtype, n, c):
     o = _operands(n, c, jnp.dtype(dtype), seed=n + c)
-    a, b = o["alpha"][0], o["bias"][:n]
-    got = mhc_kernel.read(o["x"], o["phi"], a, b, n=n, eps=EPS,
-                          interpret=True)
-    want = seq._read_plain(o["x"], o["phi"], a, b, n, EPS)
-    for name, x, y in zip(("raw", "mean_sq", "u"), got, want):
-        _close(x, y, _tol(dtype), name)
+    # raw, mean_sq, u
+    numerics.agree(
+        lambda *a: mhc_kernel.read(*a, n=n, eps=EPS, interpret=True),
+        lambda *a: seq._read_plain(*a, n, EPS),
+        (o["x"], o["phi"], o["alpha"][0], o["bias"][:n]),
+        value=kernel_tol(_tol(dtype)), same_dtype=True)
 
 
 @SHAPES
 @DTYPES
 def test_post_is_the_plain_sums(dtype, n, c):
     o = _operands(n, c, jnp.dtype(dtype), seed=n + c + 1)
-    got = mhc_kernel.post(o["x"], o["y"], o["res"], o["post"],
-                          interpret=True)
-    _close(got, seq._post_rows(o["x"], o["y"], o["res"], o["post"]),
-           _tol(dtype), "streams")
+    numerics.agree(functools.partial(mhc_kernel.post, interpret=True),
+                   seq._post_rows, (o["x"], o["y"], o["res"], o["post"]),
+                   value=kernel_tol(_tol(dtype)), same_dtype=True)
 
 
 @SHAPES
@@ -85,14 +80,13 @@ def test_post_is_the_plain_sums(dtype, n, c):
 def test_post_backward_is_jax_s_derivative_of_the_plain_sums(dtype, n, c):
     o = _operands(n, c, jnp.dtype(dtype), seed=n + c + 2)
     args = (o["x"], o["y"], o["res"], o["post"])
-    dxp, dy, d_res, d_post = mhc_kernel.post_backward(o["g"], *args,
-                                                      interpret=True)
-    want = jax.vjp(seq._post_rows, *args)[1](o["g"])
     # one token's product of two streams, 128 or 256 terms in float32
     tol = _tol(dtype) if dtype == "bfloat16" else 1e-4
-    for name, x, y in zip(("dxp", "dy", "d_res", "d_post"),
-                          (dxp, dy, d_res, d_post), want):
-        _close(x, y, tol, name)
+    # dxp, dy, d_res, d_post
+    numerics.agree(
+        lambda g, *a: mhc_kernel.post_backward(g, *a, interpret=True),
+        lambda g, *a: jax.vjp(seq._post_rows, *a)[1](g), (o["g"], *args),
+        value=kernel_tol(tol), same_dtype=True)
 
 
 @SHAPES
@@ -103,43 +97,40 @@ def test_read_backward_sums_one_cotangent_of_the_streams(dtype, n, c):
     from the logits' cotangent."""
     o = _operands(n, c, jnp.dtype(dtype), seed=n + c + 3)
     a, b = o["alpha"][0], o["bias"][:n]
-    raw, ms, _ = seq._read_plain(o["x"], o["phi"], a, b, n, EPS)
-    dx, d_phi, d_logits = mhc_kernel.read_backward(
-        o["x"], o["g"], o["du"], raw, ms, o["d_raw"], o["d_ms"], o["phi"],
-        a, b, n=n, eps=EPS, interpret=True)
-    wx, w_phi, wa, wb = jax.vjp(
-        lambda *p: seq._read_plain(*p, n, EPS), o["x"], o["phi"], a, b)[1](
-            (o["d_raw"], o["d_ms"], o["du"]))
+
+    def kernel(x, phi, a, b):
+        raw, ms, _ = seq._read_plain(x, phi, a, b, n, EPS)
+        dx, d_phi, d_logits = mhc_kernel.read_backward(
+            x, o["g"], o["du"], raw, ms, o["d_raw"], o["d_ms"], phi, a, b,
+            n=n, eps=EPS, interpret=True)
+        scaled = raw[:n] * lax.rsqrt(ms + EPS)[None]
+        return dx, d_phi, jnp.sum(d_logits * scaled), jnp.sum(d_logits,
+                                                              axis=1)
+
+    def plain(x, phi, a, b):
+        return jax.vjp(lambda *p: seq._read_plain(*p, n, EPS), x, phi, a, b)[
+            1]((o["d_raw"], o["d_ms"], o["du"]))
+
+    args = (o["x"], o["phi"], a, b)
+    (dx, d_phi, da, db), _ = numerics.traced(kernel, args)
+    (wx, w_phi, wa, wb), _ = numerics.traced(plain, args)
     # the plain form rounds its share before the write side's is added
-    tol = 1e-4 if dtype == "float32" else 2.0 ** -6
-    _close(dx, (wx.astype(jnp.float32) + o["g"].astype(jnp.float32)).astype(
-        wx.dtype), tol, "dx")
-    _close(d_phi.astype(w_phi.dtype), w_phi, tol, "d_phi")
-    scaled = raw[:n] * lax.rsqrt(ms + EPS)[None]
-    _close(jnp.sum(d_logits * scaled), wa, 1e-4, "d_alpha_pre")
-    _close(jnp.sum(d_logits, axis=1), wb, 1e-4, "d_bias_pre")
+    tol = kernel_tol(1e-4 if dtype == "float32" else 2.0 ** -6)
+    numerics.close(
+        dx, (wx.astype(jnp.float32) + o["g"].astype(jnp.float32)).astype(
+            wx.dtype), tol, "dx", same_dtype=True)
+    numerics.close(d_phi.astype(w_phi.dtype), w_phi, tol, "d_phi")
+    numerics.close((da, db), (wa, wb), kernel_tol(1e-4),
+                   "d_alpha_pre, d_bias_pre", same_dtype=True)
 
 
 # ---------------------------------------------------------------------------
 # a whole sublayer through both custom_vjp
 # ---------------------------------------------------------------------------
-class _LoweredForATpu:
-    """Stands where ``ops.seq`` names ``jax.lax``: every
-    ``platform_dependent`` takes its TPU branch, as a lowering for a TPU
-    would."""
-
-    def __getattr__(self, name):
-        return getattr(lax, name)
-
-    @staticmethod
-    def platform_dependent(*args, tpu, default):
-        return tpu(*args)
-
-
 @pytest.fixture
 def kernels_here(monkeypatch):
     """The TPU's branches on this CPU, their kernels interpreted."""
-    monkeypatch.setattr(seq, "lax", _LoweredForATpu())
+    monkeypatch.setattr(seq, "lax", numerics.LoweredForATpu())
     for name in ("read", "post", "post_backward", "read_backward"):
         monkeypatch.setattr(mhc_kernel, name, functools.partial(
             getattr(mhc_kernel, name), interpret=True))
@@ -171,17 +162,14 @@ def test_a_sublayer_through_the_kernels_is_the_plain_form_with_every_gradient(
     w = jnp.asarray(np.random.default_rng(3).normal(size=(c, c)) / c ** 0.5,
                     jnp.dtype(dtype))
     loss, args = _sublayer(o, n, c, w)
-    fn = jax.value_and_grad(loss, argnums=range(5))
     with monkeypatch.context() as m:
         m.setattr(mhc_kernel, "takes", lambda *a: False)
-        want = fn(*args)
+        want = numerics.traced(loss, args, 1.0, range(5))
     request.getfixturevalue("kernels_here")
-    got = fn(*args)
+    got = numerics.traced(loss, args, 1.0, range(5))
     tol = 2e-4 if dtype == "float32" else 2.0 ** -5
-    names = ("loss", "d_data", "d_phi", "d_alpha", "d_bias", "d_w")
-    for name, a, b in zip(names, jax.tree_util.tree_leaves(got),
-                          jax.tree_util.tree_leaves(want)):
-        _close(a, b, tol, name)
+    # the loss; the streams, phi, alpha, the bias, w
+    numerics.close(got, want, kernel_tol(tol), same_dtype=True)
 
 
 def test_a_unit_through_the_kernels_keeps_what_the_plain_form_keeps(
@@ -308,9 +296,7 @@ def test_off_a_tpu_taken_shapes_give_the_plain_form_s_value_and_gradients(
     monkeypatch.setattr(mhc_kernel, "takes", lambda *a: False)
     want = jax.jit(jax.value_and_grad(loss, argnums=range(5)))(*args)
     tol = 1e-5 if dtype == "float32" else 2.0 ** -7
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        _close(a, b, tol, "leaf")
+    numerics.close(got, want, kernel_tol(tol), same_dtype=True)
 
 
 def test_a_train_step_sets_the_gauge_to_zero_where_it_traces():

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 
 from mxnet_tpu.ops.nn import rnn, rnn_param_size
 
+import numerics
+
 GATES = {"rnn_relu": 1, "rnn_tanh": 1, "lstm": 4, "gru": 3}
 N, C, H = 3, 5, 4
 
@@ -114,22 +116,12 @@ def test_outputs_states_and_gradients_match_the_plain_scan(mode, dirs,
     rng = np.random.RandomState(1)
     weights = [jnp.asarray(rng.randn(*s.shape), jnp.float32) for s in shapes]
 
-    def scalar(fn):
-        def loss(*a):
-            outs = fn(*a)
-            return sum((o * w).sum() for o, w in zip(outs, weights)), outs
-        return jax.value_and_grad(loss, argnums=tuple(range(len(args))),
-                                  has_aux=True)
-
-    (_, want), want_grads = scalar(_ref(mode, layers, dirs))(*args)
-    (_, got), got_grads = scalar(_op(mode, layers, dirs))(*args)
-    assert len(got) == len(want) == (3 if mode == "lstm" else 2)
-    names = ["out", "h", "c"][:len(want)] \
-        + ["d data", "d parameters", "d state", "d state_cell"][:len(args)]
-    for name, a, b in zip(names, tuple(got) + tuple(got_grads),
-                          tuple(want) + tuple(want_grads)):
-        assert a.shape == b.shape and a.dtype == b.dtype, name
-        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5, err_msg=name)
+    # out, h (and c); d data, d parameters, d state (and d state_cell)
+    got, _ = numerics.agree(
+        _op(mode, layers, dirs), _ref(mode, layers, dirs), args, weights,
+        range(len(args)), value=numerics.Tol(rtol=2e-5, atol=2e-5),
+        same_dtype=True)
+    assert len(got) == (3 if mode == "lstm" else 2)
 
 
 @pytest.mark.parametrize("mode", ["lstm", "gru"])
@@ -145,8 +137,10 @@ def test_bf16_keeps_each_state_in_the_dtype_it_came_in(mode):
         outs = _op(mode, 2, 2)(*a)
         return sum(o.astype(jnp.float32).sum() for o in outs), outs
 
-    (_, outs), grads = jax.value_and_grad(
-        loss, argnums=tuple(range(len(args))), has_aux=True)(*args)
+    # the gradient of the sum alone: no cotangent for the outputs beside it
+    (_, outs), grads = numerics.traced(
+        loss, args, (1.0, (0.0,) * (3 if mode == "lstm" else 2)),
+        range(len(args)))
     assert [o.dtype for o in outs[:2]] == [jnp.bfloat16] * 2
     if mode == "lstm":
         assert outs[2].dtype == jnp.float32

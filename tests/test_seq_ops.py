@@ -16,6 +16,7 @@ import jax.numpy as jnp
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "reference"))
 import nemotron_h as ref  # noqa: E402
+import numerics  # noqa: E402
 
 from mxnet_tpu.ops import seq  # noqa: E402
 
@@ -68,38 +69,35 @@ def _x(length, seed=1, batch=2):
     return jnp.asarray(rng.standard_normal((batch, length, 64)), jnp.float32)
 
 
-def _close(got, want, tol=2e-5):
-    scale = float(jnp.max(jnp.abs(want)))
-    assert float(jnp.max(jnp.abs(got - want))) <= tol * max(scale, 1.0), \
-        (float(jnp.max(jnp.abs(got - want))), scale)
+#: of the wanted value's largest entry, or of 1 where that is smaller
+TOL = numerics.Tol(rtol=0.0, scaled=2e-5, floor=1.0)
 
 
 def _grads_close(f_got, f_want, args, tol=2e-4):
     """Gradients of a scalar of the output with respect to every
     argument."""
     w = jnp.asarray(np.random.default_rng(7).standard_normal(
-        f_want(*args).shape), jnp.float32)
-    got = jax.grad(lambda *a: jnp.sum(f_got(*a) * w),
-                   argnums=tuple(range(len(args))))(*args)
-    want = jax.grad(lambda *a: jnp.sum(f_want(*a) * w),
-                    argnums=tuple(range(len(args))))(*args)
-    for g, h in zip(got, want):
-        _close(g, h, tol)
+        jax.eval_shape(f_want, *args).shape), jnp.float32)
+    got, want = (numerics.traced(fn, args, w, range(len(args)))[1]
+                 for fn in (f_got, f_want))
+    numerics.close(got, want, TOL._replace(scaled=tol))
 
 
 def test_rms_norm_groups():
     x = _x(5)
     w = jnp.asarray(np.random.default_rng(2).standard_normal(64), jnp.float32)
-    _close(seq.rms_norm(x, w, eps=1e-5, num_groups=4),
-           ref._rms(x, w, 1e-5, 4))
+    numerics.close(seq.rms_norm(x, w, eps=1e-5, num_groups=4),
+                   ref._rms(x, w, 1e-5, 4), TOL)
 
 
 @pytest.mark.parametrize("length", [24, 21, 5])
 def test_mamba2_matches_time_step_scan(length):
     p = ref.random_params(SZ, 0)
     x = _x(length)
-    want = jax.vmap(lambda u: ref.mamba_layer(SZ, p, 0, u, "float32"))(x)
-    _close(_mamba(SZ, x, *_leaves(p, 0, M_LEAVES)), want)
+    numerics.agree(
+        lambda x: _mamba(SZ, x, *_leaves(p, 0, M_LEAVES)),
+        jax.vmap(lambda u: ref.mamba_layer(SZ, p, 0, u, "float32")), (x,),
+        value=TOL)
 
 
 def test_mamba2_gradients():
@@ -118,20 +116,22 @@ def test_scan_restarted_at_chunk_borders_is_told_apart():
     state between chunks."""
     p = ref.random_params(SZ, 0)
     x = _x(24)
-    whole = _mamba(SZ, x, *_leaves(p, 0, M_LEAVES))
-    pieces = jnp.concatenate(
-        [_mamba(SZ, x[:, i:i + 8], *_leaves(p, 0, M_LEAVES))
-         for i in range(0, 24, 8)], axis=1)
+    (whole, pieces), _ = numerics.traced(lambda x: (
+        _mamba(SZ, x, *_leaves(p, 0, M_LEAVES)),
+        jnp.concatenate(
+            [_mamba(SZ, x[:, i:i + 8], *_leaves(p, 0, M_LEAVES))
+             for i in range(0, 24, 8)], axis=1)), (x,))
     assert float(jnp.max(jnp.abs(whole - pieces)[:, 8:])) > 1e-3
-    _close(pieces[:, :8], whole[:, :8])
+    numerics.close(pieces[:, :8], whole[:, :8], TOL)
 
 
 def test_latent_moe_matches_masked_loop():
     p = ref.random_params(SZ, 0)
     x = _x(21)
-    want = ref.moe_layer(SZ, p, 1, x.reshape(-1, 64), "float32")[0]
-    got, stats = _moe(SZ, x, *_leaves(p, 1, E_LEAVES))
-    _close(got.reshape(-1, 64), want)
+    (want, (got, stats)), _ = numerics.traced(lambda x: (
+        ref.moe_layer(SZ, p, 1, x.reshape(-1, 64), "float32")[0],
+        _moe(SZ, x, *_leaves(p, 1, E_LEAVES))), (x,))
+    numerics.close(got.reshape(-1, 64), want, TOL)
     assert float(stats[0]) == 42 * 3 and float(stats[1]) == 0
 
 
@@ -184,9 +184,10 @@ def test_latent_moe_gradients():
 def test_attention_matches_dense_softmax(length, block):
     p = ref.random_params(SZ, 0)
     x = _x(length)
-    want = jax.vmap(lambda u: ref.attn_layer(SZ, p, 2, u, "float32",
-                                             block=7))(x)
-    _close(_attn(SZ, x, p["l2_qkv_weight"], p["l2_o_weight"], block), want)
+    numerics.agree(
+        lambda x: _attn(SZ, x, p["l2_qkv_weight"], p["l2_o_weight"], block),
+        jax.vmap(lambda u: ref.attn_layer(SZ, p, 2, u, "float32", block=7)),
+        (x,), value=TOL)
 
 
 def test_attention_gradients():
@@ -211,22 +212,25 @@ def test_mamba2_group_shares_add_up():
     x = _x(21)
     h, hd, g, n = 8, 16, 4, 16
     d_in, r = h * hd, 2
-    want = jax.vmap(lambda u: ref.mamba_layer(SZ, p, 0, u, "float32"))(x)
     share_sz = dict(SZ, mamba_num_heads=r, n_groups=1)
-    total = 0
-    for s in range(g):
+
+    def share(x, s):
         ch = np.arange(s * r * hd, (s + 1) * r * hd)          # channels
         bn = np.arange(s * n, (s + 1) * n)
         hs = np.arange(s * r, (s + 1) * r)
         conv_rows = np.concatenate([ch, d_in + bn, d_in + g * n + bn])
         in_rows = np.concatenate([ch, d_in + conv_rows,
                                   2 * d_in + 2 * g * n + hs])
-        total = total + _mamba(
+        return _mamba(
             share_sz, x, p["l0_in_proj_weight"][in_rows],
             p["l0_conv_weight"][conv_rows], p["l0_conv_bias"][conv_rows],
             p["l0_dt_bias"][hs], p["l0_a_log"][hs], p["l0_d"][hs],
             p["l0_gate_norm_weight"][ch], p["l0_out_proj_weight"][:, ch])
-    _close(total, want)
+
+    numerics.agree(
+        lambda x: sum(share(x, s) for s in range(g)),
+        jax.vmap(lambda u: ref.mamba_layer(SZ, p, 0, u, "float32")), (x,),
+        value=TOL)
 
 
 def test_latent_moe_shares_add_up():
@@ -235,20 +239,26 @@ def test_latent_moe_shares_add_up():
     chip and enter every share's result through its own experts only."""
     p = ref.random_params(SZ, 3)
     x = _x(21)
-    want = ref.moe_layer(SZ, p, 1, x.reshape(-1, 64), "float32")[0]
-    total, pairs = 0, 0
-    for s in range(4):
-        ids = tuple(range(4 * s, 4 * s + 4))
-        cols = np.arange(10 * s, 10 * s + 10)
-        out, stats = _moe(
-            SZ, x, p["l1_router_weight"], p["l1_router_bias"],
-            p["l1_down_weight"], p["l1_up_weight"],
-            p["l1_w1"][4 * s:4 * s + 4], p["l1_w2"][4 * s:4 * s + 4],
-            p["l1_shared_w1"][cols], p["l1_shared_w2"][:, cols], ids=ids)
-        total = total + out
-        pairs += float(stats[0])
-    _close(total.reshape(-1, 64), want)
-    assert pairs == 42 * 3          # every pair is on exactly one chip
+
+    def shares(x):
+        total, pairs = 0, 0
+        for s in range(4):
+            ids = tuple(range(4 * s, 4 * s + 4))
+            cols = np.arange(10 * s, 10 * s + 10)
+            out, stats = _moe(
+                SZ, x, p["l1_router_weight"], p["l1_router_bias"],
+                p["l1_down_weight"], p["l1_up_weight"],
+                p["l1_w1"][4 * s:4 * s + 4], p["l1_w2"][4 * s:4 * s + 4],
+                p["l1_shared_w1"][cols], p["l1_shared_w2"][:, cols], ids=ids)
+            total = total + out
+            pairs += stats[0]
+        return total.reshape(-1, 64), pairs
+
+    ((total, pairs), want), _ = numerics.traced(lambda x: (
+        shares(x), ref.moe_layer(SZ, p, 1, x.reshape(-1, 64), "float32")[0]),
+        (x,))
+    numerics.close(total, want, TOL)
+    assert float(pairs) == 42 * 3   # every pair is on exactly one chip
 
 
 def test_attention_head_shares_add_up():
@@ -257,17 +267,20 @@ def test_attention_head_shares_add_up():
     p = ref.random_params(SZ, 3)
     x = _x(21)
     hq, hk, dh = 8, 2, 16
-    want = jax.vmap(lambda u: ref.attn_layer(SZ, p, 2, u, "float32"))(x)
     share_sz = dict(SZ, num_attention_heads=1, num_key_value_heads=1)
-    total = 0
-    for s in range(hq):
+
+    def share(x, s):
         kv = s // (hq // hk)
         q_rows = np.arange(s * dh, (s + 1) * dh)
         k_rows = hq * dh + np.arange(kv * dh, (kv + 1) * dh)
         rows = np.concatenate([q_rows, k_rows, hk * dh + k_rows])
-        total = total + _attn(share_sz, x, p["l2_qkv_weight"][rows],
-                              p["l2_o_weight"][:, q_rows])
-    _close(total, want)
+        return _attn(share_sz, x, p["l2_qkv_weight"][rows],
+                     p["l2_o_weight"][:, q_rows])
+
+    numerics.agree(
+        lambda x: sum(share(x, s) for s in range(hq)),
+        jax.vmap(lambda u: ref.attn_layer(SZ, p, 2, u, "float32")), (x,),
+        value=TOL)
 
 
 # ---------------------------------------------------------------------------
@@ -342,5 +355,5 @@ def test_grouped_product_computes_unfilled_rows():
     want = jnp.einsum("erf,efd->erd",
                       jnp.square(jnp.maximum(
                           jnp.einsum("erd,edf->erf", buf, w1), 0)), w2)
-    _close(out, want, 1e-4)
+    numerics.close(out, want, TOL._replace(scaled=1e-4))
     assert bool(jnp.all(jnp.any(out != 0, axis=-1)))

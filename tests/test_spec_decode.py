@@ -246,15 +246,38 @@ def test_kv_handoff_fault_reprefills_zero_dropped():
 
 
 # ---------------------------------------------------------------------------
-# disaggregation: dedicated prefill beats unified TTFT when decode is
-# the bottleneck
+# disaggregation: a prompt's first token waits for decode launches in the
+# unified batcher and for none in the prefill role
 # ---------------------------------------------------------------------------
+class _CountedWaits:
+    """Stands before a batcher: for every stream, how many ``decode_step``
+    launches its engine made between the prompt's arrival and its first
+    token. A count, which the machine's load cannot move as it moves a
+    percentile of 16 streams on a wall clock."""
+
+    def __init__(self, batcher, engine):
+        self.batcher, self.engine, self.waited = batcher, engine, []
+
+    def submit(self, prompt, **kw):
+        before = self.engine._decode_steps
+        return self._counted(self.batcher.submit(prompt, **kw), before)
+
+    def _counted(self, stream, before):
+        for i, token in enumerate(stream):
+            if i == 0:
+                self.waited.append(self.engine._decode_steps - before)
+            yield token
+
+
 def test_disagg_ttft_p99_beats_unified_under_slow_decode():
     """Sleep-armed ``decode_step`` (the straggler stand-in, ~12 ms per
     launch) makes decode the bottleneck. In the unified batcher a new
-    prompt waits for a decode lane to free before its prefill runs; the
-    prefill-role batcher releases lanes at handoff, so its TTFT stays
-    prefill-fast on the same mixed-length workload."""
+    prompt waits for a decode lane to free before its prefill runs: with
+    8 clients on 3 lanes at least 5 prompts' first tokens come after
+    sleep-armed launches of the engine that serves them. The prefill-role
+    batcher releases lanes at handoff: its engine launches no decode step
+    at all, so no first token waits for one, on the same mixed-length
+    workload, while the decode role's engine sleeps as often."""
     mixed = loadgen.mixed_prompts({4: 3, 8: 2, 16: 1}, vocab_size=64,
                                   n=8, seed=3)
 
@@ -262,8 +285,10 @@ def test_disagg_ttft_p99_beats_unified_under_slow_decode():
     with faultinject.inject(decode_step={"action": "sleep", "ms": 12}):
         with DecodeBatcher(uni_eng, max_wait_us=0,
                            name="specuni") as bat:
-            uni = loadgen.token_closed_loop(bat, mixed, 8, 2,
+            uni_waits = _CountedWaits(bat, uni_eng)
+            uni = loadgen.token_closed_loop(uni_waits, mixed, 8, 2,
                                             max_new_tokens=6)
+    assert faultinject.fired("decode_step") == uni_eng._decode_steps > 0
 
     pre_eng = make_plain("specdispre", slots=3, seq_buckets=(8, 16))
     dec_eng = make_plain("specdisdec", slots=3, seq_buckets=(8, 16))
@@ -276,21 +301,25 @@ def test_disagg_ttft_p99_beats_unified_under_slow_decode():
         lambda req, last, produced, lane, t0:
         bool(dec.adopt(req, last, produced, lane, t0)) or True)
     pre.start()
+    dis_waits = _CountedWaits(pre, pre_eng)
     try:
         with faultinject.inject(decode_step={"action": "sleep",
                                              "ms": 12}):
-            dis = loadgen.token_closed_loop(pre, mixed, 8, 2,
+            dis = loadgen.token_closed_loop(dis_waits, mixed, 8, 2,
                                             max_new_tokens=6)
     finally:
         pre.stop()
         dec.stop()
+    assert faultinject.fired("decode_step") == dec_eng._decode_steps > 0
 
     assert uni["gave_up"] == dis["gave_up"] == 0
     assert sum(b["streams"] for b in uni["by_length"].values()) == 16
     assert sum(b["streams"] for b in dis["by_length"].values()) == 16
-    assert dis["ttft_p99_ms"] < uni["ttft_p99_ms"], (
-        f"disagg TTFT p99 {dis['ttft_p99_ms']:.1f} ms must beat "
-        f"unified {uni['ttft_p99_ms']:.1f} ms when decode holds lanes")
+    assert len(uni_waits.waited) == len(dis_waits.waited) == 16
+    # three lanes for eight clients: the other five wait for a stream to
+    # end, five launches away
+    assert sum(w >= 1 for w in uni_waits.waited) >= 8 - 3, uni_waits.waited
+    assert pre_eng._decode_steps == 0 and not any(dis_waits.waited)
     # per-length-bucket percentile families ride both runs
     for run in (uni, dis):
         assert set(run["by_length"]) == {4, 8, 16}
