@@ -5,7 +5,9 @@ grouped-query attention (the ``hybrid_override_pattern`` of the
 multi-head latent attention, ``F`` a mixture of gated experts on the full
 hidden vector (``deepseek_v3``'s two sublayers: ``LG`` a dense layer,
 ``LF`` an expert layer), ``D`` a Gated DeltaNet mixer (``qwen3_next``'s
-linear-attention layers: ``DFDFDF*F`` a period). Pre-norm
+linear-attention layers: ``DFDFDF*F`` a period), ``C`` a gated short
+convolution (``lfm2_moe``'s ``conv`` layers: ``CG`` a dense layer,
+``*FCFCFCF`` a period of expert layers). Pre-norm
 residual throughout, ``x <- x + Mixer_l(RMSNorm_l(x))``, or with
 ``post_norm`` a norm on either side of the mixer, ``x <- x +
 RMSNorm'_l(Mixer_l(RMSNorm_l(x)))``; one final RMSNorm, an untied head,
@@ -118,7 +120,8 @@ class PatternLM(HybridBlock):
     ``nn.Mamba2Mixer``, ``nn.LatentMoE``, ``nn.GQAttention``,
     ``nn.GatedMLP``, ``nn.LatentAttention`` and ``nn.GatedMoE`` after
     ``in_units`` (what each layer of that kind holds), and
-    ``linear_attention`` those of ``nn.GatedDeltaNet``. ``post_norm``: a
+    ``linear_attention`` those of ``nn.GatedDeltaNet`` and ``short_conv``
+    those of ``nn.GatedShortConv``. ``post_norm``: a
     second norm in every layer, after its mixer. ``norm_unit_offset``:
     every layer's norm and the final norm scale by ``1 + w`` from ``w =
     0``. ``loops``: how often the stack and the final norm run, each pass
@@ -144,7 +147,7 @@ class PatternLM(HybridBlock):
                  loops=1, exit_gate=False, latent_attention=None,
                  experts=None, linear_attention=None,
                  norm_unit_offset=False, residual_streams=None,
-                 hyper_connections=None, **kwargs):
+                 hyper_connections=None, short_conv=None, **kwargs):
         super().__init__(**kwargs)
         if residual_streams is not None and loops > 1:
             raise ValueError("residual_streams with loops > 1: a layer "
@@ -159,7 +162,8 @@ class PatternLM(HybridBlock):
                                                 **latent_attention),
                 "F": lambda: nn.GatedMoE(units, **experts),
                 "D": lambda: nn.GatedDeltaNet(units, epsilon=epsilon,
-                                              **linear_attention)}
+                                              **linear_attention),
+                "C": lambda: nn.GatedShortConv(units, **(short_conv or {}))}
         self._vocab, self._units = vocab, units
         with self.name_scope():
             self.embed = nn.Embedding(vocab, units)
@@ -170,8 +174,10 @@ class PatternLM(HybridBlock):
                                         residual_streams))
             for i, kind in enumerate(pattern):
                 if kind not in make:
+                    *known, last = make
                     raise ValueError(f"layer kind {kind!r} in {pattern!r}: "
-                                     "M, E, *, G, L, F and D are known")
+                                     f"{', '.join(known)} and {last} are "
+                                     "known")
                 self.stack.add(_Layer(units, make[kind], epsilon, post_norm,
                                       norm_unit_offset, residual_streams,
                                       hyper_connections, prefix=f"l{i}_"))

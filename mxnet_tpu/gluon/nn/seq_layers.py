@@ -1,6 +1,6 @@
 """Sequence-model blocks: ``RMSNorm``, ``Mamba2Mixer``, ``GatedDeltaNet``,
-``LatentMoE``, ``GatedMoE``, ``GQAttention``, ``LatentAttention``,
-``GatedMLP``, the
+``GatedShortConv``, ``LatentMoE``, ``GatedMoE``, ``GQAttention``,
+``LatentAttention``, ``GatedMLP``, the
 ``HybridLoop`` container that runs its children several times as one
 scanned body, and ``ExitGate``, over the ops of ``ops/seq.py``.
 
@@ -16,9 +16,9 @@ from ... import autograd, initializer
 from ...ndarray.ndarray import _wrap
 from ..block import HybridBlock, _TraceState, stateful_write
 
-__all__ = ["RMSNorm", "Mamba2Mixer", "GatedDeltaNet", "LatentMoE", "GatedMoE",
-           "GQAttention", "LatentAttention", "GatedMLP", "HybridLoop",
-           "ExitGate",
+__all__ = ["RMSNorm", "Mamba2Mixer", "GatedDeltaNet", "GatedShortConv",
+           "LatentMoE", "GatedMoE", "GQAttention", "LatentAttention",
+           "GatedMLP", "HybridLoop", "ExitGate",
            "MOE_COUNTERS", "publish_moe_counters", "publish_loop_counters",
            "publish_mhc_counters"]
 
@@ -181,6 +181,25 @@ class GatedDeltaNet(HybridBlock):
                                **self._attrs)
 
 
+class GatedShortConv(HybridBlock):
+    """The gated short convolution (``ops.seq.gated_short_conv``), the
+    mixer of the ``lfm2`` family's ``conv`` layers: ``in_units -> [B | C |
+    z] -> conv over B * z, ``kernel`` causal taps a channel, no bias -> C
+    * conv -> in_units``, every gate linear. Held whole: its channels are
+    the hidden size, which no chip's share cuts."""
+
+    def __init__(self, in_units, kernel=3, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            get = self.params.get
+            self.in_weight = get("in_weight", shape=(3 * in_units, in_units))
+            self.conv_weight = get("conv_weight", shape=(in_units, kernel))
+            self.out_weight = get("out_weight", shape=(in_units, in_units))
+
+    def hybrid_forward(self, F, x, in_weight, conv_weight, out_weight):
+        return F.GatedShortConv(x, in_weight, conv_weight, out_weight)
+
+
 class LatentMoE(HybridBlock):
     """A mixture of experts in a latent (``ops.seq.latent_moe``): a router
     ``num_experts`` wide with a correction bias that no gradient reaches,
@@ -241,15 +260,18 @@ class GatedMoE(HybridBlock):
     rows (a pair is beyond the buffer only when the held experts' pairs
     together outnumber its rows; ``buffer_fill`` is the pool's filled
     share); the shared experts are one gated MLP ``shared_units`` wide,
-    whole. ``scoring``: the router's scores, each expert's ``sigmoid`` or
-    a ``softmax`` over all experts. ``shared_gate``: the shared experts'
-    output goes through ``sigmoid(u . shared_gate_weight)``, a gate of
-    its own a token."""
+    whole, and with ``shared_units`` 0 the layer has none: no shared
+    parameter, the routed sum alone (``ops.seq.routed_moe``).
+    ``scoring``: the router's scores, each expert's ``sigmoid`` or a
+    ``softmax`` over all experts; ``norm_topk_eps``: added to the chosen
+    scores' sum before it divides them. ``shared_gate``: the shared
+    experts' output goes through ``sigmoid(u . shared_gate_weight)``, a
+    gate of its own a token."""
 
     def __init__(self, in_units, num_experts, expert_ids, top_k,
                  expert_units, shared_units, buffer_rows, scaling=1.0,
                  norm_topk=True, bias_update_rate=0.0, scoring="sigmoid",
-                 shared_gate=False, **kwargs):
+                 shared_gate=False, norm_topk_eps=0.0, **kwargs):
         super().__init__(**kwargs)
         held = len(expert_ids)
         self._attrs = {"expert_ids": tuple(int(e) for e in expert_ids),
@@ -259,6 +281,8 @@ class GatedMoE(HybridBlock):
                        "bias_rate": float(bias_update_rate)}
         if scoring != "sigmoid":
             self._attrs["scoring"] = scoring
+        if norm_topk_eps:
+            self._attrs["norm_topk_eps"] = float(norm_topk_eps)
         with self.name_scope():
             get = self.params.get
             self.router_weight = get("router_weight",
@@ -268,10 +292,14 @@ class GatedMoE(HybridBlock):
             self.w1 = get("w1", shape=(held, in_units, expert_units))
             self.w3 = get("w3", shape=(held, in_units, expert_units))
             self.w2 = get("w2", shape=(held, expert_units, in_units))
-            self.shared_gate_up_weight = get(
-                "shared_gate_up_weight", shape=(2 * shared_units, in_units))
-            self.shared_down_weight = get("shared_down_weight",
-                                          shape=(in_units, shared_units))
+            if shared_units:
+                self.shared_gate_up_weight = get(
+                    "shared_gate_up_weight",
+                    shape=(2 * shared_units, in_units))
+                self.shared_down_weight = get(
+                    "shared_down_weight", shape=(in_units, shared_units))
+            elif shared_gate:
+                raise ValueError("shared_gate without shared experts")
             self.counters = get("counters", shape=(len(MOE_COUNTERS),),
                                 init="zeros", grad_req="null")
             if shared_gate:
@@ -279,13 +307,18 @@ class GatedMoE(HybridBlock):
                                               shape=(1, in_units))
 
     def hybrid_forward(self, F, x, router_weight, router_bias, w1, w3, w2,
-                       shared_gate_up_weight, shared_down_weight, counters,
-                       shared_gate_weight=None):
-        more = () if shared_gate_weight is None else (shared_gate_weight,)
-        out, new, bias = F.GatedMoE(
-            x, router_weight, router_bias, w1, w3, w2,
-            shared_gate_up_weight, shared_down_weight, counters, *more,
-            **self._attrs)
+                       counters, shared_gate_up_weight=None,
+                       shared_down_weight=None, shared_gate_weight=None):
+        if shared_gate_up_weight is None:
+            out, new, bias = F.RoutedMoE(x, router_weight, router_bias, w1,
+                                         w3, w2, counters, **self._attrs)
+        else:
+            more = () if shared_gate_weight is None \
+                else (shared_gate_weight,)
+            out, new, bias = F.GatedMoE(
+                x, router_weight, router_bias, w1, w3, w2,
+                shared_gate_up_weight, shared_down_weight, counters, *more,
+                **self._attrs)
         stateful_write(self.counters, new)
         if self._attrs["bias_rate"] and autograd.is_training():
             stateful_write(self.router_bias, bias)

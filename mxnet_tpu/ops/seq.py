@@ -1,10 +1,12 @@
 """Sequence-model operators: RMSNorm, the Mamba-2 mixer (the chunked
 state-space scan, SSD), the Gated DeltaNet mixer (the gated delta rule in
 chunks, this repo's kernels where the program is lowered for a TPU,
-``ops.gdn_kernel``), a mixture of experts that is told which experts
+``ops.gdn_kernel``), the gated short convolution (a few causal taps
+between two linear gates), a mixture of experts that is told which experts
 it holds (one routing path, two expert bodies: relu2 in a latent, or
 gated SiLU on the full hidden vector, its grouped products this repo's
-kernels where the program is lowered for a TPU, ``ops.gmm_kernel``),
+kernels where the program is lowered for a TPU, ``ops.gmm_kernel``; with
+or without shared experts),
 causal grouped-query attention in blocks (fused kernels where the program
 is lowered for a TPU, ``ops.attn_kernel``) with or without rotary
 position encoding, multi-head latent attention over the same kernels, a
@@ -20,8 +22,9 @@ The step's device time is a function of shapes alone: the expert layer's
 receive buffer is static and every row of it is computed, filled or not.
 
 Named scopes (``mx_norm``, ``mx_mamba_proj``, ``mx_ssd_*``, ``mx_gdn_*``,
-``mx_moe_*``, ``mx_attn_*``, ``mx_mla_*``, ``mx_rope``, ``mx_mhc_*``,
-``mx_gated_mlp``, ``mx_exit_head``, ``mx_exit_gate``) mark each mechanism
+``mx_sconv_*``, ``mx_moe_*``, ``mx_attn_*``, ``mx_mla_*``, ``mx_rope``,
+``mx_mhc_*``, ``mx_gated_mlp``, ``mx_exit_head``, ``mx_exit_gate``) mark
+each mechanism
 in the compiled program, and each operator's registration lists its own;
 ``telemetry.trace.scope_table`` maps the program's instructions back to
 them. The expert layer's matrix
@@ -529,6 +532,52 @@ def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, dt_bias,
 
 
 # ---------------------------------------------------------------------------
+# gated short convolution
+# ---------------------------------------------------------------------------
+@register_op("GatedShortConv", names_its_parts=True)
+def gated_short_conv(data, in_weight, conv_weight, out_weight, **kw):
+    """The gated short convolution (the ``lfm2`` family's ``conv``
+    layers' mixer): ``[B | C | z] = W_in u``, ``c = conv(B * z)``, ``W_out
+    (C * c)``, the convolution depthwise and causal over
+    ``conv_weight.shape[1]`` taps, the last on the current token, zeros
+    before the sequence, no bias. Both gates are linear: there is no
+    activation anywhere in it.
+
+    ``data``: (B, L, hidden). ``in_weight``: (3 C, hidden), rows ``[B | C
+    | z]`` in that order; ``conv_weight``: (C, K); ``out_weight``:
+    (hidden, C). The channels are no share of a deployment's: every chip
+    that shares the layer holds the mixer whole.
+
+    Both products sum in float32 and round to ``data``'s dtype
+    (``_mm``, as every mixer's here). Between them both gates, the taps
+    and the taps' sum are in ``data``'s dtype, as the sibling mixers'
+    convolutions are (``mamba2_mixer``, ``_convolved``): ``B * z`` is
+    what memory holds between the gate and the taps (every tap reads it
+    at another shift, so the compiler writes it out), and in float32 it
+    would be twice the bytes of a chain that is bound by them.
+
+    Scopes: ``mx_sconv_proj`` (both products), ``mx_sconv_gate`` (``B *
+    z`` and ``C * c``), ``mx_sconv_conv`` (the taps, ``causal_conv1d``).
+    A recomputation unit around it keeps the packed projection (3 C a
+    token: a second forward would pay the 3 C x hidden product again); the
+    gates and the taps are computed again.
+
+    Returns (B, L, hidden)."""
+    with jax.named_scope("mx_sconv_proj"):
+        bcz = kept(_mm(data, in_weight))
+    width = bcz.shape[-1] // 3
+    b, c, z = (bcz[..., i * width:(i + 1) * width] for i in range(3))
+    with jax.named_scope("mx_sconv_gate"):
+        gated = b * z
+    with jax.named_scope("mx_sconv_conv"):
+        conv = causal_conv1d(gated, conv_weight, None)
+    with jax.named_scope("mx_sconv_gate"):
+        y = c * conv
+    with jax.named_scope("mx_sconv_proj"):
+        return _mm(y, out_weight)
+
+
+# ---------------------------------------------------------------------------
 # LatentMoE
 # ---------------------------------------------------------------------------
 def grouped_product(buf, w1, w2):
@@ -671,13 +720,15 @@ _pooled_kernels.defvjp(_pooled_kernels_fwd, _pooled_kernels_bwd)
 
 
 def route(scores_in, router_weight, router_bias, top_k, scaling,
-          norm_topk=True, scoring="sigmoid"):
+          norm_topk=True, scoring="sigmoid", norm_topk_eps=0.0):
     """Scores over every expert of the model (``scoring``: each expert's
     ``sigmoid``, or a ``softmax`` over all of them, float32), the
     ``top_k`` by ``score + bias`` chosen, the chosen scores normalised to
-    sum 1 and scaled. Returns ``(gate (T, E_all) float32, zero where not
-    chosen; chosen (T, E_all) bool)``. Ties at the ``top_k``-th place are
-    all taken (between floats they do not occur)."""
+    sum 1 (divided by their sum ``+ norm_topk_eps``, which the
+    ``lfm2_moe`` family adds) and scaled. Returns ``(gate (T, E_all)
+    float32, zero where not chosen; chosen (T, E_all) bool)``. Ties at
+    the ``top_k``-th place are all taken (between floats they do not
+    occur)."""
     with jax.named_scope("mx_moe_score"):
         logits = kept(lax.dot_general(
             scores_in, router_weight, (((1,), (1,)), ((), ())),
@@ -692,7 +743,9 @@ def route(scores_in, router_weight, router_bias, top_k, scaling,
         chosen = biased >= lax.stop_gradient(kth)
         gate = jnp.where(chosen, s, 0.0)
         if norm_topk:
-            gate = gate / jnp.sum(gate, -1, keepdims=True)
+            total = jnp.sum(gate, -1, keepdims=True)
+            # no addition of zero: without the option the text it had
+            gate = gate / (total + norm_topk_eps if norm_topk_eps else total)
         return gate * scaling, chosen
 
 
@@ -846,10 +899,10 @@ def _moe_stats(load, count, cap, counters):
 
 @register_op("GatedMoE", num_outputs=3, names_its_parts=True)
 def gated_moe(data, router_weight, router_bias, w1, w3, w2,
-              shared_gate_up_weight, shared_down_weight, counters=None,
-              shared_gate_weight=None, expert_ids=(0,), top_k=1,
-              buffer_rows=0, scaling=1.0, norm_topk=True, bias_rate=0.0,
-              scoring="sigmoid", **kw):
+              shared_gate_up_weight=None, shared_down_weight=None,
+              counters=None, shared_gate_weight=None, expert_ids=(0,),
+              top_k=1, buffer_rows=0, scaling=1.0, norm_topk=True,
+              bias_rate=0.0, scoring="sigmoid", norm_topk_eps=0.0, **kw):
     """A mixture of gated experts on the full hidden vector, for the
     experts held here: ``latent_moe``'s router, combine, counters and
     balancing step (one copy of each: ``route``, ``_combine``,
@@ -869,29 +922,47 @@ def gated_moe(data, router_weight, router_bias, w1, w3, w2,
     one gated MLP, ``shared_gate_up_weight`` (2 ff_s, hidden) and
     ``shared_down_weight`` (hidden, ff_s), as ``gated_mlp`` takes them;
     with ``shared_gate_weight`` (1, hidden) a token's share of them is
-    ``sigmoid(u . w)``, a gate of their own. ``scoring``: ``route``'s.
-    Arguments, counters and returns as ``latent_moe``'s."""
+    ``sigmoid(u . w)``, a gate of their own. A layer with no shared
+    expert (the ``lfm2_moe`` family's) gives ``None`` for both shared
+    weights: the routed sum alone, and no ``mx_moe_shared`` scope
+    (``routed_moe`` is that call under an operator's name of its own).
+    ``scoring``, ``norm_topk_eps``: ``route``'s. Arguments, counters and
+    returns as ``latent_moe``'s."""
     bsz, length, hidden = data.shape
     u = data.reshape(bsz * length, hidden)
     gate_all, chosen_all = route(u, router_weight, router_bias, top_k,
-                                 scaling, norm_topk, scoring)
+                                 scaling, norm_topk, scoring, norm_topk_eps)
     buf, token, row_gate, load, count, sizes = _dispatch_pooled(
         u, gate_all, chosen_all, expert_ids, buffer_rows)
     routed = _combine(pooled_gated_product(buf, w1, w3, w2, sizes), row_gate,
                       token, u.shape[0])
-    with jax.named_scope("mx_moe_shared"):
-        shared = gated_mlp(u, shared_gate_up_weight, shared_down_weight)
-        if shared_gate_weight is not None:
-            open_ = jax.nn.sigmoid(lax.dot_general(
-                u, shared_gate_weight, (((1,), (1,)), ((), ())),
-                preferred_element_type=_F32))
-            shared = (shared.astype(_F32) * open_).astype(shared.dtype)
+    shared = None
+    if shared_gate_up_weight is not None:
+        with jax.named_scope("mx_moe_shared"):
+            shared = gated_mlp(u, shared_gate_up_weight, shared_down_weight)
+            if shared_gate_weight is not None:
+                open_ = jax.nn.sigmoid(lax.dot_general(
+                    u, shared_gate_weight, (((1,), (1,)), ((), ())),
+                    preferred_element_type=_F32))
+                shared = (shared.astype(_F32) * open_).astype(shared.dtype)
     # one pool: the held experts' pairs together against all its rows
     stats = _moe_stats(load, jnp.sum(count)[None], int(buffer_rows),
                        counters)
-    return (routed.astype(data.dtype) + shared).reshape(
-        bsz, length, hidden), stats, \
+    out = routed.astype(data.dtype)
+    if shared is not None:
+        out = out + shared
+    return out.reshape(bsz, length, hidden), stats, \
         lax.stop_gradient(balanced_bias(router_bias, load, bias_rate))
+
+
+@register_op("RoutedMoE", num_outputs=3, names_its_parts=True)
+def routed_moe(data, router_weight, router_bias, w1, w3, w2, counters=None,
+               **attrs):
+    """``gated_moe`` of a layer with no shared expert: its operands
+    without the shared weights, for callers that pass operands by
+    position alone (``nn.GatedMoE(shared_units=0)``)."""
+    return gated_moe(data, router_weight, router_bias, w1, w3, w2, None,
+                     None, counters, **attrs)
 
 
 # ---------------------------------------------------------------------------

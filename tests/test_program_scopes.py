@@ -156,6 +156,10 @@ def test_the_old_scopes_keep_their_paths(step):
      ["mx_opt_update", "mx_loss", "mx_head/mx_dense", "mx_embed", "mx_norm",
       "mx_attn_proj", "mx_gdn_proj", "mx_gdn_conv", "mx_gdn_rule",
       "mx_gdn_gate", "mx_attn_qk_norm", "mx_attn_gate"]),
+    ("lfm2-24b-a2b-train-8k",
+     ["mx_opt_update", "mx_loss", "mx_head/mx_dense", "mx_embed", "mx_norm",
+      "mx_attn_proj", "mx_attn_qk_norm", "mx_sconv_proj", "mx_sconv_gate",
+      "mx_sconv_conv"]),
     ("resnet50-train",
      ["mx_opt_update", "mx_loss", "mx_metric", "mx_op_Convolution",
       "mx_op_BatchNorm", "mx_op_Activation", "mx_op_Pooling",
@@ -223,6 +227,33 @@ def test_the_streams_scopes_stand_beside_moonlight_s():
     for name in list(PARENT) + ["qwen3-next-80b-a3b-train-8k"]:
         assert not [p for p in _step_of(name)[1].values()
                     if ADDED_43 & set(p.split("/"))], name
+
+
+#: the scopes ISSUE 47 added (a gated short convolution's products, its
+#: two gates and its taps); its cell has no shared expert, so no
+#: ``mx_moe_shared``
+ADDED_47 = {"mx_sconv_proj", "mx_sconv_gate", "mx_sconv_conv"}
+
+
+def test_the_short_convolution_s_scopes_stand_beside_the_shared_ones():
+    """``lfm2-24b-a2b-train-8k`` has no parent to be compared with: the
+    paths made of older scopes alone are the ones the accepted readers
+    match in the sibling cells, its own scopes stand alone, and no older
+    cell's step holds one of them."""
+    paths = set(_step_of("lfm2-24b-a2b-train-8k")[1].values())
+    old = {p for p in paths if not any(
+        _is_new(s) or s in ADDED_47 | ADDED_41 for s in p.split("/"))}
+    assert old == {"mx_attn_fwd", "mx_rope", "mx_gated_mlp",
+                   "mx_moe_combine", "mx_moe_dispatch", "mx_moe_gmm_down",
+                   "mx_moe_gmm_up", "mx_moe_route", "mx_moe_score"}
+    for path in paths:
+        if ADDED_47 & set(path.split("/")):
+            assert "/" not in path, path
+    assert ADDED_47 | {"mx_attn_qk_norm"} <= paths
+    for name in list(PARENT) + ["qwen3-next-80b-a3b-train-8k",
+                                "xing4.0-29b-a4b-train-4k"]:
+        assert not [p for p in _step_of(name)[1].values()
+                    if ADDED_47 & set(p.split("/"))], name
 
 
 def _rnn_then_fc():

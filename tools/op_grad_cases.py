@@ -145,6 +145,23 @@ CASES = {
         attrs=dict(expert_ids=(1, 4), top_k=2, buffer_rows=10,
                    scaling=2.5),
         grad_args=[0, 3, 4, 5, 6, 7], tol=(8e-2, 8e-3)),
+    "RoutedMoE": dict(
+        # ops/seq.py: GatedMoE of a layer with no shared expert: the same
+        # routing and experts, the routed sum alone, the chosen scores
+        # over their sum + 1e-6 (tests/test_short_conv.py pins the
+        # gradients against the plain reference)
+        inputs=[_signed((1, 5, 8), 0), _signed((6, 8), 1),
+                _signed((6,), 2), 0.5 * _signed((2, 8, 6), 3),
+                0.5 * _signed((2, 8, 6), 4), 0.5 * _signed((2, 6, 8), 5)],
+        attrs=dict(expert_ids=(1, 4), top_k=2, buffer_rows=10,
+                   norm_topk_eps=1e-6),
+        grad_args=[0, 3, 4, 5], tol=(8e-2, 8e-3)),
+    "GatedShortConv": dict(
+        # ops/seq.py: in_weight rows [B 8 | C 8 | z 8], three causal taps
+        # a channel over 6 steps, no activation: a cubic in the input
+        inputs=[_signed((2, 6, 8), 0), 0.5 * _signed((24, 8), 1),
+                0.5 * _signed((8, 3), 2), 0.5 * _signed((8, 8), 3)],
+        tol=(6e-2, 6e-3)),
     "LatentAttention": dict(
         # ops/seq.py: 2 heads, q/k 4 + 2 wide (the 2-wide rotary key
         # shared by both heads), values 3 wide, a latent of 6, blocks of
