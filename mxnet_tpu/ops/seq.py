@@ -58,7 +58,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import attn_kernel, gdn_conv_kernel, gdn_kernel, gmm_kernel, mhc_kernel
+from . import (attn_kernel, gdn_conv_kernel, gdn_kernel, gmm_kernel,
+               mhc_kernel, moe_rows_kernel)
 from .registry import register_op
 from .remat import kept
 
@@ -773,8 +774,14 @@ def latent_moe(data, router_weight, router_bias, down_weight, up_weight,
     ``w2`` (E, ff, latent) are here. The (token, expert) pairs that chose
     a held expert are sorted into a static buffer of ``buffer_rows`` rows,
     ``buffer_rows / E`` an expert; pairs beyond an expert's rows are
-    counted, not computed. ``shared_w1`` (ff_s, hidden), ``shared_w2``
-    (hidden, ff_s): the shared expert's columns held here.
+    counted, not computed. The rows' way into the buffer and back, each
+    times its gate and added up by token in float32 (``_rows_to_pool``,
+    ``_combine``), is the kernels of ``ops.moe_rows_kernel`` where the
+    program is lowered for a TPU and their tiling rule takes the shapes
+    (the slices are one pool of equal sizes to them), ``jnp.take`` and a
+    scatter-add everywhere else; the gauge ``moe::rows_kernel_sites``
+    says which. ``shared_w1`` (ff_s, hidden), ``shared_w2`` (hidden,
+    ff_s): the shared expert's columns held here.
 
     Returns ``(out (B, L, hidden), counters (4,) float32, bias (E_all,)
     float32)``. The counters: the pairs that chose a held expert, the
@@ -793,7 +800,7 @@ def latent_moe(data, router_weight, router_bias, down_weight, up_weight,
     buf, token, row_gate, load, count, cap = _dispatch(
         v, gate_all, chosen_all, expert_ids, buffer_rows)
     routed = _combine(grouped_product(buf, w1, w2), row_gate, token,
-                      u.shape[0])
+                      u.shape[0], _slice_starts(len(expert_ids), cap))
     with jax.named_scope("mx_moe_latent"):
         routed = _mm(kept(routed.astype(data.dtype)), up_weight)
     with jax.named_scope("mx_moe_shared"):
@@ -828,7 +835,7 @@ def _dispatch(rows, gate_all, chosen_all, expert_ids, buffer_rows):
         # what the sort gave and the rows it gathered, kept: no backward
         # pass sorts again, nor projects to the latent for the rows' sake
         token = kept(jnp.where(valid, order.T, t))      # (E, cap); t: none
-        buf = kept(jnp.take(rows, token, axis=0, mode="fill", fill_value=0))
+        buf = kept(_rows_to_pool(rows, token, _slice_starts(n_held, cap)))
         pair = jnp.where(valid, jnp.arange(n_held)[:, None] * t + token,
                          n_held * t)
         row_gate = kept(jnp.take(gate.T.reshape(-1), pair, mode="fill",
@@ -867,22 +874,132 @@ def _dispatch_pooled(rows, gate_all, chosen_all, expert_ids, buffer_rows):
         valid = jnp.arange(cap) < ends[-1]
         # kept as ``_dispatch`` keeps them: no backward pass sorts again
         token = kept(jnp.where(valid, order % t, t))    # (cap,); t: none
-        buf = kept(jnp.take(rows, token, axis=0, mode="fill", fill_value=0))
+        taken = jnp.diff(ends, prepend=0)       # an expert's rows with a pair
+        buf = kept(_rows_to_pool(rows, token, ends - taken))
         row_gate = kept(jnp.take(gate.T.reshape(-1),
                                  jnp.where(valid, order, n_held * t),
                                  mode="fill", fill_value=0))
-        sizes = jnp.diff(ends, prepend=0).at[-1].add(cap - ends[-1])
+        sizes = taken.at[-1].add(cap - ends[-1])
     return buf, token, row_gate, load, count, sizes
 
 
-def _combine(out_buf, row_gate, token, t):
-    """The buffer's rows, each times its gate, added up by token: (T,
-    width) float32."""
+def _take_rows(rows, token):
+    return jnp.take(rows, token, axis=0, mode="fill", fill_value=0)
+
+
+def _slice_starts(n_held, cap):
+    """The first row of each expert's slice of ``cap`` rows."""
+    return jnp.arange(n_held, dtype=jnp.int32) * cap
+
+
+def _rows_to_pool(rows, token, starts):
+    """``rows[token]`` with zeros where ``token`` names no row (``rows``'
+    count): the buffer in ``token``'s shape. Two forms, chosen by what the
+    program can see. Where ``moe_rows_kernel.takes`` takes the shapes and
+    the program is lowered for a TPU, that module's kernels under one
+    ``custom_vjp``: ``rows_by_index`` forward, and backward
+    ``rows_by_token``, a token's rows of the cotangent added up in float32
+    and rounded once (no scatter-add; ``jnp.take``'s derivative sums them
+    in the compute dtype). Everywhere else ``jnp.take`` and its
+    derivative. ``starts`` (E,) int32: the first row of each held
+    expert's, by which the buffer is sorted."""
+    tile = moe_rows_kernel.takes(token.size, rows.shape[0], rows.shape[1],
+                                 rows.dtype)
+    if tile is None:
+        return _take_rows(rows, token)
+    buf = _pool_rows(rows, token.reshape(-1), starts, rows.shape[0], tile)
+    return buf.reshape(token.shape + rows.shape[1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _pool_rows(rows, token, starts, tokens, tile):
+    return _pool_rows_fwd(rows, token, starts, tokens, tile)[0]
+
+
+def _pool_rows_fwd(rows, token, starts, tokens, tile):
+    buf = lax.platform_dependent(
+        rows, token,
+        tpu=lambda rows, token: moe_rows_kernel.rows_by_index(
+            token, attn_kernel.counted_site(rows, moe_rows_kernel.GAUGE),
+            tile),
+        default=_take_rows)
+    return buf, (token, starts)
+
+
+def _pool_rows_bwd(tokens, tile, res, d_buf):
+    token, starts = res
+    plain = jax.linear_transpose(
+        lambda rows: _take_rows(rows, token),
+        jax.ShapeDtypeStruct((tokens,) + d_buf.shape[1:], d_buf.dtype))
+    # under ``mx_moe_dispatch``, the scope the forward was called in
+    d_rows = lax.platform_dependent(
+        d_buf, token, starts,
+        tpu=lambda d_buf, token, starts: moe_rows_kernel.rows_by_token(
+            token, None, d_buf, tokens, starts, tile),
+        default=lambda d_buf, token, starts: plain(d_buf)[0])
+    return d_rows, None, None
+
+
+_pool_rows.defvjp(_pool_rows_fwd, _pool_rows_bwd)
+
+
+def _scatter_add(out_buf, row_gate, token, t):
+    """``_combine`` by a scatter-add into a zeroed float32 array."""
+    weighted = out_buf.astype(_F32) * row_gate[..., None]
+    return jnp.zeros((t, out_buf.shape[-1]), _F32).at[
+        token.reshape(-1)].add(
+            weighted.reshape(-1, out_buf.shape[-1]), mode="drop").astype(
+                out_buf.dtype)
+
+
+def _combine(out_buf, row_gate, token, t, starts):
+    """The buffer's rows, each times its gate, added up by token: every
+    product and every token's sum in float32, rounded once to the buffer's
+    dtype; (T, width). Two forms, as ``_rows_to_pool``'s: where
+    ``moe_rows_kernel.takes`` takes the shapes and the program is lowered
+    for a TPU, ``rows_by_token`` forward (the sums live in VMEM; no zeroed
+    float32 (T, width) array, no scatter, no pass that rounds it), and
+    backward ``jnp.take`` of the cotangent's rows by token, times the gates
+    for the buffer's and times the buffer, added up over the width in
+    float32, for the gates'; everywhere else the scatter-add forward.
+    ``starts``: ``_rows_to_pool``'s."""
     with jax.named_scope("mx_moe_combine"):
-        weighted = out_buf.astype(_F32) * row_gate[..., None]
-        return jnp.zeros((t, out_buf.shape[-1]), _F32).at[
-            token.reshape(-1)].add(
-                weighted.reshape(-1, out_buf.shape[-1]), mode="drop")
+        width = out_buf.shape[-1]
+        tile = moe_rows_kernel.takes(token.size, t, width, out_buf.dtype)
+        if tile is None:
+            return _scatter_add(out_buf, row_gate, token, t)
+        return _pool_sum(out_buf.reshape(-1, width), row_gate.reshape(-1),
+                         token.reshape(-1), starts, t, tile)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _pool_sum(out_buf, row_gate, token, starts, t, tile):
+    return _pool_sum_fwd(out_buf, row_gate, token, starts, t, tile)[0]
+
+
+def _pool_sum_fwd(out_buf, row_gate, token, starts, t, tile):
+    routed = lax.platform_dependent(
+        out_buf, row_gate, token, starts,
+        tpu=lambda out_buf, row_gate, token, starts:
+            moe_rows_kernel.rows_by_token(token, row_gate, out_buf, t, starts,
+                                          tile),
+        default=lambda out_buf, row_gate, token, starts: _scatter_add(
+            out_buf, row_gate, token, t))
+    return routed, (out_buf, row_gate, token)
+
+
+def _pool_sum_bwd(t, tile, res, d_routed):
+    # under ``mx_moe_combine``, the scope the forward was called in; on
+    # every platform what JAX's derivative of the scatter-add computes,
+    # with the cotangent's rows gathered before they are widened
+    out_buf, row_gate, token = res
+    rows = _take_rows(d_routed, token).astype(_F32)
+    return (rows * row_gate[:, None]).astype(out_buf.dtype), \
+        jnp.sum(out_buf.astype(_F32) * rows, -1).astype(row_gate.dtype), \
+        None, None
+
+
+_pool_sum.defvjp(_pool_sum_fwd, _pool_sum_bwd)
 
 
 def _moe_stats(load, count, cap, counters):
@@ -912,8 +1029,10 @@ def gated_moe(data, router_weight, router_bias, w1, w3, w2,
     on either side (``pooled_gated_product``: the grouped-matmul kernels
     of ``ops.gmm_kernel`` where the program is lowered for a TPU and the
     widths are whole lane tiles, ``lax.ragged_dot`` everywhere else; the
-    gauge ``moe::gmm_kernel_sites`` says which). The ``buffer_rows`` rows
-    are ONE pool the held experts share (``_dispatch_pooled``): the
+    gauge ``moe::gmm_kernel_sites`` says which; the rows reach the pool
+    and leave it as ``latent_moe``'s do, by the row kernels or the plain
+    moves, under the gauge ``moe::rows_kernel_sites``). The ``buffer_rows``
+    rows are ONE pool the held experts share (``_dispatch_pooled``): the
     family trains without dropping a token, and a slice of its own for
     each expert overflowed on the chip while the pool stood a quarter
     empty (PERF.md, PR 37), so a pair is beyond the buffer only when the
@@ -935,7 +1054,7 @@ def gated_moe(data, router_weight, router_bias, w1, w3, w2,
     buf, token, row_gate, load, count, sizes = _dispatch_pooled(
         u, gate_all, chosen_all, expert_ids, buffer_rows)
     routed = _combine(pooled_gated_product(buf, w1, w3, w2, sizes), row_gate,
-                      token, u.shape[0])
+                      token, u.shape[0], jnp.cumsum(sizes) - sizes)
     shared = None
     if shared_gate_up_weight is not None:
         with jax.named_scope("mx_moe_shared"):

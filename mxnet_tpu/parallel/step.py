@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import telemetry as _telemetry
 from ..ops import attn_kernel as _attn_kernel
 from ..ops import gmm_kernel as _gmm_kernel
+from ..ops import moe_rows_kernel as _moe_rows_kernel
 from ..ops import gdn_kernel as _gdn_kernel
 from ..ops import gdn_conv_kernel as _gdn_conv_kernel
 from ..ops import mhc_kernel as _mhc_kernel
@@ -273,6 +274,7 @@ class TrainStep:
             _telemetry.gauge(_attn_kernel.GAUGE).set(0)
             _telemetry.gauge(_attn_kernel.FUSED_BWD_GAUGE).set(0)
             _telemetry.gauge(_gmm_kernel.GAUGE).set(0)
+            _telemetry.gauge(_moe_rows_kernel.GAUGE).set(0)
             _telemetry.gauge(_gdn_kernel.GAUGE).set(0)
             _telemetry.gauge(_gdn_conv_kernel.GAUGE).set(0)
             _telemetry.gauge(_seq.MHC_GAUGE).set(0)

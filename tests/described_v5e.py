@@ -81,6 +81,43 @@ def custom_calls(hlo):
         r'%?([\w.\-]+) = [^\n]*?custom_call_target="tpu_custom_call"', hlo)}
 
 
+#: the routed experts' row kernels (``ops/moe_rows_kernel.py``)
+ROW_KERNELS = ("moe_rows_by_index_kernel", "moe_rows_by_token_kernel")
+
+
+def row_kernels_stand(step, layers, width):
+    """The rows' way into the experts' buffer and back is the two row
+    kernels in ``step``, ``layers`` expert layers ``width`` wide:
+    ``rows_by_index`` once a layer under ``mx_moe_dispatch`` (the gather
+    forward), ``rows_by_token`` once under ``mx_moe_combine`` (the sum
+    forward; the unit's backward does not form the combine again, nothing
+    reads it there) and once under ``mx_moe_dispatch`` (the gather's
+    backward), no scatter of rows under either scope, no float32 value of
+    all the tokens' rows written by a scatter anywhere, and the gauge
+    reads what ``moe_rows_kernel_sites.train`` will."""
+    import collections
+    from mxnet_tpu.ops import moe_rows_kernel
+    # like layers share one lowered program: the gauge counts programs
+    assert step.gauges[moe_rows_kernel.GAUGE] == 1, step.gauges
+    under = collections.Counter(
+        (k, re.search(r"(^|/)(mx_moe_\w+)$", step.paths[i]).group(2))
+        for i, k in step.calls.items() if k in ROW_KERNELS)
+    assert under == {(ROW_KERNELS[0], "mx_moe_dispatch"): layers,
+                     (ROW_KERNELS[1], "mx_moe_dispatch"): layers,
+                     (ROW_KERNELS[1], "mx_moe_combine"): layers}, under
+    tokens = step.sizes["batch"] * step.sizes["seq_len"]
+    for line in step.text.splitlines():
+        found = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                         r"scatter\(", line)
+        if not found:
+            continue
+        name, dtype, shape = found.groups()
+        assert (dtype, shape) != ("f32", f"{tokens},{width}"), line[:200]
+        if re.search(r"(^|/)mx_moe_(dispatch|combine)$",
+                     step.paths.get(name, "")):
+            assert not shape.endswith(f",{width}"), line[:200]
+
+
 class Step:
     """A cell's step compiled for the described chip: the cell and its
     timed sizes, the compiled program, every kernel gauge as read at that
@@ -116,7 +153,7 @@ def compiled_step(name):
     process, since a step takes a minute or two to compile."""
     import mxnet_tpu as mx
     from mxnet_tpu.ops import (attn_kernel, gdn_conv_kernel, gdn_kernel,
-                               gmm_kernel, mhc_kernel, seq)
+                               gmm_kernel, mhc_kernel, moe_rows_kernel, seq)
     from mxnet_tpu.parallel import TrainStep, exit_weighted_loss
     one_chip = chip()
     cell = harness.load_cell(name)
@@ -147,7 +184,7 @@ def compiled_step(name):
     gauges = {g: mx.telemetry.gauge(g).get() for g in (
         attn_kernel.GAUGE, attn_kernel.FUSED_BWD_GAUGE, gmm_kernel.GAUGE,
         gdn_kernel.GAUGE, gdn_conv_kernel.GAUGE, mhc_kernel.GAUGE,
-        seq.MHC_GAUGE)}
+        moe_rows_kernel.GAUGE, seq.MHC_GAUGE)}
     kept = {k.rsplit("::", 1)[1]: v["value"] for k, v in
             mx.telemetry.snapshot(prefix="remat::saved_bytes::").items()}
     return Step(cell, compiled, gauges, kept)

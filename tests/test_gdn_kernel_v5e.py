@@ -22,7 +22,8 @@ import re
 
 import jax.numpy as jnp
 
-from described_v5e import CHIP_BYTES, compiled_step, harness, peak_bytes
+from described_v5e import (CHIP_BYTES, ROW_KERNELS, compiled_step, harness,
+                           peak_bytes, row_kernels_stand)
 
 CELL = "qwen3-next-80b-a3b-train-8k"
 
@@ -40,13 +41,13 @@ def test_mosaic_takes_the_rule_s_kernels_three_a_linear_layer():
     of its operands, six ``gdn_conv_fwd_kernel`` and three
     ``gdn_conv_bwd_kernel`` under a gauge of their own."""
     from mxnet_tpu.ops import (attn_kernel, gdn_conv_kernel, gdn_kernel,
-                               gmm_kernel, mhc_kernel, seq)
+                               gmm_kernel, mhc_kernel, moe_rows_kernel, seq)
     step = compiled_step(CELL)
     sizes = step.sizes
     assert step.gauges == {attn_kernel.GAUGE: 1, attn_kernel.FUSED_BWD_GAUGE: 0,
                       gmm_kernel.GAUGE: 1, gdn_kernel.GAUGE: 1,
                       gdn_conv_kernel.GAUGE: 1, mhc_kernel.GAUGE: 0,
-                      seq.MHC_GAUGE: 0}
+                      moe_rows_kernel.GAUGE: 1, seq.MHC_GAUGE: 0}
     calls = collections.Counter(step.calls.values())
     linear, layers = _linear_layers(sizes), sizes["num_hidden_layers"]
     assert calls == {
@@ -55,7 +56,8 @@ def test_mosaic_takes_the_rule_s_kernels_three_a_linear_layer():
         "attn_fwd_kernel": 1, "attn_bwd_dq_kernel": 1,
         "attn_bwd_dkv_kernel": 1,
         **{f"moe_gmm_{side}{part}_kernel": layers
-           for side in ("up", "down") for part in ("", "_rows", "_weights")}}
+           for side in ("up", "down") for part in ("", "_rows", "_weights")},
+        ROW_KERNELS[0]: layers, ROW_KERNELS[1]: 2 * layers}
 
 
 def test_no_solve_and_no_loop_is_left_for_the_rule():
@@ -170,3 +172,11 @@ def test_what_the_kernels_hold_in_vmem_is_under_their_budget():
     assert gdn_conv_kernel.takes(heads, taps, jnp.bfloat16, jnp.bfloat16)
     assert 8e6 < gdn_conv_kernel.held_bytes(heads, 2) \
         < gdn_conv_kernel._BUDGET_BYTES
+
+
+def test_rows_travel_by_the_row_kernels():
+    """32 held experts: a tile of tokens walks up to 32 stretches of the
+    pool."""
+    step = compiled_step(CELL)
+    row_kernels_stand(step, step.sizes["num_hidden_layers"],
+                      step.sizes["hidden_size"])

@@ -15,7 +15,8 @@ import re
 
 import jax.numpy as jnp
 
-from described_v5e import CHIP_BYTES, compiled_step, harness, peak_bytes
+from described_v5e import (CHIP_BYTES, compiled_step, harness, peak_bytes,
+                           row_kernels_stand)
 
 CELL = "xing4.0-29b-a4b-train-4k"
 
@@ -127,3 +128,12 @@ def test_what_the_kernels_hold_in_vmem_is_under_their_budget():
                             jnp.bfloat16)
     assert 2e7 < mhc_kernel.held_bytes(n, width, 2) \
         < mhc_kernel._BUDGET_BYTES < mhc_kernel._VMEM_LIMIT_BYTES
+
+
+def test_rows_travel_by_the_row_kernels():
+    """Rows of 3584: 28 lane tiles, 32 sublanes a row in the scratch."""
+    step = compiled_step(CELL)
+    sizes = step.sizes
+    row_kernels_stand(
+        step, sizes["num_hidden_layers"] - sizes["first_k_dense_replace"],
+        sizes["hidden_size"])

@@ -4,7 +4,7 @@ cell times: the routed experts' pooled products are this repo's kernels
 (``ops/gmm_kernel.py``). Nothing runs here: counts by XLA, not times."""
 import re
 
-from described_v5e import compiled_step, peak_bytes
+from described_v5e import compiled_step, peak_bytes, row_kernels_stand
 
 MOE_KERNELS = {"moe_gmm_up_kernel": "mx_moe_gmm_up",
                "moe_gmm_down_kernel": "mx_moe_gmm_down",
@@ -52,3 +52,9 @@ def test_pooled_experts_are_the_kernels_forward_and_backward():
     # ``sizes`` and the walk: a few hundred bytes a layer beside 1.1912 GB
     assert 1.19120e9 < kept < 1.19125e9, kept
     assert peak < 15.0e9, peak
+
+
+def test_rows_travel_by_the_row_kernels():
+    step = compiled_step("moonlight-16b-a3b-train-8k")
+    row_kernels_stand(step, step.sizes["num_hidden_layers"] - 1,
+                      step.sizes["hidden_size"])

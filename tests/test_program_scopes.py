@@ -46,8 +46,14 @@ PARENT = {
          "mx_exit_gate/mx_exit_gate/mx_exit_gate", "mx_exit_head",
          "mx_loop_body", "mx_loop_body/mx_attn_fwd",
          "mx_loop_body/mx_gated_mlp", "mx_loop_body/mx_rope", "mx_rope"}),
+    # since PR 48 ``_combine`` rounds the routed sum where it forms it, so
+    # that conversion stands before the shared experts' lines and not after
+    # them, and ``_dispatch_pooled`` takes the experts' row counts before
+    # the gather: the same operations in another order (the pool of 352
+    # rows at the rehearsal sizes is no whole tile: the plain moves). The
+    # Nemotron step rounded there already and is the parent's to the letter
     "moonlight-16b-a3b-train-8k": (
-        "de66cdea24c2a0384f457c8aa5b9eb63a11c0779458771bfada1103a6036e470",
+        "f7e5b4434779b7061f1b29e55bdc7492116e0ee82d2ff248b1b021c51fc6a817",
         {"mx_attn_fwd", "mx_gated_mlp", "mx_mla_kv_down", "mx_mla_kv_up",
          "mx_mla_out", "mx_mla_q", "mx_mla_rope", "mx_mla_rope/mx_rope",
          "mx_moe_combine", "mx_moe_dispatch", "mx_moe_gmm_down",

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from described_v5e import (compiled_step, harness, no_jax_cache,  # noqa: F401
-                           one_chip, peak_bytes)
+                           one_chip, peak_bytes, row_kernels_stand)
 
 CELL = "nemotron3-super-train-8k"
 
@@ -68,6 +68,17 @@ def test_units_keep_what_is_dear_and_the_step_fits():
     experts = pattern.count("E")
     assert len(_sorts(text, "mx_moe_dispatch/jit(argsort)")) == experts
     assert len(_sorts(text, "mx_moe_route/top_k")) == experts
+
+
+def test_rows_travel_by_the_row_kernels():
+    """The experts work in the latent: rows of 1024 into eight slices of
+    512, which the kernels see as one pool of 4096 (``bf16[8,512,1024]``
+    is the same bytes)."""
+    step = compiled_step(CELL)
+    sizes = step.sizes
+    row_kernels_stand(step, sizes["hybrid_override_pattern"].count("E"),
+                      sizes["moe_latent_size"])
+    assert "bf16[8,512,1024]" in step.text
 
 
 # -- the attention of both ``PatternLM`` cells is the kernel --------------------
