@@ -6,7 +6,11 @@ A unit (``gluon.block.HybridBlock._call_remat``, which only
 operator handed to ``kept``, and computes everything else again. An
 operator hands over what is dear to compute a second time and small to
 hold: a matrix product's output, the result of a choice or a sort, a
-scan's output, a reduction's one number a row. Activations, gates,
+scan's output, a reduction's one number a row. Dear decides where the
+two pull apart: a gated MLP's first product is the widest value of a
+layer and is held, because forming it again is 2 of the MLP's 11
+products a unit on the MXU (``ops.seq.gated_mlp`` has both readings on
+the chip). Activations, gates,
 casts, reshapes and a norm's scaled rows are left to recomputation, and
 so is a value no backward pass reads (a unit's last product): JAX holds
 no kept value that nothing reads, and ``kept_bytes`` would count it all

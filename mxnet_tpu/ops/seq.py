@@ -36,9 +36,10 @@ scatter-add alone.
 
 What a recomputation unit around these ops holds for its backward pass
 is said here, where the values are computed (``remat.kept``): the
-outputs of the matrix products a backward pass reads (not a unit's last
-ones, nor the attention's score blocks, which grow with the square of
-the length), the threshold of the routing's choice, what the dispatch's
+outputs of the matrix products a backward pass reads, a gated MLP's
+2 f wide first product among them (not a unit's last ones, nor the
+attention's score blocks, which grow with the square of the length), the
+threshold of the routing's choice, what the dispatch's
 sort gave, the convolution's, the scan's and the attention's outputs,
 the attention's log-sum-exp a row where its kernels run, and a norm's sum
 of squares. Activations, gates, decay masks, casts,
@@ -1740,11 +1741,21 @@ def gated_mlp(data, gate_up_weight, down_weight, **kw):
     """``(silu(u W_gate) * (u W_up)) W_down``. ``gate_up_weight``: (2 f,
     hidden), rows ``[gate | up]``, one product for both; ``down_weight``:
     (hidden, f). The activation and the gating in float32. A unit around
-    it keeps neither product: the first is 2 f wide, the widest value of
-    a layer (5.5 times the layer's input at f = 2.75 hidden), and is
-    computed again."""
+    it keeps the first product, as it keeps every other first product of
+    this file: 2 f wide, the widest value of a layer (5.5 times the
+    layer's input at f = 2.75 hidden) and the dearest to form again (2 of
+    the MLP's 11 products a unit). The gated rows, the activation and the
+    casts are computed again from it; the down product is kept only
+    where a norm after the sublayer reads it (``rms_norm``'s
+    ``keep_input``). On the chip (PERF.md, section 6): dropping the
+    product bought 1.8 GB for 17 ms of ``ouro-2.6b-train-4k``'s step when
+    that step did not fit (PR 33); with the attention's score blocks off
+    the chip's memory, keeping it costs 1.47 GB there and gives back 11.2
+    of 299.6 ms, and 8.6 of 186.0 ms for 0.77 GB in
+    ``lfm2-24b-a2b-train-8k`` (PR 49). ``TrainStep(remat=...)`` is the
+    lever on memory."""
     with jax.named_scope("mx_gated_mlp"):
-        gu = _mm(data, gate_up_weight)
+        gu = kept(_mm(data, gate_up_weight))
         f = gu.shape[-1] // 2
         hid = jax.nn.silu(gu[..., :f].astype(_F32)) * gu[..., f:].astype(_F32)
         return _mm(hid.astype(data.dtype), down_weight)

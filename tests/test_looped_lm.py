@@ -363,9 +363,9 @@ def test_saved_bytes_of_a_scanned_unit_are_trips_times_one_trace():
     # float32 run, 4 bytes. Attention: q k v, the heads' output, W_o's
     # product and two norms' sums of squares a row
     assert per[1]["l0_"] == 4 * tokens * (3 * width + width + d + 2)
-    # gated MLP: W_down's product and two norms' sums; the 2 f wide
-    # product is computed again
-    assert per[1]["l1_"] == 4 * tokens * (d + 2)
+    # gated MLP: the 2 f wide gate-and-up product, W_down's product and
+    # two norms' sums; a scanned unit holds ``loops`` times that (above)
+    assert per[1]["l1_"] == 4 * tokens * (2 * SZ["intermediate_size"] + d + 2)
 
 
 # ---------------------------------------------------------------------------

@@ -1,14 +1,18 @@
 """What a recomputation unit keeps (``ops.remat``; ``TrainStep(remat=
 "layer")``): the kept values change nothing that is computed, the
-backward pass chooses, sorts and multiplies the kept products once, the
-gauges say what the units hold, and a step built without recomputation
-is the program it was."""
+backward pass chooses, sorts and multiplies the kept products once (a
+gated MLP's 2 f wide first product among them, at every cell's sizes),
+the gauges say what the units hold, and a step built without
+recomputation is the program it was."""
 import collections
+import functools
 import hashlib
 import os
 import sys
+import types
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -99,6 +103,128 @@ def test_backward_chooses_sorts_and_multiplies_once(monkeypatch):
         assert now[cheap] == bare[cheap] > 0, cheap
 
 
+# -- the gated MLPs of the five cells that call ``ops.seq.gated_mlp`` ---------
+#: (cell, call site): the dense layer's ``nn.GatedMLP`` or the shared experts
+#: inside ``gated_moe``, each at the cell's rehearsal sizes
+GATED_MLPS = [("ouro-2.6b-train-4k", "dense"),
+              ("lfm2-24b-a2b-train-8k", "dense"),
+              ("moonlight-16b-a3b-train-8k", "dense"),
+              ("moonlight-16b-a3b-train-8k", "shared"),
+              ("xing4.0-29b-a4b-train-4k", "dense"),
+              ("xing4.0-29b-a4b-train-4k", "shared"),
+              ("qwen3-next-80b-a3b-train-8k", "shared")]
+gated_mlps = pytest.mark.parametrize("cell,site", GATED_MLPS)
+
+
+@functools.cache
+def _mixers(cell):
+    """The mixers of ``cell``'s net as its configuration builds it at the
+    rehearsal sizes, and those sizes."""
+    cell = harness.load_cell(cell, rehearsal=True)
+    layers = cell.model._net(cell.sizes).stack._children.values()
+    return [m.mixer for m in layers if hasattr(m, "mixer")], cell.sizes
+
+
+def _gated_mlp_unit(cell, site, dtype=jnp.bfloat16):
+    """The first gated MLP of ``cell`` at that call site: ``op``, a
+    function of its input and its weights, ``args`` (seeded, in the cells'
+    compute dtype), the ``tokens`` of a step, the MLP's width ``f`` and,
+    of an expert layer, its ``attrs`` and whether its shared experts go
+    through a gate of their own."""
+    from mxnet_tpu.gluon import nn
+    from mxnet_tpu.ops import seq
+    mixers, sizes = _mixers(cell)
+    kind = nn.GatedMLP if site == "dense" else nn.GatedMoE
+    mixer = next(m for m in mixers if isinstance(m, kind))
+    rng = np.random.default_rng(len(cell))
+    shapes = {k.rsplit("0_", 1)[1]: p.shape
+              for k, p in mixer.collect_params().items()}
+
+    def w(leaf):
+        return jnp.asarray(0.2 * rng.standard_normal(shapes[leaf]), dtype)
+
+    hidden = shapes["gate_up_weight" if site == "dense"
+                    else "router_weight"][1]
+    x = jnp.asarray(rng.standard_normal(
+        (sizes["batch"], sizes["seq_len"], hidden)), dtype)
+    tokens = sizes["batch"] * sizes["seq_len"]
+    if site == "dense":
+        return types.SimpleNamespace(
+            op=seq.gated_mlp, tokens=tokens, f=shapes["down_weight"][1],
+            args=(x, w("gate_up_weight"), w("down_weight")), own_gate=False)
+    own_gate = "shared_gate_weight" in shapes
+    args = (x, w("router_weight"), jnp.zeros(shapes["router_bias"]),
+            w("w1"), w("w3"), w("w2"), w("shared_gate_up_weight"),
+            w("shared_down_weight"), None) \
+        + ((w("shared_gate_weight"),) if own_gate else ())
+    return types.SimpleNamespace(
+        op=lambda *a: seq.gated_moe(*a, **mixer._attrs)[0], args=args,
+        tokens=tokens, f=shapes["shared_down_weight"][1],
+        attrs=mixer._attrs, own_gate=own_gate)
+
+
+def _loss_and_gradients(op, args):
+    given = [i for i, a in enumerate(args) if a is not None
+             and jnp.issubdtype(a.dtype, jnp.floating)]
+    return jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(op(*a).astype(jnp.float32) ** 2),
+        argnums=given))(*args)
+
+
+@gated_mlps
+def test_a_kept_gate_and_up_product_changes_nothing_that_is_computed(
+        cell, site):
+    """The unit around a gated MLP against the same layer with no
+    recomputation: the output's loss and every gradient to the last
+    bit."""
+    mlp = _gated_mlp_unit(cell, site)
+    got = _loss_and_gradients(jax.checkpoint(mlp.op, policy=remat.POLICY),
+                              mlp.args)
+    want = _loss_and_gradients(mlp.op, mlp.args)
+    assert np.isfinite(float(want[0])) and float(want[0]) > 0
+    moved = 0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+        moved += bool(np.asarray(b, np.float32).any())
+    # no gradient reaches a router's bias, and every other leaf has one
+    assert moved == len(jax.tree.leaves(want)) - (site == "shared")
+
+
+def _products(jaxpr, rows, width, out=0):
+    """How many ``dot_general`` of ``jaxpr`` and of the programs it calls
+    give ``rows`` rows ``width`` wide."""
+    for eqn in jaxpr.eqns:
+        aval = eqn.outvars[0].aval
+        out += eqn.primitive.name == "dot_general" \
+            and aval.shape[-1:] == (width,) and aval.size == rows * width
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out = _products(sub, rows, width, out)
+    return out
+
+
+@gated_mlps
+def test_backward_forms_the_gate_and_up_product_once(cell, site):
+    """Forward and backward of a unit around a gated MLP hold the 2 f wide
+    product once, and the backward multiplies nothing again (but where the
+    shared experts go through a gate of their own, ``qwen3_next``'s: the
+    gate's one column, and the down product its derivative reads); a unit
+    that keeps its input alone forms the wide product twice."""
+    mlp = _gated_mlp_unit(cell, site)
+
+    def backward(policy):
+        unit = jax.checkpoint(mlp.op, policy=policy)
+        return jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+            unit(*a).astype(jnp.float32))))(*mlp.args).jaxpr
+
+    now, bare = backward(remat.POLICY), backward(None)
+    assert [_products(j, mlp.tokens, 2 * mlp.f) for j in (now, bare)] == [1, 2]
+    assert _recomputed(now)["dot_general"] == 2 * mlp.own_gate
+    assert _recomputed(bare)["dot_general"] > 2 * mlp.own_gate
+    # the gating and the activation are computed again from the product
+    assert _recomputed(now)["logistic"] == _recomputed(bare)["logistic"] > 0
+
+
 def _held(op, *args):
     """What JAX itself says ``op`` under the units' policy leaves for its
     backward pass beside its arguments, in bytes, and what it names."""
@@ -183,6 +309,28 @@ def test_what_is_named_is_what_jax_holds():
             a, num_heads=2, num_kv_heads=1, head_dim=8, block=8) @ w.T,
         f(2, 15, 4 * 8), w)
     assert named > 0 and held == named
+
+
+@gated_mlps
+def test_a_unit_holds_the_gate_and_up_product_and_no_more_of_the_mlp(
+        cell, site):
+    """``tokens x 2 f`` values in the compute dtype a gated MLP: all a
+    dense layer's unit names, and what the shared experts add to an
+    expert layer's (the same layer with no shared expert names the rest);
+    JAX holds what is named."""
+    from mxnet_tpu.ops import seq
+    mlp = _gated_mlp_unit(cell, site)
+    held, named = _held(mlp.op, *mlp.args)
+    rest = unnamed = 0
+    if site == "shared":
+        rest = remat.kept_bytes(jax.make_jaxpr(lambda *a: seq.routed_moe(
+            *a, **mlp.attrs)[0])(*mlp.args[:6]).jaxpr)
+        assert rest > 0
+        # the gather of the routed rows leaves its index, twice (above)
+        unnamed = 2 * mlp.attrs["buffer_rows"] * 4
+    assert named - rest \
+        == mlp.tokens * 2 * mlp.f * mlp.args[0].dtype.itemsize
+    assert held == named + unnamed
 
 
 def test_kept_is_the_identity_outside_a_unit():
