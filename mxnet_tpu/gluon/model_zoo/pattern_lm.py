@@ -7,7 +7,11 @@ hidden vector (``deepseek_v3``'s two sublayers: ``LG`` a dense layer,
 ``LF`` an expert layer), ``D`` a Gated DeltaNet mixer (``qwen3_next``'s
 linear-attention layers: ``DFDFDF*F`` a period), ``C`` a gated short
 convolution (``lfm2_moe``'s ``conv`` layers: ``CG`` a dense layer,
-``*FCFCFCF`` a period of expert layers). Pre-norm
+``*FCFCFCF`` a period of expert layers), ``W`` a second kind of causal
+grouped-query attention with keyword arguments of its own (``laguna``'s
+``sliding_attention`` layers, more heads under a window beside the
+``full_attention`` layers' ``*``: ``*G`` the dense layer, ``WFWFWF*F`` a
+period of expert layers). Pre-norm
 residual throughout, ``x <- x + Mixer_l(RMSNorm_l(x))``, or with
 ``post_norm`` a norm on either side of the mixer, ``x <- x +
 RMSNorm'_l(Mixer_l(RMSNorm_l(x)))``; one final RMSNorm, an untied head,
@@ -120,8 +124,10 @@ class PatternLM(HybridBlock):
     ``nn.Mamba2Mixer``, ``nn.LatentMoE``, ``nn.GQAttention``,
     ``nn.GatedMLP``, ``nn.LatentAttention`` and ``nn.GatedMoE`` after
     ``in_units`` (what each layer of that kind holds), and
-    ``linear_attention`` those of ``nn.GatedDeltaNet`` and ``short_conv``
-    those of ``nn.GatedShortConv``. ``post_norm``: a
+    ``linear_attention`` those of ``nn.GatedDeltaNet``, ``short_conv``
+    those of ``nn.GatedShortConv`` and ``window_attention`` those of the
+    ``nn.GQAttention`` of the letter ``W`` (heads, window and rotation of
+    its own beside ``attention``'s). ``post_norm``: a
     second norm in every layer, after its mixer. ``norm_unit_offset``:
     every layer's norm and the final norm scale by ``1 + w`` from ``w =
     0``. ``loops``: how often the stack and the final norm run, each pass
@@ -147,7 +153,8 @@ class PatternLM(HybridBlock):
                  loops=1, exit_gate=False, latent_attention=None,
                  experts=None, linear_attention=None,
                  norm_unit_offset=False, residual_streams=None,
-                 hyper_connections=None, short_conv=None, **kwargs):
+                 hyper_connections=None, short_conv=None,
+                 window_attention=None, **kwargs):
         super().__init__(**kwargs)
         if residual_streams is not None and loops > 1:
             raise ValueError("residual_streams with loops > 1: a layer "
@@ -163,7 +170,8 @@ class PatternLM(HybridBlock):
                 "F": lambda: nn.GatedMoE(units, **experts),
                 "D": lambda: nn.GatedDeltaNet(units, epsilon=epsilon,
                                               **linear_attention),
-                "C": lambda: nn.GatedShortConv(units, **(short_conv or {}))}
+                "C": lambda: nn.GatedShortConv(units, **(short_conv or {})),
+                "W": lambda: nn.GQAttention(units, **window_attention)}
         self._vocab, self._units = vocab, units
         with self.name_scope():
             self.embed = nn.Embedding(vocab, units)

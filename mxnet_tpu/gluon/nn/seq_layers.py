@@ -340,8 +340,9 @@ class LatentAttention(HybridBlock):
     frequencies are ``ops.seq.rope_frequencies``' and, where
     ``mscale_all_dim`` is given, the softmax scale is ``(nope_dim +
     rope_dim) ** -0.5`` times ``yarn_mscale(factor, mscale_all_dim)``
-    squared; cos and sin are not scaled, so ``mscale`` has to equal
-    ``mscale_all_dim``.
+    squared; where the group gives ``attention_factor``, or ``mscale``
+    differs from ``mscale_all_dim``, the rotation's cos and sin carry
+    that factor (``_yarn_attrs``, the one parser of the group).
     Where ``nope_dim == v_dim`` is a multiple of 128 and the program is
     lowered for a TPU, the softmax is the fused kernels of
     ``ops.attn_kernel``; ``block`` is the plain form's, as in
@@ -390,23 +391,36 @@ class LatentAttention(HybridBlock):
                                  **self._attrs)
 
 
-def _yarn_attrs(group, score_dim):
-    """``latent_attention``'s ``yarn`` and ``scale`` from a
-    configuration's ``rope_scaling`` group."""
+def _yarn_attrs(group, score_dim=None):
+    """``yarn``, ``mscale`` and ``scale`` of ``causal_gq_attention`` and
+    ``latent_attention`` from a configuration's ``rope_scaling`` group
+    (``type`` or ``rope_type`` ``yarn``, ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``
+    and the factor's keys). The frequencies are
+    ``ops.seq.rope_frequencies``'. YaRN's attention factor goes where the
+    group puts it: ``attention_factor``, or without it ``yarn_mscale(
+    factor, mscale) / yarn_mscale(factor, mscale_all_dim)`` where the two
+    differ, on the rotation's cos and sin (``mscale``: the rotated
+    elements of queries and keys alone carry it); with ``score_dim``,
+    ``yarn_mscale(factor, mscale_all_dim)`` squared on the softmax
+    (``scale`` = ``score_dim ** -0.5`` times it), which is all of it
+    where ``mscale`` equals ``mscale_all_dim``."""
     from ...ops.seq import yarn_mscale
     if group.get("type", group.get("rope_type")) != "yarn":
         raise ValueError(f"rope_scaling {group!r}: only type yarn is known")
     factor = group["factor"]
     all_dim = group.get("mscale_all_dim", 0)
-    if yarn_mscale(factor, group.get("mscale", 1)) \
-            != yarn_mscale(factor, all_dim or 1):
-        raise ValueError("rope_scaling: cos and sin are not scaled here, "
-                         "so mscale has to equal mscale_all_dim")
     attrs = {"yarn": (float(factor),
                       float(group["original_max_position_embeddings"]),
                       float(group.get("beta_fast", 32)),
                       float(group.get("beta_slow", 1)))}
-    if all_dim:
+    on_rotation = group.get("attention_factor")
+    if on_rotation is None:
+        on_rotation = yarn_mscale(factor, group.get("mscale", 1)) \
+            / yarn_mscale(factor, all_dim or 1)
+    if on_rotation != 1:
+        attrs["mscale"] = float(on_rotation)
+    if all_dim and score_dim is not None:
         attrs["scale"] = float(score_dim ** -0.5
                                * yarn_mscale(factor, all_dim) ** 2)
     return attrs
@@ -433,15 +447,35 @@ class GQAttention(HybridBlock):
     ``norm_unit_offset`` the scale ``1 + w`` from ``w = 0``). ``gated``:
     the projection is ``[q | k | v | gate]``, the gate as wide as the
     queries, and every head's output is multiplied by ``sigmoid`` of its
-    gate before the output projection."""
+    gate before the output projection. ``head_gate``: the projection
+    holds ``num_heads`` more rows, ONE gate a head, and all of a head's
+    output is multiplied by ``sigmoid`` of its gate (rows of the packed
+    projection and no weight of their own: one product; ``(num_heads + 2
+    num_kv_heads) * head_dim + num_heads`` is no whole lane tile, and the
+    gates are its last columns, after every head).
+
+    ``window``: a query sees that many keys, its own the last (sliding
+    window attention); the kernels and the plain form skip the key blocks
+    wholly before it. ``rope_scaling``: a configuration's YaRN group, as
+    ``LatentAttention``'s, with ``attention_factor`` on the rotated
+    elements' cos and sin (``_yarn_attrs``)."""
 
     def __init__(self, in_units, num_heads, num_kv_heads, head_dim=128,
                  block=1024, rope_theta=None, rotary_dim=None,
                  qk_norm=False, gated=False, epsilon=1e-6,
-                 norm_unit_offset=False, **kwargs):
+                 norm_unit_offset=False, window=None, head_gate=False,
+                 rope_scaling=None, **kwargs):
         super().__init__(**kwargs)
+        if gated and head_gate:
+            raise ValueError("gated and head_gate: one gate on the output")
         self._attrs = {"num_heads": num_heads, "num_kv_heads": num_kv_heads,
                        "head_dim": head_dim, "block": block}
+        if window is not None:
+            self._attrs["window"] = int(window)
+        if head_gate:
+            self._attrs["head_gate"] = True
+        if rope_scaling is not None:
+            self._attrs.update(_yarn_attrs(rope_scaling))
         if rope_theta is not None:
             self._attrs["rope_theta"] = float(rope_theta)
         if rotary_dim is not None:
@@ -452,7 +486,7 @@ class GQAttention(HybridBlock):
             self._attrs.update(eps=epsilon,
                                unit_offset=bool(norm_unit_offset))
         rows = (num_heads * (2 if gated else 1) + 2 * num_kv_heads) \
-            * head_dim
+            * head_dim + (num_heads if head_gate else 0)
         with self.name_scope():
             self.qkv_weight = self.params.get("qkv_weight",
                                               shape=(rows, in_units))

@@ -65,6 +65,34 @@ the length: one over the queries' side for ``dQ`` (and ``dq2``), one
 over the keys' side for ``dK``, ``dV`` (and ``dk2``), each forming the
 score block for itself. The gauge ``attn::fused_bwd_sites`` counts the
 sites that take the fused kernel, beside ``attn::kernel_sites``.
+
+A window (``window=W``: query ``t`` sees keys ``t - W < j <= t``) gives a
+query block a second edge, ``W`` keys behind the diagonal, and the kernels
+a grid that walks the band alone: a query block meets ``band = 1 +
+ceil((W - 1) / block)`` key blocks, its own last (``_band``), and a key
+block as many query blocks, its own first; the grid's last dimension is
+``band`` (times the heads where it walks the keys' side) and not the
+number of blocks, so a block wholly before the window is neither formed
+nor fetched, forward or backward. A band's step that falls before the
+first block or after the last is clamped to it (an unchanged index moves
+nothing) and computes nothing. A block pair that the window's edge
+crosses (``(i - j + 1) block > W``) takes a second mask, ``t - j < W``,
+beside the diagonal's; a row of such a block may be masked whole, which
+the running maximum forgets at the diagonal, where every row sees
+itself. ``dQ`` of a query block starts at the first block of its band and
+is complete at its diagonal, ``dK`` and ``dV`` of a key block when the
+last query block of its band is through. The block under a window is the
+largest of 512, 256, 128 that divides the padded length
+(``_WINDOW_BLOCKS``), chosen on the chip at ``W`` = 512, 36 heads on 4 and
+8192 rows (PERF.md section 6, PR 50; forward + backward by side, ms): 512
+gives two blocks a query block of which half is masked and read 1.37 +
+4.42, 256 three of which a third is and read 2.41 + 5.99, 128 five of
+which a fifth is and read 6.11 + 11.56, 1024 two of which three quarters
+are and read 2.13 + 6.90: what a smaller block saves in masked pairs it
+loses several times over in steps of too little work. A window that
+reaches the whole length is no window. The fused backward holds ``dQ`` for all the
+rows as it does without a window. The gauge ``attn::window_sites``
+counts the sites whose kernels walk a band.
 """
 from __future__ import annotations
 
@@ -82,6 +110,7 @@ _F32 = jnp.float32
 _NEG = -1e30
 _LANES = 128
 _BLOCKS = (1024, 512, 256, 128)
+_WINDOW_BLOCKS = (512, 256, 128)    # under a window: the module's docstring
 _VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 # of them, what the fused backward may hold for all the rows, beside a
 # grid step's blocks and about 24 MB of float32 (block, block) temporaries
@@ -90,12 +119,66 @@ _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 
 
-def block_size(length):
+def _no_second_part(extra, window):
+    """The second part's index maps walk all the blocks: a window with a
+    second part is not written."""
+    if extra is not None and window is not None:
+        raise NotImplementedError("a window with a second score part")
+
+
+def block_size(length, window=None):
     """The block of queries and of keys for a sequence of ``length``:
-    the largest of 1024, 512, 256, 128 that divides the length padded to
-    whole lane tiles. Returns ``(block, padded length)``."""
+    the largest of 1024, 512, 256, 128 (under a ``window``, of
+    ``_WINDOW_BLOCKS``) that divides the length padded to whole lane
+    tiles. Returns ``(block, padded length)``."""
     padded = -(-int(length) // _LANES) * _LANES
-    return next(b for b in _BLOCKS if padded % b == 0), padded
+    blocks = _BLOCKS if window is None else _WINDOW_BLOCKS
+    return next(b for b in blocks if padded % b == 0), padded
+
+
+def _band(window, blk):
+    """How many key blocks a query block meets under ``window`` (and a
+    key block query blocks): its own and those the window reaches over."""
+    return 1 - (1 - int(window)) // blk
+
+
+def _crossed(i, j, blk, window):
+    """Whether the window's edge crosses query block ``i`` against key
+    block ``j``: its last query is ``window`` or more past its first
+    key."""
+    return (i - j + 1) * blk > window
+
+
+def _whole_blocks(blk, window):
+    """Whether a band holds a block pair off the diagonal that the
+    window's edge does not cross (none at ``window`` below two blocks)."""
+    return 2 * blk <= window
+
+
+def _on_diagonal(blk, window):
+    """The window a diagonal block is masked by beside the causal order:
+    none unless a block is longer than the window."""
+    return window if window is not None and blk > window else None
+
+
+def _band_step(i, step, blk, window):
+    """Of a grid that brings key blocks to query block ``i``: the key
+    block of ``step`` and whether it lies before the diagonal. Without a
+    window the steps are the blocks; under one they are the band, which
+    ends at ``i`` and may start before block 0."""
+    if window is None:
+        return step, step < i
+    j = i - (_band(window, blk) - 1) + step
+    return j, (j >= 0) & (j < i)
+
+
+def _ahead(j, t, n, blk, window):
+    """Of a grid that brings a head's query blocks to key block ``j``
+    under a window, step ``t``: how far ahead of ``j`` its query block
+    lies, whether there is such a block, and the block (the last where
+    there is none)."""
+    ahead = t % _band(window, blk)
+    return ahead, j + ahead < n, jnp.minimum(j + ahead, n - 1)
 
 
 def _lanes(x, width):
@@ -105,30 +188,34 @@ def _lanes(x, width):
 
 
 def _scores(rows, cols, i, j, blk, scale, diagonal, transposed=False,
-            rows2=None, cols2=None):
+            rows2=None, cols2=None, window=None):
     """The scaled float32 scores of query block ``i`` against key block
     ``j``, ``rows @ cols.T``, with ``rows2 @ cols2.T`` added where a
     second part is given: (queries, keys), or (keys, queries)
     ``transposed``; on the ``diagonal`` block masked by the causal
-    order."""
+    order, and with ``window`` by the window's edge (a key ``window`` or
+    more before its query)."""
     s = lax.dot_general(rows, cols, _NT, preferred_element_type=_F32)
     if rows2 is not None:
         s = s + lax.dot_general(rows2, cols2, _NT,
                                 preferred_element_type=_F32)
     s = s * scale
-    if not diagonal:
+    if not diagonal and window is None:
         return s
     query = i * blk + lax.broadcasted_iota(jnp.int32, s.shape,
                                            1 if transposed else 0)
     key = j * blk + lax.broadcasted_iota(jnp.int32, s.shape,
                                          0 if transposed else 1)
-    return jnp.where(query >= key, s, _NEG)
+    if window is None:
+        return jnp.where(query >= key, s, _NEG)
+    seen = query - key < window
+    return jnp.where((query >= key) & seen if diagonal else seen, s, _NEG)
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, blk):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, blk, window=None):
     q2_ref, k2_ref = refs[:-5] or (None, None)
     o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[-5:]
     i, j = pl.program_id(2), pl.program_id(3)
@@ -140,10 +227,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, blk):
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def block(diagonal):
+    def block(diagonal, window=None):
         v = v_ref[...]
         s = _scores(q_ref[...], k_ref[...], i, j, blk, scale, diagonal,
-                    **_second(q2_ref, k2_ref))
+                    window=window, **_second(q2_ref, k2_ref))
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
         p = jnp.exp(s - _lanes(m_next, blk))
@@ -153,16 +240,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, blk):
         acc_ref[...] = acc_ref[...] * _lanes(alpha, width) + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=_F32)
 
-    pl.when(j < i)(functools.partial(block, False))
+    j, before = _band_step(i, j, blk, window)
+    _off_diagonal(block, before, i, j, blk, window)
 
     @pl.when(j == i)
     def _():
-        block(True)
+        block(True, _on_diagonal(blk, window))
         l = l_ref[...]
         o_ref[...] = (acc_ref[...] / _lanes(l, width)).astype(o_ref.dtype)
         # a row's number sits in every lane: turned, one row of it is the
         # block's log-sum-exp along the lanes
         lse_ref[...] = (m_ref[...] + jnp.log(l)).T[:1]
+
+
+def _off_diagonal(block, inside, i, j, blk, window):
+    """``block(False)`` where ``inside`` holds, and under a ``window``
+    ``block(False, window)`` where its edge crosses the block pair."""
+    if window is None:
+        pl.when(inside)(functools.partial(block, False))
+        return
+    crossed = _crossed(i, j, blk, window)
+    if _whole_blocks(blk, window):
+        pl.when(inside & ~crossed)(functools.partial(block, False))
+    pl.when(inside & crossed)(functools.partial(block, False, window))
 
 
 def _second(rows2_ref, cols2_ref):
@@ -191,6 +291,12 @@ def _q_at(b, h, i, j):
 
 def _kv_at(group, b, h, i, j):
     return b, jnp.minimum(j, i), h // group
+
+
+def _band_kv_at(group, band, b, h, i, j):
+    """Step ``j`` of query block ``i``'s band; before the first block,
+    the first block."""
+    return b, jnp.maximum(i - (band - 1) + j, 0), h // group
 
 
 def _row_at(b, h, i, j):
@@ -228,6 +334,17 @@ def _k2_at(b, h, i, j):
     return b, jnp.minimum(j, i), 0
 
 
+def _queries_side(n, blk, group, window):
+    """Of a grid (batch, query head, query block, step) that brings the
+    key blocks to a query block: its last dimension and the keys' and
+    values' index map, over all ``n`` blocks up to the diagonal or, under
+    a ``window``, over the band."""
+    if window is None:
+        return n, functools.partial(_kv_at, group)
+    band = _band(window, blk)
+    return band, functools.partial(_band_kv_at, group, band)
+
+
 def _head_major(q2, hq, padded):
     """``q2`` (B, L, Hq * D2) as (B, Hq, padded L, D2)."""
     bsz, length, _ = q2.shape
@@ -236,18 +353,20 @@ def _head_major(q2, hq, padded):
 
 
 def forward(q, k, v, num_heads, num_kv_heads, scale, interpret=False,
-            extra=None):
+            extra=None, window=None):
     """``(out, lse)``: the attention's output (B, L, Hq * D) in ``q``'s
     dtype and every row's float32 log-sum-exp of its scaled, masked
     scores (B, Hq, L). ``extra``: ``(q2 (B, L, Hq * D2), k2 (B, L, D2))``,
-    the scores' second part."""
+    the scores' second part. ``window``: a query sees that many keys, its
+    own the last (not with ``extra``)."""
+    _no_second_part(extra, window)
     hq, group = int(num_heads), int(num_heads) // int(num_kv_heads)
     bsz, length, _ = q.shape
     dim = q.shape[-1] // hq
-    blk, padded = block_size(length)
+    blk, padded = block_size(length, window)
     q, k, v = (_padded(t, 1, padded) for t in (q, k, v))
     n = padded // blk
-    kv_at = functools.partial(_kv_at, group)
+    steps, kv_at = _queries_side(n, blk, group, window)
     operands, specs = [q, k, v], [_head_spec(blk, dim, _q_at),
                                   _head_spec(blk, dim, kv_at),
                                   _head_spec(blk, dim, kv_at)]
@@ -256,8 +375,8 @@ def forward(q, k, v, num_heads, num_kv_heads, scale, interpret=False,
         operands += [_head_major(q2, hq, padded), _padded(k2, 1, padded)]
         specs += _extra_specs(blk, k2.shape[-1], _q2_at, _k2_at)
     out, lse = _call(
-        functools.partial(_fwd_kernel, scale=scale, blk=blk),
-        "attn_fwd_kernel", (bsz, hq, n, n), interpret,
+        functools.partial(_fwd_kernel, scale=scale, blk=blk, window=window),
+        _named("fwd_kernel", window), (bsz, hq, n, steps), interpret,
         in_specs=specs,
         out_specs=[_head_spec(blk, dim, _q_at), _row_spec(blk, _row_at)],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -268,11 +387,17 @@ def forward(q, k, v, num_heads, num_kv_heads, scale, interpret=False,
     return out[:, :length], lse[:, :, 0, :length]
 
 
+def _named(kernel, window):
+    """``attn_<kernel>``, under a window ``attn_swa_<kernel>``."""
+    return ("attn_" if window is None else "attn_swa_") + kernel
+
+
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
 def _keys_side(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q2_ref,
-               k2_ref, dk_acc, dv_acc, i, j, blk, scale, diagonal):
+               k2_ref, dk_acc, dv_acc, i, j, blk, scale, diagonal,
+               window=None):
     """Key block ``j`` against query block ``i`` with the keys along the
     sublanes and the queries along the lanes, so that a query's
     log-sum-exp and delta are rows as they lie: ``P^T`` and ``dS^T``
@@ -280,7 +405,7 @@ def _keys_side(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q2_ref,
     block's sums. Returns ``dS^T`` rounded to the compute dtype."""
     q, do = q_ref[...], do_ref[...]
     st = _scores(k_ref[...], q, i, j, blk, scale, diagonal, transposed=True,
-                 **_second(k2_ref, q2_ref))
+                 window=window, **_second(k2_ref, q2_ref))
     pt = jnp.exp(st - lse_ref[...])
     dv_acc[...] += jnp.dot(pt.astype(do.dtype), do,
                            preferred_element_type=_F32)
@@ -290,23 +415,33 @@ def _keys_side(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q2_ref,
     return dst
 
 
-def _keys_side_at(n, head, kv_head):
+def _keys_side_at(n, head, kv_head, band=None):
     """Index maps of a grid (batch, h, key block ``j``, ``t``) whose step
     ``t`` brings query block ``t % n`` of query head ``head(h, t)`` to key
     block ``j`` of key/value head ``kv_head(h, t)``: ``(q_at, k_at,
     row_at, q2_at, k2_at)``. The steps before a key block's diagonal
-    fetch the diagonal's block again and compute nothing."""
+    fetch the diagonal's block again and compute nothing. With ``band``
+    (a window) a head's steps are the ``band`` query blocks from ``j``
+    on, ``j + t % band``, and those past the last block fetch the last
+    again."""
+    if band is None:
+        def at(j, t):
+            return jnp.maximum(t % n, j)
+    else:
+        def at(j, t):
+            return jnp.minimum(j + t % band, n - 1)
+
     def q_at(b, h, j, t):
-        return b, jnp.maximum(t % n, j), head(h, t)
+        return b, at(j, t), head(h, t)
 
     def k_at(b, h, j, t):
         return b, j, kv_head(h, t)
 
     def row_at(b, h, j, t):
-        return b, head(h, t), 0, jnp.maximum(t % n, j)
+        return b, head(h, t), 0, at(j, t)
 
     def q2_at(b, h, j, t):
-        return b, head(h, t), jnp.maximum(t % n, j), 0
+        return b, head(h, t), at(j, t), 0
 
     def k2_at(b, h, j, t):
         return b, j, 0
@@ -319,9 +454,10 @@ def _like(t):
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                scale, blk, n):
+                scale, blk, n, window=None):
     # grid (batch, key/value head, key block j, t): a key block meets, for
     # each query head of its group in turn, the query blocks at or after it
+    # (under a window: the ``band`` blocks from it on, those that exist)
     if len(refs) == 6:
         q2_ref = k2_ref = None
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
@@ -329,7 +465,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         (q2_ref, k2_ref, dq_ref, dk_ref, dv_ref, dq2_ref, dk2_ref,
          dq_acc, dk_acc, dv_acc, dq2_acc, dk2_acc) = refs
     h, j, t = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    g, i = t // n, t % n
+    if window is None:
+        g, i = t // n, t % n
+    else:
+        band = _band(window, blk)
+        g = t // band
+        ahead, there, i = _ahead(j, t, n, blk, window)
     group, dim = dq_acc.shape[0], dq_acc.shape[-1]
     keys = pl.ds(pl.multiple_of(j * blk, blk), blk)
     queries = pl.ds(pl.multiple_of(i * blk, blk), blk)
@@ -339,7 +480,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(j == 0)        # a query block's first key block
+    # a query block's first key block: block 0, or where its band starts
+    @pl.when(j == 0 if window is None
+             else there & ((ahead == band - 1) | (j == 0)))
     def _():
         dq_acc[g, queries] = jnp.zeros((blk, dim), _F32)
         if q2_ref is not None:
@@ -350,20 +493,22 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         def _():
             dk2_acc[keys] = jnp.zeros((blk, dk2_acc.shape[-1]), _F32)
 
-    def block(diagonal):
+    def block(diagonal, window=None):
         dst = _keys_side(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          q2_ref, k2_ref, dk_acc, dv_acc, i, j, blk, scale,
-                         diagonal)
+                         diagonal, window)
         dq_acc[g, queries] += _turned_product(dst, k_ref[...])
         if q2_ref is not None:
             dk2_acc[keys] += _product(dst, q2_ref[...])
             dq2_acc[g, queries] += _turned_product(dst, k2_ref[...])
 
-    pl.when(i > j)(functools.partial(block, False))
+    _off_diagonal(block, i > j if window is None else there & (ahead > 0),
+                  i, j, blk, window)
 
-    @pl.when(i == j)        # a query block's last key block
+    # a query block's last key block
+    @pl.when(i == j if window is None else ahead == 0)
     def _():
-        block(True)
+        block(True, _on_diagonal(blk, window))
         dq = (dq_acc[g, queries] * scale).astype(dq_ref.dtype)
         for head in range(group):
             @pl.when(g == head)
@@ -413,16 +558,18 @@ def resident_bytes(padded, group, dim, d2, itemsize):
 
 
 def backward(q, k, v, out, lse, dout, num_heads, num_kv_heads, scale,
-             interpret=False, extra=None):
+             interpret=False, extra=None, window=None):
     """``(dq, dk, dv)`` from what ``forward`` took and gave and the
     output's cotangent; with ``extra``, ``(dq, dk, dv, dq2, dk2)``. One
     fused kernel where a group's float32 ``dQ`` over all the rows fits in
     VMEM (``resident_bytes`` against ``_RESIDENT_LIMIT_BYTES``), else the
-    kernel for the queries' side and the kernel for the keys' side."""
+    kernel for the queries' side and the kernel for the keys' side.
+    ``window``: ``forward``'s."""
+    _no_second_part(extra, window)
     hq, group = int(num_heads), int(num_heads) // int(num_kv_heads)
     bsz, length, _ = q.shape
     dim = q.shape[-1] // hq
-    blk, padded = block_size(length)
+    blk, padded = block_size(length, window)
     delta = jnp.sum((dout.astype(_F32) * out.astype(_F32)).reshape(
         bsz, length, hq, dim), axis=-1).transpose(0, 2, 1)
     q, k, v, dout = (_padded(t, 1, padded) for t in (q, k, v, dout))
@@ -436,7 +583,8 @@ def backward(q, k, v, out, lse, dout, num_heads, num_kv_heads, scale,
     if fused:
         dout = counted_site(dout, FUSED_BWD_GAUGE)
     grads = (_backward_fused if fused else _backward_by_side)(
-        (q, k, v, dout, lse, delta), extra, group, dim, blk, scale, interpret)
+        (q, k, v, dout, lse, delta), extra, group, dim, blk, scale, interpret,
+        window)
     dq, dk, dv = (t[:, :length] for t in grads[:3])
     if extra is None:
         return dq, dk, dv
@@ -445,7 +593,8 @@ def backward(q, k, v, out, lse, dout, num_heads, num_kv_heads, scale,
     return dq, dk, dv, dq2, grads[4][:, :length]
 
 
-def _backward_fused(operands, extra, group, dim, blk, scale, interpret):
+def _backward_fused(operands, extra, group, dim, blk, scale, interpret,
+                    window=None):
     """The fused kernel over ``backward``'s padded operands ``(q, k, v,
     dout, lse, delta)`` and second part ``(q2 head-major, k2)``: ``(dq,
     dk, dv)`` and with a second part ``dq2`` head-major and ``dk2``,
@@ -455,8 +604,10 @@ def _backward_fused(operands, extra, group, dim, blk, scale, interpret):
     n = padded // blk
     head, row = functools.partial(_head_spec, blk, dim), \
         functools.partial(_row_spec, blk)
+    band = None if window is None else _band(window, blk)
+    steps = band or n       # of a query head at a key block
     q_at, k_at, row_at, q2_at, k2_at = _keys_side_at(
-        n, lambda h, t: h * group + t // n, lambda h, t: h)
+        n, lambda h, t: h * group + t // steps, lambda h, t: h, band)
     in_specs = [head(q_at), head(k_at), head(k_at), head(q_at),
                 row(row_at), row(row_at)]
     # a group's ``dQ`` is one block of all the rows, written a query
@@ -479,8 +630,10 @@ def _backward_fused(operands, extra, group, dim, blk, scale, interpret):
                     pltpu.VMEM((padded, d2), _F32)]
     # ``dk2`` adds up over the heads, so with a second part they run in turn
     return _call(
-        functools.partial(_bwd_kernel, scale=scale, blk=blk, n=n),
-        "attn_bwd_kernel", (bsz, k.shape[-1] // dim, n, group * n), interpret,
+        functools.partial(_bwd_kernel, scale=scale, blk=blk, n=n,
+                          window=window),
+        _named("bwd_kernel", window),
+        (bsz, k.shape[-1] // dim, n, group * steps), interpret,
         semantics=("parallel", "parallel" if extra is None else "arbitrary",
                    "arbitrary", "arbitrary"),
         in_specs=in_specs, out_specs=out_specs,
@@ -493,7 +646,7 @@ def _backward_fused(operands, extra, group, dim, blk, scale, interpret):
 # (seven products and two passes of vector work where the fused kernel has
 # five and one), and holds one block's sums whatever the length.
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-               scale, blk):
+               scale, blk, window=None):
     if len(refs) == 2:
         (dq_ref, acc_ref), q2_ref, k2_ref = refs, None, None
     else:
@@ -506,10 +659,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         if q2_ref is not None:
             acc2_ref[...] = jnp.zeros_like(acc2_ref)
 
-    def block(diagonal):
+    def block(diagonal, window=None):
         k, v = k_ref[...], v_ref[...]
         s = _scores(q_ref[...], k, i, j, blk, scale, diagonal,
-                    **_second(q2_ref, k2_ref))
+                    window=window, **_second(q2_ref, k2_ref))
         p = jnp.exp(s - jnp.expand_dims(lse_ref[0], -1))
         dp = lax.dot_general(do_ref[...], v, _NT,
                              preferred_element_type=_F32)
@@ -519,27 +672,32 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
             acc2_ref[...] += jnp.dot(ds, k2_ref[...],
                                      preferred_element_type=_F32)
 
-    pl.when(j < i)(functools.partial(block, False))
+    j, before = _band_step(i, j, blk, window)
+    _off_diagonal(block, before, i, j, blk, window)
 
     @pl.when(j == i)
     def _():
-        block(True)
+        block(True, _on_diagonal(blk, window))
         dq_ref[...] = (acc_ref[...] * scale).astype(dq_ref.dtype)
         if q2_ref is not None:
             dq2_ref[...] = (acc2_ref[...] * scale).astype(dq2_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
-                scale, blk, n, span):
+                scale, blk, n, span, window=None):
     # grid (batch, 1, key block j, t): a key block meets every query head
     # in turn, ``span`` steps to a key/value head, each at the query
-    # blocks at or after it
+    # blocks at or after it (under a window: the ``band`` blocks from it
+    # on, those that exist)
     if len(refs) == 4:
         (dk_ref, dv_ref, dk_acc, dv_acc), q2_ref, k2_ref = refs, None, None
     else:
         q2_ref, k2_ref, dk_ref, dv_ref, dk2_ref, dk_acc, dv_acc, dk2_acc = refs
     j, t = pl.program_id(2), pl.program_id(3)
-    i = t % n
+    if window is None:
+        i = t % n
+    else:
+        ahead, there, i = _ahead(j, t, n, blk, window)
 
     @pl.when(t % span == 0)
     def _():
@@ -551,16 +709,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         def _():
             dk2_acc[...] = jnp.zeros_like(dk2_acc)
 
-    def block(diagonal):
+    def block(diagonal, window=None):
         dst = _keys_side(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          q2_ref, k2_ref, dk_acc, dv_acc, i, j, blk, scale,
-                         diagonal)
+                         diagonal, window)
         if q2_ref is not None:
             dk2_acc[...] += jnp.dot(dst, q2_ref[...],
                                     preferred_element_type=_F32)
 
-    pl.when(i > j)(functools.partial(block, False))
-    pl.when(i == j)(functools.partial(block, True))
+    _off_diagonal(block, i > j if window is None else there & (ahead > 0),
+                  i, j, blk, window)
+    pl.when(i == j if window is None else ahead == 0)(functools.partial(
+        block, True, _on_diagonal(blk, window)))
 
     @pl.when(t % span == span - 1)
     def _():
@@ -573,12 +733,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
             dk2_ref[...] = (dk2_acc[...] * scale).astype(dk2_ref.dtype)
 
 
-def _backward_by_side(operands, extra, group, dim, blk, scale, interpret):
+def _backward_by_side(operands, extra, group, dim, blk, scale, interpret,
+                      window=None):
     """``_backward_fused``'s results from the two kernels."""
     q, k, v = operands[:3]
     bsz, padded, _ = q.shape
     n, hq = padded // blk, q.shape[-1] // dim
-    kv_at = functools.partial(_kv_at, group)
+    steps, kv_at = _queries_side(n, blk, group, window)
     head, row = functools.partial(_head_spec, blk, dim), \
         functools.partial(_row_spec, blk)
     extra = extra or ()
@@ -586,21 +747,23 @@ def _backward_by_side(operands, extra, group, dim, blk, scale, interpret):
     sums2 = [pltpu.VMEM((blk, d2), _F32)] if extra else []
     second = _extra_specs(blk, d2, _q2_at, _k2_at) if extra else []
     dq = _call(
-        functools.partial(_dq_kernel, scale=scale, blk=blk),
-        "attn_bwd_dq_kernel", (bsz, hq, n, n), interpret,
+        functools.partial(_dq_kernel, scale=scale, blk=blk, window=window),
+        _named("bwd_dq_kernel", window), (bsz, hq, n, steps), interpret,
         in_specs=[head(_q_at), head(kv_at), head(kv_at), head(_q_at),
                   row(_row_at), row(_row_at)] + second,
         out_specs=[head(_q_at)] + second[:1],
         out_shape=[_like(q)] + [_like(t) for t in extra[:1]],
         scratch_shapes=[pltpu.VMEM((blk, dim), _F32)] + sums2)(
             *operands, *extra)
-    span = group * n
+    span = group * steps
     q_at, k_at, row_at, q2_at, k2_at = _keys_side_at(
-        n, lambda _, t: t // n, lambda _, t: t // span)
+        n, lambda _, t: t // steps, lambda _, t: t // span,
+        None if window is None else steps)
     second = _extra_specs(blk, d2, q2_at, k2_at) if extra else []
     dkv = _call(
-        functools.partial(_dkv_kernel, scale=scale, blk=blk, n=n, span=span),
-        "attn_bwd_dkv_kernel", (bsz, 1, n, hq * n), interpret,
+        functools.partial(_dkv_kernel, scale=scale, blk=blk, n=n, span=span,
+                          window=window),
+        _named("bwd_dkv_kernel", window), (bsz, 1, n, hq * steps), interpret,
         in_specs=[head(q_at), head(k_at), head(k_at), head(q_at),
                   row(row_at), row(row_at)] + second,
         out_specs=[head(k_at), head(k_at)] + second[1:],
@@ -619,9 +782,13 @@ def _backward_by_side(operands, extra, group, dim, blk, scale, interpret):
 # identity whose lowering rule, reached only inside the TPU branch, adds
 # one to a gauge: ``attn::kernel_sites`` for a site that takes the
 # kernels, ``attn::fused_bwd_sites`` for one whose backward is the fused
-# kernel. ``TrainStep`` sets both to zero where it traces its step.
+# kernel, ``attn::window_sites`` for one whose kernels walk a window's
+# band. ``TrainStep`` sets the three to zero where it traces its step.
 GAUGE = "attn::kernel_sites"
 FUSED_BWD_GAUGE = "attn::fused_bwd_sites"
+#: of the sites that take the kernels, those whose kernels walk a window's
+#: band and skip the blocks before it
+WINDOW_GAUGE = "attn::window_sites"
 
 _site_p = Primitive("mx_attn_kernel_site")
 _site_p.def_impl(lambda x, gauge: x)

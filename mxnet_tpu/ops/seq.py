@@ -9,7 +9,8 @@ kernels where the program is lowered for a TPU, ``ops.gmm_kernel``; with
 or without shared experts),
 causal grouped-query attention in blocks (fused kernels where the program
 is lowered for a TPU, ``ops.attn_kernel``) with or without rotary
-position encoding, multi-head latent attention over the same kernels, a
+position encoding, a window and a gate a head, multi-head latent
+attention over the same kernels, a
 gated MLP, and the exit gate and exit-weighted loss of a stack that is
 run several times.
 
@@ -22,7 +23,8 @@ The step's device time is a function of shapes alone: the expert layer's
 receive buffer is static and every row of it is computed, filled or not.
 
 Named scopes (``mx_norm``, ``mx_mamba_proj``, ``mx_ssd_*``, ``mx_gdn_*``,
-``mx_sconv_*``, ``mx_moe_*``, ``mx_attn_*``, ``mx_mla_*``, ``mx_rope``,
+``mx_sconv_*``, ``mx_moe_*``, ``mx_attn_*``, ``mx_swa_fwd``, ``mx_mla_*``,
+``mx_rope``,
 ``mx_mhc_*``, ``mx_gated_mlp``, ``mx_exit_head``, ``mx_exit_gate``) mark
 each mechanism
 in the compiled program, and each operator's registration lists its own;
@@ -1139,7 +1141,8 @@ def rope_frequencies(dim, theta=10000.0, yarn=None):
 
 
 @register_op("RoPE", names_its_parts=True)
-def rope(data, theta=10000.0, rotary_dim=None, yarn=None, **kw):
+def rope(data, theta=10000.0, rotary_dim=None, yarn=None, mscale=None,
+         **kw):
     """Rotary position encoding over the whole head, in the
     ``rotate_half`` convention: with ``x = [x1 | x2]`` the two halves of
     a head, position ``t`` and ``angle_i = t * theta^(-2i/D)`` for ``i <
@@ -1147,12 +1150,17 @@ def rope(data, theta=10000.0, rotary_dim=None, yarn=None, **kw):
     below ``D`` the head's first ``rotary_dim`` elements are rotated so,
     as a head of that width, and the rest go through as they are. With
     ``yarn = (factor, original length, beta_fast, beta_slow)`` the
-    frequencies are YaRN's (``rope_frequencies``); cos and sin are not
-    scaled. ``data``: (B, L, H, D), position = index along ``L``; the
-    angles and the rotation in float32, the result in ``data``'s dtype."""
+    frequencies are YaRN's (``rope_frequencies``). With ``mscale`` cos
+    and sin are multiplied by it in float32 (YaRN's attention factor
+    where a configuration puts it on the rotation): the rotated elements
+    alone are scaled, so under ``rotary_dim`` the rotated part of a score
+    carries its square and the rest does not. ``data``: (B, L, H, D),
+    position = index along ``L``; the angles and the rotation in float32,
+    the result in ``data``'s dtype."""
     if rotary_dim is not None and int(rotary_dim) < data.shape[-1]:
         r = int(rotary_dim)
-        return jnp.concatenate([rope(data[..., :r], theta, yarn=yarn),
+        return jnp.concatenate([rope(data[..., :r], theta, yarn=yarn,
+                                     mscale=mscale),
                                 data[..., r:]], axis=-1)
     length, d = data.shape[1], data.shape[-1]
     half = d // 2
@@ -1160,6 +1168,8 @@ def rope(data, theta=10000.0, rotary_dim=None, yarn=None, **kw):
         inv = rope_frequencies(d, theta, yarn)
         ang = jnp.arange(length, dtype=_F32)[:, None] * inv[None, :]
         cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+        if mscale is not None:
+            cos, sin = cos * _F32(mscale), sin * _F32(mscale)
         x = data.astype(_F32)
         x1, x2 = x[..., :half], x[..., half:]
         out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -1167,27 +1177,37 @@ def rope(data, theta=10000.0, rotary_dim=None, yarn=None, **kw):
         return out.astype(data.dtype)
 
 
-def _blocked_attention(q, k, v, blk, scale, with_lse=False):
+def _blocked_attention(q, k, v, blk, scale, with_lse=False, window=None):
     """The blocked recurrence in plain JAX: blocks of ``blk`` queries
     against the blocks of keys at or before them, accumulated by the
     running maximum and denominator; blocks past the diagonal are never
-    formed. ``q``, ``k``, ``v``: (B, L, H, D), as many heads each. Returns
-    (B, L, H, D) float32, and ``with_lse`` each row's log-sum-exp (B, H,
-    L) beside it."""
+    formed. With ``window`` a query sees that many keys, its own the
+    last: key blocks wholly before a query block's window are not formed
+    either, and the block its edge crosses is masked (scope
+    ``mx_swa_fwd``). ``q``, ``k``, ``v``: (B, L, H, D), as many heads
+    each. Returns (B, L, H, D) float32, and ``with_lse`` each row's
+    log-sum-exp (B, H, L) beside it."""
     length = q.shape[1]
     outs, lses = [], []
-    with jax.named_scope("mx_attn_fwd"):
+    with _attention_scope(window):
         for i0 in range(0, length, blk):
             i1 = min(i0 + blk, length)
             qi = q[:, i0:i1]
             o = m = l = None
-            for j0 in range(0, i1, blk):
+            # the block that holds the first key the block's first query sees
+            start = 0 if window is None \
+                else max(i0 - window + 1, 0) // blk * blk
+            for j0 in range(start, i1, blk):
                 j1 = min(j0 + blk, i1)
-                bias = None
+                seen = None
                 if j1 > i0:     # the block on the diagonal
-                    bias = jnp.where(
-                        jnp.arange(i0, i1)[:, None]
-                        >= jnp.arange(j0, j1)[None, :], 0.0, _NEG)
+                    seen = jnp.arange(i0, i1)[:, None] \
+                        >= jnp.arange(j0, j1)[None, :]
+                if window is not None and i1 - 1 - j0 >= window:
+                    inside = jnp.arange(i0, i1)[:, None] \
+                        - jnp.arange(j0, j1)[None, :] < window
+                    seen = inside if seen is None else seen & inside
+                bias = None if seen is None else jnp.where(seen, 0.0, _NEG)
                 o_j, m_j, l_j = _attention_block(
                     qi, k[:, j0:j1], v[:, j0:j1], bias, scale)
                 if o is None:
@@ -1206,7 +1226,7 @@ def _blocked_attention(q, k, v, blk, scale, with_lse=False):
     return (out, jnp.concatenate(lses, axis=-1)) if with_lse else out
 
 
-def _blocked_rows(q, k, v, hq, hk, scale, blk, extra=None):
+def _blocked_rows(q, k, v, hq, hk, scale, blk, extra=None, window=None):
     """``_blocked_attention`` over rows of heads: ``q`` (B, L, hq * D),
     ``k``, ``v`` (B, L, hk * D); with ``extra = (q2 (B, L, hq * D2), k2
     (B, L, D2))`` every head's query and key are ``[q | q2]`` and ``[k |
@@ -1221,45 +1241,58 @@ def _blocked_rows(q, k, v, hq, hk, scale, blk, extra=None):
         qh = jnp.concatenate([qh, q2.reshape(bsz, length, hq, -1)], axis=-1)
         k = jnp.concatenate([k, jnp.broadcast_to(
             k2[:, :, None], (bsz, length, hq, k2.shape[-1]))], axis=-1)
-    out, lse = _blocked_attention(qh, k, v, blk, scale, with_lse=True)
+    out, lse = _blocked_attention(qh, k, v, blk, scale, with_lse=True,
+                                  window=window)
     return out.reshape(bsz, length, -1).astype(q.dtype), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _fused_attention(q, k, v, extra, hq, hk, scale, blk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _fused_attention(q, k, v, extra, hq, hk, scale, blk, window=None):
     """Attention over rows of heads whose program takes its form when it
     is lowered: for a TPU the kernels of ``ops.attn_kernel``, forward and
     backward, for any other platform the blocked recurrence and JAX's own
     derivative of it. ``extra``: nothing, or the scores' second part
-    ``(q2, k2)`` (``_blocked_rows``). Either way the unit around it keeps
+    ``(q2, k2)`` (``_blocked_rows``). ``window``: nothing, or how many
+    keys a query sees, its own the last; both forms then skip the key
+    blocks before it, under the scope ``mx_swa_fwd`` in ``mx_attn_fwd``'s
+    place. Either way the unit around it keeps
     the output and one float32 log-sum-exp a row, and its backward pass
     runs no forward a second time where the kernels are."""
-    return _fused_attention_fwd(q, k, v, extra, hq, hk, scale, blk)[0]
+    return _fused_attention_fwd(q, k, v, extra, hq, hk, scale, blk,
+                                window)[0]
 
 
-def _fused_attention_fwd(q, k, v, extra, hq, hk, scale, blk):
-    with jax.named_scope("mx_attn_fwd"):
+def _attention_scope(window):
+    return jax.named_scope("mx_attn_fwd" if window is None else "mx_swa_fwd")
+
+
+def _fused_attention_fwd(q, k, v, extra, hq, hk, scale, blk, window=None):
+    def kernels(q, k, v, *extra):
+        q = attn_kernel.counted_site(q)
+        if window is not None:
+            q = attn_kernel.counted_site(q, attn_kernel.WINDOW_GAUGE)
+        return attn_kernel.forward(q, k, v, hq, hk, scale,
+                                   extra=extra or None, window=window)
+
+    with _attention_scope(window):
         out, lse = lax.platform_dependent(
-            q, k, v, *(extra or ()),
-            tpu=lambda q, k, v, *extra: attn_kernel.forward(
-                attn_kernel.counted_site(q), k, v, hq, hk, scale,
-                extra=extra or None),
+            q, k, v, *(extra or ()), tpu=kernels,
             default=lambda q, k, v, *extra: _blocked_rows(
-                q, k, v, hq, hk, scale, blk, extra or None))
+                q, k, v, hq, hk, scale, blk, extra or None, window))
     out, lse = kept(out), kept(lse)
     return out, (q, k, v, extra, out, lse)
 
 
-def _fused_attention_bwd(hq, hk, scale, blk, res, dout):
+def _fused_attention_bwd(hq, hk, scale, blk, window, res, dout):
     q, k, v, extra, out, lse = res
-    with jax.named_scope("mx_attn_fwd"):
+    with _attention_scope(window):
         grads = lax.platform_dependent(
             q, k, v, out, lse, dout, *(extra or ()),
-            tpu=lambda *a: attn_kernel.backward(*a[:6], hq, hk, scale,
-                                                extra=a[6:] or None),
+            tpu=lambda *a: attn_kernel.backward(
+                *a[:6], hq, hk, scale, extra=a[6:] or None, window=window),
             default=lambda q, k, v, out, lse, dout, *extra: jax.vjp(
                 lambda q, k, v, *extra: _blocked_rows(
-                    q, k, v, hq, hk, scale, blk, extra or None)[0],
+                    q, k, v, hq, hk, scale, blk, extra or None, window)[0],
                 q, k, v, *extra)[1](dout))
     return tuple(grads[:3]) + (tuple(grads[3:]) or None,)
 
@@ -1272,14 +1305,20 @@ def causal_gq_attention(data, q_norm_weight=None, k_norm_weight=None,
                         num_heads=1, num_kv_heads=1, head_dim=128,
                         block=1024, scale=None, rope_theta=None,
                         rotary_dim=None, gated=False, eps=1e-6,
-                        unit_offset=False, **kw):
+                        unit_offset=False, window=None, head_gate=False,
+                        yarn=None, mscale=None, **kw):
     """Causal attention over packed ``[q | k | v]`` rows, ``num_heads``
     query heads sharing ``num_kv_heads`` key/value heads; with
     ``rope_theta`` the queries and keys are rotated by their position
     first (``rope``; over a head's first ``rotary_dim`` elements alone
-    where that is given), without it there is no positional encoding. The
+    where that is given; with ``yarn`` at YaRN's frequencies, with
+    ``mscale`` the rotated elements multiplied by it), without it there
+    is no positional encoding. The
     scores and every sum in float32, the probabilities in ``data``'s
     dtype for the weighted sum, scale ``head_dim ** -0.5`` unless given.
+    ``window``: query ``t`` sees the keys ``t - window < j <= t`` alone
+    (``window`` keys, its own among them); a window that reaches the
+    whole length is none.
 
     With ``q_norm_weight`` and ``k_norm_weight`` (head_dim,) every query
     head and every key head goes through an RMSNorm of its own width
@@ -1287,10 +1326,17 @@ def causal_gq_attention(data, q_norm_weight=None, k_norm_weight=None,
     ``mx_attn_qk_norm``). ``gated``: the rows are ``[q | k | v | gate]``,
     the gate as wide as the queries, and each head's output is multiplied
     by ``sigmoid`` of its gate, in float32 (scope ``mx_attn_gate``).
+    ``head_gate``: the rows are ``[q | k | v | gate]`` with ONE gate a
+    head, ``num_heads`` wide, and all of a head's output is multiplied by
+    ``sigmoid`` of it, in float32, under the same scope (the head-wise
+    gate after the weighted sum of arXiv:2505.06708; rows of the packed
+    projection and no weight of their own: one product, and the unit
+    keeps the gates' logits with the packed rows).
 
     Two forms of one recurrence (blocks of queries against the blocks of
     keys at or before them, a running maximum and denominator, blocks
-    past the diagonal never formed), chosen by what the program can see,
+    past the diagonal never formed, nor under a ``window`` those wholly
+    before it), chosen by what the program can see,
     not by the caller. Where ``head_dim`` is a multiple of 128 and the
     program is lowered for a TPU, one fused kernel forward and, backward,
     one fused kernel or, where a group's float32 ``dQ`` over all the rows
@@ -1302,7 +1348,12 @@ def causal_gq_attention(data, q_norm_weight=None, k_norm_weight=None,
     Everywhere else (another ``head_dim``, another backend) the
     recurrence in plain JAX over blocks of ``block`` rows
     (``parallel.ring.local_attention_block``'s), differentiated by JAX. No
-    result depends on ``block``.
+    result depends on ``block``. So a site's backward takes one of three
+    forms, with a window and without: the fused kernel, the kernel pair
+    by side, or JAX's derivative of the plain recurrence; under a window
+    each walks the band alone (the kernels' grid is the band's; the plain
+    form starts at the band's first block), under the scope
+    ``mx_swa_fwd`` where a site without one has ``mx_attn_fwd``.
 
     A recomputation unit around it keeps the packed rows, the output and,
     where ``head_dim`` is a multiple of 128, one float32 log-sum-exp a
@@ -1313,8 +1364,8 @@ def causal_gq_attention(data, q_norm_weight=None, k_norm_weight=None,
     second score part: ``latent_attention``.)
 
     ``data``: (B, L, (num_heads + 2 num_kv_heads) * head_dim), and
-    ``num_heads * head_dim`` more where ``gated``. Returns (B, L,
-    num_heads * head_dim)."""
+    ``num_heads * head_dim`` more where ``gated``, ``num_heads`` more
+    where ``head_gate``. Returns (B, L, num_heads * head_dim)."""
     hq, hk, dh = int(num_heads), int(num_kv_heads), int(head_dim)
     bsz, length, _ = data.shape
     data = kept(data)       # the projection's output, named where it is read
@@ -1327,21 +1378,27 @@ def causal_gq_attention(data, q_norm_weight=None, k_norm_weight=None,
             q = _rms_norm(q, q_norm_weight, eps, unit_offset=unit_offset)
             k = _rms_norm(k, k_norm_weight, eps, unit_offset=unit_offset)
     if rope_theta is not None:
-        q, k = (rope(t, rope_theta, rotary_dim) for t in (q, k))
+        q, k = (rope(t, rope_theta, rotary_dim, yarn, mscale)
+                for t in (q, k))
     blk = min(int(block), length)
     scale = scale if scale is not None else dh ** -0.5
+    if window is not None:
+        window = int(window) if int(window) < length else None
     if dh % 128 == 0:
         out = _fused_attention(
             q.reshape(bsz, length, hq * dh), k.reshape(bsz, length, hk * dh),
-            v.reshape(bsz, length, hk * dh), None, hq, hk, float(scale), blk)
+            v.reshape(bsz, length, hk * dh), None, hq, hk, float(scale), blk,
+            window)
     else:
         k = jnp.repeat(k, hq // hk, axis=2)
         v = jnp.repeat(v, hq // hk, axis=2)
-        out = _blocked_attention(q, k, v, blk, scale)
+        out = _blocked_attention(q, k, v, blk, scale, window=window)
         out = kept(out.reshape(bsz, length, hq * dh).astype(data.dtype))
-    if gated:
+    if gated or head_gate:
         with jax.named_scope("mx_attn_gate"):
             gate = jax.nn.sigmoid(data[..., (hq + 2 * hk) * dh:].astype(_F32))
+            if head_gate:       # one number a head: over its elements
+                gate = jnp.repeat(gate, dh, axis=-1)
             out = (out.astype(_F32) * gate).astype(out.dtype)
     return out
 
@@ -1355,7 +1412,7 @@ def latent_attention(data, q_weight, kv_down_weight, kv_norm_weight,
                      q_norm_weight=None, num_heads=1, nope_dim=128,
                      rope_dim=64, v_dim=128, latent_dim=512,
                      rope_theta=10000.0, eps=1e-5, block=1024, scale=None,
-                     yarn=None, **kw):
+                     yarn=None, mscale=None, **kw):
     """Causal multi-head latent attention over the ``num_heads`` heads
     held here. Keys and values are expanded from one ``latent_dim``-wide
     vector a token; a head's score is ``q_nope . k_nope + q_pe . k_pe``
@@ -1364,7 +1421,8 @@ def latent_attention(data, q_weight, kv_down_weight, kv_norm_weight,
     rope_dim) ** -0.5`` unless given (a model whose positions are
     stretched multiplies it by ``yarn_mscale`` squared); values are
     ``v_dim`` wide. ``yarn``: the rotation's frequencies are YaRN's
-    (``rope_frequencies``).
+    (``rope_frequencies``); ``mscale``: cos and sin are multiplied by it
+    (``rope``).
 
     With ``q_down_weight`` (q_latent, hidden) and ``q_norm_weight``
     (q_latent,) the queries come through a latent of their own: ``c_q =
@@ -1411,9 +1469,9 @@ def latent_attention(data, q_weight, kv_down_weight, kv_norm_weight,
         kv = _mm(c, kv_up_weight)
     with jax.named_scope("mx_mla_rope"):
         q_pe = rope(q[..., h * dn:].reshape(bsz, length, h, dr), rope_theta,
-                    yarn=yarn)
+                    yarn=yarn, mscale=mscale)
         k_pe = rope(ckv[..., latent_dim:].reshape(bsz, length, 1, dr),
-                    rope_theta, yarn=yarn)
+                    rope_theta, yarn=yarn, mscale=mscale)
     blk = min(int(block), length)
     scale = float((dn + dr) ** -0.5 if scale is None else scale)
     q_nope, k_nope, v = q[..., :h * dn], kv[..., :h * dn], kv[..., h * dn:]

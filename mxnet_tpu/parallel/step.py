@@ -273,6 +273,7 @@ class TrainStep:
             # that follows counts the sites it gives the kernels
             _telemetry.gauge(_attn_kernel.GAUGE).set(0)
             _telemetry.gauge(_attn_kernel.FUSED_BWD_GAUGE).set(0)
+            _telemetry.gauge(_attn_kernel.WINDOW_GAUGE).set(0)
             _telemetry.gauge(_gmm_kernel.GAUGE).set(0)
             _telemetry.gauge(_moe_rows_kernel.GAUGE).set(0)
             _telemetry.gauge(_gdn_kernel.GAUGE).set(0)

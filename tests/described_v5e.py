@@ -182,7 +182,8 @@ def compiled_step(name):
             spec((tokens,), jnp.int32), spec((), jnp.uint32),
             spec(())).compile()
     gauges = {g: mx.telemetry.gauge(g).get() for g in (
-        attn_kernel.GAUGE, attn_kernel.FUSED_BWD_GAUGE, gmm_kernel.GAUGE,
+        attn_kernel.GAUGE, attn_kernel.FUSED_BWD_GAUGE,
+        attn_kernel.WINDOW_GAUGE, gmm_kernel.GAUGE,
         gdn_kernel.GAUGE, gdn_conv_kernel.GAUGE, mhc_kernel.GAUGE,
         moe_rows_kernel.GAUGE, seq.MHC_GAUGE)}
     kept = {k.rsplit("::", 1)[1]: v["value"] for k, v in

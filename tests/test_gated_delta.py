@@ -231,7 +231,7 @@ def test_block_and_pattern_kind():
     gammas = [p for n, p in net.collect_params().items()
               if n.endswith("gamma")]
     assert len(gammas) == 3
-    with pytest.raises(ValueError, match=r"M, E, \*, G, L, F, D and"):
+    with pytest.raises(ValueError, match=r"M, E, \*, G, L, F, D, C and W are known"):
         PatternLM("X", 32, 16)
 
 

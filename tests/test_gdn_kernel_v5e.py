@@ -45,9 +45,10 @@ def test_mosaic_takes_the_rule_s_kernels_three_a_linear_layer():
     step = compiled_step(CELL)
     sizes = step.sizes
     assert step.gauges == {attn_kernel.GAUGE: 1, attn_kernel.FUSED_BWD_GAUGE: 0,
-                      gmm_kernel.GAUGE: 1, gdn_kernel.GAUGE: 1,
-                      gdn_conv_kernel.GAUGE: 1, mhc_kernel.GAUGE: 0,
-                      moe_rows_kernel.GAUGE: 1, seq.MHC_GAUGE: 0}
+                           attn_kernel.WINDOW_GAUGE: 0,
+                           gmm_kernel.GAUGE: 1, gdn_kernel.GAUGE: 1,
+                           gdn_conv_kernel.GAUGE: 1, mhc_kernel.GAUGE: 0,
+                           moe_rows_kernel.GAUGE: 1, seq.MHC_GAUGE: 0}
     calls = collections.Counter(step.calls.values())
     linear, layers = _linear_layers(sizes), sizes["num_hidden_layers"]
     assert calls == {

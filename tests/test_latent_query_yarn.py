@@ -139,10 +139,19 @@ def test_the_softmax_scale_is_the_published_rule():
     assert ref.softmax_scale(dict(SMALL, qk_nope_head_dim=128,
                                   qk_rope_head_dim=64)) \
         == pytest.approx(block._attrs["scale"], rel=1e-6)
-    # cos and sin are not scaled here: a group that would scale them is
-    # refused, and so is a rule that is not YaRN's
-    with pytest.raises(ValueError, match="mscale"):
-        nn.LatentAttention(64, 2, rope_scaling=dict(YARN, mscale=2))
+    # where mscale equals mscale_all_dim the softmax carries all of the
+    # factor and cos and sin none; a group whose two differ puts their
+    # ratio on cos and sin (``rope``'s ``mscale``), and one that gives
+    # ``attention_factor`` puts that there; a rule that is not YaRN's is
+    # refused
+    assert "mscale" not in block._attrs
+    both = nn.LatentAttention(64, 2, rope_scaling=dict(YARN, mscale=2))
+    assert both._attrs["mscale"] == pytest.approx(
+        seq.yarn_mscale(64, 2) / seq.yarn_mscale(64, 1))
+    assert both._attrs["scale"] == pytest.approx(
+        192 ** -0.5 * seq.yarn_mscale(64, 1) ** 2)
+    assert nn.LatentAttention(64, 2, rope_scaling=dict(
+        YARN, attention_factor=1.25))._attrs["mscale"] == 1.25
     with pytest.raises(ValueError, match="yarn"):
         nn.LatentAttention(64, 2, rope_scaling=dict(YARN, type="linear"))
     # no mscale_all_dim: the plain scale

@@ -168,7 +168,7 @@ def test_block_holds_three_leaves_and_pattern_lm_knows_its_letter():
     kinds = [type(layer.mixer).__name__ for layer in
              net.stack._children.values() if hasattr(layer, "mixer")]
     assert kinds == ["GatedShortConv", "GatedMLP"]
-    with pytest.raises(ValueError, match=r"F, D and C are known"):
+    with pytest.raises(ValueError, match=r"F, D, C and W are known"):
         PatternLM("CQ", 50, HIDDEN)
 
 
@@ -340,7 +340,8 @@ def test_the_block_at_64_wide_heads_is_the_reference_s():
     block.initialize(mx.init.Zero())
     w = _att_weights(ATT)
     for k, p in block.collect_params().items():
-        p.set_data(mx.nd.array(w[k.split("attention0_", 1)[1]]))
+        # (the block's number counts every GQAttention of the process)
+        p.set_data(mx.nd.array(w[k.split("_", 1)[1]]))
     x = _normal(1, (2, 9, 48))
     np.testing.assert_allclose(block(mx.nd.array(x)).asnumpy(),
                                _plain_attention(w, x, ATT), rtol=1e-4,
