@@ -1,15 +1,23 @@
-"""The gated delta rule as kernels (Pallas on Mosaic), for ``ops.seq
-.gated_delta_rule`` where its program is lowered for a TPU and the heads
-are whole lane tiles: one forward kernel and one backward kernel under one
-``custom_vjp``.
+"""The gated delta rule and the gated norm after it as kernels (Pallas on
+Mosaic), for ``ops.seq.gated_delta_net`` where its program is lowered for
+a TPU and the heads are whole lane tiles: one forward kernel and one
+backward kernel, under the mixer's one ``custom_vjp``
+(``ops.seq._mixer_kernels``). What they compute is ``y = rmsnorm(o) * w *
+silu(z)`` a value head, ``o`` ``ops.seq.gated_delta_rule``'s output.
 
 The arrays stay as the mixer wrote them: ``q`` and ``k`` are (B, L, G * N),
 ``v`` (B, L, H * P), and a key head with the ``H / G`` value heads it
 serves is the (rows, N) and (rows, H / G * P) window at column ``j`` of a
 block of rows, so nothing is transposed to (B, H, L, .) on the way in or
-out. A value head's ``beta`` and the running sum ``G`` of its log decay
-inside a chunk (a cumulative sum XLA makes of a (B, L, H) array) come as
-rows, (B, H, 1, L), and are turned to columns in the kernel.
+out. The gate's ``z`` is read where the mixer's projection wrote it: the
+last H * P columns of the packed rows (B, L, 2 G N + 2 H P), windowed at
+their offset, a whole number of a key head's H / G * P columns (32 blocks
+of 256 into 12288 at the Qwen3-Next cell's heads), so no slice of ``z`` is
+copied out; ``y`` and its cotangent are (B, L, H * P) in the compute
+dtype, as the output product reads and writes them. A value head's
+``beta`` and the running sum ``G`` of its log decay inside a chunk (a
+cumulative sum XLA makes of a (B, L, H) array) come as rows, (B, H, 1, L),
+and are turned to columns in the kernel.
 
 The grid is (batch, key head, blocks of ``step_rows`` rows: four chunks of
 64), the blocks in turn. A value head's (N, P) float32 state lives in a VMEM scratch from
@@ -48,7 +56,14 @@ six, whose halves are added into each other's place by one turn of the
 lanes, which also leaves the result held twice; the operands are selects
 between pieces, nothing else is moved across the lanes.
 
-Nothing a chunk forms goes to memory except ``out`` and what the backward
+**The gated norm** is taken where a chunk's ``out`` (chunk, P) of one
+value head is still in VMEM, exactly the rows and columns one norm reads:
+``out rsqrt(mean_P(out^2) + eps) w silu(z)`` in float32, rounded once to
+the compute dtype at the store (where the plain form rounds before the
+output product); SiLU by one ``tanh``. ``out`` itself never goes to
+memory.
+
+Nothing a chunk forms goes to memory except ``y`` and what the backward
 pass reads: each chunk's entering state (N, P) and its ``T`` (chunk,
 chunk), both float32: the state because the chain cannot be run backwards
 without it, ``T`` because it is ten float32 products to form and 16 KB to
@@ -60,15 +75,23 @@ never run it.)
 
 The backward kernel walks the blocks, and the chunks in them, in reverse
 and carries ``dS`` in the same scratch. It reads a chunk's entering state
-and ``T``, forms the decays, ``k k^T``, ``q k^T``, ``[W | U]`` and
-``written`` again, and gives ``dq``, ``dk`` (each summed over the value
-heads that share the key head, in float32, rounded once), ``dv``,
-``dbeta`` and ``dG``. The gradient through the inverse is ``dA = -T^T (dW
+and ``T``, forms the decays, ``k k^T``, ``q k^T``, ``[W | U]``,
+``written`` and ``out`` (two products a unit that the forward made too)
+again, goes back through the gated norm where it loads ``y``'s cotangent
+(``r = rsqrt(mean(out^2) + eps)``, ``o^ = out r``, ``t = dy w silu(z)``:
+``d_out = r (t - o^ mean(t o^))``, rounded as the plain form's products
+round the output's cotangent; ``dz = dy o^ w silu'(z)``, a block of its
+own in ``z``'s dtype; the weight's gradient as row sums of ``dy o^
+silu(z)``, added up over a sequence's steps in one float32 block a batch
+entry and key head, which XLA sums), and gives ``dq``, ``dk`` (each summed
+over the value heads that share the key head, in float32, rounded once),
+``dv``, ``dbeta`` and ``dG``. The gradient through the inverse is ``dA = -T^T (dW
 rhs_w^T + dU rhs_u^T) T^T = -([dRw | dRu]) [W | U]^T`` with ``[dRw | dRu] =
 T^T [dW | dU]``: two float32 products, no second inverse.
 
-The arithmetic is ``gated_delta_rule``'s plain form's: decays, system,
-inverse, state and every sum in float32; the operands of the products that
+The arithmetic is the plain form's (``ops.seq.gated_delta_rule``, then
+``_gated_norm``): decays, system, inverse, state, the norm, SiLU, their
+derivatives and every sum in float32; the operands of the products that
 the plain form rounds to ``v``'s dtype are rounded here, exactly there, and
 a cotangent that meets such an operand in a product is rounded as it (on a
 TPU XLA's default precision does the same to the plain form's
@@ -97,6 +120,7 @@ GAUGE = "gdn::kernel_sites"
 _F32 = jnp.float32
 _BF16 = jnp.bfloat16
 _LANES = 128
+_SUBLANES = 8
 #: the chunks the rule of shapes takes: a diagonal block of 8 rows doubled
 #: a few times, whole sublane tiles, at most a lane tile
 _CHUNKS_TAKEN = (8, 16, 32, 64, 128)
@@ -134,13 +158,14 @@ def steps(length, chunk):
 def forward_bytes(n, p, chunk, group, itemsize):
     """What the forward call names in VMEM for a step of ``step_rows``
     rows and ``group`` value heads a key head: every block twice, for the
-    pipeline (q, k, v, out, both rows of floats, the kept states and
-    inverses), the states' scratch, and what a block's chunks hold before
-    the chain (``W``, ``U`` in float32; the decayed queries and keys and
-    the masked scores in the compute dtype)."""
+    pipeline (q, k, v, the gate's ``z`` and the gated output in the
+    compute dtype, the norm's weight, both rows of floats, the kept states
+    and inverses), the states' scratch, and what a block's chunks hold
+    before the chain (``W``, ``U`` in float32; the decayed queries and
+    keys and the masked scores in the compute dtype)."""
     rows = step_rows(chunk)
     chunks = rows // chunk
-    blocks = rows * (2 * n + group * p) * itemsize + rows * group * p * 4 \
+    blocks = rows * (2 * n + 3 * group * p) * itemsize + p * 4 \
         + 2 * group * rows * 4 \
         + chunks * group * (n * p + chunk * chunk) * 4
     held = rows * group * ((n + p) * 4 + (2 * n + chunk) * itemsize)
@@ -149,29 +174,34 @@ def forward_bytes(n, p, chunk, group, itemsize):
 
 def backward_bytes(n, p, chunk, group, itemsize):
     """What the backward call names in VMEM: every block twice (q, k, v,
-    the output's cotangent, the rows, the states and inverses in; dq, dk,
-    dv and two rows out), ``dS``'s scratch, and a block's recomputed
-    ``W``, ``U``, ``written`` and scores."""
+    ``z``, the gated output's cotangent, the norm's weight, the rows, the
+    states and inverses in; dq, dk, dv, dz, two rows and the weight's row
+    sums out), ``dS``'s scratch, and a block's recomputed ``W``, ``U``,
+    ``written``, ``out`` and scores."""
     rows = step_rows(chunk)
     chunks = rows // chunk
-    blocks = rows * (4 * n + 2 * group * p) * itemsize \
-        + rows * group * p * 4 + 4 * group * rows * 4 \
+    blocks = rows * (4 * n + 5 * group * p) * itemsize \
+        + (1 + _SUBLANES) * p * 4 + 4 * group * rows * 4 \
         + chunks * group * (n * p + chunk * chunk) * 4
-    held = rows * group * ((n + 2 * p) * 4 + (2 * n + chunk) * itemsize)
+    held = rows * group * ((n + 3 * p) * 4 + (2 * n + chunk) * itemsize)
     return 2 * blocks + group * n * p * 4 + held
 
 
-def takes(n, p, chunk, dtype, group=1):
+def takes(n, p, chunk, dtype, group=1, gate_offset=0):
     """Whether the kernels take a rule whose keys are ``n`` wide and
     values ``p``, in chunks of ``chunk`` rows, ``group`` value heads to a
-    key head, the products' operands in ``dtype``: ``n`` and ``p`` whole
-    lane tiles of 128, the chunk 8, 16, 32, 64 or 128 rows and whole
-    sublane tiles of ``dtype`` (8 rows of float32, 16 of bfloat16), and
-    what the calls hold in VMEM under the budget. Shapes alone."""
+    key head, the products' operands in ``dtype``, the gate's ``z`` read
+    ``gate_offset`` columns into the rows that hold it: ``n`` and ``p``
+    whole lane tiles of 128, the chunk 8, 16, 32, 64 or 128 rows and whole
+    sublane tiles of ``dtype`` (8 rows of float32, 16 of bfloat16), the
+    offset whole blocks of a key head's ``group * p`` columns, and what
+    the calls hold in VMEM under the budget. Shapes alone."""
     if jnp.dtype(dtype) not in (jnp.dtype(_BF16), jnp.dtype(_F32)):
         return False
     itemsize = jnp.dtype(dtype).itemsize
     if n <= 0 or p <= 0 or n % _LANES or p % _LANES:
+        return False
+    if group <= 0 or gate_offset % (group * p):
         return False
     if chunk not in _CHUNKS_TAKEN or chunk % (32 // itemsize):
         return False
@@ -411,10 +441,30 @@ def _units(q_ref, k_ref, v_ref, b_ref, g_ref, chunk, group, p):
 
 
 # ---------------------------------------------------------------------------
+# the gated norm
+# ---------------------------------------------------------------------------
+def _normed(out, eps):
+    """``(out r, r)`` with ``r = rsqrt(mean(out^2) + eps)`` over the lanes:
+    a chunk's rows of one value head, each over its ``p`` numbers."""
+    r = lax.rsqrt(jnp.mean(out * out, axis=1, keepdims=True) + eps)
+    return out * r, r
+
+
+def _sigmoid(z):
+    """By one ``tanh``, as ``gdn_conv_kernel`` takes its SiLU."""
+    return 0.5 + 0.5 * jnp.tanh(0.5 * z)
+
+
+def _head(ref, u, p):
+    """A unit's rows and columns of a block of value heads, float32."""
+    return ref[u.rows, u.h * p:(u.h + 1) * p].astype(_F32)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, o_ref, states_ref,
-                inverses_ref, s_ref, *, chunk, group, n, p):
+def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, z_ref, w_ref, o_ref,
+                states_ref, inverses_ref, s_ref, *, chunk, group, n, p, eps):
     dtype = v_ref.dtype
 
     @pl.when(pl.program_id(2) == 0)
@@ -434,6 +484,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, o_ref, states_ref,
     inside = [(u.qk * u.upto)[:, :chunk].astype(dtype) for u in units]
     q_in = [(u.q32 * u.grow).astype(dtype) for u in units]
     k_out = [(u.k32 * u.to_end).astype(dtype) for u in units]
+    weight = w_ref[...]
     # the chain, a chunk's value heads side by side
     for first in range(0, len(units), group):
         mine = range(first, first + group)
@@ -449,20 +500,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, o_ref, states_ref,
                 for i, out, w in zip(mine, outs, written)]
         for i, out, w, state in zip(mine, outs, written, states):
             u = units[i]
-            o_ref[u.rows, u.h * p:(u.h + 1) * p] = out
+            # the gated norm where the head's rows are: rounded once, here
+            z = _head(z_ref, u, p)
+            o_ref[u.rows, u.h * p:(u.h + 1) * p] = (
+                _normed(out, eps)[0] * weight * (z * _sigmoid(z))).astype(
+                    o_ref.dtype)
             s_ref[u.h] = u.leave * state + _dot(k_out[i], w, _TN)
 
 
-def _specs(chunk, chunks, group, n, p, at):
+def _specs(chunk, chunks, group, n, p, at, gate):
     """Block specs by what they window, for a grid (batch, key head,
     block) whose step ``i`` works the block ``at(i)``: a key head's rows,
-    the rows of its value heads, their rows of floats, and their chunks'
-    (a, b) float32 matrices."""
+    the rows of its value heads, the same of the gate's ``z`` (``gate``
+    blocks into the rows that hold it), the norm's weight, their rows of
+    floats, their chunks' (a, b) float32 matrices, and a key head's row
+    sums for the weight (one block for all the steps of a sequence)."""
     rows = chunk * chunks
     return {
         "key": pl.BlockSpec((None, rows, n), lambda b, j, i: (b, at(i), j)),
         "value": pl.BlockSpec((None, rows, group * p),
                               lambda b, j, i: (b, at(i), j)),
+        "gate": pl.BlockSpec((None, rows, group * p),
+                             lambda b, j, i: (b, at(i), gate + j)),
+        "weight": pl.BlockSpec((1, p), lambda b, j, i: (0, 0)),
+        "sums": pl.BlockSpec((None, None, _SUBLANES, p),
+                             lambda b, j, i: (b, j, 0, 0)),
         "floats": pl.BlockSpec((None, group, 1, rows),
                                lambda b, j, i: (b, j, 0, at(i))),
         "chunks": lambda a, b_: pl.BlockSpec(
@@ -484,60 +546,73 @@ def _rows_of_floats(x, heads):
     return x.astype(_F32).transpose(0, 2, 1)[:, :, None]
 
 
-def _operands(q, k, v, beta, g, chunk):
-    """The kernels' operands from the rule's: ``q``, ``k`` (B, L, G * N)
-    and ``v`` (B, L, H * P) padded to whole steps (``steps``; a padded row
-    has ``beta = 0`` and ``g = 0``), ``beta`` and the running sum of ``g``
-    inside each chunk as rows of floats; and the chunks of a step."""
+def _operands(q, k, v, beta, g, z, weight, chunk):
+    """The kernels' operands from the rule's and the gate's: ``q``, ``k``
+    (B, L, G * N) and ``v`` (B, L, H * P) padded to whole steps (``steps``;
+    a padded row has ``beta = 0`` and ``g = 0``), ``beta`` and the running
+    sum of ``g`` inside each chunk as rows of floats, the rows that hold
+    ``z`` in their last H * P columns as they are (a padded tail: those
+    columns alone, padded) and the norm's weight as a float32 row; the
+    chunks of a step; and the block of a key head's columns at which ``z``
+    begins."""
     bsz, length, h, p = v.shape
     padded, rows = steps(length, chunk)
     if padded != length:
-        q, k, v, beta, g = (
+        z = z[..., z.shape[-1] - h * p:]
+        q, k, v, beta, g, z = (
             jnp.pad(t, ((0, 0), (0, padded - length)) + ((0, 0),) * (t.ndim - 2))
-            for t in (q, k, v, beta, g))
+            for t in (q, k, v, beta, g, z))
     run = jnp.cumsum(g.astype(_F32).reshape(bsz, padded // chunk, chunk, h),
                      axis=2).reshape(bsz, padded, h)
+    gate = (z.shape[-1] - h * p) // (h // k.shape[2] * p)
     return (q.reshape(bsz, padded, -1), k.reshape(bsz, padded, -1),
             v.reshape(bsz, padded, -1),
-            _rows_of_floats(beta, h), _rows_of_floats(run, h)), rows // chunk
+            _rows_of_floats(beta, h), _rows_of_floats(run, h), z,
+            weight.astype(_F32).reshape(1, p)), rows // chunk, gate
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def forward(q, k, v, beta, g, chunk, interpret=False):
-    """``(out, states, inverses)``: ``gated_delta_rule``'s output (B, L,
-    H, P) float32 from its operands, and what ``backward`` reads: every
-    chunk's entering state (B, H, chunks, N, P) and inverse (B, H, chunks,
-    chunk, chunk), float32, ``chunks`` those of the padded length
-    (``steps``). (Jitted, as ``backward`` is: a step's like layers and
-    both passes of a recomputation unit then share one trace of the
-    kernel.)"""
+@functools.partial(jax.jit, static_argnames=("chunk", "eps", "interpret"))
+def forward(q, k, v, beta, g, z, weight, chunk, eps, interpret=False):
+    """``(y, states, inverses)``: ``rmsnorm(o) * weight * silu(z)`` a value
+    head, (B, L, H * P) in ``v``'s dtype, with ``o`` ``gated_delta_rule``'s
+    output of ``q``, ``k``, ``v``, ``beta``, ``g``, the norm over a head's
+    ``P`` numbers with ``eps``; and what ``backward`` reads: every chunk's
+    entering state (B, H, chunks, N, P) and inverse (B, H, chunks, chunk,
+    chunk), float32, ``chunks`` those of the padded length (``steps``).
+    ``z`` is the last H * P columns of the rows it comes in, (B, L, >= H *
+    P), which are read where they are (``takes``' ``gate_offset`` is what
+    stands before them); ``weight`` (P,). (Jitted, as ``backward`` is: a
+    step's like layers and both passes of a recomputation unit then share
+    one trace of the kernel.)"""
     bsz, length, h, p = v.shape
     gk, n = k.shape[2], k.shape[3]
     group = h // gk
-    operands, step = _operands(q, k, v, beta, g, chunk)
+    operands, step, gate = _operands(q, k, v, beta, g, z, weight, chunk)
     chunks = operands[0].shape[1] // chunk
-    specs = _specs(chunk, step, group, n, p, lambda i: i)
+    specs = _specs(chunk, step, group, n, p, lambda i: i, gate)
     out, states, inverses = _call(
-        functools.partial(_fwd_kernel, chunk=chunk, group=group, n=n, p=p),
+        functools.partial(_fwd_kernel, chunk=chunk, group=group, n=n, p=p,
+                          eps=eps),
         "gdn_fwd_kernel", (bsz, gk, chunks // step), interpret,
         in_specs=[specs["key"], specs["key"], specs["value"],
-                  specs["floats"], specs["floats"]],
+                  specs["floats"], specs["floats"], specs["gate"],
+                  specs["weight"]],
         out_specs=[specs["value"], specs["chunks"](n, p),
                    specs["chunks"](chunk, chunk)],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, chunks * chunk, h * p), _F32),
+            jax.ShapeDtypeStruct((bsz, chunks * chunk, h * p), v.dtype),
             jax.ShapeDtypeStruct((bsz, h, chunks, n, p), _F32),
             jax.ShapeDtypeStruct((bsz, h, chunks, chunk, chunk), _F32)],
         scratch_shapes=[pltpu.VMEM((group, n, p), _F32)])(*operands)
-    return out[:, :length].reshape(bsz, length, h, p), states, inverses
+    return out[:, :length], states, inverses
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, s_ref, t_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, db_ref, dg_ref, ds_ref, *, chunk,
-                group, n, p):
+def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, z_ref, w_ref, s_ref,
+                t_ref, dy_ref, dq_ref, dk_ref, dv_ref, db_ref, dg_ref, dz_ref,
+                dw_ref, ds_ref, *, chunk, group, n, p, eps):
     dtype = v_ref.dtype
     row, col = _masks(chunk)
     last = (lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
@@ -546,6 +621,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, s_ref, t_ref, do_ref,
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_ref[...] = jnp.zeros_like(ds_ref)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
 
     def total(x):
         return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0,
@@ -581,15 +657,38 @@ def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, s_ref, t_ref, do_ref,
     scores = [u.qk * u.upto for u in units]
     inside = [x.astype(dtype) for x in scores]
     q_in = [u.q32 * u.grow for u in units]
+    q_ins = [x.astype(dtype) for x in q_in]
     k_out = [u.k32 * u.to_end for u in units]
-    dos = [do_ref[u.rows, u.h * p:(u.h + 1) * p].astype(dtype)
-           for u in units]
+    outs = [_dot(q_ins[i], ss[i]) + _dot(inside[i], written[i])
+            for i in count]
+    # the gated norm, backwards: y = (out r) w silu(z), a row's r over its
+    # head's lanes; what reaches the rule is rounded as the plain form's
+    # products round the output's cotangent
+    weight = w_ref[...]
+    dos, d_weight = [], jnp.zeros(dw_ref.shape, _F32)
+    for u, out in zip(units, outs):
+        z, dy = _head(z_ref, u, p), _head(dy_ref, u, p)
+        normed, r = _normed(out, eps)
+        sig = _sigmoid(z)
+        silu = z * sig
+        t = dy * weight * silu
+        dos.append((r * (t - normed * jnp.mean(t * normed, axis=1,
+                                               keepdims=True))).astype(dtype))
+        dyn = dy * normed
+        dz_ref[u.rows, u.h * p:(u.h + 1) * p] = (
+            dyn * weight * (sig * (1.0 + z * (1.0 - sig)))).astype(
+                dz_ref.dtype)
+        # a chunk's rows to one sublane tile: whole registers added up
+        sums = dyn * silu
+        for first in range(0, chunk, _SUBLANES):
+            d_weight = d_weight + sums[first:first + _SUBLANES]
+    dw_ref[...] += d_weight
     # what of the backward does not read dS (above the diagonal d_inside
     # is read by nothing: every use below is times ``upto``)
     d_inside = [_dot(dos[i], written[i], _NT) for i in count]
     d_q_in = [_dot(dos[i], ss[i], _NT) for i in count]
     from_out = [_dot(inside[i], dos[i], _TN) for i in count]
-    to_state = [_dot(q_in[i].astype(dtype), dos[i], _TN) for i in count]
+    to_state = [_dot(q_ins[i], dos[i], _TN) for i in count]
     k_outs = [x.astype(dtype) for x in k_out]
     # the chain, backwards, a chunk's value heads side by side
     d_states, d_written, d_k_out = ([None] * len(units) for _ in range(3))
@@ -640,37 +739,43 @@ def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, g_ref, s_ref, t_ref, do_ref,
             dk_ref[u.rows, :] = dk.astype(dk_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def backward(q, k, v, beta, g, states, inverses, d_out, chunk,
+@functools.partial(jax.jit, static_argnames=("chunk", "eps", "interpret"))
+def backward(q, k, v, beta, g, z, weight, states, inverses, dy, chunk, eps,
              interpret=False):
-    """``(dq, dk, dv, dbeta, dg)`` from the rule's operands, what
-    ``forward`` kept and the output's cotangent (B, L, H, P)."""
+    """``(dq, dk, dv, dbeta, dg, dz, dweight)`` from ``forward``'s
+    operands, what it kept and the cotangent of its ``y`` (B, L, H * P):
+    ``dz`` (B, L, H * P) in ``z``'s dtype, the gate's columns alone."""
     bsz, length, h, p = v.shape
     gk, n = k.shape[2], k.shape[3]
     group = h // gk
-    operands, step = _operands(q, k, v, beta, g, chunk)
+    operands, step, gate = _operands(q, k, v, beta, g, z, weight, chunk)
     padded = operands[0].shape[1]
     chunks = padded // chunk
-    d_out = d_out.astype(_F32).reshape(bsz, length, h * p)
+    dy = dy.astype(v.dtype)
     if padded != length:
-        d_out = jnp.pad(d_out, ((0, 0), (0, padded - length), (0, 0)))
+        dy = jnp.pad(dy, ((0, 0), (0, padded - length), (0, 0)))
     blocks = chunks // step
-    specs = _specs(chunk, step, group, n, p, lambda i: blocks - 1 - i)
+    specs = _specs(chunk, step, group, n, p, lambda i: blocks - 1 - i, gate)
     floats = jax.ShapeDtypeStruct((bsz, h, 1, padded), _F32)
-    dq, dk, dv, d_beta, d_run = _call(
-        functools.partial(_bwd_kernel, chunk=chunk, group=group, n=n, p=p),
+    dq, dk, dv, d_beta, d_run, dz, d_weight = _call(
+        functools.partial(_bwd_kernel, chunk=chunk, group=group, n=n, p=p,
+                          eps=eps),
         "gdn_bwd_kernel", (bsz, gk, blocks), interpret,
         in_specs=[specs["key"], specs["key"], specs["value"],
-                  specs["floats"], specs["floats"], specs["chunks"](n, p),
+                  specs["floats"], specs["floats"], specs["gate"],
+                  specs["weight"], specs["chunks"](n, p),
                   specs["chunks"](chunk, chunk), specs["value"]],
         out_specs=[specs["key"], specs["key"], specs["value"],
-                   specs["floats"], specs["floats"]],
+                   specs["floats"], specs["floats"], specs["value"],
+                   specs["sums"]],
         out_shape=[jax.ShapeDtypeStruct(operands[0].shape, q.dtype),
                    jax.ShapeDtypeStruct(operands[1].shape, k.dtype),
                    jax.ShapeDtypeStruct(operands[2].shape, v.dtype),
-                   floats, floats],
+                   floats, floats,
+                   jax.ShapeDtypeStruct(operands[2].shape, z.dtype),
+                   jax.ShapeDtypeStruct((bsz, gk, _SUBLANES, p), _F32)],
         scratch_shapes=[pltpu.VMEM((group, n, p), _F32)])(
-            *operands, states, inverses, d_out)
+            *operands, states, inverses, dy)
     # a step's log decay is in every later running sum of its chunk
     d_run = d_run[:, :, 0].transpose(0, 2, 1).reshape(bsz, chunks, chunk, h)
     d_g = jnp.flip(jnp.cumsum(jnp.flip(d_run, 2), axis=2), 2).reshape(
@@ -679,4 +784,5 @@ def backward(q, k, v, beta, g, states, inverses, d_out, chunk,
     return (dq[:, :length].reshape(q.shape), dk[:, :length].reshape(k.shape),
             dv[:, :length].reshape(v.shape),
             d_beta[:, :length].astype(beta.dtype),
-            d_g[:, :length].astype(g.dtype))
+            d_g[:, :length].astype(g.dtype), dz[:, :length],
+            jnp.sum(d_weight, axis=(0, 1, 2)).astype(weight.dtype))

@@ -265,32 +265,14 @@ def gated_delta_rule(q, k, v, beta, g, chunk=64):
     ``L``: the tail is padded with steps of ``beta = 0`` and ``g = 0``,
     which write nothing and decay nothing. Returns (B, L, H, P) float32.
 
-    Two forms of one algorithm, chosen by what the program can see, not by
-    the caller. Where the kernels' rule of shapes takes them
-    (``gdn_kernel.takes``: ``N`` and ``P`` whole lane tiles of 128, the
-    chunk whole sublane tiles and at most 128, one dtype for ``q``, ``k``
-    and ``v``, the blocks within VMEM) and the program is lowered for a
-    TPU, this repo's own kernels under one ``custom_vjp``
-    (``ops.gdn_kernel``): a head's state and a chunk's system and inverse
-    in VMEM, forward and backward, the heads read where the mixer wrote
-    them. Everywhere else (other shapes, another backend) the plain form
-    below, ``_solve_then_scan``, differentiated by JAX. The gauge
-    ``gdn::kernel_sites`` says which."""
-    n, p, c = k.shape[3], v.shape[3], int(chunk)
-    if q.dtype == k.dtype == v.dtype and gdn_kernel.takes(
-            n, p, c, v.dtype, v.shape[2] // k.shape[2]):
-        return _rule_kernels(q, k, v, beta, g, c)
-    return _solve_then_scan(q, k, v, beta, g, c)
-
-
-def _solve_then_scan(q, k, v, beta, g, chunk):
-    """``gated_delta_rule`` in plain JAX: the system and every product
-    that does not read the state for all chunks at once, the chunks then
-    chained by a ``lax.scan``."""
+    Plain JAX, differentiated by JAX. The mixer around it,
+    ``gated_delta_net``, takes this repo's kernels for the rule and the
+    gated norm after it together (``ops.gdn_kernel``) where their rule of
+    shapes takes them and the program is lowered for a TPU."""
     bsz, length, h, p = v.shape
     gk, n = k.shape[2], k.shape[3]
     r = h // gk
-    c = chunk
+    c = int(chunk)
     pad = (-length) % c
     if pad:
         q, k, v, beta, g = (
@@ -349,122 +331,124 @@ def _solve_then_scan(q, k, v, beta, g, chunk):
         :, :length]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _rule_kernels(q, k, v, beta, g, chunk):
-    """``gated_delta_rule`` whose program takes its form when it is
-    lowered: for a TPU the kernels of ``ops.gdn_kernel``, forward and
-    backward, for any other platform ``_solve_then_scan`` and JAX's own
-    derivative of it. Nothing of it is handed to a recomputation unit:
-    what the backward kernel reads of the forward (every chunk's entering
-    state and inverse) lives from the rule's forward to its backward
-    inside the unit's backward pass, one layer at a time."""
-    return _rule_kernels_fwd(q, k, v, beta, g, chunk)[0]
-
-
-def _rule_kernels_fwd(q, k, v, beta, g, chunk):
-    bsz, length, h, p = v.shape
-    n, chunks = k.shape[3], gdn_kernel.steps(length, chunk)[0] // chunk
-
-    def plain(*a):
-        # the kernels' kept values have no part in this form's derivative
-        return (_solve_then_scan(*a, chunk),
-                jnp.zeros((bsz, h, chunks, n, p), _F32),
-                jnp.zeros((bsz, h, chunks, chunk, chunk), _F32))
-
-    out, states, inverses = lax.platform_dependent(
-        q, k, v, beta, g,
-        tpu=lambda *a: gdn_kernel.forward(
-            attn_kernel.counted_site(a[0], gdn_kernel.GAUGE), *a[1:],
-            chunk=chunk),
-        default=plain)
-    return out, (q, k, v, beta, g, states, inverses)
-
-
-def _rule_kernels_bwd(chunk, res, d_out):
-    # no scope of its own: the backward rule carries the scope its forward
-    # was called under (``gated_delta_net``'s ``mx_gdn_rule``), and one
-    # opened here would file the kernel under ``mx_gdn_rule/mx_gdn_rule``,
-    # outside what ``^mx_gdn_rule$`` reads
-    return lax.platform_dependent(
-        *res, d_out,
-        tpu=lambda *a: gdn_kernel.backward(*a, chunk=chunk),
-        default=lambda q, k, v, beta, g, states, inverses, d_out:
-            jax.vjp(lambda *a: _solve_then_scan(*a, chunk),
-                    q, k, v, beta, g)[1](d_out))
-
-
-_rule_kernels.defvjp(_rule_kernels_fwd, _rule_kernels_bwd)
-
-
-def _convolved(qkvz, conv_weight, heads):
-    """``silu(conv([q | k | v]))`` of the packed projection, (B, L, 2 G N
-    + H P)."""
-    conv = 2 * heads.keys * heads.n + heads.values * heads.p
-    return jax.nn.silu(causal_conv1d(qkvz[..., :conv], conv_weight, None))
-
-
-def _normalised(qkv, heads, dtype):
-    """The rule's ``(q, k, v)`` from the convolved heads: ``q`` and ``k``
-    (B, L, G, N) L2-normalised a head in float32, ``q`` scaled by ``N **
-    -0.5``, in ``dtype``; ``v`` (B, L, H, P) as it is."""
-    bsz, length, _ = qkv.shape
+def _operands_plain(qkvz, conv_weight, heads):
+    """The rule's ``(q, k, v)`` from the packed projection, plain JAX:
+    ``silu(conv([q | k | v]))``, then ``q`` and ``k`` (B, L, G, N)
+    L2-normalised a head in float32, ``q`` scaled by ``N ** -0.5``, in the
+    projection's dtype; ``v`` (B, L, H, P) as it is."""
+    bsz, length, _ = qkvz.shape
     wide = heads.keys * heads.n
+    conv = 2 * wide + heads.values * heads.p
+    qkv = jax.nn.silu(causal_conv1d(qkvz[..., :conv], conv_weight, None))
     q, k = (_l2_norm(t.reshape(bsz, length, heads.keys, heads.n), 1e-6)
             for t in (qkv[..., :wide], qkv[..., wide:2 * wide]))
     v = qkv[..., 2 * wide:].reshape(bsz, length, heads.values, heads.p)
-    return (q * heads.n ** -0.5).astype(dtype), k.astype(dtype), v
+    return (q * heads.n ** -0.5).astype(qkvz.dtype), k.astype(qkvz.dtype), v
 
 
-def _operands_plain(qkvz, conv_weight, heads):
-    """The rule's ``(q, k, v)`` from the packed projection, plain JAX."""
-    return _normalised(_convolved(qkvz, conv_weight, heads), heads,
-                       qkvz.dtype)
+def _gated_norm(o, qkvz, norm_weight, eps):
+    """``rmsnorm(o) * norm_weight * silu(z)`` a value head in float32,
+    ``z`` the last columns of the packed projection: (B, L, H P) in the
+    projection's dtype."""
+    bsz, length, hv, dv = o.shape
+    with jax.named_scope("mx_gdn_gate"):
+        z = qkvz[..., qkvz.shape[-1] - hv * dv:].astype(_F32).reshape(o.shape)
+        y = _rms_norm(o, norm_weight, eps=eps) * jax.nn.silu(z)
+    return y.reshape(bsz, length, hv * dv).astype(qkvz.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _operand_kernels(qkvz, conv_weight, heads):
-    """The rule's ``(q, k, v)`` from the packed projection, a program that
-    takes its form when it is lowered: for a TPU the kernels of
-    ``ops.gdn_conv_kernel``, which read ``[q | k | v]`` where the
-    projection wrote them and convolve, activate and normalise in one
-    pass, forward and backward; for any other platform ``_convolved`` and
-    ``_normalised`` and JAX's own derivative of them. A recomputation
-    unit keeps nothing of it: both passes read the kept projection."""
-    return _operand_kernels_fwd(qkvz, conv_weight, heads)[0]
+def _mixer_plain(qkvz, conv_weight, beta, g, norm_weight, heads, chunk, eps):
+    """The mixer between its input products and its output product, plain
+    JAX, each part under its scope."""
+    with jax.named_scope("mx_gdn_conv"):
+        q, k, v = _operands_plain(qkvz, conv_weight, heads)
+    with jax.named_scope("mx_gdn_rule"):
+        o = gated_delta_rule(q, k, v, beta, g, chunk)
+    return _gated_norm(o, qkvz, norm_weight, eps)
 
 
-def _operand_kernels_fwd(qkvz, conv_weight, heads):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _mixer_kernels(qkvz, conv_weight, beta, g, norm_weight, heads, chunk, eps):
+    """The mixer between its input products and its output product, (B, L,
+    H P) in the projection's dtype, a program that takes its form when it
+    is lowered. For a TPU two pairs of kernels: ``ops.gdn_conv_kernel``
+    reads ``[q | k | v]`` where the projection wrote them and convolves,
+    activates and normalises in one pass; ``ops.gdn_kernel`` runs the rule
+    with a head's state and a chunk's system in VMEM and, where a chunk's
+    output is still there, the gated norm, reading ``z`` from the packed
+    rows in place. Backward in reverse: the rule's kernel goes back
+    through the gated norm as it loads the output's cotangent and gives
+    ``dz`` beside ``dq``, ``dk``, ``dv``; the convolution's kernel takes
+    those three back to the rows; the projection's cotangent is put
+    together once, ``[dq | dk | dv | dz]``. For any other platform
+    ``_mixer_plain`` and JAX's own derivative of it. A recomputation unit
+    keeps nothing of it: both passes read the kept projection, and what
+    the backward kernels read of the forward (``q``, ``k``, ``v``, every
+    chunk's entering state and inverse) lives from the forward to the
+    backward inside the unit's backward pass, one layer at a time."""
+    return _mixer_kernels_fwd(qkvz, conv_weight, beta, g, norm_weight, heads,
+                              chunk, eps)[0]
+
+
+def _mixer_kernels_fwd(qkvz, conv_weight, beta, g, norm_weight, heads, chunk,
+                       eps):
     lead = qkvz.shape[:-1]
+    keys, values = lead + (heads.keys, heads.n), lead + (heads.values, heads.p)
+    chunks = gdn_kernel.steps(lead[1], chunk)[0] // chunk
 
-    def kernels(x, w):
-        q, k, v = gdn_conv_kernel.forward(
-            attn_kernel.counted_site(x, gdn_conv_kernel.GAUGE), w, heads)
-        return (q.reshape(lead + (heads.keys, heads.n)),
-                k.reshape(lead + (heads.keys, heads.n)),
-                v.reshape(lead + (heads.values, heads.p)))
+    def kernels(x, w, beta, g, gamma):
+        with jax.named_scope("mx_gdn_conv"):
+            q, k, v = gdn_conv_kernel.forward(
+                attn_kernel.counted_site(x, gdn_conv_kernel.GAUGE), w, heads)
+        q, k, v = q.reshape(keys), k.reshape(keys), v.reshape(values)
+        with jax.named_scope("mx_gdn_rule"):
+            y, states, inverses = gdn_kernel.forward(
+                attn_kernel.counted_site(q, gdn_kernel.GAUGE), k, v, beta, g,
+                x, gamma, chunk=chunk, eps=eps)
+        return y, q, k, v, states, inverses
 
-    out = lax.platform_dependent(
-        qkvz, conv_weight, tpu=kernels,
-        default=lambda x, w: _operands_plain(x, w, heads))
-    return out, (qkvz, conv_weight)
+    def plain(x, w, beta, g, gamma):
+        # what the kernels keep has no part in this form's derivative
+        return (_mixer_plain(x, w, beta, g, gamma, heads, chunk, eps),
+                jnp.zeros(keys, x.dtype), jnp.zeros(keys, x.dtype),
+                jnp.zeros(values, x.dtype),
+                jnp.zeros(lead[:1] + (heads.values, chunks, heads.n, heads.p),
+                          _F32),
+                jnp.zeros(lead[:1] + (heads.values, chunks, chunk, chunk),
+                          _F32))
+
+    operands = (qkvz, conv_weight, beta, g, norm_weight)
+    y, *kept_here = lax.platform_dependent(*operands, tpu=kernels,
+                                           default=plain)
+    return y, operands + tuple(kept_here)
 
 
-def _operand_kernels_bwd(heads, res, cts):
-    # no scope of its own: the backward rule carries ``mx_gdn_conv`` from
-    # its forward's call site (``_rule_kernels_bwd``)
-    def kernels(x, w, *cts):
-        d_rows, d_w = gdn_conv_kernel.backward(
-            x, w, *(d.reshape(x.shape[:-1] + (-1,)) for d in cts), heads)
-        gate = jnp.zeros(x.shape[:-1] + (x.shape[-1] - w.shape[0],), x.dtype)
-        return jnp.concatenate(d_rows + (gate,), axis=-1), d_w
+def _mixer_kernels_bwd(heads, chunk, eps, res, dy):
+    # the scopes are opened here because the call site stands under none: a
+    # backward rule carries the scope its forward was called under, and a
+    # kernel filed under ``mx_gdn_rule/mx_gdn_rule`` would stand outside
+    # what ``^mx_gdn_rule$`` reads
+    def kernels(x, w, beta, g, gamma, q, k, v, states, inverses, dy):
+        with jax.named_scope("mx_gdn_rule"):
+            dq, dk, dv, d_beta, d_g, dz, d_gamma = gdn_kernel.backward(
+                q, k, v, beta, g, x, gamma, states, inverses, dy,
+                chunk=chunk, eps=eps)
+        with jax.named_scope("mx_gdn_conv"):
+            d_rows, d_w = gdn_conv_kernel.backward(
+                x, w, *(d.reshape(x.shape[:-1] + (-1,))
+                        for d in (dq, dk, dv)), heads)
+            d_x = jnp.concatenate(d_rows + (dz,), axis=-1)
+        return d_x, d_w, d_beta, d_g, d_gamma
 
-    return lax.platform_dependent(
-        *res, *cts, tpu=kernels,
-        default=lambda x, w, *d: jax.vjp(
-            lambda x, w: _operands_plain(x, w, heads), x, w)[1](d))
+    def plain(x, w, beta, g, gamma, *rest):
+        return jax.vjp(
+            lambda *a: _mixer_plain(*a, heads, chunk, eps),
+            x, w, beta, g, gamma)[1](rest[-1])
+
+    return lax.platform_dependent(*res, dy, tpu=kernels, default=plain)
 
 
-_operand_kernels.defvjp(_operand_kernels_fwd, _operand_kernels_bwd)
+_mixer_kernels.defvjp(_mixer_kernels_fwd, _mixer_kernels_bwd)
 
 
 @register_op("GatedDeltaNet", names_its_parts=True)
@@ -491,48 +475,52 @@ def gated_delta_net(data, qkvz_weight, ba_weight, conv_weight, dt_bias,
     in float32; ``q`` and ``k`` L2-normalised over a head, ``q`` scaled by
     ``key_dim ** -0.5``; ``gated_delta_rule`` in chunks of
     ``chunk_size``; ``rmsnorm(o) * norm_weight * silu(z)`` a head in
-    float32; the output product. The rule is ``gated_delta_rule``'s two
-    forms: at heads that are whole lane tiles, in a program lowered for a
-    TPU, the kernels of ``ops.gdn_kernel`` (the heads read where the
-    convolution wrote them, a head's state and a chunk's system in VMEM),
-    everywhere else the solve and the scan in plain JAX. Scopes:
-    ``mx_gdn_proj`` (the three products), ``mx_gdn_conv``,
-    ``mx_gdn_rule`` (normalisation, decays and the rule, kernels or
-    plain), ``mx_gdn_gate``. A recomputation unit around it keeps both
-    input products; the convolution and the rule are computed again (the
-    chain's own backward wants every chunk's entering state, which no
-    unit holds between its passes: the kernels write them in the unit's
-    recomputation and read them in its backward).
+    float32; the output product. Two forms of one algorithm, chosen by
+    what the program can see, not by the caller. Where both kernel pairs'
+    rules of shapes take the mixer (``gdn_conv_kernel.takes``,
+    ``gdn_kernel.takes``: heads whole lane tiles of 128, 2 to 9 taps, the
+    chunk whole sublane tiles and at most 128, ``z`` whole blocks of a key
+    head's value columns into the packed rows, one dtype, the blocks
+    within VMEM) and the program is lowered for a TPU, everything between
+    the input products and the output product is this repo's kernels under
+    one ``custom_vjp`` (``_mixer_kernels``): the convolution, SiLU and the
+    heads' norm read from the packed projection in place, then the rule
+    with a head's state and a chunk's system in VMEM and the gated norm
+    where a chunk's output is still there, ``z`` read from the same packed
+    rows; backward the same kernels' derivatives in reverse and the
+    projection's cotangent put together once. Everywhere else (other
+    shapes, another backend) the same lines in plain JAX, differentiated
+    by JAX. The gauges ``gdn::kernel_sites`` and
+    ``gdn::conv_kernel_sites`` say which. Scopes: ``mx_gdn_proj`` (the
+    three products), ``mx_gdn_conv``, ``mx_gdn_rule`` (decays and the
+    rule; through the kernels the gated norm too, which is inside them),
+    ``mx_gdn_gate`` (the gated norm in plain JAX: it names no instruction
+    of a program that took the kernels). A recomputation unit around it
+    keeps both input products; the convolution and the rule are computed
+    again (the chain's own backward wants every chunk's entering state,
+    which no unit holds between its passes: the kernels write them in the
+    unit's recomputation and read them in its backward).
 
     Returns (B, L, hidden), this share's partial sum."""
     hk, hv, dk, dv = int(num_k_heads), int(num_v_heads), int(key_dim), \
         int(value_dim)
-    bsz, length, _ = data.shape
+    chunk = int(chunk_size)
     with jax.named_scope("mx_gdn_proj"):
         qkvz = kept(_mm(data, qkvz_weight))
         ba = kept(_mm(data, ba_weight))
-    conv = 2 * hk * dk + hv * dv
     heads = gdn_conv_kernel.Heads(hk, dk, hv, dv)
-    fused = qkvz.dtype == data.dtype and gdn_conv_kernel.takes(
-        heads, conv_weight.shape[1], qkvz.dtype, conv_weight.dtype)
-    with jax.named_scope("mx_gdn_conv"):
-        if fused:
-            q, k, v = _operand_kernels(qkvz, conv_weight, heads)
-        else:
-            qkv = _convolved(qkvz, conv_weight, heads)
     with jax.named_scope("mx_gdn_rule"):
         beta = jax.nn.sigmoid(ba[..., :hv].astype(_F32))
         g = -jnp.exp(a_log.astype(_F32)) * jax.nn.softplus(
             ba[..., hv:].astype(_F32) + dt_bias.astype(_F32))
-        if not fused:
-            q, k, v = _normalised(qkv, heads, data.dtype)
-        o = gated_delta_rule(q, k, v, beta, g, chunk_size)
-    with jax.named_scope("mx_gdn_gate"):
-        z = qkvz[..., conv:].astype(_F32).reshape(bsz, length, hv, dv)
-        y = _rms_norm(o, norm_weight, eps=eps) * jax.nn.silu(z)
+    taken = qkvz.dtype == data.dtype and gdn_conv_kernel.takes(
+        heads, conv_weight.shape[1], qkvz.dtype, conv_weight.dtype) \
+        and gdn_kernel.takes(dk, dv, chunk, qkvz.dtype, hv // hk,
+                             gate_offset=2 * hk * dk + hv * dv)
+    y = (_mixer_kernels if taken else _mixer_plain)(
+        qkvz, conv_weight, beta, g, norm_weight, heads, chunk, float(eps))
     with jax.named_scope("mx_gdn_proj"):
-        return _mm(y.reshape(bsz, length, hv * dv).astype(data.dtype),
-                   out_weight)
+        return _mm(y, out_weight)
 
 
 # ---------------------------------------------------------------------------
@@ -555,7 +543,7 @@ def gated_short_conv(data, in_weight, conv_weight, out_weight, **kw):
     Both products sum in float32 and round to ``data``'s dtype
     (``_mm``, as every mixer's here). Between them both gates, the taps
     and the taps' sum are in ``data``'s dtype, as the sibling mixers'
-    convolutions are (``mamba2_mixer``, ``_convolved``): ``B * z`` is
+    convolutions are (``mamba2_mixer``, ``_operands_plain``): ``B * z`` is
     what memory holds between the gate and the taps (every tap reads it
     at another shift, so the compiler writes it out), and in float32 it
     would be twice the bytes of a chain that is bound by them.

@@ -3,7 +3,8 @@ the convolution over ``[q | k | v]``, SiLU and the heads' L2 norm, read
 from the packed projection in place) against the plain form
 (``causal_conv1d``, ``silu``, ``_l2_norm``) and JAX's own derivative of it,
 interpreted on the CPU; the rule of shapes they are taken by; and which
-form ``gated_delta_net`` takes: the kernels where the rule takes the
+form ``gated_delta_net`` takes: the kernels (these and the rule's,
+``ops/gdn_kernel.py``, under one ``custom_vjp``) where both rules take the
 shapes and the program is lowered for a TPU, the plain form everywhere
 else, with the gauge ``gdn::conv_kernel_sites`` counting the sites.
 Nothing here is a time."""
@@ -44,56 +45,93 @@ def _flat(outs):
     return tuple(o.reshape(o.shape[:2] + (-1,)) for o in outs)
 
 
-def _cots(outs, seed=9):
+def _cots(qkvz, heads, seed=9):
+    """Cotangents for ``q``, ``k`` and ``v``, (B, L, .) in the
+    projection's dtype."""
     rng = np.random.default_rng(seed)
-    return tuple(jnp.asarray(rng.normal(size=o.shape), o.dtype) for o in outs)
+    wide_k = heads.keys * heads.n
+    return tuple(jnp.asarray(rng.normal(size=qkvz.shape[:2] + (wide,)),
+                             qkvz.dtype)
+                 for wide in (wide_k, wide_k, heads.values * heads.p))
+
+
+@functools.cache
+def _plain_program(heads):
+    """The plain form's ``(q, k, v)`` and JAX's gradients of it for the
+    projection and the taps as one jitted function of float32 operands:
+    the cases of one shape, whatever their dtype, share its trace."""
+    def both(x, w, *cots):
+        out, vjp = jax.vjp(
+            lambda x, w: _flat(seq._operands_plain(x, w, heads)), x, w)
+        return out, vjp(cots)
+
+    return jax.jit(both)
 
 
 def _plain(qkvz, weight, heads, cots):
     """The plain form's ``(q, k, v)`` and JAX's gradients of it for the
     projection and the taps, in float32 from the operands as given."""
-    return numerics.traced(
-        lambda x, w: _flat(seq._operands_plain(x, w, heads)),
-        (qkvz.astype(jnp.float32), weight.astype(jnp.float32)), cots, (0, 1))
+    f32 = jnp.float32
+    return _plain_program(heads)(qkvz.astype(f32), weight.astype(f32),
+                                 *(c.astype(f32) for c in cots))
 
 
 def _kernels(qkvz, weight, heads, seed=9):
     """``(q, k, v)`` of the forward kernel, cotangents drawn for them, and
-    the backward kernel's ``(d_rows, d_weight)``, both interpreted."""
-    got, _ = numerics.traced(lambda x, w: gdn_conv_kernel.forward(
-        x, w, tuple(heads), interpret=True), (qkvz, weight))
-    cots = _cots(got, seed)
-    grads, _ = numerics.traced(lambda x, w, *c: gdn_conv_kernel.backward(
-        x, w, *c, tuple(heads), interpret=True), (qkvz, weight, *cots))
+    the backward kernel's ``(d_rows, d_weight)``, both interpreted, one
+    program."""
+    cots = _cots(qkvz, heads, seed)
+    (got, grads), _ = numerics.traced(
+        lambda x, w, *c: (
+            gdn_conv_kernel.forward(x, w, tuple(heads), interpret=True),
+            gdn_conv_kernel.backward(x, w, *c, tuple(heads),
+                                     interpret=True)),
+        (qkvz, weight, *cots))
     return got, cots, grads
 
 
-# two blocks of 256 rows; one block and a row; shorter than the taps
-@pytest.mark.parametrize("length", [512, 257, 2])
+# one block of 256 rows and a row (257 rows show both edges of a block, the
+# halo after it and the carried rows before it, as two whole blocks would);
+# one step of a group and a padded tail; shorter than the taps
+@functools.cache
+def _batch_of_two(dtype, group, length):
+    """The operands of a batch of two entries and what ``_kernels`` gives
+    for them: one traced program serves the cases of both entries."""
+    qkvz, weight, heads = _operands(2, length, group, jnp.dtype(dtype),
+                                    seed=length + group)
+    return (qkvz, weight, heads) + _kernels(qkvz, weight, heads)
+
+
+@pytest.mark.parametrize("length", [257, 33, 2])
 @pytest.mark.parametrize("group", [1, 2])
-@pytest.mark.parametrize("bsz", [1, 2])
+@pytest.mark.parametrize("entry", [0, 1])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_kernels_are_the_plain_form_and_its_derivative(dtype, bsz, group,
+def test_kernels_are_the_plain_form_and_its_derivative(dtype, entry, group,
                                                        length):
     """``q``, ``k``, ``v`` and the gradients for the projection's
     convolved columns and for the taps: in float32 to 1e-5 of the largest
-    value, in bfloat16 within the rounding of one output. A batch entry's
-    first rows see zeros before them, not the entry before; a block's
-    first rows see the block before through the halo, and its last rows'
-    cotangent the block after through the carried rows."""
-    qkvz, weight, heads = _operands(bsz, length, group, jnp.dtype(dtype),
-                                    seed=length + group)
-    got, cots, (d_rows, d_weight) = _kernels(qkvz, weight, heads)
-    want, (want_dx, want_dw) = _plain(qkvz, weight, heads, cots)
+    value, in bfloat16 within the rounding of one output. An entry of a
+    batch of two against the plain form of that entry alone: its first
+    rows see zeros before them, not the entry before, and its last rows'
+    cotangent nothing of the entry after; a block's first rows see the
+    block before through the halo, and its last rows' cotangent the block
+    after through the carried rows. The taps' gradient is the sum of both
+    entries'."""
+    qkvz, weight, heads, got, cots, (d_rows, d_weight) = _batch_of_two(
+        dtype, group, length)
+    (want, (want_dx, want_dw)), (_, (_, other_dw)) = (
+        _plain(qkvz[e:e + 1], weight, heads, tuple(c[e:e + 1] for c in cots))
+        for e in (entry, 1 - entry))
     tol = 1e-5 if dtype == "float32" else 2.0 ** -7
     assert {a.dtype for a in got} == {qkvz.dtype}
-    numerics.close(got, want, kernel_tol(tol), "qkv")
+    numerics.close(tuple(a[entry:entry + 1] for a in got), want,
+                   kernel_tol(tol), "qkv")
     assert d_weight.dtype == weight.dtype
     conv = weight.shape[0]
     assert not np.asarray(want_dx[..., conv:]).any()    # the gate's columns
-    numerics.close(jnp.concatenate(d_rows, axis=-1), want_dx[..., :conv],
-                   kernel_tol(tol), "dx")
-    numerics.close(d_weight, want_dw, kernel_tol(4 * tol), "dw")
+    numerics.close(jnp.concatenate(d_rows, axis=-1)[entry:entry + 1],
+                   want_dx[..., :conv], kernel_tol(tol), "dx")
+    numerics.close(d_weight, want_dw + other_dw, kernel_tol(4 * tol), "dw")
 
 
 def test_a_second_sequence_does_not_see_the_first_one_s_rows():
@@ -177,9 +215,10 @@ def test_the_rule_of_shapes_reads_shapes_alone():
 # ---------------------------------------------------------------------------
 # the mixer's two forms
 # ---------------------------------------------------------------------------
-def _mixer(head, dtype, length=40, bsz=2, seed=4):
+def _mixer(head, dtype, length=40, bsz=2, seed=4, chunk=16):
     """``loss(data, *weights)`` of a ``gated_delta_net`` with one key head
-    and two value heads ``head`` wide, and its arguments."""
+    and two value heads ``head`` wide, in chunks of ``chunk`` rows, and
+    its arguments."""
     hidden = 32
     conv = 4 * head
     rng = np.random.default_rng(seed)
@@ -196,32 +235,84 @@ def _mixer(head, dtype, length=40, bsz=2, seed=4):
 
     def loss(*a):
         out = seq.gated_delta_net(*a, num_k_heads=1, num_v_heads=2,
-                                  key_dim=head, value_dim=head, chunk_size=16)
+                                  key_dim=head, value_dim=head,
+                                  chunk_size=chunk)
         return jnp.sum(out * cot)
 
     return loss, args
 
 
+@pytest.fixture()
+def kernels_here(monkeypatch):
+    """``ops.seq`` takes its TPU branches on this backend, both kernel
+    pairs interpreted."""
+    monkeypatch.setattr(seq, "lax", numerics.LoweredForATpu())
+    for module in (gdn_conv_kernel, seq.gdn_kernel):
+        for name in ("forward", "backward"):
+            monkeypatch.setattr(module, name, functools.partial(
+                getattr(module, name), interpret=True))
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_the_mixer_through_the_kernels_is_the_plain_form_with_every_gradient(
-        dtype, monkeypatch):
-    """The TPU's branch of the mixer's ``custom_vjp`` on this CPU, the
-    kernels interpreted (the rule's stay the plain form): the value and
-    the gradients for the input and all seven weights, the projection's
-    cotangent put together from the kernels' three parts and zeros for
-    the gate's columns, which the gate's own path fills."""
+        dtype, monkeypatch, kernels_here):
+    """The TPU's branch of the mixer's ``custom_vjp`` on this CPU, both
+    kernel pairs interpreted: the value and the gradients for the input
+    and all seven weights (the norm's, which is not all ones, among
+    them), the projection's cotangent put together from the operands'
+    kernels' three parts and the rule's kernel's ``dz``."""
     loss, args = _mixer(N, jnp.dtype(dtype))
-    monkeypatch.setattr(seq.gdn_kernel, "takes", lambda *a: False)
     with monkeypatch.context() as m:
         m.setattr(gdn_conv_kernel, "takes", lambda *a: False)
         want = numerics.traced(loss, args, 1.0, range(len(args)))
-    monkeypatch.setattr(seq, "lax", numerics.LoweredForATpu())
-    for name in ("forward", "backward"):
-        monkeypatch.setattr(gdn_conv_kernel, name, functools.partial(
-            getattr(gdn_conv_kernel, name), interpret=True))
     got = numerics.traced(loss, args, 1.0, range(len(args)))
     tol = 2e-4 if dtype == "float32" else 2.0 ** -5
     numerics.close(got, want, kernel_tol(tol), same_dtype=True)
+
+
+def test_the_projection_s_cotangent_is_four_parts_side_by_side(kernels_here):
+    """``[dq | dk | dv | dz]``, put together once: the convolved columns
+    hold what the operands' backward kernel gives for the rule's three
+    cotangents, the gate's columns hold the rule's backward kernel's
+    ``dz``, each to the bit, nothing added to either. (One chunk of one
+    value head: the kernels' programs at their smallest.)"""
+    chunk, eps = 16, 1e-6
+    qkvz, weight, heads = _operands(1, 16, 1, jnp.bfloat16, seed=6)
+    rng = np.random.default_rng(7)
+    beta = jnp.asarray(rng.uniform(0.1, 0.9, (1, 16, 1)), jnp.float32)
+    g = jnp.asarray(-rng.uniform(0, 1, (1, 16, 1)), jnp.float32)
+    gamma = jnp.asarray(1 + 0.3 * rng.normal(size=(P,)), jnp.float32)
+    dy = jnp.asarray(rng.normal(size=(1, 16, P)), jnp.bfloat16)
+
+    def by_hand(x, w, beta, g, gamma, dy):
+        q, k, v = gdn_conv_kernel.forward(x, w, tuple(heads))
+        q, k, v = (t.reshape(t.shape[:2] + (-1, N)) for t in (q, k, v))
+        _, states, inverses = seq.gdn_kernel.forward(
+            q, k, v, beta, g, x, gamma, chunk=chunk, eps=eps)
+        dq, dk, dv, _, _, dz, _ = seq.gdn_kernel.backward(
+            q, k, v, beta, g, x, gamma, states, inverses, dy, chunk=chunk,
+            eps=eps)
+        d_rows, _ = gdn_conv_kernel.backward(
+            x, w, *(d.reshape(x.shape[:2] + (-1,)) for d in (dq, dk, dv)),
+            tuple(heads))
+        return d_rows, dz
+
+    def through(x, w, beta, g, gamma, dy):
+        return jax.vjp(lambda x: seq._mixer_kernels(
+            x, w, beta, g, gamma, heads, chunk, eps), x)[1](dy)[0]
+
+    args = (qkvz, weight, beta, g, gamma, dy)
+    (d_rows, dz), _ = numerics.traced(by_hand, args)
+    d_x, _ = numerics.traced(through, args)
+    conv = weight.shape[0]
+    assert d_x.shape == qkvz.shape and d_x.dtype == qkvz.dtype
+    assert dz.shape == qkvz.shape[:2] + (qkvz.shape[2] - conv,)
+    assert np.asarray(dz, np.float32).any()
+    np.testing.assert_array_equal(
+        np.asarray(d_x[..., conv:], np.float32), np.asarray(dz, np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(d_x[..., :conv], np.float32),
+        np.asarray(jnp.concatenate(d_rows, axis=-1), np.float32))
 
 
 def _lowered(head, platform, dtype=jnp.bfloat16):
@@ -263,16 +354,18 @@ def test_taps_of_another_dtype_stay_the_plain_form():
 def _parent_s_mixer(data, qkvz_weight, ba_weight, conv_weight, dt_bias, a_log,
                     norm_weight, out_weight, hk, hv, dk, dv, chunk):
     """``gated_delta_net`` as it stood before the kernels, line for
-    line."""
+    line (but ``beta`` and ``g``, which it formed after the convolution
+    and forms before it since the mixer's middle is one function: the
+    same operations, two lines higher in the text)."""
     _F32 = jnp.float32
     bsz, length, _ = data.shape
     qkvz = seq.kept(seq._mm(data, qkvz_weight))
     ba = seq.kept(seq._mm(data, ba_weight))
     conv = 2 * hk * dk + hv * dv
-    qkv = jax.nn.silu(seq.causal_conv1d(qkvz[..., :conv], conv_weight, None))
     beta = jax.nn.sigmoid(ba[..., :hv].astype(_F32))
     g = -jnp.exp(a_log.astype(_F32)) * jax.nn.softplus(
         ba[..., hv:].astype(_F32) + dt_bias.astype(_F32))
+    qkv = jax.nn.silu(seq.causal_conv1d(qkvz[..., :conv], conv_weight, None))
     q, k = (seq._l2_norm(t.reshape(bsz, length, hk, dk), 1e-6)
             for t in (qkv[..., :hk * dk], qkv[..., hk * dk:2 * hk * dk]))
     v = qkv[..., 2 * hk * dk:].reshape(bsz, length, hv, dv)

@@ -3,7 +3,10 @@ described and not attached, at the sizes the cell times: Mosaic takes the
 gated delta rule's kernels (``ops/gdn_kernel.py``) at the cell's shapes
 (16 key heads, 32 value heads, 128 x 128, chunks of 64, bfloat16), forward
 and backward, three calls a linear-attention layer, each under the scope
-path ``mx_gdn_rule`` and no longer one; no ``triangular-solve`` expansion
+path ``mx_gdn_rule`` and no longer one; the gated norm is inside them (no
+instruction under ``mx_gdn_gate``, their output and its cotangent in
+bfloat16, ``z`` read from the packed projection where it is); no
+``triangular-solve`` expansion
 and no ``while`` is left for the rule; the kernels of the rule's operands
 (``ops/gdn_conv_kernel.py``: convolution, SiLU and the heads' norm from
 the packed projection) are there too, three calls a linear layer under
@@ -83,11 +86,77 @@ def test_every_kernel_of_the_rule_is_under_mx_gdn_rule_and_no_longer_path():
             if kernel in ("gdn_fwd_kernel", "gdn_bwd_kernel")}
     assert len(mine) == 9
     assert set(mine.values()) == {"mx_gdn_rule"}, mine
-    # and no instruction of the step has the scope twice in its path (what
-    # XLA fuses or copies across the rule's border with the gated norm
-    # carries both parts' names, ``mx_gdn_gate/mx_gdn_rule``: its choice)
+    # and no instruction of the step has the scope twice in its path
     assert not [p for p in set(paths.values())
                 if p.split("/").count("mx_gdn_rule") > 1]
+
+
+def _instructions(hlo):
+    """``{name: (shape, operation, operands' names, line)}`` of the entry
+    computation (a tuple's shape whole)."""
+    found = re.findall(
+        r"\n\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\((.*?)\)"
+        r"([^\n]*)", hlo[hlo.index("\nENTRY"):])
+    return {name: (shape, op, re.findall(r"%([\w.\-]+)", args), rest)
+            for name, shape, op, args, rest in found}
+
+
+def test_the_gated_norm_is_inside_the_rule_s_kernels():
+    """Read off the same compiled text: nothing of the step stands under
+    ``mx_gdn_gate``; a forward kernel's first result and a backward
+    kernel's last operand, the output's cotangent, are the tokens' rows of
+    value heads in bfloat16, no float32 array that XLA turns for the gate's
+    fusions; the output product (and, backward, its weight's gradient)
+    reads the kernel's result as it is, no ``copy`` or ``convert`` between;
+    and ``z`` is the packed projection itself, the product's result handed
+    to the kernel whole: no slice, pad or copy of the 12288-wide rows gives
+    a 4096-wide array anywhere in the step."""
+    step = compiled_step(CELL)
+    sizes, paths = step.sizes, step.paths
+    assert not [p for p in set(paths.values()) if "mx_gdn_gate" in p]
+    tokens = sizes["batch"] * sizes["seq_len"]
+    heads = f"bf16[1,{tokens},4096]"
+    packed = f"bf16[1,{tokens},12288]"
+    ins = _instructions(step.text)
+    users = collections.defaultdict(list)
+    for name, (_, _, operands, _) in ins.items():
+        for operand in operands:
+            users[operand].append(name)
+    rule = [n for n, k in step.calls.items()
+            if k in ("gdn_fwd_kernel", "gdn_bwd_kernel")]
+    assert len(rule) == 9
+    for name in rule:
+        shape, _, operands, rest = ins[name]
+        taken = re.search(r"operand_layout_constraints=\{(.*?)\}\}, ", rest
+                          ).group(1).split("}, ")
+        assert taken[5].startswith(packed), taken         # z, in place
+        assert ins[operands[5]][0].startswith(packed)
+        assert ins[operands[5]][1] in ("fusion", "get-tuple-element"), \
+            ins[operands[5]]
+        if step.calls[name] == "gdn_bwd_kernel":
+            assert taken[-1].startswith(heads), taken     # the cotangent
+            assert shape.count(heads) == 2                # dv and dz
+            continue
+        assert shape.startswith("(" + heads), shape
+        (y,) = [u for u in users[name] if "index=0" in ins[u][3]]
+        assert users[y]
+        for reader in users[y]:
+            assert ins[reader][1] == "fusion" and re.search(
+                r"mx_gdn_proj/(dot_general|transpose)", ins[reader][3]), \
+                ins[reader]
+    # nothing 4096 wide is cut out of rows 12288 wide
+    narrow = re.compile(rf"\w+\[(?:1,)?{tokens},(?:4096|32,128)\]")
+    for name, (shape, op, operands, _) in ins.items():
+        if op in ("slice", "pad", "copy", "dynamic-slice") and narrow.match(
+                shape):
+            assert not [o for o in operands
+                        if f"{tokens},12288]" in ins.get(o, ("",))[0]], name
+    # in a fusion either: no slice anywhere takes the gate's columns
+    wide = sizes["linear_num_value_heads"] * sizes["linear_value_head_dim"]
+    conv = wide + 2 * sizes["linear_num_key_heads"] \
+        * sizes["linear_key_head_dim"]
+    assert (conv, wide) == (8192, 4096)
+    assert f"[{conv}:{conv + wide}]" not in step.text
 
 
 def test_every_kernel_of_the_operands_is_under_exactly_mx_gdn_conv():
@@ -135,8 +204,12 @@ def test_step_fits_one_v5e_and_a_unit_keeps_nothing_of_the_rule():
     """625.7 M parameters with Adam's moments, 8192 tokens, recomputation
     by layer: arguments, outputs and temporaries on one described v5e,
     under the 13.5 GB the chip's run is held to; a delta-rule unit
-    keeps both input products and the gated norm's statistics, not the
-    convolution, not the rule's output, states or inverses."""
+    keeps both input products, not the convolution, not the rule's
+    output, states or inverses. (The count walks every branch of the
+    mixer's program, so it still names the plain form's norm statistics, a
+    float a token and value head, 1 MB a layer, which a program that took
+    the kernels never forms: ``remat_saved_gb.train`` reads what it
+    read.)"""
     step = compiled_step(CELL)
     sizes, compiled, kept = step.sizes, step.compiled, step.kept
     m = compiled.memory_analysis()
@@ -155,7 +228,10 @@ def test_step_fits_one_v5e_and_a_unit_keeps_nothing_of_the_rule():
 
 def test_what_the_kernels_hold_in_vmem_is_under_their_budget():
     """At the cell's shapes, by the modules' own statements: the blocks
-    twice, the states' scratch and a block's values before the chain; the
+    twice (the gate's ``z``, the gated output and its cotangent in
+    bfloat16, ``dz``, the weight and its row sums among them), the
+    states' scratch and a block's values before the chain, ``z`` 32 whole
+    blocks of a key head's 256 value columns into the packed rows; the
     operands' kernels' blocks of half the convolved columns."""
     from mxnet_tpu.ops import gdn_conv_kernel, gdn_kernel
     cell = harness.load_cell(CELL)
@@ -163,7 +239,9 @@ def test_what_the_kernels_hold_in_vmem_is_under_their_budget():
     n, p = sz["linear_key_head_dim"], sz["linear_value_head_dim"]
     group = sz["linear_num_value_heads"] // sz["linear_num_key_heads"]
     assert (n, p, group) == (128, 128, 2)
-    assert gdn_kernel.takes(n, p, 64, jnp.bfloat16, group)
+    conv = 2 * sz["linear_num_key_heads"] * n \
+        + sz["linear_num_value_heads"] * p
+    assert gdn_kernel.takes(n, p, 64, jnp.bfloat16, group, gate_offset=conv)
     for held in (gdn_kernel.forward_bytes(n, p, 64, group, 2),
                  gdn_kernel.backward_bytes(n, p, 64, group, 2)):
         assert 2e6 < held < gdn_kernel._BUDGET_BYTES
