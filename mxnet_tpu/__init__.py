@@ -14,6 +14,8 @@ Usage mirrors the reference:
         y = (x + 1).sum()
     y.backward()
 """
+import time as _time
+_T_IMPORT = _time.perf_counter()    # import_s.setup: this import, timed
 __version__ = "0.1.0"
 
 from . import base
@@ -89,3 +91,5 @@ from . import gluon
 from . import rnn
 from . import parallel
 from .io import DataBatch, DataIter
+telemetry.registry.timer("prof::setup::import").record(
+    _time.perf_counter() - _T_IMPORT)

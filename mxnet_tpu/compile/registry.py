@@ -26,16 +26,30 @@ rows) next to the ``serving::``/``ft::`` domains and, in a traced run,
 on the profiler's clock. An entry point that stays a plain ``jax.jit``
 (``parallel.TrainStep``) reports its first call through
 :func:`jit_acquire`.
+
+Beside the programs somebody registers, ``compile_report()["jax"]``
+files EVERY program JAX builds in the process by phase (trace, lower,
+backend: the XLA compile or the load from JAX's persistent cache) and
+by the jitted function's name, with hit or miss a program: one
+``jax.monitoring`` listener pair, registered when this module is
+imported (the package's import, before anything can compile), feeds a
+``prof::jax::<phase>:<name>`` aggregate, a bounded log and, under
+``MXTPU_TRACE_DIR``, the ring (for the Chrome export).
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import logging
 import pickle
+import re
 import threading
 import time
 import weakref
 
+import jax.monitoring
+
+from ..telemetry import registry as _treg
 from ..telemetry import trace as _trace
 from .cache import CacheEntryError, default_cache
 from .key import arg_signature  # noqa: F401  (re-export for callers)
@@ -306,16 +320,157 @@ def _load_or_compile(key, lower, cache):
 # ---------------------------------------------------------------------------
 # plain-jit path: parallel.TrainStep
 # ---------------------------------------------------------------------------
-_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
-_JAX_CACHE_MISS = "/jax/compilation_cache/cache_misses"
-_jax_cache_events = threading.local()   # this thread's hits and misses
-_jax_cache_listening = [False]
+# every program JAX builds, by phase and name: the one listener pair
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # wraps the XLA compile OR the load from JAX's persistent cache
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_JAX_CACHE = {"/jax/compilation_cache/cache_hits": "hit",
+              "/jax/compilation_cache/cache_misses": "miss"}
+_JIT_NAME = re.compile(r"^jit\((.*)\)$")
+_MAX_JAX_NAMES = 256     # later names fold into "other"
+_MAX_JAX_EVENTS = 4096   # the log; the oldest event goes first
+_jax_programs = {}       # name -> _jax_row()
+_jax_events = collections.deque(maxlen=_MAX_JAX_EVENTS)
+_jax_tls = threading.local()   # .cache: this thread's last hit or miss;
+#                                .acquiring / .verdict: jit_acquire's
 
 
-def _on_jax_event(event, **kwargs):
-    if event in (_JAX_CACHE_HIT, _JAX_CACHE_MISS):
-        ev = _jax_cache_events
-        ev.seen = getattr(ev, "seen", ()) + (event,)
+def _jax_row():
+    """What JAX spent on the programs of one name (a jitted function's):
+    ``trace_s`` is inclusive of the traces nested in it, ``programs``
+    counts the backend events (compiled or loaded)."""
+    return {"traces": 0, "trace_s": 0.0, "lower_s": 0.0, "programs": 0,
+            "backend_s": 0.0, "cache_hits": 0, "cache_misses": 0}
+
+
+def _on_jax_cache(event, **kwargs):
+    found = _JAX_CACHE.get(event)
+    if found is not None:
+        _jax_tls.cache = found
+
+
+def _on_jax_duration(event, duration, fun_name=None, **kwargs):
+    phase = _JAX_PHASES.get(event)
+    if phase is not None:
+        try:
+            _file_jax_phase(phase, duration, fun_name)
+        except Exception:
+            # JAX calls from inside its compile: the record may fail, the
+            # user's program may not
+            logger.warning("jax %s event of %s not filed", phase, fun_name,
+                           exc_info=True)
+
+
+def _file_jax_phase(phase, duration, fun_name):
+    """One phase of one program is over: JAX says so when the interval
+    has ended, from inside its trace, lowering or compile. Feeds the
+    aggregate ``prof::jax::<phase>:<name>``, the table and the log behind
+    ``compile_report()["jax"]`` and, under ``MXTPU_TRACE_DIR``, the ring."""
+    end = _trace._now()
+    name = _JIT_NAME.sub(r"\1", str(fun_name))
+    cache = None
+    if phase == "backend":
+        # JAX reports a hit or a miss on the compiling thread, inside the
+        # interval; neither where it did not ask its persistent cache or
+        # kept no entry of a compile under its threshold
+        cache = getattr(_jax_tls, "cache", None) or "none"
+        _jax_tls.cache = None
+        if getattr(_jax_tls, "acquiring", None) == name:
+            _jax_tls.verdict = cache
+    ts = (end - duration - _trace._EPOCH) * 1e6
+    tid = threading.get_ident()
+    folded = dropped = False
+    with _lock:
+        row = _jax_programs.get(name)
+        if row is None:
+            if len(_jax_programs) >= _MAX_JAX_NAMES:
+                name, folded = "other", True
+                row = _jax_programs.get(name)
+            if row is None:
+                row = _jax_programs[name] = _jax_row()
+        row[phase + "_s"] += duration
+        if phase == "trace":
+            row["traces"] += 1
+        elif phase == "backend":
+            row["programs"] += 1
+            row["cache_hits"] += cache == "hit"
+            row["cache_misses"] += cache == "miss"
+        if phase != "backend":
+            # the traces inside this interval ended before it (every jnp
+            # function is a jit of its own, and a lowering rule traces
+            # some: thousands a step): the log keeps the outermost, the
+            # table has counted them all
+            aside = []
+            while _jax_events and _jax_events[-1][0] >= ts:
+                inner = _jax_events.pop()
+                if inner[2] != "trace" or inner[4] != tid:
+                    aside.append(inner)
+            _jax_events.extend(reversed(aside))
+        dropped = len(_jax_events) == _MAX_JAX_EVENTS
+        _jax_events.append((ts, duration * 1e6, phase, name, tid, cache))
+    _treg.timer(f"prof::jax::{phase}:{name}").record(duration)
+    if folded:
+        _count("compile.jax_names_folded")
+    if dropped:
+        _count("compile.jax_events_dropped")
+    if _trace.exporting():
+        # for the Chrome export, beside the step that paid for it; no
+        # TraceAnnotation: the interval is over when the event arrives
+        parent = _trace.current()
+        _trace.record_span(
+            f"jax:{phase}:{name}", "compile", end - duration, duration,
+            trace_id=parent.trace_id if parent else _trace.new_trace_id(),
+            span_id=_trace.new_span_id(),
+            parent_id=parent and parent.span_id,
+            args=cache and {"cache": cache})
+
+
+jax.monitoring.register_event_listener(_on_jax_cache)
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _union_s(intervals):
+    """Seconds the ``(start, duration)`` intervals cover together."""
+    total, upto = 0.0, None
+    for start, dur in sorted(intervals):
+        stop = start + dur
+        if upto is None or start > upto:
+            total, upto = total + dur, stop
+        elif stop > upto:
+            total, upto = total + stop - upto, stop
+    return total
+
+
+def _jax_report():
+    """``compile_report()["jax"]``; the caller holds ``_lock``."""
+    programs = [dict({k: round(v, 6) for k, v in row.items()}, name=name)
+                for name, row in _jax_programs.items()]
+    events = [dict({"ts": ts, "dur": dur, "phase": phase, "name": name,
+                    "tid": tid}, **({"cache": cache} if cache else {}))
+              for ts, dur, phase, name, tid, cache in _jax_events]
+    by_thread = {}
+    for e in events:
+        if e["phase"] == "trace":
+            by_thread.setdefault(e["tid"], []).append((e["ts"], e["dur"]))
+    return {
+        "programs": sorted(
+            programs, key=lambda p: (-(p["trace_s"] + p["lower_s"]
+                                       + p["backend_s"]), p["name"])),
+        "events": events,
+        "totals": {
+            # a trace inside a trace counts once; over the log's events
+            "trace_s": round(1e-6 * sum(map(_union_s, by_thread.values())),
+                             6),
+            "lower_s": round(sum(p["lower_s"] for p in programs), 6),
+            "backend_s": round(sum(p["backend_s"] for p in programs), 6),
+            "programs": sum(p["programs"] for p in programs),
+            "cache_hits": sum(p["cache_hits"] for p in programs),
+            "cache_misses": sum(p["cache_misses"] for p in programs),
+        },
+    }
 
 
 @contextlib.contextmanager
@@ -324,24 +479,22 @@ def jit_acquire(name, kind, args):
     call that traces and compiles — as one program acquisition: a
     ``compile/acquire:<name>`` span and a row in ``compile_report()``
     keyed on the call's argument signature. It counts as loaded when
-    JAX's persistent cache reported a hit and no miss on this thread
-    meanwhile (``jax.monitoring``), as a fresh compile otherwise. The
-    program itself is not routed through the AOT cache."""
-    import jax.monitoring
+    JAX found the program named ``name`` in its persistent cache (the
+    ``cache`` of that program's own backend event, whatever else was
+    compiled meanwhile), as a fresh compile otherwise. The program
+    itself is not routed through the AOT cache."""
     from .key import program_key
-    with _lock:
-        if not _jax_cache_listening[0]:
-            jax.monitoring.register_event_listener(_on_jax_event)
-            _jax_cache_listening[0] = True
-    _jax_cache_events.seen = ()
-    with _trace.span(f"acquire:{name}", "compile") as sp:
-        yield
-    seen = _jax_cache_events.seen
+    _jax_tls.acquiring, _jax_tls.verdict = name, None
+    try:
+        with _trace.span(f"acquire:{name}", "compile") as sp:
+            yield
+    finally:
+        verdict, _jax_tls.acquiring = _jax_tls.verdict, None
     sig = arg_signature(args)
     key = program_key(kind, name, input_sigs=sig)
     rec = _ensure(key)
     rec.arg_sig = sig
-    if _JAX_CACHE_HIT in seen and _JAX_CACHE_MISS not in seen:
+    if verdict == "hit":
         rec.load_s += sp.dur
         rec.cache_hits += 1
         rec.source = "cache"
@@ -500,7 +653,18 @@ def _collect(reset=False):
       argument signature / key material that caused each;
     - ``totals``: summed counters (the subprocess warm-start tests pin
       ``fresh_compiles == 0`` on these);
-    - ``cache``: the persistent-cache configuration in effect.
+    - ``cache``: the persistent-cache configuration in effect;
+    - ``jax``: every program JAX built in this process, whoever asked for
+      it, from the one ``jax.monitoring`` listener pair: ``programs``
+      (one row a jitted function's name: traces and their seconds,
+      inclusive of the traces nested in them, seconds lowering, programs
+      compiled or loaded and their seconds, hits and misses of JAX's
+      persistent cache; by total seconds), ``events`` (the log of the
+      last 4096 phases, oldest first: ``ts`` and ``dur`` in microseconds
+      on the clock of ``telemetry.trace.spans()``, ``phase``, ``name``,
+      ``tid`` and, of a ``backend`` phase, ``cache``: ``hit``, ``miss``
+      or ``none``) and ``totals`` (``trace_s`` is the union of the log's
+      trace intervals a thread).
 
     ``reset=True`` reads and clears inside ONE lock acquisition — a
     compile landing between the read and the clear counts in exactly
@@ -513,10 +677,9 @@ def _collect(reset=False):
         retraces = {n: {"count": e["count"],
                         "events": list(e["events"])}
                     for n, e in _retraces.items()}
+        jax_programs = _jax_report()
         if reset:
-            _records.clear()
-            _entry_points.clear()
-            _retraces.clear()
+            _clear()
     if reset:
         _refresh_prof_counters()
     totals = {
@@ -534,6 +697,7 @@ def _collect(reset=False):
                            key=lambda p: (-p["compile_s"], p["name"])),
         "retraces": retraces,
         "totals": totals,
+        "jax": jax_programs,
         "cache": {
             "enabled": cache_enabled(),
             "dir": str(config.get("MXTPU_COMPILE_CACHE_DIR") or "") or
@@ -541,8 +705,6 @@ def _collect(reset=False):
         },
     }
 
-
-from ..telemetry import registry as _treg  # noqa: E402
 
 compile_report = _treg.collector_view("compile", _collect)
 
@@ -552,7 +714,14 @@ def reset():
     or test cases). Live programs keep running; their records recreate
     on the next acquisition."""
     with _lock:
-        _records.clear()
-        _entry_points.clear()
-        _retraces.clear()
+        _clear()
     _refresh_prof_counters()
+
+
+def _clear():
+    """The caller holds ``_lock``."""
+    _records.clear()
+    _entry_points.clear()
+    _retraces.clear()
+    _jax_programs.clear()
+    _jax_events.clear()

@@ -382,11 +382,16 @@ class TrainStep:
     def _call(self, x, y, on):
         first = self._step_jit is None
         if first:
+            # set-up's parts by name, beside Module's setup/bind and
+            # setup/init_optimizer (aggregates prof::setup::*)
             with _trace.span("compile", "step", on=on):
                 if self._pvals is None:
-                    self._materialize(x)
-                    self._init_state()
-                self._build_step()
+                    with _trace.span("materialize", "setup", on=on):
+                        self._materialize(x)
+                    with _trace.span("init_state", "setup", on=on):
+                        self._init_state()
+                with _trace.span("build_step", "setup", on=on):
+                    self._build_step()
         xa = x._data if isinstance(x, NDArray) else jnp.asarray(x)
         ya = y._data if isinstance(y, NDArray) else jnp.asarray(y)
         if self.mesh is not None:
@@ -405,16 +410,20 @@ class TrainStep:
         if first:
             # the first call traces and compiles, or loads from JAX's
             # persistent cache: a plain jax.jit, so the compile registry
-            # is told of the acquisition, it does not make it
+            # is told of the acquisition, it does not make it; what is
+            # inside it, JAX's trace, lowering and compile or load, is
+            # compile_report()["jax"]'s row mx_train_step
             from ..compile import registry as _creg
             shapes = _trace.shapes_of(args)     # the call donates args
-            with _trace.span("compile", "step", on=on), \
-                    _creg.jit_acquire("mx_train_step", "train_step", args):
-                out = self._step_jit(*args)
-            # the one reference scope_table() is built from when somebody
-            # asks: the call's own trace, found again (no second trace)
-            self._program = _trace.note_program(
-                "jit_mx_train_step", self._step_jit.trace(*shapes))
+            with _trace.span("compile", "step", on=on):
+                with _creg.jit_acquire("mx_train_step", "train_step", args):
+                    out = self._step_jit(*args)
+                # the one reference scope_table() is built from when
+                # somebody asks: the call's own trace, found again (no
+                # second trace; JAX reports the lookup as a trace of
+                # microseconds)
+                self._program = _trace.note_program(
+                    "jit_mx_train_step", self._step_jit.trace(*shapes))
         else:
             # on the host an enqueue; once the runtime's limit of
             # executions in flight is reached it blocks for a device step

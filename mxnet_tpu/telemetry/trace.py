@@ -33,10 +33,16 @@ a ``jax.profiler`` trace is running in this process. ``fit()`` and
   (wait) where the loop blocks on the device; ``TrainStep`` opens
   ``step/step`` with ``step/compile`` / ``step/h2d_stage`` /
   ``step/dispatch`` inside on every call,
-- set-up: ``setup/bind``, ``setup/init_optimizer``;
+- set-up: ``setup/bind``, ``setup/init_optimizer`` (``Module``);
+  ``setup/materialize``, ``setup/init_state``, ``setup/build_step``
+  (inside ``TrainStep``'s first ``step/compile``);
   ``pass/apply:<pass>`` and ``pass/gate:<pass>`` -> ``compile/lower``,
   ``compile/compile``; ``compile/acquire:<program>`` ->
-  ``compile/load|compile|serialize``,
+  ``compile/load|compile|serialize``; ``compile/jax:<phase>:<function>``
+  for every trace, lowering and compile or cache load JAX makes
+  (``compile/registry.py``'s listener: JAX says so when the interval is
+  over, hence :func:`record_span` and no annotation; under
+  ``MXTPU_TRACE_DIR`` alone, :func:`exporting`),
 - serving: ``serving:request`` (submit -> complete, measured across
   threads, hence :func:`record_span`), ``serving:batch``
   (DynamicBatcher micro-batch; its args carry the member request trace
@@ -78,7 +84,7 @@ import weakref
 
 from . import registry
 
-__all__ = ["enabled", "trace_dir", "new_trace_id", "new_span_id",
+__all__ = ["enabled", "exporting", "trace_dir", "new_trace_id", "new_span_id",
            "span", "current", "record_span", "spans", "export_trace",
            "trace_files", "read_trace", "reset", "hlo_scopes",
            "scope_table", "note_program", "shapes_of", "XLA_NAMED"]
@@ -132,13 +138,21 @@ def _profiler_running():
         _PROFILE_STATE.profile_session is not None
 
 
+def exporting():
+    """``MXTPU_TRACE_DIR`` is set: the ring will be written out as a
+    Chrome trace. The guard of a producer whose records are for that
+    file alone (``compile/registry.py``'s ``jax:*`` spans, which have no
+    annotation on a profiler's clock and no reader in the ring)."""
+    # the registered variable is a plain string: read where it lives,
+    # a typed config.get costs as much as the span it guards
+    return bool(os.environ.get("MXTPU_TRACE_DIR"))
+
+
 def enabled():
     """Tracing is on: ``MXTPU_TRACE_DIR`` is set, or a ``jax.profiler``
     trace is running in this process. The producers' guard: one
     attribute read and one env read, no path construction."""
-    # the registered variable is a plain string: read where it lives,
-    # a typed config.get costs as much as the span it guards
-    return _profiler_running() or bool(os.environ.get("MXTPU_TRACE_DIR"))
+    return _profiler_running() or exporting()
 
 
 def _pid_tag():
